@@ -22,7 +22,7 @@ import numpy as np
 import optax
 from jax.sharding import PartitionSpec as P
 
-import horovod_tpu as hvd  # installs the jax<0.5 compat shims
+import horovod_tpu as hvd
 
 shard_map = jax.shard_map
 

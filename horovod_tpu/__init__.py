@@ -18,44 +18,6 @@ Typical use (the reference MNIST pattern, ``examples/pytorch/pytorch_mnist.py``)
 
 from .version import __version__  # noqa: F401
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.5 ships shard_map under jax.experimental only (with the
-    # replication check spelled check_rep, not check_vma); the op
-    # layers target the stable jax.shard_map spelling.
-    from jax.experimental.shard_map import shard_map as _xp_shard_map
-
-    def _shard_map(f, *args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _xp_shard_map(f, *args, **kwargs)
-
-    _jax.shard_map = _shard_map
-
-if not hasattr(_jax.lax, "axis_size"):
-    # jax < 0.6 spelling: the static named-axis size lives on
-    # jax.core.axis_frame.
-    _jax.lax.axis_size = lambda name: _jax.core.axis_frame(name)
-
-if not hasattr(_jax, "typeof"):
-    # jax < 0.5 has no jax.typeof; the abstract value carries the same
-    # shape/dtype info (and no .vma attribute — callers that probe
-    # varying-mesh-axes via getattr(..., "vma", None) see None, which is
-    # correct: the vma system doesn't exist under check_rep semantics).
-    _jax.typeof = lambda x: _jax.core.get_aval(x)
-
-if not hasattr(_jax.lax, "pcast"):
-    # jax < 0.5 has no lax.pcast / varying-mesh-axes marking.  Under the
-    # shimmed shard_map (check_rep=False) a loop carry needs no vma
-    # annotation to match device-varying step outputs, so the marking is
-    # an identity.
-    def _pcast(x, axes, to="varying"):
-        del axes, to
-        return x
-
-    _jax.lax.pcast = _pcast
-
 from . import runtime as _runtime
 from .exceptions import (  # noqa: F401
     CheckpointCorruptionError,

@@ -52,6 +52,31 @@ def _stale() -> bool:
     return False
 
 
+def ensure_built() -> None:
+    """Build the native core from ``cpp/src`` with make when it is
+    missing or older than a source file.  Raises with make's output
+    when the build fails — for callers (``chip_smoke.py``) to which a
+    quiet pure-Python path would hide a broken toolchain or source."""
+    # Serialize concurrent builds (multiple worker processes on one
+    # host share cpp/build): flock + re-check.
+    import fcntl
+
+    lock_path = os.path.join(_CPP_DIR, ".build.lock")
+    with open(lock_path, "w") as lock_fh:
+        fcntl.flock(lock_fh, fcntl.LOCK_EX)
+        if not _stale():
+            return
+        proc = subprocess.run(
+            ["make", "-C", _CPP_DIR], capture_output=True, text=True,
+            timeout=300,
+        )
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"native core build failed (make rc={proc.returncode}):\n"
+            f"{(proc.stdout + proc.stderr)[-2000:]}"
+        )
+
+
 def load(build: bool = True) -> Optional[ctypes.CDLL]:
     """Load (building if needed) the native core; None if unavailable."""
     global _lib, _build_failed
@@ -60,20 +85,7 @@ def load(build: bool = True) -> Optional[ctypes.CDLL]:
             return _lib
         if _stale() and build and not _build_failed:
             try:
-                # Serialize concurrent builds (multiple worker processes
-                # on one host share cpp/build): flock + re-check.
-                import fcntl
-
-                lock_path = os.path.join(_CPP_DIR, ".build.lock")
-                with open(lock_path, "w") as lock_fh:
-                    fcntl.flock(lock_fh, fcntl.LOCK_EX)
-                    if _stale():
-                        subprocess.run(
-                            ["make", "-C", _CPP_DIR],
-                            check=True,
-                            capture_output=True,
-                            timeout=300,
-                        )
+                ensure_built()
             except Exception:
                 _build_failed = True
                 # A failed REbuild must not abandon a loadable library

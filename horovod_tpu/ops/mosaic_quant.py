@@ -54,7 +54,6 @@ from jax.experimental import pallas as pl
 from .. import metrics
 from .pallas_kernels import _sds
 from .pallas_quant import (
-    _TPU_VMEM_CAP,
     _dequant_rows_kernel,
     _perm,
     _position,
@@ -76,9 +75,8 @@ except Exception:  # pragma: no cover - environment-dependent
 _GPU_PLATFORMS = ("gpu", "cuda", "rocm")
 
 # Per-rank packed-payload cap for the single-shot GPU ring (HBM staging
-# is roomier than VMEM but the all-hops-resident layout still bounds
-# it); shared figure with the TPU path so tuner entries compare.
-_GPU_STAGING_CAP = _TPU_VMEM_CAP
+# is roomy, but the all-hops-resident layout still bounds it).
+_GPU_STAGING_CAP = 8 * 1024 * 1024
 
 
 def _on_gpu() -> bool:

@@ -1066,6 +1066,19 @@ class TrainStep:
         if fn is None:
             fn = self._build_step(specs)
             self._step_cache[key] = fn
+            # Put the carried state where the step's outputs will live
+            # before the first call: host-placed inputs at step 0 and
+            # the mesh-placed outputs fed back at step 1 would
+            # otherwise be two argument signatures, and the whole step
+            # would compile twice.
+            params, model_state, opt_state = jax.device_put(
+                (params, model_state, opt_state),
+                jax.tree.map(
+                    lambda spec: NamedSharding(self.mesh, spec),
+                    (self._param_spec, self._param_spec, specs),
+                    is_leaf=lambda x: isinstance(x, P),
+                ),
+            )
 
         rt = get_runtime()
         tl = rt.timeline

@@ -18,20 +18,21 @@ records into the metrics registry:
   wall compile time (satellite 3's re-lowering cost signal rides the
   same clock through the svc cache's ``on_compile`` callback).
 
-Graceful degradation is the hard requirement: any backend that lacks
-``cost_analysis``/``memory_analysis``, or any program AOT refuses to
-lower, permanently falls back to calling the raw fn for that argument
-signature — one attempt, no retry storm, never an exception out of the
-wrapper that plain ``jit`` would not also raise.  The contract has two
-halves: the cache key folds in each leaf's *sharding* alongside shape
-and dtype (so same-shape inputs arriving with a new sharding after an
-elastic resize compile their own variant instead of hitting a stale
-``Compiled``), and any exception the cached ``Compiled`` raises at
-call time — layout/committedness mismatches the key cannot see —
-permanently demotes that signature to the raw fn, whose own call then
-either succeeds (jit would have resharded/recompiled) or raises the
-genuine error.  ``HVD_TPU_PROF=off`` never constructs a wrapper at
-all.
+What the wrapper may hide, and what it may not.  A callable with no
+``lower`` (not a jit function) has nothing to introspect and is called
+raw.  A failed AOT compile is the failure plain ``jit`` would have hit
+on the same call, so it propagates as itself — compiling again through
+the raw fn would only double the wait.  The cache key folds in each
+leaf's *sharding* alongside shape and dtype (same-shape inputs arriving
+with a new sharding after an elastic resize compile their own variant
+instead of hitting a stale ``Compiled``); what the key cannot see — a
+layout or committedness drift — the cached ``Compiled`` rejects with a
+``TypeError``/``ValueError`` *before* it executes or donates anything,
+and only that demotes the signature to the raw fn (``prof.fallbacks``),
+which reshards or recompiles as jit would.  An error from the execution
+itself propagates: by then donated arguments are gone, and a retry
+would report deleted buffers instead of the real fault.
+``HVD_TPU_PROF=off`` never constructs a wrapper at all.
 """
 
 from __future__ import annotations
@@ -44,8 +45,9 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 from .. import metrics
 from .config import enabled
 
-# Per-signature compile map sentinel: AOT was tried for this argument
-# signature and failed; call the raw fn forever after.
+# Per-signature compile map sentinel: the cached Compiled rejected this
+# argument signature (or the fn cannot be lowered at all); call the raw
+# fn forever after.
 _FALLBACK = object()
 
 # Registry of every program the plane has introspected:
@@ -168,13 +170,13 @@ class ProfiledExecutor:
             with trace.span(f"exec.{self.workload}", "exec",
                             program=self.key):
                 return compiled(*args)
-        except Exception:
-            # A call-time aval/layout/committedness mismatch the
-            # signature cannot see (e.g. same-shape inputs whose
-            # placement changed after an elastic resize): plain jit
-            # would transparently recompile, the cached Compiled raises
-            # instead.  Demote the signature to the raw fn forever; a
-            # genuine execution error re-raises from the raw call.
+        except (TypeError, ValueError):
+            # The Compiled's own pre-execution argument check: an
+            # aval/layout/committedness mismatch the signature cannot
+            # see (e.g. same-shape inputs whose placement changed after
+            # an elastic resize).  Nothing has run or been donated yet;
+            # plain jit would transparently recompile, so demote the
+            # signature to the raw fn forever.
             self._mark_fallback(sig)
         return self._fn(*args)
 
@@ -197,13 +199,12 @@ class ProfiledExecutor:
 
     # -------------------------------------------------------- compile
     def _compile(self, sig: Any, args: Tuple[Any, ...]) -> Any:
-        try:
-            t0 = time.monotonic()
-            compiled = self._fn.lower(*args).compile()
-            dt = time.monotonic() - t0
-        except Exception:
+        if not hasattr(self._fn, "lower"):
             self._mark_fallback(sig)
             return _FALLBACK
+        t0 = time.monotonic()
+        compiled = self._fn.lower(*args).compile()
+        dt = time.monotonic() - t0
         with self._lock:
             self._compiled[sig] = compiled
         self._record(compiled, dt)

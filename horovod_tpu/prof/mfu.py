@@ -9,18 +9,16 @@ finalized step span:
   ran (``prof/introspect.py`` stamps each executor call with its
   program key);
 * each program's cost-analysis FLOPs divided by the step wall-clock,
-  against the device peak from :mod:`prof.peak` (the shared
-  bench-table/measured-matmul model), becomes
-  ``prof.mfu{workload=...}``;
+  against the device peak from :mod:`prof.peak` (the shared datasheet
+  table), becomes ``prof.mfu{workload=...}``;
 * total step FLOPs split across tenants proportionally to each
   tenant's device-busy seconds (the host-gap attribution's
   ``tenant_busy_s``) becomes ``prof.mfu{tenant=...}`` — device-time
   accounting through the same trace tenant slot the arbiter's
   fairness story uses.
 
-Backends whose ``cost_analysis`` is unavailable simply never register
-FLOPs, so every gauge here silently stays absent — same graceful
-degradation as the introspection layer.
+Backends whose ``cost_analysis`` is unavailable never register FLOPs,
+and a CPU backend has no peak, so there every gauge here stays absent.
 """
 
 from __future__ import annotations
@@ -60,15 +58,10 @@ def on_step(span: Any, stats: Dict[str, Any]) -> None:
         total_flops += rec["flops"]
     if total_flops <= 0:
         return
-    # Step path: only the cached peak is acceptable here — resolving it
-    # can mean an 8-iteration benchmark matmul on unknown device kinds,
-    # which runs on a background thread instead (MFU stays absent for
-    # the first steps until the denominator lands).
-    cached = peak.cached_peak()
-    if cached is None:
-        peak.ensure_default_peak_async()
+    resolved = peak.default_peak_tflops()
+    if resolved is None:
         return
-    peak_tflops, _source = cached
+    peak_tflops, _source = resolved
     if peak_tflops <= 0:
         return
     denom = wall * peak_tflops * 1e12
@@ -91,15 +84,14 @@ def on_step(span: Any, stats: Dict[str, Any]) -> None:
 
 def publish(workload: str, achieved_tflops: float,
             peak_tflops: Optional[float] = None) -> Optional[float]:
-    """Direct MFU publication for bench-style offline measurements
-    (``tools/resnet_cpu_bench.py`` records its sweep winner through
-    this so the ResNet CPU-sim MFU shows up on ``/prof`` like any
-    online workload)."""
+    """Direct MFU publication for an offline measurement, so it shows
+    up on ``/prof`` like any online workload.  None when no peak is
+    given and the device has none."""
     if peak_tflops is None:
-        try:
-            peak_tflops, _ = peak.default_peak_tflops()
-        except Exception:
+        resolved = peak.default_peak_tflops()
+        if resolved is None:
             return None
+        peak_tflops = resolved[0]
     if peak_tflops <= 0:
         return None
     v = min(achieved_tflops / peak_tflops, 1.0)

@@ -1,25 +1,20 @@
 """Device peak-FLOPs model shared by the benches and the online MFU
 gauge.
 
-One peak table, three consumers: ``bench.py`` (full-workload MFU
-records), ``tools/resnet_cpu_bench.py`` (stem/batch sweep), and
-``prof/mfu.py`` (the per-step online gauge).  Before PR 17 the first
-two each carried their own copy; the table lives here now and both
-import it, so a new device generation is added exactly once.
+One peak table, three consumers: ``bench.py`` and ``chip_smoke.py``
+(full-workload records) and ``prof/mfu.py`` (the per-step online
+gauge), so a new device generation is added exactly once.
 
-Datasheet peaks are keyed by ``device_kind`` substring; unknown kinds
-(CPU smoke runs, unreleased generations) fall back to the achieved
-TFLOP/s of a compiled square bf16 matmul — a utilization-of-achievable
-denominator rather than of-datasheet, but non-null and comparable
-across rounds on the same host.
+Datasheet peaks are keyed by ``device_kind`` substring.  An accelerator
+whose kind is not in the table is an error, not a default; on a CPU
+backend there is no peak and MFU stays absent — it is never estimated.
 """
 
 from __future__ import annotations
 
-import atexit
-import threading
-import time
 from typing import Optional, Tuple
+
+from ..exceptions import HorovodTpuError
 
 # Peak dense bf16 TFLOP/s per chip by device_kind substring (public
 # cloud.google.com/tpu/docs system-architecture figures).
@@ -53,9 +48,8 @@ PEAK_BF16_TFLOPS_GPU = [
 # (fwd + bwd) ~3x forward.
 RESNET50_TRAIN_GFLOPS_PER_IMAGE = 4.1 * 3
 
-_lock = threading.Lock()
-_MEASURED_PEAK: Optional[float] = None
 _DEFAULT_PEAK: Optional[Tuple[float, str]] = None
+_resolved = False
 _override: Optional[float] = None
 
 
@@ -77,134 +71,57 @@ def chip_peak_tflops(device) -> Optional[float]:
     return None
 
 
-def measured_peak_tflops() -> float:
-    """Peak fallback for device kinds missing from the public table:
-    the achieved TFLOP/s of a compiled square bf16 matmul — the closest
-    measurable stand-in for the matrix-unit roofline.  Measured once
-    per process and cached."""
-    global _MEASURED_PEAK
-    with _lock:
-        if _MEASURED_PEAK is not None:
-            return _MEASURED_PEAK
-    import jax
-    import jax.numpy as jnp
-
-    n, iters = 1024, 8
-    a = jnp.full((n, n), 0.5, jnp.bfloat16)
-    f = jax.jit(lambda x: jnp.tanh(x @ x))  # tanh keeps values bounded
-    float(jnp.sum(f(a).astype(jnp.float32)))  # compile + warm
-    out = a
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        out = f(out)
-    float(jnp.sum(out.astype(jnp.float32)))
-    dt = time.perf_counter() - t0
-    measured = max(2.0 * n ** 3 * iters / dt / 1e12, 1e-6)
-    with _lock:
-        if _MEASURED_PEAK is None:
-            _MEASURED_PEAK = measured
-        return _MEASURED_PEAK
-
-
-def peak_tflops(device) -> Tuple[float, str]:
-    """(peak TFLOP/s, source): datasheet when the chip is known,
-    measured-matmul fallback otherwise — MFU is always computable."""
+def peak_tflops(device) -> Optional[Tuple[float, str]]:
+    """(peak TFLOP/s, source) for a jax device: ``"table"`` when its
+    kind is in the datasheet table, ``"override"`` when a test pinned
+    one.  None on a CPU device (no peak, no MFU).  Any other device
+    missing from the table raises — add its datasheet figure."""
     if _override is not None:
         return _override, "override"
     peak = chip_peak_tflops(device)
     if peak is not None:
         return peak, "table"
-    return measured_peak_tflops(), "measured"
+    if device.platform == "cpu":
+        return None
+    raise HorovodTpuError(
+        f"no bf16 peak for device_kind {device.device_kind!r} "
+        f"(platform {device.platform!r}): add its datasheet figure to "
+        "horovod_tpu/prof/peak.py"
+    )
 
 
-def default_peak_tflops() -> Tuple[float, str]:
-    """(peak, source) for this process's first jax device, computed at
-    most once — the denominator ``prof/mfu.py`` prices every step
-    against."""
-    global _DEFAULT_PEAK
+def default_peak_tflops() -> Optional[Tuple[float, str]]:
+    """:func:`peak_tflops` of this process's first jax device, resolved
+    once — the denominator ``prof/mfu.py`` prices every step against.
+    (The lookup is idempotent, so two threads racing here only resolve
+    the same value twice.)"""
+    global _DEFAULT_PEAK, _resolved
     if _override is not None:
         return _override, "override"
-    with _lock:
-        if _DEFAULT_PEAK is not None:
-            return _DEFAULT_PEAK
-    import jax
+    if not _resolved:
+        import jax
 
-    result = peak_tflops(jax.devices()[0])
-    with _lock:
-        if _DEFAULT_PEAK is None:
-            _DEFAULT_PEAK = result
-        return _DEFAULT_PEAK
+        _DEFAULT_PEAK = peak_tflops(jax.devices()[0])
+        _resolved = True
+    return _DEFAULT_PEAK
 
 
 def cached_peak() -> Optional[Tuple[float, str]]:
-    """The already-computed default peak, or None — what a telemetry
-    scrape (and the per-step MFU hook) reads, so neither ever triggers
-    the measurement matmul itself."""
+    """The already-resolved default peak, or None — what a telemetry
+    scrape reads, so it never opens a jax backend itself."""
     if _override is not None:
         return _override, "override"
-    with _lock:
-        return _DEFAULT_PEAK
-
-
-_measure_thread: Optional[threading.Thread] = None
-
-
-def ensure_default_peak_async() -> None:
-    """Kick the default-peak resolution on a background thread when it
-    is not cached yet.  For a device kind missing from the datasheet
-    table this runs the 8-iteration measured-matmul benchmark —
-    seconds of work that must never run inside step-finalize
-    (``mfu.on_step`` skips MFU until the cache fills).  Single-flight;
-    returns immediately."""
-    global _measure_thread
-    if cached_peak() is not None:
-        return
-    with _lock:
-        if _measure_thread is not None and _measure_thread.is_alive():
-            return
-        thread = threading.Thread(
-            target=_measure_quietly, name="hvd-tpu-prof-peak",
-            daemon=True,
-        )
-        _measure_thread = thread
-    thread.start()
-
-
-def _measure_quietly() -> None:
-    try:
-        default_peak_tflops()
-    except Exception:
-        pass  # no denominator -> MFU simply stays absent
-
-
-def drain_async(timeout_s: float = 30.0) -> None:
-    """Join an in-flight background measurement.  Registered atexit: a
-    daemon thread still inside XLA while the interpreter tears down
-    aborts the whole process, so exit waits for the measurement (or
-    the timeout) first."""
-    with _lock:
-        thread = _measure_thread
-    if thread is not None:
-        thread.join(timeout_s)
-
-
-atexit.register(drain_async)
+    return _DEFAULT_PEAK
 
 
 def set_peak_override(value: Optional[float]) -> None:
     """Pin the peak (tests assert exact MFU values through this); None
-    restores table/measured resolution."""
+    restores table resolution."""
     global _override
     _override = None if value is None else float(value)
 
 
 def reset() -> None:
-    """Forget cached measurements and any override (test isolation).
-    Joins an in-flight background measurement first so a late writer
-    cannot repopulate the cache after the reset."""
-    global _MEASURED_PEAK, _DEFAULT_PEAK, _override
-    drain_async()
-    with _lock:
-        _MEASURED_PEAK = None
-        _DEFAULT_PEAK = None
-    _override = None
+    """Forget the resolved peak and any override (test isolation)."""
+    global _DEFAULT_PEAK, _resolved, _override
+    _DEFAULT_PEAK, _resolved, _override = None, False, None

@@ -42,7 +42,11 @@ from ..utils.logging import get_logger
 from ..utils.retry import RetryPolicy
 from . import controller_py, exec_utils
 from . import hosts as hosts_mod
-from .launch import free_port, make_worker_env
+from .launch import (
+    free_port,
+    make_worker_env,
+    require_one_tpu_process_per_host,
+)
 
 RESTART_CODE = 73
 # A worker resharded AWAY by an in-process remesh exits with this code
@@ -84,31 +88,24 @@ DEFAULT_REMESH_TIMEOUT_S = 60.0
 
 
 def _with_compilation_cache(extra_env):
-    """Default a job-scoped persistent XLA compilation cache into the
-    worker env (recompilation dominates respawn-per-round restart cost
-    on TPU; measured in tests/integration/test_elastic.py::
+    """Default the persistent XLA compilation cache into the worker env
+    (recompilation dominates respawn-per-round restart cost on TPU;
+    measured in tests/integration/test_elastic.py::
     test_elastic_restart_cost_bounded).
 
     Precedence: HVD_TPU_NO_COMPILATION_CACHE=1 disables; an explicit
-    extra_env dir wins; a driver-environment dir is COPIED into the
-    worker env (remote ssh workers never inherit the driver
-    environment); otherwise a fresh temp dir is created and returned
-    for end-of-job cleanup.  Returns (env, created_dir_or_None).
+    extra_env dir wins; otherwise the directory ``utils/compile_cache``
+    has in effect — the driver's ``JAX_COMPILATION_CACHE_DIR`` when set
+    (copied, because remote ssh workers never inherit the driver
+    environment), else the fixed ``<checkout>/.jax_cache``.
     """
-    env = dict(extra_env or {})
-    if (os.environ.get("HVD_TPU_NO_COMPILATION_CACHE", "") == "1"
-            or "JAX_COMPILATION_CACHE_DIR" in env):
-        return env, None
-    if "JAX_COMPILATION_CACHE_DIR" in os.environ:
-        env["JAX_COMPILATION_CACHE_DIR"] = (
-            os.environ["JAX_COMPILATION_CACHE_DIR"]
-        )
-        return env, None
-    import tempfile
+    from ..utils import compile_cache
 
-    created = tempfile.mkdtemp(prefix="hvd_tpu_xla_cache_")
-    env["JAX_COMPILATION_CACHE_DIR"] = created
-    return env, created
+    env = dict(extra_env or {})
+    if os.environ.get("HVD_TPU_NO_COMPILATION_CACHE", "") != "1":
+        env.setdefault("JAX_COMPILATION_CACHE_DIR",
+                       compile_cache.directory())
+    return env
 
 
 class ElasticDriver:
@@ -256,7 +253,9 @@ class ElasticDriver:
             raise RuntimeError(
                 f"only {total} slot(s) available, need min_np={self.min_np}"
             )
-        return hosts_mod.get_host_assignments(hosts, np_, max_np=np_)
+        assignments = hosts_mod.get_host_assignments(hosts, np_, max_np=np_)
+        require_one_tpu_process_per_host(assignments)
+        return assignments
 
     # -- main loop -------------------------------------------------------
     def run_rounds(
@@ -290,12 +289,12 @@ class ElasticDriver:
         the winning round's per-rank results.
         """
         # Respawn-per-round makes recompilation the dominant restart
-        # cost on TPU; a job-scoped persistent XLA compilation cache
-        # turns round-2+ compiles into cache reads (measured in
+        # cost on TPU; the persistent XLA compilation cache turns
+        # round-2+ compiles into cache reads (measured in
         # tests/integration/test_elastic.py::test_elastic_restart_cost
-        # _bounded).  Opt out with HVD_TPU_NO_COMPILATION_CACHE=1 or by
-        # setting JAX_COMPILATION_CACHE_DIR yourself.
-        extra_env, created_cache_dir = _with_compilation_cache(extra_env)
+        # _bounded).  Opt out with HVD_TPU_NO_COMPILATION_CACHE=1; place
+        # it with JAX_COMPILATION_CACHE_DIR.
+        extra_env = _with_compilation_cache(extra_env)
         secret = pysecrets.token_hex(16)
         server = controller_py.make_server(secret, self.min_np)
         control = controller_py.make_client(
@@ -456,12 +455,6 @@ class ElasticDriver:
             control.close()
             server.stop()
             self.stop()
-            if created_cache_dir is not None:
-                # job-scoped cache (a fresh dir per job): useless after
-                # the job and easily GBs of XLA programs — remove it
-                import shutil
-
-                shutil.rmtree(created_cache_dir, ignore_errors=True)
 
     def _start_telemetry(self, control):
         """Start the HTTP /metrics + /health endpoint for this job.

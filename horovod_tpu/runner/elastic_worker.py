@@ -360,6 +360,19 @@ class WorkerNotificationManager:
 
     def close(self) -> None:
         self._stop.set()
+        # The poll and heartbeat threads call into the client, and the
+        # native client's close() deletes it: freeing it under a call
+        # still in flight is a use-after-free that takes the whole
+        # process down.  Both threads wake on _stop, so wait for them; a
+        # thread that does not finish (a scripted hang) keeps the client
+        # alive instead.
+        threads = [
+            t for t in (self._thread, self._hb_thread)
+            if t is not None and t is not threading.current_thread()
+        ]
+        for t in threads:
+            t.join(timeout=5.0)
         if self._client is not None:
-            self._client.close()
+            if not any(t.is_alive() for t in threads):
+                self._client.close()
             self._client = None

@@ -21,7 +21,7 @@ import sys
 from typing import Dict, List, Optional
 
 from . import hosts as hosts_mod
-from .launch import start_job_services
+from .launch import require_one_tpu_process_per_host, start_job_services
 from ..utils.logging import get_logger
 
 # env vars forwarded to workers (reference mpi_run.py's -x list is the
@@ -93,6 +93,7 @@ def mpi_run(
         else [hosts_mod.HostInfo("localhost", np_)]
     )
     assignments = hosts_mod.get_host_assignments(host_list, np_)
+    require_one_tpu_process_per_host(assignments, extra_env)
     server, service_env = start_job_services(
         np_, [a.hostname for a in assignments], nic_probe=False
     )

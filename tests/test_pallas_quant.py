@@ -253,6 +253,17 @@ class TestBackendDispatch:
         assert dispatch_mode(((0, 1, 2, 3), (4, 5, 6, 7)), 4) == "interp"
         assert dispatch_mode(None, 1) is None  # degenerate ring
 
+    def test_dispatch_refuses_fused_on_tpu(self, monkeypatch):
+        """On a TPU the ring kernels do not compile: a requested
+        ``fused`` is an error naming the compiler's reason, never a
+        quiet ``phase``."""
+        from horovod_tpu.exceptions import HorovodTpuError
+        from horovod_tpu.ops import pallas_quant
+
+        monkeypatch.setattr(pallas_quant, "_interpret", lambda: False)
+        with pytest.raises(HorovodTpuError, match="aligned to tiling"):
+            pallas_quant.dispatch_mode(None, N)
+
     def test_env_knob_reaches_primitives(self, hvd_module, monkeypatch):
         from horovod_tpu import metrics
 

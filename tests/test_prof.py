@@ -134,38 +134,71 @@ class TestIntrospection:
         assert metrics.get_counter("prof.fallbacks") >= 1
         assert metrics.get_counter("prof.compiles") == 0
 
-    def test_calltime_failure_falls_back(self):
-        # A Compiled whose lowering succeeded but whose *call* blows up
-        # (the AOT-vs-jit gap: layout/sharding drift the signature key
-        # cannot see) must demote to the raw fn, not raise.
-        calls = []
+    class _FakeJit:
+        """A lowerable whose Compiled raises ``exc`` when called."""
 
-        class Boom:
-            def cost_analysis(self):
-                return [{"flops": 1.0}]
+        def __init__(self, exc=None, compile_exc=None):
+            self.exc, self.compile_exc = exc, compile_exc
+            self.calls, self.lowers = [], 0
 
-            def memory_analysis(self):
-                return None
+        def lower(self, *args):
+            self.lowers += 1
+            return self
 
-            def __call__(self, *args):
-                raise RuntimeError("layout mismatch")
+        def compile(self):
+            if self.compile_exc is not None:
+                raise self.compile_exc
+            outer = self
 
-        class FakeJit:
-            def lower(self, *args):
-                return self
+            class Compiled:
+                def cost_analysis(self):
+                    return [{"flops": 1.0}]
 
-            def compile(self):
-                return Boom()
+                def memory_analysis(self):
+                    return None
 
-            def __call__(self, x):
-                calls.append(x)
-                return x + 1
+                def __call__(self, *args):
+                    raise outer.exc
 
-        ex = introspect.wrap(FakeJit(), key="intro_e", kind="step")
+            return Compiled()
+
+        def __call__(self, x):
+            self.calls.append(x)
+            return x + 1
+
+    def test_argument_check_failure_falls_back(self):
+        # The Compiled's pre-execution argument check (layout/sharding
+        # drift the signature key cannot see) raises ValueError or
+        # TypeError before anything runs: demote to the raw fn.
+        fake = self._FakeJit(exc=ValueError("input shardings disagree"))
+        ex = introspect.wrap(fake, key="intro_e", kind="step")
         assert ex(1) == 2 and ex(2) == 3  # results survive the fallback
-        assert calls == [1, 2]  # raw fn served both calls
+        assert fake.calls == [1, 2]  # raw fn served both calls
         assert introspect.get("intro_e")["fallback"] is True
         assert metrics.get_counter("prof.fallbacks") >= 1
+
+    def test_execution_failure_surfaces_as_itself(self):
+        # An error from the execution itself (device OOM, a failed
+        # collective) arrives after donation: retrying through the raw
+        # fn would report deleted buffers instead of the real fault.
+        fake = self._FakeJit(exc=RuntimeError("RESOURCE_EXHAUSTED"))
+        ex = introspect.wrap(fake, key="intro_f", kind="step")
+        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+            ex(1)
+        assert fake.calls == []  # never retried
+        assert introspect.get("intro_f")["fallback"] is False
+        assert metrics.get_counter("prof.fallbacks") == 0
+
+    def test_compile_failure_surfaces_once(self):
+        # A program that cannot compile fails as jit would have — not
+        # swallowed, and not compiled a second time through the raw fn.
+        fake = self._FakeJit(compile_exc=RuntimeError("Mosaic failed"))
+        ex = introspect.wrap(fake, key="intro_g", kind="step")
+        with pytest.raises(RuntimeError, match="Mosaic failed"):
+            ex(1)
+        assert fake.lowers == 1 and fake.calls == []
+        assert metrics.get_counter("prof.fallbacks") == 0
+        assert metrics.get_counter("prof.compiles") == 0
 
     def test_off_returns_fn_unwrapped(self):
         prof.set_enabled_override(False)
@@ -268,29 +301,30 @@ class TestMFU:
         mfu.on_step(root, hostgap.attribute(root))
         assert metrics.get_gauge("prof.mfu", {"workload": "mfu_c"}) == 1.0
 
-    def test_peak_resolves_off_step_path(self, monkeypatch):
-        # No cached peak yet: the step hook must skip MFU and kick the
-        # (potentially benchmark-running) resolution onto a background
-        # thread, then price normally once the denominator lands.
-        monkeypatch.setattr(peak, "measured_peak_tflops", lambda: 1.0)
-        monkeypatch.setattr(
-            peak, "chip_peak_tflops", lambda device: None)
+    def test_no_peak_on_cpu_means_no_mfu(self):
+        # Off the chip there is no peak: MFU is absent, never estimated.
         peak.reset()
-        flops = self._introspected("mfu_async")
-        assert flops and flops > 0
+        assert peak.peak_tflops(jax.devices()[0]) is None
+        self._introspected("mfu_cpu")
         root = _step_span(2.0, [
-            _span("e", "exec", 0.0, 0.5, program="mfu_async")])
-        stats = hostgap.attribute(root)
-        mfu.on_step(root, stats)  # peak unknown: skipped, kicked async
+            _span("e", "exec", 0.0, 0.5, program="mfu_cpu")])
+        mfu.on_step(root, hostgap.attribute(root))
         assert metrics.get_gauge(
-            "prof.mfu", {"workload": "mfu_async"}) is None
-        thread = peak._measure_thread
-        assert thread is not None
-        thread.join(10)
-        assert peak.cached_peak() == (1.0, "measured")
-        mfu.on_step(root, stats)
-        assert metrics.get_gauge("prof.mfu", {"workload": "mfu_async"}) \
-            == pytest.approx(min(flops / (2.0 * 1e12), 1.0))
+            "prof.mfu", {"workload": "mfu_cpu"}) is None
+        assert mfu.publish("mfu_cpu", 0.5) is None
+
+    def test_unknown_tpu_kind_raises(self):
+        from horovod_tpu.exceptions import HorovodTpuError
+
+        class Dev:
+            platform = "tpu"
+            device_kind = "TPU v99 (unreleased)"
+
+        peak.reset()
+        with pytest.raises(HorovodTpuError, match="v99"):
+            peak.peak_tflops(Dev())
+        Dev.device_kind = "TPU v5 lite"
+        assert peak.peak_tflops(Dev()) == (197.0, "table")
 
     def test_untraced_step_publishes_nothing(self):
         root = _step_span(0.5)  # no exec spans -> no FLOPs known

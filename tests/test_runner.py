@@ -199,6 +199,56 @@ def test_check_build_reports(capsys):
     assert "Adasum" in out
 
 
+def test_tpu_backend_configured_without_opening_a_backend(monkeypatch):
+    """--check-build's TPU line: libtpu installed and JAX_PLATFORMS not
+    ruling the TPU out — decided without calling jax.devices()."""
+    import importlib.util
+
+    from horovod_tpu.runner import launch
+
+    has_libtpu = importlib.util.find_spec("libtpu") is not None
+    assert launch.tpu_backend_configured({}) is has_libtpu
+    assert launch.tpu_backend_configured(
+        {"JAX_PLATFORMS": "tpu,cpu"}) is has_libtpu
+    assert launch.tpu_backend_configured({"JAX_PLATFORMS": "cpu"}) is False
+    monkeypatch.setattr(importlib.util, "find_spec", lambda name: None)
+    assert launch.tpu_backend_configured({}) is False
+
+
+def test_one_tpu_process_per_host(monkeypatch):
+    """Several TPU workers on one host are refused with an error that
+    says to use one process per host (on the four-chip host they hung:
+    PERF.md, Bring-up); CPU jobs and one-per-host TPU jobs pass."""
+    from horovod_tpu import runner
+    from horovod_tpu.runner import launch
+
+    four_local = hosts_mod.get_host_assignments(
+        [hosts_mod.HostInfo("localhost", 4)], 4)
+    one_per_host = hosts_mod.get_host_assignments(
+        [hosts_mod.HostInfo("a", 1), hosts_mod.HostInfo("b", 1)], 2)
+
+    monkeypatch.setattr(launch, "tpu_backend_configured", lambda env: True)
+    with pytest.raises(ValueError, match="one process per host"):
+        launch.require_one_tpu_process_per_host(four_local)
+    launch.require_one_tpu_process_per_host(one_per_host)
+    # the CLI refuses before it starts anything, with a usage error
+    assert launch.run_commandline(["-np", "4", "true"]) == 2
+    with pytest.raises(ValueError, match="one process per host"):
+        runner.run(lambda: 0, np=2)
+
+    monkeypatch.setattr(launch, "tpu_backend_configured", lambda env: False)
+    launch.require_one_tpu_process_per_host(four_local)
+
+
+def test_run_refused_under_a_parent_that_holds_the_tpu(monkeypatch):
+    from horovod_tpu import runner
+
+    assert not runner._holds_tpu()  # this process runs on the CPU
+    monkeypatch.setattr(runner, "_holds_tpu", lambda: True)
+    with pytest.raises(RuntimeError, match="already holds"):
+        runner.run(lambda: 0, np=1)
+
+
 def test_config_parser_hash_in_value(tmp_path):
     from horovod_tpu.runner.config_parser import parse_config_file
 
@@ -228,39 +278,61 @@ def test_config_parser_apostrophe_in_value(tmp_path):
     assert parsed["timeline"]["quoted"] == "#literal"
 
 
-def test_elastic_driver_defaults_compilation_cache(monkeypatch, tmp_path):
-    """_with_compilation_cache: job-scoped default, explicit dir wins,
-    driver-env dir is copied for remote workers, opt-out respected."""
+def test_compile_cache_helper(monkeypatch):
+    """utils/compile_cache: with JAX_COMPILATION_CACHE_DIR set, code
+    sets no directory at all; unset, the cache is the fixed
+    <checkout>/.jax_cache — the same on every call (the path is part of
+    the cache key) and never under a temporary directory."""
+    import tempfile
+
+    import jax
+
+    from horovod_tpu.utils import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        jax.config.update("jax_compilation_cache_dir", "sentinel")
+        assert compile_cache.enable() == "/some/dir"
+        assert jax.config.jax_compilation_cache_dir == "sentinel"
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        first, second = compile_cache.enable(), compile_cache.enable()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert first == second == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == first
+        assert not first.startswith(tempfile.gettempdir())
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_elastic_driver_defaults_compilation_cache(monkeypatch):
+    """_with_compilation_cache: the helper's directory by default,
+    explicit dir wins, driver-env dir is copied for remote workers,
+    opt-out respected."""
     from horovod_tpu.runner.elastic_driver import _with_compilation_cache
+    from horovod_tpu.utils import compile_cache
 
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.delenv("HVD_TPU_NO_COMPILATION_CACHE", raising=False)
 
-    env, created = _with_compilation_cache({})
-    assert created is not None and "hvd_tpu_xla_cache_" in created
-    assert env["JAX_COMPILATION_CACHE_DIR"] == created
-    import shutil
+    env = _with_compilation_cache({})
+    assert env["JAX_COMPILATION_CACHE_DIR"] == compile_cache.DEFAULT_DIR
 
-    shutil.rmtree(created, ignore_errors=True)
-
-    # explicit user dir wins, nothing created
-    env, created = _with_compilation_cache(
-        {"JAX_COMPILATION_CACHE_DIR": "/x"}
-    )
-    assert created is None and env["JAX_COMPILATION_CACHE_DIR"] == "/x"
+    # explicit user dir wins
+    env = _with_compilation_cache({"JAX_COMPILATION_CACHE_DIR": "/x"})
+    assert env["JAX_COMPILATION_CACHE_DIR"] == "/x"
 
     # driver-env dir is COPIED into the worker env (remote ssh workers
     # never inherit the driver environment), not merely skipped
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/driver/cache")
-    env, created = _with_compilation_cache({})
-    assert created is None
+    env = _with_compilation_cache({})
     assert env["JAX_COMPILATION_CACHE_DIR"] == "/driver/cache"
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
 
     # opt-out respected
     monkeypatch.setenv("HVD_TPU_NO_COMPILATION_CACHE", "1")
-    env, created = _with_compilation_cache({})
-    assert created is None and "JAX_COMPILATION_CACHE_DIR" not in env
+    assert "JAX_COMPILATION_CACHE_DIR" not in _with_compilation_cache({})
 
 
 def test_elastic_timeout_env_knob(monkeypatch):

@@ -401,35 +401,3 @@ class TestKVSeeding:
         mgr._fetch_schedules()  # must not raise
         mgr._push_schedules(mgr._client)
         assert mgr._client.kv == {}
-
-
-# ----------------------------------------------- bench probe cache key
-
-class TestBenchProbeCacheKey:
-    def test_knob_fingerprint_in_key(self, monkeypatch):
-        import bench
-
-        base = bench._probe_cache_key()
-        monkeypatch.setenv("HVD_TPU_SCHED_WIRE", "int8")
-        k1 = bench._probe_cache_key()
-        assert k1 != base
-        monkeypatch.setenv("HVD_TPU_TOPO", "2x4")
-        k2 = bench._probe_cache_key()
-        assert k2 != k1
-        monkeypatch.setenv("HOROVOD_WIRE_X", "1")
-        assert bench._probe_cache_key() != k2
-        # unrelated env does not churn the cache
-        monkeypatch.setenv("HVD_BENCH_SWEEP", "0")
-        assert bench._probe_cache_key() == bench._probe_cache_key()
-
-    def test_cache_roundtrip(self, tmp_path, monkeypatch):
-        import bench
-
-        monkeypatch.setenv("HVD_BENCH_PROBE_CACHE",
-                           str(tmp_path / "probe.json"))
-        assert not bench._probe_cached_ok()
-        bench._probe_cache_store()
-        assert bench._probe_cached_ok()
-        # a knob change invalidates the cached probe
-        monkeypatch.setenv("HVD_TPU_SCHED_MODE", "reduce_scatter")
-        assert not bench._probe_cached_ok()
