@@ -958,6 +958,9 @@ class TrainStep:
 
         self.init = make_init()
         self._step_cache = {}
+        self._ran = None  # the executor of the last call
+        self._last_entry = None  # perf_counter at the last call's entry
+        self._built = False  # the last call built a variant
         self._step_body = step_body
         self._param_spec = param_spec
         self._batch_spec = batch_spec
@@ -1023,96 +1026,152 @@ class TrainStep:
         )
 
     def __call__(self, params, *args):
-        if self.stateful:
-            model_state, opt_state, batch = args
-        else:
-            opt_state, batch = args
-            model_state = None
-        specs = self._state_specs(opt_state)
+        import time as _time
+
+        from .. import metrics as _metrics, trace as _trace
+
+        # The call returns futures (async dispatch), so its own time is
+        # the dispatch; the step's time is the interval between
+        # entries, which a loop that keeps the device fed makes the
+        # device's (and a loop that blocks every step, step plus its
+        # own time: what that user pays).  A first call has none, and
+        # one that holds the previous call's build says so.
+        entered = _time.perf_counter()
+        interval = (
+            None if self._last_entry is None else entered - self._last_entry
+        )
+        holds_build, self._built = self._built, False
+        self._last_entry = entered
+        # One step call is one tree of host spans (trace/, on the
+        # profiler's clock; docs/tracing.md has the table): ``hvd_step``
+        # around all of it and under it ``hvd_step_resolve``, on a miss
+        # ``hvd_step_build``, and ``hvd_train_step`` (the enqueue);
+        # ``hvd_step_finalize`` follows as the root closes, feeding the
+        # flight recorder's slow-step check and the profiling plane.
+        # Host-side only — the traced computation is untouched.
+        step_span = _trace.step(
+            interval_s=interval, interval_holds_build=holds_build,
+        )
+        span = step_span.__enter__()
+        try:
+            return self._call(params, args, span)
+        finally:
+            step_span.__exit__(None, None, None)
+            _metrics.observe(
+                "train.dispatch_seconds", _time.perf_counter() - entered
+            )
+            if interval is not None and not holds_build:
+                _metrics.observe("train.step_seconds", interval)
+            _metrics.inc_counter("train.steps")
+
+    def _call(self, params, args, span):
+        from .. import prof, trace as _trace
+        from ..prof.introspect import ProfiledExecutor
         from ..xir import interp as _xinterp
 
-        # Whole-step emission mode is a trace-time constant (the update
-        # closure either folds into the exchange or runs after it), so
-        # each resolved mode is its own compiled variant — flipping
-        # HVD_TPU_ONESTEP mid-run retraces instead of silently running
-        # the stale shape.
-        onestep = _xinterp.onestep_mode()
-        threshold = None
-        hier = None
-        quant = None
-        if self._autotune is not None:
-            threshold = self._autotune.threshold_bytes()
-            hier = self._autotune.hierarchical()
-            quant = self._autotune.quantized()
-            if self._autotune.converged and len(self._step_cache) > 1:
+        def profiled(fn):
+            # HVD_TPU_PROF flipped off mid-run calls the raw fn again.
+            return isinstance(fn, ProfiledExecutor) and prof.enabled()
+
+        with _trace.span("step_resolve", "resolve"):
+            if self.stateful:
+                model_state, opt_state, batch = args
+            else:
+                opt_state, batch = args
+                model_state = None
+            specs = self._state_specs(opt_state)
+            # Whole-step emission mode is a trace-time constant (the
+            # update closure either folds into the exchange or runs
+            # after it), so each resolved mode is its own compiled
+            # variant — flipping HVD_TPU_ONESTEP mid-run retraces
+            # instead of silently running the stale shape.
+            onestep = _xinterp.onestep_mode()
+            threshold = None
+            hier = None
+            quant = None
+            if self._autotune is not None:
+                threshold = self._autotune.threshold_bytes()
+                hier = self._autotune.hierarchical()
+                quant = self._autotune.quantized()
+            key = (
+                jax.tree.structure(opt_state),
+                jax.tree.structure(model_state),
+                threshold, hier, quant, onestep,
+            )
+            if (self._autotune is not None and self._autotune.converged
+                    and len(self._step_cache) > 1):
                 # Exploration over: drop the losing compiled variants
                 # (each is a full XLA executable holding device code).
-                frozen_key = (
-                    jax.tree.structure(opt_state),
-                    jax.tree.structure(model_state),
-                    threshold, hier, quant, onestep,
-                )
                 self._step_cache = {
-                    k: v for k, v in self._step_cache.items()
-                    if k == frozen_key
+                    k: v for k, v in self._step_cache.items() if k == key
                 }
-        key = (
-            jax.tree.structure(opt_state),
-            jax.tree.structure(model_state),
-            threshold, hier, quant, onestep,
-        )
-        fn = self._step_cache.get(key)
-        built_here = fn is None
-        if fn is None:
-            fn = self._build_step(specs)
-            self._step_cache[key] = fn
-            # Put the carried state where the step's outputs will live
-            # before the first call: host-placed inputs at step 0 and
-            # the mesh-placed outputs fed back at step 1 would
-            # otherwise be two argument signatures, and the whole step
-            # would compile twice.
-            params, model_state, opt_state = jax.device_put(
-                (params, model_state, opt_state),
-                jax.tree.map(
-                    lambda spec: NamedSharding(self.mesh, spec),
-                    (self._param_spec, self._param_spec, specs),
-                    is_leaf=lambda x: isinstance(x, P),
-                ),
-            )
+            fn = self._step_cache.get(key)
+            built_here = fn is None
+            call_args = (params, model_state, opt_state, batch)
+            # With the profiling plane on the executor compiles ahead
+            # of time, per argument signature (every leaf of the
+            # carried state): finding the Compiled is part of resolving.
+            sig = compiled = None
+            if profiled(fn):
+                sig, compiled = fn.lookup(call_args)
+        if span is not None:
+            # The onestep attr rides the step span so prof/hostgap.py
+            # counts the folded step as exactly one dispatch (the exec
+            # span covers exchange + update; without the attr a
+            # fallback-demoted wrapper would read 0 and the epilogue
+            # could double-count).
+            span.attrs["compiled"] = not built_here
+            span.attrs["onestep"] = 1 if onestep == "on" else 0
 
         rt = get_runtime()
         tl = rt.timeline
         if tl is not None:
             tl.begin("TrainStep", "STEP")
-        import time as _time
-
-        from .. import metrics as _metrics, trace as _trace
-
-        # Step span (trace/): the root every exchange/bucket/rail span
-        # emitted during this dispatch nests under; finalization feeds
-        # the flight recorder's slow-step check and derives the
-        # measured topo.rail_busy_frac gauges.  Host-side only — the
-        # traced computation is untouched.
-        # The onestep attr rides the step span so prof/hostgap.py
-        # counts the folded step as exactly one dispatch (the exec span
-        # covers exchange + update; without the attr a fallback-demoted
-        # wrapper would read 0 and the epilogue could double-count).
-        _step_span = _trace.step(
-            compiled=not built_here,
-            onestep=1 if onestep == "on" else 0,
-        )
-        _step_span.__enter__()
-        _t0 = _time.perf_counter()
         try:
-            # Tracing for a new cache entry happens inside this call, so
+            # Tracing for a new variant happens inside this call, so
             # the candidate threshold (and lowering/wire choices) must
             # be visible to bucket_plan / traced.allreduce /
             # _reduce_pytree now.
             fusion.set_threshold_override(threshold)
             traced.set_hierarchical_override(hier)
             set_quantized_override(quant)
-            with jax.profiler.TraceAnnotation("hvd_train_step"):
-                out = fn(params, model_state, opt_state, batch)
+            if fn is None or (sig is not None and compiled is None):
+                self._built = True
+                with _trace.span("step_build", "build", variant=built_here):
+                    if fn is None:
+                        fn = self._build_step(specs)
+                        self._step_cache[key] = fn
+                        # Put the carried state where the step's
+                        # outputs will live before the first call:
+                        # host-placed inputs at step 0 and the
+                        # mesh-placed outputs fed back at step 1 would
+                        # otherwise be two argument signatures, and
+                        # the whole step would compile twice.
+                        with _trace.span("step_place", "build"):
+                            placed = jax.device_put(
+                                call_args[:3],
+                                jax.tree.map(
+                                    lambda spec: NamedSharding(
+                                        self.mesh, spec),
+                                    (self._param_spec, self._param_spec,
+                                     specs),
+                                    is_leaf=lambda x: isinstance(x, P),
+                                ),
+                            )
+                        call_args = (*placed, batch)
+                        if profiled(fn):
+                            sig, compiled = fn.lookup(call_args)
+                    if compiled is None and sig is not None:
+                        compiled = fn.compile(sig, call_args)
+            if sig is not None:
+                # ``hvd_train_step``: the executor's own exec span.
+                out = fn.run(sig, compiled, call_args, "train_step")
+            else:
+                # HVD_TPU_PROF=off: the plain jit function (its first
+                # call traces and compiles inside this span).
+                with _trace.span("train_step", "exec"):
+                    out = fn(*call_args)
+            self._ran = fn
         except QuantizedWireError:
             if quant and built_here and self._autotune is not None \
                     and not self._autotune.converged:
@@ -1134,19 +1193,12 @@ class TrainStep:
                 from ..sched import hooks as _sched_hooks
 
                 _sched_hooks.reset()  # drop the aborted trace's capture
-                return self(params, *args)
+                return self._call(params, args, span)
             raise
         finally:
             fusion.set_threshold_override(None)
             traced.set_hierarchical_override(None)
             set_quantized_override(None)
-            _step_span.__exit__(None, None, None)
-            # Dispatch latency, not device latency: the step returns
-            # futures (async dispatch); a cache miss shows the compile.
-            _metrics.observe(
-                "train.step_seconds", _time.perf_counter() - _t0
-            )
-            _metrics.inc_counter("train.steps")
             if tl is not None:
                 tl.end("TrainStep", "STEP")
                 if self._mark_cycles:
@@ -1154,6 +1206,16 @@ class TrainStep:
         if self._autotune is not None:
             self._autotune.after_step(out[-1])
         return out
+
+    def compiled(self):
+        """The ``Compiled`` (``jax.stages.Compiled``: ``as_text()``,
+        ``cost_analysis()``, ``memory_analysis()``) of the variant the
+        last call ran, or None before a first call and at
+        ``HVD_TPU_PROF=off``, where nothing is compiled ahead of time."""
+        from ..prof.introspect import ProfiledExecutor
+
+        ran = self._ran
+        return ran.compiled() if isinstance(ran, ProfiledExecutor) else None
 
 
 def distributed_train_step(

@@ -1,18 +1,21 @@
-"""Device-time profiling plane (PR 17).
+"""Profiling plane (PR 17).
 
-The trace package (PR 13) answers "what did the *host* do"; this
-package answers "what did the *device* do" — the blind spot behind
-ROADMAP items 3 (MFU target argued from bench guesses) and 4 (host
-round-trips claimed, never measured).  Four instruments, one knob
+The trace package (PR 13) gives the host's spans; this package prices
+them — what XLA built, how long a step is, what share of the device's
+peak that is — the blind spot behind ROADMAP items 3 (MFU target argued
+from bench guesses) and 4 (host round-trips claimed, never measured).
+What the *device* did inside a step only a device trace says (the
+benchmark's ``device.*`` metrics).  Four instruments, one knob
 (``HVD_TPU_PROF``, default on):
 
 * :mod:`prof.introspect` — every compiled executor (svc cache, train
   step, stale step) AOT-lowered so XLA cost/memory analysis and wall
   compile time land in ``prof.*`` series keyed by program signature;
-* :mod:`prof.hostgap` — per-step device-busy vs wall-clock attribution
-  from the PR 13 span trees plus service dispatch counts
-  (``prof.host_gap_seconds``, ``prof.dispatches_per_step`` — ROADMAP
-  item 4's before/after instrument);
+* :mod:`prof.hostgap` — the step clock (the entry-to-entry interval
+  the step span carries), the host time inside a step call that no
+  executor call covers, and service dispatch counts, from the PR 13
+  span trees (``prof.host_gap_seconds``, ``prof.dispatches_per_step``
+  — ROADMAP item 4's before/after instrument);
 * :mod:`prof.mfu` — cost-analysis FLOPs over measured step time
   against the shared device peak table (``prof.mfu`` per workload and
   per tenant);

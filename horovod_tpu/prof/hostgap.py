@@ -1,25 +1,36 @@
-"""Host-gap profiler: how much of each step was the device idle?
+"""Host-gap profiler: how much of a step call does the host spend
+outside every executor call, and how long is a step?
 
-ROADMAP item 4's claim — "host round-trips per cycle bound small-step
+ROADMAP's claim — "host round-trips per cycle bound small-step
 throughput" — had no instrument.  This module is it.  Every finalized
-step span tree (the PR 13 tracer hands them over from
-``_finalize_root``) is attributed into device-busy vs host-gap time:
+step span tree (the tracer hands them over from ``_finalize_root``) is
+attributed:
 
-* **busy** = the union of intervals covered by device-work spans
-  (``exec`` executor calls, ``dispatch``, ``exchange``/``bucket``
-  emission, and the ``rs_ici``/``ag_ici``/``dcn`` rail phases) —
-  union, not sum, so pipelined/overlapped phases are not double
-  counted;
-* **gap** = step wall-clock minus busy — the host-side scheduling,
-  negotiation, and round-trip time the single-dispatch refactor will
-  squeeze out;
-* **dispatches** = device-work span count in the tree plus the delta
+* **step time** = :func:`trace.tracer.step_seconds`: the entry-to-entry
+  interval the span carries (``TrainStep`` gives it), or the span's own
+  duration where it carries none.  The span's duration alone is the
+  *dispatch* — a step returns futures —, a few ms of a 200 ms step on a
+  chip.  The rolling p50 of the step time (:func:`step_p50`) is the
+  sentinel's observed step time, and ``prof/mfu.py`` divides the step's
+  FLOPs by it (``step_p50_s`` in the stats it is handed);
+* **busy** = the union of intervals covered, inside the call, by
+  executor-call and emission spans (``exec`` executor calls,
+  ``dispatch``, ``exchange``/``bucket`` emission, and the
+  ``rs_ici``/``ag_ici``/``dcn`` rail phases) — union, not sum, so
+  pipelined/overlapped phases are not double counted;
+* **gap** = the call's duration minus busy — host time inside the call
+  that no executor call covers: scheduling, negotiation, bookkeeping
+  and round-trips between dispatches, the time the single-dispatch
+  refactor squeezes out of a multi-dispatch step.  It is host time, not
+  device idle time: whether the *device* idles only a device trace can
+  say (the benchmark's ``device.idle_share``);
+* **dispatches** = call-shaped span count in the tree plus the delta
   of the service loop's ``svc.dispatches`` counter since the previous
-  step — the per-step dispatch count whose target under ROADMAP item
-  4 is 1.
+  step — the per-step dispatch count whose target is 1.
 
 Published per step: ``prof.host_gap_seconds`` (histogram),
-``prof.host_gap_frac`` + ``prof.dispatches_per_step`` (gauges), and a
+``prof.host_gap_frac`` (gauge: the gap as a share of the step time, not
+of the dispatch) + ``prof.dispatches_per_step`` (gauge), and a
 ``prof.dispatches_per_step_hist`` histogram on count buckets.  The
 attribution itself (:func:`attribute`) is a pure function over a span
 tree so the math is testable on synthetic trees.
@@ -31,9 +42,11 @@ import threading
 from typing import Any, Dict, List, Optional, Tuple
 
 from .. import metrics
+from ..trace.tracer import step_seconds
 from .config import check_every, enabled
 
-# Span phases that represent the device (or the wire) doing work.  The
+# Span phases that cover an executor call or the emission of one (the
+# host is then busy *for* the device or the wire).  The
 # rail phases mirror trace.tracer.RAIL_PHASES; "exec"/"dispatch" are
 # the executor-call and service-dispatch phases; "exchange"/"bucket"
 # cover the sched/xir emission path.
@@ -88,11 +101,13 @@ def _count_dispatches(span: Any, *, root: bool = True) -> int:
 
 
 def attribute(span: Any) -> Dict[str, Any]:
-    """Pure device-busy/host-gap attribution of one step span tree.
+    """Pure busy/host-gap attribution of one step span tree.
 
-    Returns ``{wall_s, busy_s, gap_s, dispatches, tenant_busy_s}``
-    where ``tenant_busy_s`` maps tenant name to that tenant's own
-    busy-interval union — the device-seconds split ``prof/mfu.py``
+    Returns ``{step_s, wall_s, busy_s, gap_s, dispatches,
+    tenant_busy_s}``: ``step_s`` is the step's time
+    (``trace.tracer.step_seconds``), ``wall_s`` the call's own duration
+    that busy and gap split, and ``tenant_busy_s`` maps tenant name to
+    that tenant's own busy-interval union — the split ``prof/mfu.py``
     prices per-tenant MFU with."""
     wall = span.dur
     intervals: List[Tuple[float, float]] = []
@@ -114,6 +129,7 @@ def attribute(span: Any) -> Dict[str, Any]:
             per_tenant.setdefault(s.tenant, []).append(iv)
     busy = min(_union_seconds(intervals), wall) if wall > 0 else 0.0
     return {
+        "step_s": step_seconds(span),
         "wall_s": wall,
         "busy_s": busy,
         "gap_s": max(wall - busy, 0.0),
@@ -146,20 +162,24 @@ def on_step(span: Any) -> Optional[Dict[str, Any]]:
     stats = attribute(span)
     stats["dispatches"] += _svc_dispatch_delta()
     metrics.observe("prof.host_gap_seconds", stats["gap_s"])
-    if stats["wall_s"] > 0:
+    if stats["step_s"] > 0:
         metrics.set_gauge(
             "prof.host_gap_frac",
-            min(stats["gap_s"] / stats["wall_s"], 1.0),
+            min(stats["gap_s"] / stats["step_s"], 1.0),
         )
     metrics.set_gauge("prof.dispatches_per_step", float(stats["dispatches"]))
     metrics.observe("prof.dispatches_per_step_hist", stats["dispatches"],
                     buckets=COUNT_BUCKETS)
     with _lock:
         durs = _state["durs"]
-        durs.append(stats["wall_s"])
+        durs.append(stats["step_s"])
         del durs[:-_WINDOW]
         _state["steps"] += 1
         steps = _state["steps"]
+    # MFU is priced on the rolling p50, not on this step alone: one
+    # held-up step (a checkpoint, a profiler stopping) must not read
+    # as the job's utilization on the next scrape or sentinel check.
+    stats["step_p50_s"] = step_p50()
     from . import mfu
 
     mfu.on_step(span, stats)
@@ -175,8 +195,9 @@ def on_step(span: Any) -> Optional[Dict[str, Any]]:
 
 
 def step_p50() -> Optional[float]:
-    """Rolling p50 of recent step wall-clocks — the sentinel's observed
-    step time."""
+    """Rolling p50 of recent step times (entry-to-entry intervals
+    where the step span carries one) — the sentinel's observed step
+    time."""
     with _lock:
         durs = sorted(_state["durs"])
     if not durs:

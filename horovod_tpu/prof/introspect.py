@@ -16,7 +16,14 @@ records into the metrics registry:
   output + temp footprint;
 * ``prof.compile_seconds`` histogram + ``prof.compiles`` counter —
   wall compile time (satellite 3's re-lowering cost signal rides the
-  same clock through the svc cache's ``on_compile`` callback).
+  same clock through the svc cache's ``on_compile`` callback).  The
+  program's record keeps it in its three parts — ``trace_seconds``
+  (``fn.trace``: jax tracing the Python), ``lower_seconds``
+  (``.lower()``: jaxpr to StableHLO) and ``backend_seconds``
+  (``.compile()``: XLA, or the read from the persistent cache, which
+  ``cache_hit`` tells apart) — each under a span of its own
+  (``hvd_compile_trace`` / ``_lower`` / ``_backend``);
+  ``compile_seconds`` stays their sum.
 
 What the wrapper may hide, and what it may not.  A callable with no
 ``lower`` (not a jit function) has nothing to introspect and is called
@@ -52,7 +59,8 @@ _FALLBACK = object()
 
 # Registry of every program the plane has introspected:
 # key -> {kind, workload, flops, bytes_accessed, peak_hbm_bytes,
-#         compile_seconds, compiles, calls, fallback}
+#         compile_seconds, trace_seconds, lower_seconds,
+#         backend_seconds, cache_hit, compiles, calls, fallback}
 _programs: Dict[str, Dict[str, Any]] = {}
 _lock = threading.Lock()
 
@@ -126,7 +134,7 @@ class ProfiledExecutor:
     compile wall-clock are observable."""
 
     __slots__ = ("_fn", "key", "kind", "workload", "_on_compile",
-                 "_compiled", "_lock", "__weakref__")
+                 "_compiled", "_last", "_lock", "__weakref__")
 
     def __init__(self, fn: Callable, key: str, kind: str,
                  workload: Optional[str] = None,
@@ -137,12 +145,15 @@ class ProfiledExecutor:
         self.workload = workload or kind
         self._on_compile = on_compile
         self._compiled: Dict[Any, Any] = {}
+        self._last: Any = None
         self._lock = threading.Lock()
         with _lock:
             _programs.setdefault(key, {
                 "kind": kind, "workload": self.workload,
                 "flops": None, "bytes_accessed": None,
                 "peak_hbm_bytes": None, "compile_seconds": 0.0,
+                "trace_seconds": 0.0, "lower_seconds": 0.0,
+                "backend_seconds": 0.0, "cache_hit": None,
                 "compiles": 0, "calls": 0, "fallback": False,
             })
 
@@ -151,13 +162,28 @@ class ProfiledExecutor:
         if not enabled():
             return self._fn(*args)
         try:
-            sig = _args_signature(args)
-            with self._lock:
-                compiled = self._compiled.get(sig)
+            sig, compiled = self.lookup(args)
         except Exception:  # unflattenable args or an unhashable leaf
             return self._fn(*args)
         if compiled is None:
-            compiled = self._compile(sig, args)
+            compiled = self.compile(sig, args)
+        return self.run(sig, compiled, args)
+
+    # The three stations of a call, public so that a caller with spans
+    # of its own (``TrainStep.__call__``) can name each: resolve the
+    # signature, build on a miss, enqueue.
+    def lookup(self, args: Tuple[Any, ...]) -> Tuple[Any, Any]:
+        """(argument signature, what is cached for it: a ``Compiled``,
+        None before its first compile, or the fallback mark)."""
+        sig = _args_signature(args)
+        with self._lock:
+            return sig, self._compiled.get(sig)
+
+    def run(self, sig: Any, compiled: Any, args: Tuple[Any, ...],
+            span_name: Optional[str] = None) -> Any:
+        """Call ``compiled`` (from :meth:`lookup` or :meth:`compile`)
+        under the executor's ``exec`` span, ``exec.<workload>`` unless
+        the caller names it."""
         with _lock:
             rec = _programs.get(self.key)
             if rec is not None:
@@ -167,9 +193,11 @@ class ProfiledExecutor:
         from .. import trace
 
         try:
-            with trace.span(f"exec.{self.workload}", "exec",
+            with trace.span(span_name or f"exec.{self.workload}", "exec",
                             program=self.key):
-                return compiled(*args)
+                out = compiled(*args)
+            self._last = compiled
+            return out
         except (TypeError, ValueError):
             # The Compiled's own pre-execution argument check: an
             # aval/layout/committedness mismatch the signature cannot
@@ -179,6 +207,11 @@ class ProfiledExecutor:
             # signature to the raw fn forever.
             self._mark_fallback(sig)
         return self._fn(*args)
+
+    def compiled(self) -> Any:
+        """The ``Compiled`` the last call ran (calls that fell back to
+        the raw fn do not count), or None before one."""
+        return self._last
 
     def _mark_fallback(self, sig: Any) -> None:
         with self._lock:
@@ -198,24 +231,40 @@ class ProfiledExecutor:
         return getattr(object.__getattribute__(self, "_fn"), name)
 
     # -------------------------------------------------------- compile
-    def _compile(self, sig: Any, args: Tuple[Any, ...]) -> Any:
-        if not hasattr(self._fn, "lower"):
+    def compile(self, sig: Any, args: Tuple[Any, ...]) -> Any:
+        """Build the ``Compiled`` for ``sig`` in jit's own three stages,
+        each under its span and on the record."""
+        if not hasattr(self._fn, "trace"):
             self._mark_fallback(sig)
             return _FALLBACK
-        t0 = time.monotonic()
-        compiled = self._fn.lower(*args).compile()
-        dt = time.monotonic() - t0
+        from .. import trace
+
+        clock = time.monotonic
+        t0 = clock()
+        with trace.span("compile_trace", "compile", program=self.key):
+            traced = self._fn.trace(*args)
+        t1 = clock()
+        with trace.span("compile_lower", "compile", program=self.key):
+            lowered = traced.lower()
+        t2 = clock()
+        with trace.span("compile_backend", "compile",
+                        program=self.key) as span, _CacheHit() as cache:
+            compiled = lowered.compile()
+            if span is not None:
+                span.attrs["cache_hit"] = cache.hit
+        t3 = clock()
         with self._lock:
             self._compiled[sig] = compiled
-        self._record(compiled, dt)
+        self._record(compiled, (t1 - t0, t2 - t1, t3 - t2), cache.hit)
         if self._on_compile is not None:
             try:
-                self._on_compile(dt)
+                self._on_compile(t3 - t0)
             except Exception:
                 pass
         return compiled
 
-    def _record(self, compiled: Any, dt: float) -> None:
+    def _record(self, compiled: Any, parts: Tuple[float, float, float],
+                cache_hit: bool) -> None:
         try:
             cost = compiled.cost_analysis()
         except Exception:
@@ -231,12 +280,18 @@ class ProfiledExecutor:
         if hbm is not None:
             metrics.set_gauge("prof.peak_hbm_bytes", hbm, labels)
         metrics.inc_counter("prof.compiles")
-        metrics.observe("prof.compile_seconds", dt)
+        metrics.observe("prof.compile_seconds", sum(parts))
         with _lock:
             rec = _programs.get(self.key)
             if rec is not None:
                 rec["compiles"] += 1
-                rec["compile_seconds"] += dt
+                for field, dt in zip(("trace_seconds", "lower_seconds",
+                                      "backend_seconds"), parts):
+                    rec[field] += dt
+                rec["compile_seconds"] = (
+                    rec["trace_seconds"] + rec["lower_seconds"]
+                    + rec["backend_seconds"])
+                rec["cache_hit"] = cache_hit  # of the last compile
                 # keep the largest variant's numbers (re-lowers for a
                 # new shape overwrite only upward)
                 for field, v in (("flops", flops),
@@ -245,6 +300,31 @@ class ProfiledExecutor:
                     if v is not None and (rec[field] is None
                                           or v > rec[field]):
                         rec[field] = v
+
+
+class _CacheHit:
+    """While open: did jax's persistent compilation cache serve a
+    program (its own monitoring event)?"""
+
+    _EVENT = "/jax/compilation_cache/cache_hits"
+
+    def __init__(self):
+        self.hit = False
+
+    def _on_event(self, event: str, **_: Any) -> None:
+        if event == self._EVENT:
+            self.hit = True
+
+    def __enter__(self) -> "_CacheHit":
+        import jax.monitoring
+
+        jax.monitoring.register_event_listener(self._on_event)
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        import jax.monitoring
+
+        jax.monitoring.unregister_event_listener(self._on_event)
 
 
 def wrap(fn: Callable, key: str, kind: str,

@@ -8,9 +8,15 @@ finalized step span:
 * the ``exec`` spans in the tree name the introspected programs that
   ran (``prof/introspect.py`` stamps each executor call with its
   program key);
-* each program's cost-analysis FLOPs divided by the step wall-clock,
-  against the device peak from :mod:`prof.peak` (the shared datasheet
-  table), becomes ``prof.mfu{workload=...}``;
+* each program's cost-analysis FLOPs divided by the step time — the
+  rolling p50 of the spans' entry-to-entry intervals
+  (``prof/hostgap.py``; this step's own where there is no history),
+  never the dispatch, and not one held-up step alone — against the device peak
+  from :mod:`prof.peak` (the shared datasheet table), becomes
+  ``prof.mfu{workload=...}``.  XLA's count is of the program it built
+  (recomputation counted, the inside of a Mosaic kernel not), per
+  device.  The value is not clamped: above 1 it is a fault to see (a
+  wrong peak, a wrong clock), not to hide;
 * total step FLOPs split across tenants proportionally to each
   tenant's device-busy seconds (the host-gap attribution's
   ``tenant_busy_s``) becomes ``prof.mfu{tenant=...}`` — device-time
@@ -42,7 +48,7 @@ def on_step(span: Any, stats: Dict[str, Any]) -> None:
     dependency."""
     if not enabled():
         return
-    wall = stats.get("wall_s") or 0.0
+    wall = stats.get("step_p50_s") or stats.get("step_s") or 0.0
     if wall <= 0:
         return
     per_workload: Dict[str, float] = {}
@@ -67,7 +73,7 @@ def on_step(span: Any, stats: Dict[str, Any]) -> None:
     denom = wall * peak_tflops * 1e12
     with _lock:
         for w, fl in per_workload.items():
-            v = min(fl / denom, 1.0)
+            v = fl / denom
             metrics.set_gauge("prof.mfu", v, {"workload": w})
             _last_mfu[w] = v
     metrics.set_gauge("prof.flops_per_step", total_flops)
@@ -77,7 +83,7 @@ def on_step(span: Any, stats: Dict[str, Any]) -> None:
         for tenant, busy in tenant_busy.items():
             share = busy / busy_total
             metrics.set_gauge(
-                "prof.mfu", min(total_flops * share / denom, 1.0),
+                "prof.mfu", total_flops * share / denom,
                 {"tenant": tenant},
             )
 
@@ -94,7 +100,7 @@ def publish(workload: str, achieved_tflops: float,
         peak_tflops = resolved[0]
     if peak_tflops <= 0:
         return None
-    v = min(achieved_tflops / peak_tflops, 1.0)
+    v = achieved_tflops / peak_tflops
     metrics.set_gauge("prof.mfu", v, {"workload": workload})
     with _lock:
         _last_mfu[workload] = v

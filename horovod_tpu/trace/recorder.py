@@ -8,7 +8,10 @@ spans from the service loop) per rank, and writes the whole ring to
 
 * **slow step** — step time exceeding ``HVD_TPU_TRACE_ANOMALY_Z`` x
   the rolling p50 of recent steps (the z-test a human eyeballing a
-  step-time plot runs);
+  step-time plot runs).  The step time is the interval the step span
+  carries (``interval_s``: ``TrainStep``'s entry to entry), not the
+  span's own duration, which is the dispatch; a span that carries
+  none is judged on its duration;
 * **fault site** — any armed :mod:`horovod_tpu.faults` injection
   firing (``trace/__init__.on_fault``), so a scripted game-day run
   leaves span evidence of the window around the fault;
@@ -34,6 +37,7 @@ from collections import deque
 from typing import Any, Dict, List, Optional
 
 from ..utils import env
+from .tracer import step_seconds
 
 DEFAULT_RING = 16
 DEFAULT_Z = 3.0
@@ -84,28 +88,30 @@ class FlightRecorder:
 
     def on_step(self, span) -> None:
         """Record one finished step tree; run the slow-step check
-        against the rolling p50 of the steps before it."""
+        against the rolling p50 of the steps before it, on the step's
+        time (:func:`step_seconds`)."""
         from .. import metrics
 
-        dur = span.dur
+        step_s = step_seconds(span)
         with self._lock:
             baseline = sorted(self._durs)
             self._ring.append({
                 "kind": "step",
-                "step": span.attrs.get("step") if span.attrs else None,
+                "step": span.attrs.get("step_num") if span.attrs else None,
                 "wall_ts": time.time(),
-                "dur_s": dur,
+                "dur_s": span.dur,
+                "step_s": step_s,
                 "spans": span.to_dict(),
             })
-            self._durs.append(dur)
+            self._durs.append(step_s)
         metrics.inc_counter("trace.steps")
         if len(baseline) >= _MIN_HISTORY:
             p50 = baseline[len(baseline) // 2]
             z = anomaly_z()
-            if dur > z * p50 and dur - p50 > _MIN_EXCESS_S:
+            if step_s > z * p50 and step_s - p50 > _MIN_EXCESS_S:
                 self.dump(
                     "slow_step",
-                    step_seconds=dur, rolling_p50=p50, z=z,
+                    step_seconds=step_s, rolling_p50=p50, z=z,
                 )
 
     def on_background(self, span) -> None:

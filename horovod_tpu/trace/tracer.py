@@ -32,6 +32,14 @@ Every finalized root tree is:
 Step spans additionally derive the measured per-rail utilization
 gauges ``topo.rail_busy_frac{rail=ici|dcn}`` from the rail-phase spans
 (the pipeliner's overlap claims as a measurement, not a counter).
+
+One clock with the device: entering a span also enters a
+``jax.profiler.TraceAnnotation`` named ``hvd_<span name>`` (the span's
+attributes ride as keyword arguments, never inside the name), so
+whenever a profile is open — ``prof/capture.py``'s bounded window, a
+``jax.profiler.trace`` of the user's — every program span sits on the
+profiler's ``/host:CPU`` plane beside the device planes.  With no
+profile open an annotation is a flag check; at ``off`` none is made.
 """
 
 from __future__ import annotations
@@ -41,6 +49,8 @@ import itertools
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from ..utils import env
 
@@ -145,6 +155,15 @@ class Span:
             yield from c.walk()
 
 
+def step_seconds(span: Span) -> float:
+    """The step's time as a finished step span tells it: the
+    entry-to-entry interval it carries (``interval_s``), or — a first
+    step, a step of another caller than ``TrainStep``, a hand-made
+    tree — its own duration."""
+    interval = (span.attrs or {}).get("interval_s")
+    return span.dur if interval is None else interval
+
+
 class _NoopSpan:
     """The shared do-nothing span ``HVD_TPU_TRACE=off`` hands back —
     one module-level instance, so the off path allocates nothing."""
@@ -161,30 +180,41 @@ class _NoopSpan:
 NOOP = _NoopSpan()
 
 
+def annotation_name(name: str) -> str:
+    """The profiler-side name of the span ``name``."""
+    return "hvd_" + name
+
+
 class _ActiveSpan:
-    """Context manager around one live span on this thread's stack."""
-
-    __slots__ = ("_tracer", "_span")
-
-    def __init__(self, tracer: "Tracer", span: Span):
-        self._tracer = tracer
-        self._span = span
-
-    def __enter__(self) -> Span:
-        self._tracer._push(self._span)
-        return self._span
-
-    def __exit__(self, *exc):
-        self._tracer._pop(self._span)
-        return False
-
-
-class _StepSpan(_ActiveSpan):
-    """Step-scoped span: finalization additionally feeds the flight
+    """Context manager around one live span on this thread's stack and
+    the profiler annotation that puts it on the device trace's clock.
+    A ``step`` span's finalization additionally feeds the flight
     recorder's anomaly check and the rail-utilization gauges."""
 
+    __slots__ = ("_tracer", "_span", "_step", "_annotation")
+
+    def __init__(self, tracer: "Tracer", span: Span, step: bool = False):
+        self._tracer = tracer
+        self._span = span
+        self._step = step
+        self._annotation = None
+
+    def __enter__(self) -> Span:
+        span = self._span
+        # The attributes are formatted only while a profile is open
+        # (the annotation checks the profiler's flag first).
+        self._annotation = TraceAnnotation(
+            annotation_name(span.name), **span.attrs)
+        self._annotation.__enter__()
+        self._tracer._push(span)
+        return span
+
     def __exit__(self, *exc):
-        self._tracer._pop(self._span, step=True)
+        self._span.t1 = time.monotonic()
+        # The annotation ends with the span: folding a finished step
+        # (``_finalize_root``) is timed under a name of its own.
+        self._annotation.__exit__(None, None, None)
+        self._tracer._pop(self._span, step=self._step)
         return False
 
 
@@ -226,7 +256,6 @@ class Tracer:
         st.append(span)
 
     def _pop(self, span: Span, step: bool = False) -> None:
-        span.t1 = time.monotonic()
         st = self._stack()
         while st and st[-1] is not span:  # tolerate unbalanced exits
             st.pop()
@@ -260,16 +289,22 @@ class Tracer:
 
     def step(self, **attrs):
         """Open the per-step root span (``TrainStep.__call__`` wraps
-        the whole dispatch in one).  Finalization runs the flight
+        the whole call in one).  Finalization runs the flight
         recorder's anomaly check and publishes the per-rail busy
-        fractions measured from the rail-phase spans underneath."""
+        fractions measured from the rail-phase spans underneath.
+
+        The span's own duration is the *dispatch* (a step returns
+        futures).  A caller that knows the step's time passes it as
+        ``interval_s`` — ``TrainStep`` gives the time since its
+        previous entry — and the recorder, the sentinel and MFU then
+        run on that; a span without one keeps its own duration."""
         self._step_idx += 1
         sp = Span(
-            f"step{self._step_idx}", "step", time.monotonic(),
+            "step", "step", time.monotonic(),
             span_id=f"s{next(_span_counter)}",
-            attrs={"step": self._step_idx, **attrs},
+            attrs={"step_num": self._step_idx, **attrs},
         )
-        return _StepSpan(self, sp)
+        return _ActiveSpan(self, sp, step=True)
 
     def record_complete(self, name: str, phase: str, t0: float,
                         t1: Optional[float] = None, ctx=None,
@@ -304,6 +339,20 @@ class Tracer:
     # ------------------------------------------------------- finalize
 
     def _finalize_root(self, span: Span, step: bool = False) -> None:
+        if not step:
+            self._fold_root(span, step=False)
+            return
+        # The tracing's own cost, measured by itself: the step's tree
+        # has ended, so folding it has an annotation and a histogram of
+        # its own.
+        from .. import metrics
+
+        t0 = time.monotonic()
+        with TraceAnnotation(annotation_name("step_finalize")):
+            self._fold_root(span, step=True)
+        metrics.observe("trace.finalize_seconds", time.monotonic() - t0)
+
+    def _fold_root(self, span: Span, step: bool) -> None:
         from .. import metrics
 
         n = 0
@@ -323,9 +372,9 @@ class Tracer:
         metrics.inc_counter("trace.spans", n)
         if step:
             self._publish_rail_utilization(span)
-            # Device-time profiling plane (prof/): host-gap + MFU +
-            # sentinel all derive from the finalized step tree.  The
-            # hook never raises and is a no-op at HVD_TPU_PROF=off.
+            # Profiling plane (prof/): step clock, host gap, MFU and
+            # the sentinel all derive from the finalized step tree.
+            # The hook never raises and is a no-op at HVD_TPU_PROF=off.
             from .. import prof
 
             prof.on_step_span(span)

@@ -1,5 +1,5 @@
-"""Device-time profiling plane (prof/): compiled-step introspection,
-host-gap attribution, online MFU, perf-regression sentinel, /prof.
+"""Profiling plane (prof/): compiled-step introspection, the step clock
+and host-gap attribution, online MFU, perf-regression sentinel, /prof.
 
 Contracts under test:
 
@@ -9,13 +9,17 @@ Contracts under test:
   signature, and degrades to the raw fn (one attempt, forever) when
   AOT lowering is impossible.
 * **Host gap** — ``attribute()`` is pure math on a span tree: busy is
-  the *union* of device-phase intervals (overlap never double counts),
-  gap is wall minus busy, dispatches count exec/dispatch spans plus
-  the service-loop counter delta, and tenant busy splits by the trace
-  tenant slot.
-* **MFU** — cost-analysis FLOPs over step wall-clock against a pinned
-  peak gives the exact expected ratio (clamped to 1.0), per workload
-  and per tenant; ``publish()`` is the bench-side entry point.
+  the *union* of executor-call intervals (overlap never double counts),
+  gap is the call's duration minus busy, the step's time is the
+  interval the span carries (else its duration) and the gap's share is
+  of that, dispatches count exec/dispatch spans plus the service-loop
+  counter delta, and tenant busy splits by the trace tenant slot.
+  ``TrainStep`` observes the same two clocks as ``train.step_seconds``
+  (entry to entry) and ``train.dispatch_seconds`` (the call).
+* **MFU** — cost-analysis FLOPs over the step's time (the interval,
+  never the dispatch) against a pinned peak gives the exact expected
+  ratio, not clamped, per workload and per tenant; ``publish()`` is
+  the bench-side entry point.
 * **Sentinel** — the baseline store roundtrips through the
   ScheduleStore machinery (keep-best keeps the fastest run), an
   identical second run verdicts ``ok``, a slower run verdicts
@@ -86,8 +90,8 @@ def _span(name, phase, t0, t1, tenant="", **attrs):
     return s
 
 
-def _step_span(wall, children=()):
-    root = _span("step", "step", 0.0, wall)
+def _step_span(wall, children=(), **attrs):
+    root = _span("step", "step", 0.0, wall, **attrs)
     root.children.extend(children)
     return root
 
@@ -106,9 +110,37 @@ class TestIntrospection:
         assert rec is not None and rec["compiles"] == 1
         assert rec["flops"] is not None and rec["flops"] > 0
         assert rec["compile_seconds"] > 0
+        assert ex.compiled().as_text().startswith("HloModule")
         assert metrics.get_counter("prof.compiles") == 1
         assert metrics.get_gauge(
             "prof.flops", {"key": "intro_a", "kind": "step"}) == rec["flops"]
+
+    def test_compile_record_in_three_parts(self):
+        # jit's own three stages, each on the record and under a span
+        # of its own; compile_seconds stays their sum.
+        f = jax.jit(lambda x: jnp.tanh(x @ x).sum())
+        ex = introspect.wrap(f, key="intro_p", kind="step")
+        with trace.span("outer", "test") as outer:
+            ex(jnp.ones((8, 8), jnp.float32))
+        rec = introspect.get("intro_p")
+        parts = [rec[k] for k in ("trace_seconds", "lower_seconds",
+                                  "backend_seconds")]
+        assert all(p > 0 for p in parts)
+        assert rec["compile_seconds"] == pytest.approx(sum(parts))
+        assert rec["cache_hit"] is False  # no persistent cache here
+        names = [c.name for c in outer.children]
+        assert names == ["compile_trace", "compile_lower",
+                         "compile_backend", "exec.step"]  # workload = kind
+        assert outer.children[2].attrs["cache_hit"] is False
+        assert [c.dur for c in outer.children[:3]] == pytest.approx(
+            parts, abs=1e-3)
+        ex(jnp.ones((4, 4), jnp.float32))  # a second variant adds up
+        again = introspect.get("intro_p")
+        assert again["compiles"] == 2
+        assert again["compile_seconds"] == pytest.approx(
+            again["trace_seconds"] + again["lower_seconds"]
+            + again["backend_seconds"])
+        assert again["compile_seconds"] > rec["compile_seconds"]
 
     def test_compiles_once_per_signature(self):
         f = jax.jit(lambda x: x * 2.0)
@@ -141,7 +173,10 @@ class TestIntrospection:
             self.exc, self.compile_exc = exc, compile_exc
             self.calls, self.lowers = [], 0
 
-        def lower(self, *args):
+        def trace(self, *args):
+            return self
+
+        def lower(self):
             self.lowers += 1
             return self
 
@@ -237,6 +272,7 @@ class TestHostGap:
         ])
         stats = hostgap.attribute(root)
         assert stats["wall_s"] == pytest.approx(1.0)
+        assert stats["step_s"] == pytest.approx(1.0)  # no interval: its own
         assert stats["busy_s"] == pytest.approx(0.5 + 0.1 + 0.05)
         assert stats["gap_s"] == pytest.approx(1.0 - 0.65)
         assert stats["dispatches"] == 2  # exec + dispatch, not rails
@@ -248,6 +284,28 @@ class TestHostGap:
         stats = hostgap.attribute(root)
         assert stats["busy_s"] == pytest.approx(0.2)
         assert stats["gap_s"] == 0.0
+
+    def test_gap_is_inside_the_call_and_its_share_is_of_the_step(self):
+        # A 10 ms call with 4 ms under the executor, in a step that
+        # lasts 200 ms entry to entry: the gap is the call's other
+        # 6 ms, a 3% share of the step, never 60% of the dispatch.
+        root = _step_span(0.010, [_span("e", "exec", 0.002, 0.006)],
+                          interval_s=0.2)
+        stats = hostgap.on_step(root)
+        assert stats["wall_s"] == pytest.approx(0.010)
+        assert stats["step_s"] == 0.2
+        assert stats["gap_s"] == pytest.approx(0.006)
+        assert metrics.get_gauge("prof.host_gap_frac") == pytest.approx(
+            0.006 / 0.2)
+        gap = metrics.get_histogram("prof.host_gap_seconds")
+        assert gap["count"] == 1 and gap["sum"] == pytest.approx(0.006)
+        # the sentinel's clock is the interval, never the dispatch
+        assert hostgap.step_p50() == 0.2
+        # a span that carries no interval keeps its own duration
+        hostgap.reset()
+        hostgap.on_step(_step_span(0.010, [_span("e", "exec", 0.002, 0.006)]))
+        assert metrics.get_gauge("prof.host_gap_frac") == pytest.approx(0.6)
+        assert hostgap.step_p50() == pytest.approx(0.010)
 
     def test_on_step_adds_svc_dispatch_delta(self):
         first = hostgap.on_step(_step_span(0.1))
@@ -281,25 +339,30 @@ class TestMFU:
         flops = self._introspected("mfu_w")
         assert flops and flops > 0
         peak.set_peak_override(1.0)  # 1 TFLOP/s
-        wall = 2.0
-        root = _step_span(wall, [
-            _span("exec.mfu_w", "exec", 0.0, 0.5, tenant="t0",
+        wall = 2.0  # the interval; the span itself lasts 5 ms
+        root = _step_span(0.005, [
+            _span("exec.mfu_w", "exec", 0.0, 0.005, tenant="t0",
                   program="mfu_w"),
-        ])
+        ], interval_s=wall)
         mfu.on_step(root, hostgap.attribute(root))
-        expect = min(flops / (wall * 1.0 * 1e12), 1.0)
+        expect = flops / (wall * 1.0 * 1e12)
         assert metrics.get_gauge("prof.mfu", {"workload": "mfu_w"}) == expect
         assert metrics.get_gauge("prof.mfu", {"tenant": "t0"}) == expect
         assert mfu.observed() == expect
         assert metrics.get_gauge("prof.flops_per_step") == flops
 
-    def test_mfu_clamped_to_one(self):
-        self._introspected("mfu_c")
+    def test_mfu_over_one_is_shown_not_clamped(self):
+        # A value above 1 is a fault to see (a wrong peak, a wrong
+        # clock), not to hide behind a clamp.
+        flops = self._introspected("mfu_c")
         peak.set_peak_override(1e-12)  # absurdly slow "peak"
         root = _step_span(0.5, [
-            _span("e", "exec", 0.0, 0.1, program="mfu_c")])
+            _span("e", "exec", 0.0, 0.1, tenant="t0", program="mfu_c")])
         mfu.on_step(root, hostgap.attribute(root))
-        assert metrics.get_gauge("prof.mfu", {"workload": "mfu_c"}) == 1.0
+        got = metrics.get_gauge("prof.mfu", {"workload": "mfu_c"})
+        assert got == flops / 0.5 and got > 1.0
+        assert metrics.get_gauge("prof.mfu", {"tenant": "t0"}) == got
+        assert mfu.publish("bench_over", 3.0, peak_tflops=2.0) == 1.5
 
     def test_no_peak_on_cpu_means_no_mfu(self):
         # Off the chip there is no peak: MFU is absent, never estimated.
@@ -576,6 +639,115 @@ class TestCompileCost:
         assert metrics.get_counter("prof.emissions") == 1
         assert metrics.get_gauge(
             "prof.emitted_ops", {"src": "sched.tr"}) == 4.0
+
+
+# -------------------------------------------------------- step clock
+
+
+def _tiny_step(**kwargs):
+    import optax
+    from horovod_tpu.optim.distributed_optimizer import TrainStep
+
+    def loss_fn(params, batch):
+        x, y = batch
+        return jnp.mean((x @ params["w"] - y) ** 2)
+
+    step = TrainStep(loss_fn, optax.sgd(0.01), **kwargs)
+    params = {"w": jnp.ones((4, 2), jnp.float32)}
+    x = jnp.arange(N * 4, dtype=jnp.float32).reshape(N, 4) / 32.0
+    return step, params, step.init(params), (x, jnp.ones((N, 2), jnp.float32))
+
+
+@pytest.mark.usefixtures("hvd_module")
+class TestStepClock:
+    def test_step_seconds_is_the_interval_dispatch_the_call(self):
+        metrics.reset_counters("train.")
+        step, params, state, batch = _tiny_step()
+        pause = 0.05
+        for _ in range(5):
+            params, state, loss = step(params, state, batch)
+            float(loss)
+            time.sleep(pause)
+        steps = metrics.get_histogram("train.step_seconds")
+        calls = metrics.get_histogram("train.dispatch_seconds")
+        assert metrics.get_counter("train.steps") == 5
+        # entry to entry: four intervals for five calls, and the one
+        # that holds the first call's build stays out of the histogram
+        assert steps["count"] == 3 and calls["count"] == 5
+        assert 3 * pause <= steps["sum"] < 3 * pause + 0.1
+        # the step span carries the same interval, for the recorder,
+        # the sentinel and MFU
+        spans = [r["spans"] for r in trace.get_recorder().steps()]
+        assert spans[0]["attrs"]["interval_s"] is None
+        assert [sp["attrs"]["interval_holds_build"] for sp in spans] == [
+            False, True, False, False, False]
+        assert all(sp["attrs"]["interval_s"] >= pause for sp in spans[1:])
+        assert all(sp["dur"] < sp["attrs"]["interval_s"]
+                   for sp in spans[2:])
+        assert hostgap.step_p50() >= pause
+        # the gap's share is of the step, so the loop's pause dilutes it
+        assert metrics.get_gauge("prof.host_gap_frac") < 0.5
+
+    def test_online_mfu_divides_by_the_rolling_interval(self):
+        peak.set_peak_override(1e-9)  # 1 kFLOP/s: the tiny step registers
+        step, params, state, batch = _tiny_step()
+        pauses = [0.05] * 6 + [0.6]  # the last step is held up
+        for pause in pauses:
+            time.sleep(pause)
+            params, state, loss = step(params, state, batch)
+            float(loss)
+        flops = introspect.get("train_step_0")["flops"]
+        last = trace.get_recorder().steps()[-1]["spans"]
+        assert last["attrs"]["interval_s"] >= 0.6 > 0.05 > last["dur"]
+        # neither the dispatch nor the one held-up step: the rolling p50
+        p50 = hostgap.step_p50()
+        assert 0.05 <= p50 < 0.3
+        assert metrics.get_gauge(
+            "prof.mfu", {"workload": "train_step"}) == pytest.approx(
+            flops / (p50 * 1e3))
+
+    def test_compiled_is_public(self):
+        step, params, state, batch = _tiny_step()
+        assert step.compiled() is None  # nothing has run yet
+        for _ in range(3):
+            params, state, _ = step(params, state, batch)
+        compiled = step.compiled()
+        assert "hvd_compute_grads" in compiled.as_text()
+        # what the benchmark reads today keeps working
+        (executor,) = step._step_cache.values()
+        assert list(executor._compiled.values()) == [compiled]
+        rec = introspect.get(executor.key)
+        assert rec["compiles"] == 1 and rec["calls"] == 3
+        assert rec["compile_seconds"] == pytest.approx(
+            rec["trace_seconds"] + rec["lower_seconds"]
+            + rec["backend_seconds"])
+        assert rec["peak_hbm_bytes"] is not None
+
+    def test_off_off_makes_no_span_annotation_or_signature(
+            self, monkeypatch):
+        from horovod_tpu.trace import tracer
+
+        made = []
+        monkeypatch.setattr(
+            tracer, "Span", lambda *a, **k: made.append("span"))
+        monkeypatch.setattr(
+            tracer, "TraceAnnotation",
+            lambda *a, **k: made.append("annotation"))
+        monkeypatch.setattr(
+            introspect, "_args_signature",
+            lambda args: made.append("signature"))
+        trace.set_level_override("off")
+        prof.set_enabled_override(False)
+        metrics.reset_counters("train.")
+        step, params, state, batch = _tiny_step()
+        for _ in range(4):
+            params, state, loss = step(params, state, batch)
+        assert made == [] and step.compiled() is None
+        assert jnp.isfinite(loss)
+        # the two clocks do not depend on tracing
+        assert metrics.get_histogram("train.step_seconds")["count"] == 2
+        assert metrics.get_histogram(
+            "train.dispatch_seconds")["count"] == 4
 
 
 # ------------------------------------------------------------ parity

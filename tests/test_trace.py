@@ -14,9 +14,16 @@ Contracts under test:
 * **Nesting** — rail-phase spans (rs_ici / dcn / ag_ici) emitted while
   a hier step traces nest under that step's span tree, and the
   measured ``topo.rail_busy_frac{rail=}`` gauges come out nonzero.
+* **One clock** — entering a span enters a profiler annotation of the
+  stable name ``hvd_<span>`` (attributes as keyword arguments, never
+  in the name) and leaving it leaves the annotation; ``off`` makes
+  neither.  A step call is one tree: ``hvd_step`` with exactly the
+  children docs/tracing.md tabulates, joined by parent ids, and
+  ``hvd_step_finalize`` after it.
 * **Flight recorder** — the ring evicts FIFO at capacity; anomaly
-  dumps fire on an injected slow step (z x rolling p50) and on a
-  ``svc.loop`` fault, writing JSON to ``HVD_TPU_TRACE_DIR``.
+  dumps fire on an injected slow step (the interval the span carries,
+  else its duration, against z x rolling p50) and on a ``svc.loop``
+  fault, writing JSON to ``HVD_TPU_TRACE_DIR``.
 * **Neutrality** — f32 dense losses are bitwise identical with
   tracing off / summary / full (host-side spans, no inserted ops).
 * **Tools** — ``merge_timeline_files`` reports per-file parse status
@@ -110,6 +117,148 @@ class TestLevels:
         assert a.trace_id != b.trace_id
         assert a.child("s9").span_id == "s9"
         assert a.child("s9").trace_id == a.trace_id
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation`` inside the
+    tracer: a log of what was entered and left, in order."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **kwargs):
+        outer = self
+
+        class Annotation:
+            def __enter__(self):
+                outer.log.append(("enter", name, kwargs))
+                return self
+
+            def __exit__(self, *exc):
+                outer.log.append(("exit", name, kwargs))
+                return False
+
+        return Annotation()
+
+    def names(self, what):
+        return [n for w, n, _ in self.log if w == what]
+
+
+@pytest.fixture()
+def annotations(monkeypatch):
+    fake = _Annotations()
+    monkeypatch.setattr(trace.tracer, "TraceAnnotation", fake)
+    return fake
+
+
+class TestOneClock:
+    def test_span_enters_and_leaves_a_stable_annotation(self, annotations):
+        with trace.span("outer", "test", bucket=3):
+            assert annotations.log == [
+                ("enter", "hvd_outer", {"bucket": 3})]
+            with trace.span("inner.kind", "test"):
+                pass
+        assert annotations.names("enter") == ["hvd_outer", "hvd_inner.kind"]
+        assert annotations.names("exit") == ["hvd_inner.kind", "hvd_outer"]
+
+    def test_step_number_is_an_argument_not_in_the_name(self, annotations):
+        for _ in range(2):
+            with trace.step(compiled=True) as sp:
+                assert sp.name == "step"
+        entered = [(n, k) for w, n, k in annotations.log if w == "enter"]
+        assert [n for n, _ in entered] == [
+            "hvd_step", "hvd_step_finalize"] * 2
+        assert [k["step_num"] for n, k in entered if n == "hvd_step"] == [
+            1, 2]
+        # the step's own annotation has ended before its tree is folded
+        assert annotations.log[1][:2] == ("exit", "hvd_step")
+        assert annotations.log[2][:2] == ("enter", "hvd_step_finalize")
+        # folding is timed by itself
+        assert metrics.get_histogram("trace.finalize_seconds")["count"] == 2
+
+    def test_off_makes_no_annotation(self, annotations):
+        trace.set_level_override("off")
+        with trace.span("a", "b"), trace.step():
+            pass
+        assert annotations.log == []
+
+    def test_real_annotation_outside_a_profile(self):
+        # no profile is open: the annotation is a flag check, and the
+        # span tree is what it was
+        with trace.span("outer", "test", n=1) as outer:
+            with trace.span("inner", "test"):
+                pass
+        assert [c.name for c in outer.children] == ["inner"]
+
+
+@pytest.mark.usefixtures("hvd_module")
+class TestStepTree:
+    """docs/tracing.md's table: what one ``TrainStep`` call is made
+    of, on a miss and on a hit."""
+
+    def _steps(self, n=3):
+        def lf(p, b):
+            x, y = b
+            return jnp.mean((x @ p["w"] - y) ** 2)
+
+        tx = hvd.DistributedOptimizer(optax.sgd(0.05))
+        step = hvd.distributed_train_step(lf, tx)
+        p = {"w": jnp.ones((4, 2), jnp.float32)}
+        st = step.init(p)
+        batch = (jnp.ones((N, 4), jnp.float32),
+                 jnp.ones((N, 2), jnp.float32))
+        for _ in range(n):
+            p, st, _ = step(p, st, batch)
+        return [r["spans"] for r in trace.get_recorder().steps()]
+
+    def test_children_are_the_tables_and_parent_ids_join(self, annotations):
+        miss, after_miss, hit = self._steps()
+        assert [c["name"] for c in hit["children"]] == [
+            "step_resolve", "train_step"]
+        assert [c["name"] for c in miss["children"]] == [
+            "step_resolve", "step_build", "train_step"]
+        build = miss["children"][1]
+        assert [c["name"] for c in build["children"]] == [
+            "step_place", "compile_trace", "compile_lower",
+            "compile_backend"]
+        assert build["children"][3]["attrs"]["cache_hit"] is False
+        # the exchange is emitted while jax traces the step
+        emitted = [sp["name"] for sp in _walk(build["children"][1])]
+        assert "exchange.dense_grad" in emitted
+        for tree in (miss, hit):
+            assert tree["name"] == "step" and "parent_id" not in tree
+        # (spans under compile_trace join the exchange program's own
+        # context, as they did)
+        for parent in (miss, hit, build):
+            for child in parent["children"]:
+                assert child["parent_id"] == parent["span_id"]
+                assert child["t0"] >= parent["t0"]
+        assert miss["attrs"]["compiled"] is False
+        assert hit["attrs"]["compiled"] is True
+        # the step's time rides each span: none on the first call, one
+        # that holds the build on the second, the step's on the third
+        assert miss["attrs"]["interval_s"] is None
+        assert after_miss["attrs"]["interval_holds_build"] is True
+        assert after_miss["attrs"]["interval_s"] > miss["dur"]
+        assert hit["attrs"]["interval_holds_build"] is False
+        assert hit["attrs"]["interval_s"] >= after_miss["dur"]
+        # every one of them is on the profiler's clock under hvd_<name>
+        entered = annotations.names("enter")
+        for name in ("hvd_step", "hvd_step_resolve", "hvd_step_build",
+                     "hvd_step_place", "hvd_compile_trace",
+                     "hvd_compile_lower", "hvd_compile_backend",
+                     "hvd_train_step", "hvd_step_finalize"):
+            assert name in entered, name
+        assert entered.count("hvd_train_step") == 3
+        assert entered.count("hvd_step_build") == 1
+        assert sorted(annotations.names("exit")) == sorted(entered)
+
+    def test_step_call_holds_no_literal_annotation(self):
+        import inspect
+
+        from horovod_tpu.optim.distributed_optimizer import TrainStep
+
+        assert "TraceAnnotation" not in inspect.getsource(TrainStep)
 
 
 @pytest.mark.usefixtures("hvd_module")
@@ -248,11 +397,17 @@ class TestStepNesting:
 
 
 class TestFlightRecorder:
-    def _mk_span(self, name="s", phase="step", dur=0.001, step=None):
+    def _mk_span(self, name="s", phase="step", dur=0.001, step=None,
+                 interval=None):
+        """A finished step span: its own time (the dispatch) is
+        ``dur``, the step's time is ``interval`` where it carries
+        one."""
         sp = trace.tracer.Span(name, phase, time.monotonic())
         sp.t1 = sp.t0 + dur
         if step is not None:
-            sp.attrs = {"step": step}
+            sp.attrs["step_num"] = step
+        if interval is not None:
+            sp.attrs["interval_s"] = interval
         return sp
 
     def test_ring_evicts_fifo(self):
@@ -281,6 +436,23 @@ class TestFlightRecorder:
         rec = FlightRecorder(capacity=8)
         rec.on_step(self._mk_span(dur=5.0))  # first step: no baseline
         assert rec.dump_seq == 0
+
+    def test_slow_step_is_judged_on_the_interval_it_carries(self):
+        rec = FlightRecorder(capacity=16)
+        for i in range(6):
+            rec.on_step(self._mk_span(interval=0.01, step=i))
+        # a long dispatch inside a step of the usual length is not a
+        # slow step ...
+        rec.on_step(self._mk_span(dur=1.0, interval=0.01, step=6))
+        assert rec.dump_seq == 0
+        # ... a long interval is, however short the call
+        rec.on_step(self._mk_span(interval=1.0, step=7))
+        assert rec.dump_seq == 1
+        detail = rec.last_dump()["detail"]
+        assert detail["step_seconds"] == 1.0
+        assert detail["rolling_p50"] == 0.01
+        last = rec.steps()[-1]
+        assert last["step_s"] == 1.0 and last["dur_s"] < 0.01
 
     @pytest.mark.usefixtures("hvd_module")
     def test_anomaly_dump_fires_on_svc_loop_fault(self, tmp_path):
