@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -561,11 +562,16 @@ def _scale_of(scale: Optional[float], q: jax.Array) -> float:
 
 def _flash_fwd_res(q, k, v, causal, scale, block_q, block_k, segments=None):
     """(out [B, T, H, D], residuals): q, k, v are kept as the kernel
-    read them, [B, H, T, D], so the backward transposes none of them."""
-    qt, kt, vt = _bhtd(q), _bhtd(k), _bhtd(v)
+    read them, [B, H, T, D], so the backward transposes none of them.
+    The residuals carry names, so that a caller's ``jax.checkpoint``
+    policy can keep them where it recomputes the rest: "flash_qkv", and
+    "flash_out" for the output and the row logsumexp (with both kept the
+    backward does not run the forward kernel again)."""
+    qt, kt, vt = (checkpoint_name(_bhtd(x), "flash_qkv") for x in (q, k, v))
     out_t, lse = _flash_forward(
         qt, kt, vt, causal, _scale_of(scale, q), block_q, block_k, segments)
-    out = _bhtd(out_t)
+    out = checkpoint_name(_bhtd(out_t), "flash_out")
+    lse = checkpoint_name(lse, "flash_out")
     return out, (qt, kt, vt, out, lse)
 
 

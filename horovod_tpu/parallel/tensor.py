@@ -104,7 +104,10 @@ class TensorParallelMLP(nn.Module):
     """Transformer MLP block sharded column→row: one psum per block.
 
     ``hidden`` and ``features`` are GLOBAL widths; the hidden dimension
-    is sharded ``hidden / tp`` per device.
+    is sharded ``hidden / tp`` per device.  ``gated`` makes it the gated
+    unit of current decoders, ``wo(act(wg(x)) * wi(x))`` (SwiGLU with
+    ``act=nn.silu``): the gate ``wg`` is a second column shard beside
+    ``wi``, so the pairing and its one psum stay as they are.
     """
 
     hidden: int
@@ -112,13 +115,21 @@ class TensorParallelMLP(nn.Module):
     axis: str = TP_AXIS
     dtype: Optional[Dtype] = None
     act: Callable = nn.gelu
+    gated: bool = False
+    use_bias: bool = True
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
-        h = ColumnParallelDense(
-            self.hidden, axis=self.axis, dtype=self.dtype, name="wi"
-        )(x)
-        h = self.act(h)
+        def column(name):
+            return ColumnParallelDense(
+                self.hidden, axis=self.axis, use_bias=self.use_bias,
+                dtype=self.dtype, name=name)
+
+        if self.gated:
+            h = self.act(column("wg")(x)) * column("wi")(x)
+        else:
+            h = self.act(column("wi")(x))
         return RowParallelDense(
-            self.features, axis=self.axis, dtype=self.dtype, name="wo"
+            self.features, axis=self.axis, use_bias=self.use_bias,
+            dtype=self.dtype, name="wo"
         )(h)

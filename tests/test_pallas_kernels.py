@@ -62,6 +62,7 @@ def test_flash_attention_rejects_unequal_seq_lens():
         (2, 128, 4, 64, True),
         (1, 100, 2, 32, True),   # ragged T → padding path
         (1, 257, 3, 64, False),  # ragged, multiple blocks
+        (1, 320, 2, 128, True),  # heads of 128 (Ouro), five blocks
     ],
 )
 def test_flash_attention_forward(b, t, h, d, causal):
@@ -110,6 +111,12 @@ _GRAD_CASES = {
     "bf16": ((2, 128, 2, 32), True, None, jnp.bfloat16, (512, 512), 2e-2),
     "bf16_packed_blocks":
         ((1, 256, 2, 64), True, (100,), jnp.bfloat16, (512, 512), 2e-2),
+    # heads of 128 (Ouro's): three 128-blocks, dense and packed, both types
+    "d128_blocks": ((1, 384, 2, 128), True, None, jnp.float32, (512, 512), 2e-4),
+    "d128_packed_blocks":
+        ((1, 384, 2, 128), True, (90, 200), jnp.float32, (512, 512), 2e-4),
+    "d128_bf16_packed":
+        ((1, 256, 2, 128), True, (100,), jnp.bfloat16, (512, 512), 2e-2),
 }
 
 

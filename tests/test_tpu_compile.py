@@ -74,6 +74,9 @@ def _compile_grad(one_chip, shape, dtype, causal, packed):
     ((2, 100, 4, 64), jnp.float32, True, False),
     # a long sequence: one head's q, do and dq stay in VMEM
     ((1, 16384, 2, 64), jnp.bfloat16, True, False),
+    # ouro26b.ring2x4096: heads of 128 at 4096 tokens, dense and packed
+    ((2, 4096, 16, 128), jnp.bfloat16, True, False),
+    ((2, 4096, 16, 128), jnp.bfloat16, True, True),
 ])
 def test_flash_attention_grad_compiles_for_the_chip(
         one_chip, mosaic, shape, dtype, causal, packed):

@@ -385,3 +385,173 @@ class TestSequencePacking:
         assert toks.shape == (4, 16)
         # padded rows carry segment 0 everywhere
         assert (segs[(segs > 0).any(axis=1) == False] == 0).all()  # noqa: E712
+
+
+# ---------------------------------------------------------------------------
+# The current block and the loop as settings of TransformerConfig
+# ---------------------------------------------------------------------------
+
+def _tree_shapes(params):
+    return {jax.tree_util.keystr(path): leaf.shape for path, leaf in
+            jax.tree_util.tree_flatten_with_path(params)[0]}
+
+
+def test_defaults_keep_gpt2s_parameter_tree():
+    """The settings the looped decoder added leave GPT-2's parameters as
+    they were, names and shapes."""
+    model = gpt_tiny()
+    shapes = _tree_shapes(model.init(jax.random.PRNGKey(1), _tokens(t=16)))
+    block = {
+        "['attn']['proj']['Dense_0']['kernel']": (64, 64),
+        "['attn']['proj']['bias']": (64,),
+        "['attn']['qkv']['Dense_0']['bias']": (192,),
+        "['attn']['qkv']['Dense_0']['kernel']": (64, 192),
+        "['ln_attn']['bias']": (64,), "['ln_attn']['scale']": (64,),
+        "['ln_mlp']['bias']": (64,), "['ln_mlp']['scale']": (64,),
+        "['mlp']['wi']['Dense_0']['bias']": (128,),
+        "['mlp']['wi']['Dense_0']['kernel']": (64, 128),
+        "['mlp']['wo']['Dense_0']['kernel']": (128, 64),
+        "['mlp']['wo']['bias']": (64,),
+    }
+    want = {f"['params']['block_{i}']{k}": v
+            for i in range(2) for k, v in block.items()}
+    want.update({
+        "['params']['ln_f']['bias']": (64,),
+        "['params']['ln_f']['scale']": (64,),
+        "['params']['wpe']": (256, 64),
+        "['params']['wte']['embedding']": (256, 64),
+    })
+    assert shapes == want
+
+
+def _looped(**overrides):
+    cfg = dict(
+        vocab_size=96, num_layers=2, model_dim=32, num_heads=2, head_dim=16,
+        ff_dim=48, max_len=64, dtype=jnp.float32, norm="rmsnorm",
+        positions="rope", rope_theta=1e4, use_bias=False, fused_qkv=False,
+        mlp="gated_silu", post_norm=True, tie_head=False, ut_steps=3,
+        exit_gate=True)
+    cfg.update(overrides)
+    return Transformer(TransformerConfig(**cfg))
+
+
+def test_looped_model_shares_one_set_of_weights_over_its_passes():
+    from horovod_tpu import metrics
+
+    toks = _tokens(t=16, vocab=96)
+    model = _looped()
+    params = model.init(jax.random.PRNGKey(1), toks)
+    names = set(params["params"])
+    assert names == {"block_0", "block_1", "ln_f", "wte", "head",
+                     "exit_gate"}  # no wpe, and no block per pass
+    assert set(params["params"]["block_0"]) == {
+        "attn", "mlp", "ln_attn", "ln_attn_post", "ln_mlp", "ln_mlp_post"}
+    assert set(params["params"]["block_0"]["attn"]) == {
+        "q", "k", "v", "proj"}
+    assert set(params["params"]["block_0"]["mlp"]) == {"wg", "wi", "wo"}
+    assert all("bias" not in k for k in _tree_shapes(params)
+               if "exit_gate" not in k)
+    logits, exits, aux = model.apply(params, toks)
+    assert logits.shape == (3, 2, 16, 96) and exits.shape == (3, 2, 16)
+    assert metrics.get_gauge("model.layer_applications") == 6
+    assert metrics.get_gauge("model.ut_steps") == 3
+    # the first pass of the loop is the one-pass model with these weights
+    once, once_exits, _ = _looped(ut_steps=1).apply(params, toks)
+    np.testing.assert_allclose(once[0], logits[0], atol=1e-6)
+    np.testing.assert_allclose(once_exits[0], exits[0], atol=1e-6)
+    assert metrics.get_gauge("model.layer_applications") == 2
+    # without a gate only the last pass has a head
+    gateless = {"params": {k: v for k, v in params["params"].items()
+                           if k != "exit_gate"}}
+    last, _ = _looped(exit_gate=False).apply(gateless, toks)
+    np.testing.assert_allclose(last, logits[-1], atol=1e-6)
+
+
+@pytest.mark.parametrize("save", [(), ("flash_out",),
+                                  ("flash_out", "flash_qkv")])
+def test_what_a_rematerialised_block_keeps_changes_no_value(save):
+    toks = _tokens(t=16, vocab=96)
+    plain = _looped()
+    remat = _looped(remat=True, remat_save=save)
+    params = plain.init(jax.random.PRNGKey(0), toks)
+
+    def loss(model, p):
+        logits, exits, _ = model.apply(p, toks)
+        return jnp.mean(logits ** 2) + jnp.mean(exits ** 2)
+
+    l1, g1 = jax.value_and_grad(lambda p: loss(plain, p))(params)
+    l2, g2 = jax.value_and_grad(lambda p: loss(remat, p))(params)
+    np.testing.assert_allclose(float(l1), float(l2), rtol=1e-6)
+    for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-7)
+    # the names reach jax.checkpoint's policy: with the kernel's output
+    # kept, the backward runs the forward kernel once a call and not twice
+    jaxpr = str(jax.make_jaxpr(jax.grad(lambda p: loss(remat, p)))(params))
+    forward_calls = jaxpr.count("pallas_call") - jaxpr.count(
+        "flash_bwd_dq_dkv")
+    assert forward_calls == (12 if not save else 6)
+
+
+def test_rope_scores_depend_on_the_offset_only():
+    from horovod_tpu.models.transformer import apply_rope, rope_tables
+
+    q, k = jax.random.normal(jax.random.PRNGKey(2), (2, 1, 1, 1, 16))
+    def score(i, j):
+        rot = lambda x, p: apply_rope(  # noqa: E731
+            x, rope_tables(jnp.array([p]), 16, 1e4))
+        return float(jnp.sum(rot(q, i) * rot(k, j)))
+
+    assert score(7, 3) == pytest.approx(score(40, 36), rel=1e-4)
+    assert score(7, 3) != pytest.approx(score(7, 4), rel=1e-3)
+    # position 0 is the identity
+    np.testing.assert_allclose(
+        apply_rope(q, rope_tables(jnp.array([0]), 16, 1e4)), q)
+
+
+def test_packed_rope_positions_restart_at_each_document():
+    """A document gives the same logits alone in a row and packed behind
+    another."""
+    model = _looped(attn_impl="full")
+    doc = _tokens(b=1, t=10, vocab=96, seed=4)
+    other = _tokens(b=1, t=6, vocab=96, seed=5)
+    params = model.init(jax.random.PRNGKey(1), doc)
+    alone, _, _ = model.apply(params, doc, jnp.ones((1, 10), jnp.int32))
+    row = jnp.concatenate([other, doc], axis=1)
+    seg = jnp.asarray([[1] * 6 + [2] * 10])
+    packed, _, _ = model.apply(params, row, seg)
+    np.testing.assert_allclose(packed[:, :, 6:], alone, atol=2e-5)
+
+
+def test_looped_loss_is_finite_with_saturated_gates():
+    from horovod_tpu.models.transformer import looped_token_cross_entropy
+
+    logits = jax.random.normal(jax.random.PRNGKey(0), (3, 2, 5, 7))
+    targets = jnp.zeros((2, 5), jnp.int32)
+    for z in (-200.0, 200.0):
+        exits = jnp.full((3, 2, 5), z)
+        loss, grad = jax.value_and_grad(
+            lambda e: looped_token_cross_entropy(logits, e, targets, 0.1)
+        )(exits)
+        assert np.isfinite(float(loss)) and np.isfinite(grad).all()
+
+
+def test_param_shard_axes_know_the_separate_projections():
+    from horovod_tpu.models.transformer import param_shard_axes
+
+    model = _looped()
+    params = model.init(jax.random.PRNGKey(1), _tokens(t=16, vocab=96))
+    axes = param_shard_axes(params, model.cfg)["params"]["block_0"]
+    for name in ("q", "k", "v", "proj"):
+        assert axes["attn"][name]["Dense_0"]["kernel"] == "tp", name
+    for name in ("wg", "wi", "wo"):
+        assert axes["mlp"][name]["Dense_0"]["kernel"] == "tp", name
+    assert axes["ln_attn_post"]["scale"] == ""
+
+
+def test_unknown_settings_are_errors():
+    toks = _tokens(t=16, vocab=96)
+    for bad in (dict(norm="batchnorm"), dict(positions="alibi"),
+                dict(mlp="relu")):
+        with pytest.raises(ValueError, match="unknown"):
+            _looped(**bad).init(jax.random.PRNGKey(0), toks)
