@@ -132,94 +132,92 @@ _scale_buffer_vjp.defvjp(_scale_buffer_fwd, _scale_buffer_bwd)
 # ---------------------------------------------------------------------------
 
 
+# a·bᵀ and aᵀ·b as dot_general dimension numbers.
+_NT = (((1,), (1,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))
+
+
+def _and(mask, other):
+    return other if mask is None else jnp.logical_and(mask, other)
+
+
 def _flash_fwd_kernel(
     *refs,
     scale: float,
     causal: bool,
     packed: bool,
-    block_q: int,
-    block_k: int,
+    block: int,
     t_actual: int,
-    nk: int,
+    nblocks: int,
 ):
+    """One (batch, head): every Q block's output and row logsumexp, each
+    from one walk over the K blocks that Q block can see.
+
+    The schedule is the backward's.  The score tile is held transposed,
+    sᵀ = k·qᵀ, [block (keys), block (queries)], so a row's running max
+    and sum are reductions along sublanes and lie along lanes as
+    [1, block]; they and the accumulator accᵀ += vᵀ·pᵀ, [D, block], are
+    carried through the walk as values, and the output leaves as oᵀ for
+    XLA to turn (cheaper by the step than a turn in here).  A walk
+    starts at the one tile a position mask can touch (the diagonal when
+    causal, else the last, which holds the padding) and goes on below it
+    in a ``fori_loop``: no tile above the diagonal is visited, and no
+    other tile builds a mask beside the segments'."""
     if packed:
-        (q_ref, k_ref, v_ref, sq_ref, sk_ref,
-         o_ref, lse_ref, acc_ref, m_ref, l_ref) = refs
+        q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, lse_ref = refs
     else:
-        (q_ref, k_ref, v_ref,
-         o_ref, lse_ref, acc_ref, m_ref, l_ref) = refs
+        q_ref, k_ref, v_ref, o_ref, lse_ref = refs
         sq_ref = sk_ref = None
-    qj = pl.program_id(2)
-    kk = pl.program_id(3)
 
-    @pl.when(kk == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    def q_block(j):
+        q = q_ref[j]
+        first = j if causal else nblocks - 1
 
-    # For causal attention, K blocks strictly above the diagonal band
-    # contribute nothing: skip their matmuls entirely (the reference has
-    # no analog — Horovod never sees attention — this is the TPU flash
-    # schedule).
-    run = True
-    if causal:
-        run = kk * block_k <= qj * block_q + block_q - 1
+        def tile(kb, carry):
+            k, v = k_ref[kb], v_ref[kb]
+            # Both matmuls take the input dtype (bf16 fast path) and
+            # accumulate in f32; scores and statistics are f32.
+            st = lax.dot_general(
+                k, q, _NT, preferred_element_type=jnp.float32) * scale
+            mask = None
+            if carry is None:  # the walk's first tile
+                row = lax.broadcasted_iota(jnp.int32, (block, block), 0)
+                if causal:  # the diagonal: kb == j
+                    mask = lax.broadcasted_iota(
+                        jnp.int32, (block, block), 1) >= row
+                if t_actual < nblocks * block:  # K rows past the end
+                    mask = _and(mask, kb * block + row < t_actual)
+            if packed:
+                # sk_ref[kb] is [block, 1], sq_ref[j] [1, block]
+                mask = _and(mask, sk_ref[kb] == sq_ref[j])
+            if mask is not None:
+                st = jnp.where(mask, st, _NEG_INF)
+            m = jnp.max(st, axis=0, keepdims=True)
+            if carry is not None:
+                m = jnp.maximum(carry[0], m)
+            # A row that has seen no key yet keeps p = 0, not exp(0).
+            m_safe = m if mask is None else jnp.where(m <= _NEG_INF, 0.0, m)
+            pt = jnp.exp(st - m_safe)
+            l = jnp.sum(pt, axis=0, keepdims=True)
+            acc = lax.dot_general(  # vᵀ·pᵀ
+                v, pt.astype(v.dtype), _TN,
+                preferred_element_type=jnp.float32)
+            if carry is not None:
+                corr = jnp.exp(carry[0] - m_safe)
+                l += carry[1] * corr
+                acc += carry[2] * corr
+            return m, l, acc
 
-    @pl.when(run)
-    def _compute():
-        # Both matmuls run in the input dtype (bf16 fast path) with f32
-        # accumulation; softmax state is f32 throughout.
-        s = (
-            jax.lax.dot_general(
-                q_ref[:],
-                k_ref[:],
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )  # [block_q, block_k]
+        m, l, acc = lax.fori_loop(0, first, tile, tile(first, None))
+        seen = l > 0.0
+        l = jnp.where(seen, l, 1.0)
+        o_ref[j] = (acc / l).astype(o_ref.dtype)
+        lse_ref[j] = jnp.where(seen, m + jnp.log(l), _NEG_INF)
+        return j + 1
 
-        k_pos = kk * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = k_pos < t_actual
-        if causal:
-            q_pos = qj * block_q + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            mask = jnp.logical_and(mask, q_pos >= k_pos)
-        if packed:
-            # Packed sequences: tokens attend only within their own
-            # segment (sq_ref is [block_q, 1], sk_ref [1, block_k]).
-            mask = jnp.logical_and(mask, sq_ref[:] == sk_ref[:])
-        s = jnp.where(mask, s, _NEG_INF)
-
-        m_prev = m_ref[:, :1]  # [block_q, 1]
-        blk_max = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, blk_max)
-        m_safe = jnp.where(m_new <= _NEG_INF, 0.0, m_new)
-        p = jnp.exp(s - m_safe)
-        p = jnp.where(mask, p, 0.0)
-        corr = jnp.exp(jnp.where(m_prev <= _NEG_INF, _NEG_INF, m_prev) - m_safe)
-        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        # p @ v runs in the input dtype (bf16 on the fast path) with f32
-        # accumulation — the standard flash trade; scores stay f32.
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p.astype(v_ref.dtype),
-            v_ref[:],
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-
-    @pl.when(kk == nk - 1)
-    def _finalize():
-        l = l_ref[:, :1]
-        o_ref[:] = (
-            acc_ref[:] / jnp.where(l == 0.0, 1.0, l)
-        ).astype(o_ref.dtype)
-        m = m_ref[:, :1]
-        lse = jnp.where(l == 0.0, _NEG_INF, m + jnp.log(jnp.maximum(l, 1e-37)))
-        # lse is [block_q, 1]; the output carries 128 equal lanes (the
-        # minimum TPU tile width) — lane 0 is read back by the wrapper.
-        lse_ref[:] = jnp.broadcast_to(lse, lse_ref.shape)
+    # A while_loop and not a fori_loop, which would be a scan here: in
+    # interpret mode a scan cannot carry refs that vary under shard_map.
+    lax.while_loop(lambda j: j < nblocks, q_block, 0)
 
 
 def _sds(shape, dtype, like: jax.Array) -> jax.ShapeDtypeStruct:
@@ -253,93 +251,111 @@ def _pad_seg(seg: jax.Array, block: int) -> jax.Array:
     return _pad_axis(seg, 1, block, -1)
 
 
+# One tile rule for both kernels, so they cut a sequence the same way:
+# 512 is the fastest edge on the v5e, forward and backward, at T = 1024,
+# D = 64 and at T = 4096, D = 128 (PERF.md, PR 26 and PR 28).
+_MAX_TILE = 512
+
+
+def _tile_edge(t: int, cap: int) -> int:
+    """The kernels' tile edge for sequence length ``t``: square, a
+    multiple of 128 lanes that divides ``t`` rounded up to 128, as large
+    as ``cap`` (the smaller of the caller's blocks) and 512 allow.  A
+    short sequence is one tile; a caller that asked for tiles under 128
+    gets them."""
+    if cap < _LANES or t <= _LANES:
+        return min(cap, -(-max(t, 16) // 16) * 16)
+    n = -(-t // _LANES)
+    return _LANES * max(
+        u for u in range(1, min(cap, _MAX_TILE) // _LANES + 1) if n % u == 0)
+
+
+def _blocked(x: jax.Array, block: int) -> jax.Array:
+    """[B, H, T, ...] -> [B, H, n, block, ...], T padded up to n blocks:
+    a kernel picks a block by an index on an untiled dim."""
+    x = _pad_axis(x, 2, block)
+    return x.reshape(x.shape[:2] + (-1, block) + x.shape[3:])
+
+
 def _flash_forward(
     qt: jax.Array,
     kt: jax.Array,
     vt: jax.Array,
     causal: bool,
     scale: float,
-    block_q: int,
-    block_k: int,
+    block: int,
     segments: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Forward kernel on [B, H, T, D] operands: (out [B, H, T, D],
-    lse [B, H, T])."""
+    lse [B, H, T] float32).
+
+    The grid is (batch, head), so no grid step is without work; one
+    head's q, k, v and output stay in VMEM meanwhile (2 KB a token at
+    D <= 128 in bf16, 3 KB with segment ids), which bounds T at some
+    45 000 a device, 30 000 packed."""
     b, h, t, d = qt.shape
-    block_q = min(block_q, max(t, 16))
-    block_k = min(block_k, max(t, 16))
-    qp = _pad_axis(qt, 2, block_q)
-    kp = _pad_axis(kt, 2, block_k)
-    vp = _pad_axis(vt, 2, block_k)
-    tq, tk = qp.shape[2], kp.shape[2]
-    nq, nk = tq // block_q, tk // block_k
+    block = _tile_edge(t, block)
+    q, k, v = (_blocked(x, block) for x in (qt, kt, vt))
+    n = q.shape[2]
+    tp = n * block
+
+    head = lambda b_, h_: (b_, h_, 0, 0, 0)
+    per_head = pl.BlockSpec((None, None, n, block, d), head)
+    inputs = [q, k, v]
+    in_specs = [per_head] * 3
+    if segments is not None:
+        seg = _pad_seg(jnp.asarray(segments, jnp.int32), tp)
+        # along lanes for the Q side, along sublanes for the K side
+        inputs += [seg.reshape(b, n, 1, block), seg.reshape(b, n, block, 1)]
+        in_specs += [
+            pl.BlockSpec((None, n, 1, block), lambda b_, h_: (b_, 0, 0, 0)),
+            pl.BlockSpec((None, n, block, 1), lambda b_, h_: (b_, 0, 0, 0)),
+        ]
 
     kernel = functools.partial(
         _flash_fwd_kernel,
         scale=scale,
         causal=causal,
         packed=segments is not None,
-        block_q=block_q,
-        block_k=block_k,
+        block=block,
         t_actual=t,
-        nk=nk,
+        nblocks=n,
     )
-    in_specs = [
-        pl.BlockSpec((None, None, block_q, d), lambda b_, h_, j, kk: (b_, h_, j, 0)),
-        pl.BlockSpec((None, None, block_k, d), lambda b_, h_, j, kk: (b_, h_, kk, 0)),
-        pl.BlockSpec((None, None, block_k, d), lambda b_, h_, j, kk: (b_, h_, kk, 0)),
-    ]
-    inputs = [qp, kp, vp]
-    if segments is not None:
-        seg = jnp.asarray(segments, jnp.int32)
-        # [B, Tq, 1] / [B, 1, Tk] so the blocks arrive pre-oriented for
-        # the (block_q, block_k) mask broadcast.
-        inputs.append(_pad_seg(seg, block_q)[:, :, None])
-        inputs.append(_pad_seg(seg, block_k)[:, None, :])
-        in_specs.append(pl.BlockSpec(
-            (None, block_q, 1), lambda b_, h_, j, kk: (b_, j, 0)
-        ))
-        in_specs.append(pl.BlockSpec(
-            (None, 1, block_k), lambda b_, h_, j, kk: (b_, 0, kk)
-        ))
+    # VMEM: what stays per head (q, k, v and the output twice for the
+    # pipeline, lanes padded to 128; the K side's segment ids at a lane
+    # row each) and a dozen float32 tiles.
+    resident = tp * (8 * max(d, _LANES) * qt.dtype.itemsize
+                     + (8 * _LANES if segments is not None else 0))
+    vmem = min(100 << 20, max(32 << 20, resident + 12 * 4 * block * block))
     # No ``name=`` here, unlike the backward's call: jax enters a named
     # scope of that name, and the benchmark's flash_fwd_roofline finds
     # this kernel by ".../attn/pallas_call" in its scope path
     # (benchmark/layer_metrics/flash_fwd_roofline.py).
     out, lse = pl.pallas_call(
         kernel,
-        grid=(b, h, nq, nk),
+        grid=(b, h),
         in_specs=in_specs,
+        # Rows along lanes: a Q block's oᵀ is [D, block] and its row
+        # logsumexp [1, block], one float32 a row, as the backward reads it.
         out_specs=[
-            pl.BlockSpec((None, None, block_q, d), lambda b_, h_, j, kk: (b_, h_, j, 0)),
-            pl.BlockSpec(
-                (None, None, block_q, _LANES),
-                lambda b_, h_, j, kk: (b_, h_, j, 0),
-            ),
+            pl.BlockSpec((None, None, n, d, block), head),
+            pl.BlockSpec((None, None, n, 1, block), head),
         ],
         out_shape=[
-            _sds((b, h, tq, d), qt.dtype, qp),
-            _sds((b, h, tq, _LANES), jnp.float32, qp),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            _sds((b, h, n, d, block), qt.dtype, qt),
+            _sds((b, h, n, 1, block), jnp.float32, qt),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=vmem,
         ),
         interpret=_interpret(),
     )(*inputs)
-    return out[:, :, :t], lse[:, :, :t, 0]
-
-
-# a·bᵀ as dot_general dimension numbers: the minor dims contract.
-_NT = (((1,), (1,)), ((), ()))
-
-
-def _and(mask, other):
-    return other if mask is None else jnp.logical_and(mask, other)
+    # oᵀ -> o is a swap of the minor two dims.  Folded into the caller's
+    # copy to [B, T, H, D] as one transpose it costs the step 3% more
+    # (PERF.md, PR 28).
+    out = out.transpose(0, 1, 2, 4, 3).reshape(b, h, tp, d)
+    return out[:, :, :t], lse.reshape(b, h, tp)[:, :, :t]
 
 
 def _flash_bwd_kernel(
@@ -416,22 +432,6 @@ def _flash_bwd_kernel(
     dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
 
-_BWD_BLOCK = 512  # fastest on the v5e at T = 1024, D = 64 (PERF.md, PR 26)
-
-
-def _bwd_block(t: int, cap: int) -> int:
-    """The backward's tile edge for sequence length ``t``: square, a
-    multiple of 128 lanes that divides ``t`` rounded up to 128, as large
-    as ``cap`` (the smaller of the forward's blocks) and 512 allow.  A
-    short sequence is one tile; a caller that asked for tiles under 128
-    gets them here too."""
-    if cap < _LANES or t <= _LANES:
-        return min(cap, -(-max(t, 16) // 16) * 16)
-    n = -(-t // _LANES)
-    return _LANES * max(
-        u for u in range(1, min(cap, _BWD_BLOCK) // _LANES + 1) if n % u == 0)
-
-
 def _flash_backward(
     qt: jax.Array,
     kt: jax.Array,
@@ -451,13 +451,10 @@ def _flash_backward(
     its float32 dq stay in VMEM meanwhile (about 1.5 KB a token at
     D = 64), which bounds T at some tens of thousands a device."""
     b, h, t, d = qt.shape
-    block = _bwd_block(t, block)
+    block = _tile_edge(t, block)
     tp = -(-t // block) * block
     n = tp // block
-
-    def q_side(x):  # [B, H, T, ...] -> [B, H, n, block, ...]
-        x = _pad_axis(x, 2, tp)
-        return x.reshape(x.shape[:2] + (n, block) + x.shape[3:])
+    q_side = functools.partial(_blocked, block=block)
 
     # Row statistics as [.., n, 1, block]: a Q block's row lies along
     # lanes, and the kernel picks it by an index on an untiled dim.
@@ -569,7 +566,8 @@ def _flash_fwd_res(q, k, v, causal, scale, block_q, block_k, segments=None):
     backward does not run the forward kernel again)."""
     qt, kt, vt = (checkpoint_name(_bhtd(x), "flash_qkv") for x in (q, k, v))
     out_t, lse = _flash_forward(
-        qt, kt, vt, causal, _scale_of(scale, q), block_q, block_k, segments)
+        qt, kt, vt, causal, _scale_of(scale, q), min(block_q, block_k),
+        segments)
     out = checkpoint_name(_bhtd(out_t), "flash_out")
     lse = checkpoint_name(lse, "flash_out")
     return out, (qt, kt, vt, out, lse)
@@ -641,15 +639,17 @@ def flash_attention(
 ) -> jax.Array:
     """Fused flash attention: [B, T, H, D] → [B, T, H, D].
 
-    Forward is a Pallas kernel: the [T,T] score matrix never leaves
-    VMEM — each (q-block, k-block) tile is a pair of MXU matmuls with
-    online softmax carried in VMEM scratch, causal upper blocks skipped.
-    Backward is a Pallas kernel too (``_flash_bwd_kernel``): it
-    recomputes each tile's scores in VMEM from the saved logsumexp
-    (flash identities) and gives dq, dk and dv in one pass, causal upper
-    blocks skipped, so neither pass writes a [T,T] array to HBM.
-    ``block_q``/``block_k`` are the forward's tile; the backward takes
-    its own from ``T``, never above the smaller of the two.  Numerics match
+    Both passes are Pallas kernels on one plan (``_flash_fwd_kernel``,
+    ``_flash_bwd_kernel``): the [T,T] score matrix never leaves VMEM.
+    A tile is held transposed, keys by queries, so row statistics lie
+    along lanes; the forward walks a Q block's K blocks inside the
+    kernel with the online softmax carried as values and writes one
+    float32 logsumexp a row, from which the backward recomputes each
+    tile's scores (flash identities) and gives dq, dk and dv in one
+    pass.  Causal upper tiles are visited by neither.  One tile rule
+    serves both kernels (``_tile_edge``): square, taken from ``T``,
+    with ``block_q``/``block_k`` as caps (the smaller of the two), so
+    both cut a sequence the same way.  Numerics match
     ``parallel.ring_attention.full_attention`` to fp tolerance.
 
     ``segment_ids`` ([B, T] int32) enables packed-sequence attention:

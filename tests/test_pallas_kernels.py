@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from horovod_tpu.ops import pallas_kernels
 from horovod_tpu.ops.pallas_kernels import flash_attention, scale_buffer
 from horovod_tpu.parallel.ring_attention import full_attention
 
@@ -55,21 +56,32 @@ def test_flash_attention_rejects_unequal_seq_lens():
         flash_attention(q, kv, kv)
 
 
-@pytest.mark.parametrize(
-    "b,t,h,d,causal",
-    [
-        (2, 128, 4, 64, False),
-        (2, 128, 4, 64, True),
-        (1, 100, 2, 32, True),   # ragged T → padding path
-        (1, 257, 3, 64, False),  # ragged, multiple blocks
-        (1, 320, 2, 128, True),  # heads of 128 (Ouro), five blocks
-    ],
-)
-def test_flash_attention_forward(b, t, h, d, causal):
-    rng = jax.random.PRNGKey(0)
-    q, k, v = jax.random.normal(rng, (3, b, t, h, d), jnp.float32)
+# (b, t, h, d), causal, the caller's (block_q, block_k).  Both kernels take
+# their tile from T and the smaller cap: 64 here, else a multiple of 128.
+_FORWARD_CASES = {
+    "one_tile": ((2, 128, 4, 64), False, (64, 64)),
+    "one_tile_causal": ((2, 128, 4, 64), True, (64, 64)),
+    "ragged": ((1, 100, 2, 32), True, (64, 64)),  # ragged T → padding path
+    "ragged_blocks": ((1, 257, 3, 64), False, (64, 64)),
+    "d128_blocks": ((1, 320, 2, 128), True, (64, 64)),  # heads of 128 (Ouro)
+    # T = 1100: three tiles of 384 from the shapes alone, 52 rows of padding
+    "padded_three_tiles": ((1, 1100, 2, 32), True, (512, 512)),
+    "padded_three_tiles_noncausal": ((1, 1100, 2, 32), False, (512, 512)),
+    # heads of 128, five tiles of 128
+    "d128_tiles": ((1, 640, 2, 128), True, (512, 512)),
+    "d128_tiles_noncausal": ((1, 640, 2, 128), False, (512, 512)),
+    # blocks capped under 128 by the caller, and unequal: tiles of 32
+    "capped_blocks": ((2, 200, 2, 32), True, (48, 32)),
+    "capped_blocks_noncausal": ((2, 200, 2, 32), False, (32, 48)),
+}
+
+
+@pytest.mark.parametrize("case", list(_FORWARD_CASES))
+def test_flash_attention_forward(case):
+    shape, causal, (block_q, block_k) = _FORWARD_CASES[case]
+    q, k, v = jax.random.normal(jax.random.PRNGKey(0), (3,) + shape)
     ref = full_attention(q, k, v, causal=causal)
-    out = flash_attention(q, k, v, causal, None, 64, 64)
+    out = flash_attention(q, k, v, causal, None, block_q, block_k)
     assert out.shape == ref.shape and out.dtype == ref.dtype
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
@@ -81,6 +93,81 @@ def _segments(b, t, cuts):
     for i, c in enumerate(cuts):
         seg[:, c:] = i + 1
     return jnp.asarray(seg)
+
+
+def _logsumexp_reference(q, k, causal, seg):
+    """The row logsumexp of the scaled, masked scores, [B, H, T] float32."""
+    t = q.shape[1]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    mask = jnp.ones((t, t), bool)
+    if causal:
+        mask = jnp.tril(mask)
+    mask = mask[None, None]
+    if seg is not None:
+        mask = mask & (seg[:, None, :, None] == seg[:, None, None, :])
+    return jax.nn.logsumexp(jnp.where(mask, s, -jnp.inf), axis=-1)
+
+
+# (b, t, h, d), segment cuts, the caller's blocks
+_LSE_CASES = {
+    "padded_three_tiles": ((1, 1100, 2, 32), None, (512, 512)),
+    "d128_tiles": ((1, 640, 2, 128), None, (512, 512)),
+    "packed_capped_blocks": ((2, 70, 2, 8), (23, 41), (16, 16)),
+    "packed_tiles": ((1, 300, 2, 32), (77, 130, 260), (128, 128)),
+}
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("case", list(_LSE_CASES))
+def test_flash_forward_logsumexp(case, causal):
+    """The residual the backward kernel rebuilds every tile's p from:
+    one float32 a row, [B, H, T], against a float32 reference."""
+    shape, cuts, (block_q, block_k) = _LSE_CASES[case]
+    q, k, v = jax.random.normal(jax.random.PRNGKey(4), (3,) + shape)
+    seg = None if cuts is None else _segments(shape[0], shape[1], cuts)
+    _, (_, _, _, _, lse) = pallas_kernels._flash_fwd_res(
+        q, k, v, causal, None, block_q, block_k, seg)
+    b, t, h, _ = shape
+    assert lse.shape == (b, h, t) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(_logsumexp_reference(q, k, causal, seg)),
+        atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_packed_padding_rows(causal, monkeypatch):
+    """A packed row whose documents end inside a tile (at 77, 130 and
+    260 of tiles of 128) and whose padding, 300 to 384, carries the
+    wrapper's -1: such a row sees no key at all, and the kernel gives it
+    0 and a logsumexp of -1e30 (not exp(0) a key), where the wrapper
+    cuts it off; every row inside T is finite and the reference's."""
+    raw = []
+    real = pallas_kernels.pl.pallas_call
+
+    def spy(*args, **kwargs):
+        call = real(*args, **kwargs)
+
+        def run(*operands):
+            raw.append(call(*operands))
+            return raw[-1]
+
+        return run
+
+    monkeypatch.setattr(pallas_kernels.pl, "pallas_call", spy)
+    b, t, h, d = 1, 300, 2, 32
+    q, k, v = jax.random.normal(jax.random.PRNGKey(5), (3, b, t, h, d))
+    seg = _segments(b, t, (77, 130, 260))
+    out = flash_attention(q, k, v, causal, None, 128, 128, segment_ids=seg)
+    ref = full_attention(q, k, v, causal=causal, segment_ids=seg)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
+    (out_raw, lse_raw), = raw
+    # the kernel's own layouts: oᵀ [B, H, n, D, block], lse [B, H, n, 1, block]
+    out_raw = np.asarray(out_raw).transpose(0, 1, 2, 4, 3).reshape(b, h, 384, d)
+    lse_raw = np.asarray(lse_raw).reshape(b, h, 384)
+    assert np.isfinite(lse_raw[:, :, :t]).all()
+    assert (out_raw[:, :, t:] == 0).all() and (lse_raw[:, :, t:] <= -1e30).all()
 
 
 def _grads(attn, q, k, v, **kw):

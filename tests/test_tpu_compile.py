@@ -10,6 +10,7 @@ and in this file only: one process may hold the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -90,6 +91,51 @@ def test_flash_attention_grad_compiles_for_the_chip(
     b, t, h, d = shape
     scores = b * h * t * t * 4
     assert compiled.memory_analysis().temp_size_in_bytes < scores / 2
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        else:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("packed", [False, True])
+@pytest.mark.parametrize("shape", [
+    (16, 1024, 12, 64),  # gpt2s.dense / gpt2s.dp4 / gpt2s.packed
+    (2, 4096, 16, 128),  # ouro26b.ring2x4096
+])
+def test_flash_attention_forward_is_one_call_on_a_grid_of_heads(
+        one_chip, mosaic, shape, packed):
+    """What the benchmark's forward rooflines count on: the forward is
+    exactly one Mosaic call, found under ".../attn/pallas_call" and not
+    under flash_bwd.  Its grid is (batch, head): no K-block axis whose
+    steps could be empty.  And the row logsumexp leaves it one float32 a
+    row: neither an operand nor a result is a float32 array with 128
+    lanes of row statistics."""
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct(shape[:2], jnp.int32, sharding=one_chip)
+
+    def forward(q, k, v, seg):
+        with jax.named_scope("attn"):
+            return pallas_kernels.flash_attention(
+                q, k, v, True, segment_ids=seg if packed else None)
+
+    call, = _pallas_calls(jax.make_jaxpr(forward)(x, x, x, seg).jaxpr)
+    assert call.params["grid_mapping"].grid == (shape[0], shape[2])
+
+    text = jax.jit(forward).lower(x, x, x, seg).compile().as_text()
+    line, = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert "attn/pallas_call" in line and "flash_bwd" not in line
+    signature = line.split("custom_call_target")[0]
+    statistics = re.findall(r"f32\[([\d,]+)\]", signature)
+    assert statistics  # the logsumexp, [B, H, n, 1, block]
+    for dims in statistics:
+        assert not dims.endswith(",128"), dims
+    b, t, h, _ = shape
+    assert f"f32[{b},{h},{t // 512},1,512]" in signature
 
 
 def test_scale_buffer_compiles_for_the_chip(one_chip, mosaic):
