@@ -99,33 +99,9 @@ def main():
     center, context = synthetic_pairs(args.num_samples)
     steps = min(args.steps, args.num_samples // global_batch)
 
-    # The sparse exchange runs through the exchange IR by default
-    # (HVD_TPU_XIR=on routes the allgather-of-slices as a
-    # gather_dense_from_sparse program — docs/exchange_ir.md).  Prove
-    # the parity contract in-script before training: two steps from
-    # identical state, IR on vs off, must produce bitwise-equal losses.
-    check = []
-    for flag in (True, False):
-        hvd.xir.set_enabled_override(flag)
-        try:
-            p, st = params, tx.init(params)
-            s = make_step()
-            ls = []
-            for i in range(2):
-                c = jnp.asarray(center[i * global_batch:(i + 1) * global_batch])
-                t = jnp.asarray(context[i * global_batch:(i + 1) * global_batch])
-                p, st, loss = s(p, st, c, t)
-                ls.append(float(loss))
-            check.append(ls)
-        finally:
-            hvd.xir.set_enabled_override(None)
-    assert check[0] == check[1], \
-        f"exchange-IR parity violated: {check[0]} vs {check[1]}"
-    a2a = hvd.metrics.get_counter("xir.programs.sparse_embed")
-    if hvd.rank() == 0:
-        print(f"exchange-IR parity OK (IR on == off bitwise over "
-              f"{len(check[0])} steps; {a2a} sparse programs)")
-
+    # The sparse exchange runs through the exchange IR: the
+    # allgather-of-slices is a gather_dense_from_sparse program
+    # (docs/exchange_ir.md).
     step = make_step()
     for i in range(steps):
         lo = i * global_batch

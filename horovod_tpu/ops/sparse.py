@@ -100,20 +100,13 @@ def densify(s: IndexedSlices) -> jax.Array:
 def _routed_gather(s: IndexedSlices, axis, process_set):
     """The embedding exchange through the exchange IR: one
     ``gather_dense_from_sparse`` op (allgather of indices + values).
-    The interpreter emits the identical ``traced.allgather`` pair on
-    the dense wire (``HVD_TPU_XIR=off`` calls them directly — bitwise
-    either way); a bf16 ``HVD_TPU_XIR_WIRE`` request casts only the
+    The interpreter emits a ``traced.allgather`` pair on the dense
+    wire; a bf16 ``HVD_TPU_XIR_WIRE`` request casts only the
     values leg, indices always ride dense int wire.  The exchange gains
     the SPARSE_EMBED_EXCHANGE timeline lane, kind-labeled byte gauges,
     and a persistent-store key."""
     from .. import xir
 
-    if not xir.enabled():
-        idx = traced.allgather(s.indices, axis=axis,
-                               process_set=process_set)
-        vals = traced.allgather(s.values, axis=axis,
-                                process_set=process_set)
-        return idx, vals
     op = xir.gather_dense_from_sparse(
         axis, wire=xir.wire_request(),
         set_ranks=(tuple(process_set.ranks)

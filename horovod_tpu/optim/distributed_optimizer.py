@@ -28,7 +28,7 @@ transform in your own shard_map.
 
 from __future__ import annotations
 
-from typing import Any, Callable, NamedTuple, Optional, Sequence
+from typing import Any, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -113,6 +113,281 @@ def _adasum_hier_eligible(axis, process_set) -> bool:
     return topo.factor_axis(jax.lax.axis_size(axis))[0] > 1
 
 
+def _check_quantized_compressor(op, axis, process_set) -> None:
+    """What a quantized compressor (``Compression.int8``/``fp8``, or the
+    autotune probe's forced wire) needs of the reduction.  Checked before
+    the sparse split so that it also covers all-sparse trees and sparse
+    leaves, which would otherwise silently ship fp32 through the
+    identity compressor."""
+    if op not in (Average, Sum):
+        # Narrowed raise (PR 10): hierarchical Adasum quantizes only
+        # the DCN hop (the intra-slice sum stays dense), so a
+        # cross-slice topology serves Compression.int8/fp8 + Adasum
+        # through the hier_adasum lowering.  Flat Adasum (single
+        # slice, process subsets, multi-axis) still raises — the
+        # VHDD tree has no quantized form.
+        if not (op == Adasum and _adasum_hier_eligible(axis, process_set)):
+            raise QuantizedWireError(
+                "the quantized wire requires op=Average/Sum "
+                "(ops/quantized.py); flat Adasum has no quantized "
+                "lowering — on a cross-slice topology hier_adasum "
+                "quantizes just the DCN hop (docs/adasum.md)"
+            )
+    if process_set is not None and process_set.process_set_id != 0:
+        # v2 serves sets that tile the axis into equal replica
+        # groups (the phase collectives ride replica_groups);
+        # anything else raises rather than silently going dense.
+        table = get_runtime().process_set_table
+        if table.partition_groups(process_set) is None and \
+                len(process_set.ranks) != table.world_size:
+            raise QuantizedWireError(
+                f"the quantized wire serves the global set or sets "
+                f"that tile the axis into equal replica groups; "
+                f"{process_set!r} does neither — use the dense "
+                "path for arbitrary subsets"
+            )
+
+
+def _check_wire_request(wire_req: str, op, axis, process_set) -> None:
+    """Satellite contract: a quantized per-bucket wire (the compressor's
+    or ``HVD_TPU_SCHED_WIRE``'s) raises instead of silently degrading
+    when the reduction shape cannot carry it (non-Sum/Average ops,
+    multi-axis reductions; process sets were validated before the
+    sparse split, non-tiling ones at trace time).  Adasum is the
+    narrowed exception: on a cross-slice topology the hier_adasum
+    lowering quantizes just the DCN hop, so only *flat* Adasum still
+    raises."""
+    if wire_req not in ("int8", "fp8"):
+        return
+    if op not in (Average, Sum) and not (
+        op == Adasum and _adasum_hier_eligible(axis, process_set)
+    ):
+        raise QuantizedWireError(
+            f"quantized wire {wire_req!r} requires op=Average/"
+            "Sum; flat Adasum and min/max reductions have no "
+            "quantized lowering — unset HVD_TPU_SCHED_WIRE or "
+            "use a cast compressor (cross-slice topologies "
+            "quantize Adasum's DCN hop via hier_adasum)"
+        )
+    if not isinstance(axis, str):
+        raise QuantizedWireError(
+            f"quantized wire {wire_req!r} needs one named mesh "
+            f"axis (got {axis!r}); the all_to_all phase has no "
+            "multi-axis form"
+        )
+
+
+def _reduce_sparse_leaf(
+    s, *, axis, op, compression, prescale_factor, postscale_factor,
+    process_set,
+) -> jax.Array:
+    """One ``IndexedSlices`` gradient: allgather of slices, densified.
+    Same wire semantics as the dense path: compress the payload,
+    prescale before the collective, postscale after."""
+    from ..ops.sparse import IndexedSlices, densify, sparse_allreduce
+
+    wire, ctx = compression.compress(s.values)
+    if prescale_factor != 1.0:
+        wire = wire * jnp.asarray(prescale_factor, wire.dtype)
+    out = sparse_allreduce(
+        IndexedSlices(s.indices, wire, s.dense_shape),
+        axis=axis, op=op, process_set=process_set,
+    )
+    vals = compression.decompress(out.values, ctx)
+    if postscale_factor != 1.0:
+        vals = vals * jnp.asarray(postscale_factor, vals.dtype)
+    reduced = densify(IndexedSlices(out.indices, vals, s.dense_shape))
+    if process_set is not None:
+        # Non-members keep their own local gradient (the dense
+        # path's jnp.where(mask, y, x) pass-through,
+        # traced.py:236); allgather hands them zeros or foreign
+        # slices instead, so mask at the densified level.
+        from ..ops.traced import _set_info
+
+        _, mask, _, _ = _set_info(axis, process_set)
+        if mask is not None:
+            reduced = jnp.where(mask, reduced, densify(s))
+    return reduced
+
+
+class _DensePlan(NamedTuple):
+    """The plan stage's verdict on one dense exchange: the bucket
+    schedule and which lowerings the reduction's shape admits."""
+
+    schedule: Any
+    hier_ok: bool    # two-level ICI/DCN staging of a sum/average
+    adasum_ok: bool  # hier_adasum: adaptive summation across slices
+    rs_ok: bool      # reduce_scatter + all_gather per dense bucket
+
+
+def _plan_dense(
+    wire, *, quantized, compression, axis, op, process_set,
+    fusion_threshold_bytes, groups, lowering,
+) -> _DensePlan:
+    """Plan the dense leaves' exchange in reverse-backward order
+    (observed by the grad-boundary taps when TrainStep armed them)."""
+    import dataclasses
+
+    from .. import sched
+    from ..parallel.tensor import _axis_present
+
+    cfg = sched.current_config()
+    if cfg.bucket_bytes is None and fusion_threshold_bytes is not None:
+        cfg = dataclasses.replace(cfg, bucket_bytes=fusion_threshold_bytes)
+    # Per-bucket wire request: an explicit quantized compressor
+    # wins; otherwise the HVD_TPU_SCHED_WIRE / tuner choice rides.
+    wire_req = (
+        getattr(compression, "wire_format", "int8") if quantized
+        else cfg.wire
+    )
+    _check_wire_request(wire_req, op, axis, process_set)
+    # Hierarchical (ICI/DCN) lowerings need one named axis and the
+    # global set (topology groups factor the whole axis).  A plain
+    # sum/average takes the cost model's per-bucket choice; op=Adasum
+    # rides the same machinery (ROADMAP 5a) as hier_adasum — the
+    # reference's AdasumGpuAllreduceOp schedule (sum inside the slice,
+    # adaptive summation across) — unless the lowering is forced flat,
+    # in which case (and on single-slice topologies, where the plan
+    # resolves flat anyway) the flat VHDD tree serves the bucket.
+    # Ineligible shapes stay flat.
+    one_axis_global = (
+        isinstance(axis, str)
+        and _axis_present(axis)
+        and (process_set is None or process_set.process_set_id == 0)
+    )
+    hier_ok = op in (Average, Sum) and one_axis_global
+    adasum_ok = op == Adasum and one_axis_global
+    req_lowering = cfg.lowering if lowering is None else lowering
+    if hier_ok:
+        lower_req = req_lowering
+    elif adasum_ok:
+        lower_req = "flat" if req_lowering == "flat" else "hier_adasum"
+    else:
+        lower_req = "flat"
+    # Wire bytes per element: 1 on the int8 path (the in-memory
+    # tensors stay fp32 there — compress() is identity), so buckets
+    # fill to the intended wire-size threshold.
+    sizes = [w.size * (1 if quantized else w.dtype.itemsize) for w in wire]
+    schedule = sched.build_schedule(
+        sizes, [str(w.dtype) for w in wire], cfg,
+        order=sched.hooks.consume_order(len(wire)),
+        # Explicit tensor groups (reference optimizer.py:128-162
+        # `groups`): each listed group fuses atomically; ungrouped
+        # tensors bucket by threshold.
+        pinned=[list(g) for g in groups] if groups is not None else [],
+        wire=wire_req,
+        lowering=lower_req,
+        axis_size=(
+            jax.lax.axis_size(axis) if (hier_ok or adasum_ok) else None
+        ),
+    )
+    # reduce_scatter+all_gather exchange (arXiv:2004.13336) needs a
+    # plain sum/average over one whole-world axis; anything else
+    # (Adasum, process sets, multi-axis) keeps the allreduce
+    # lowering per dense bucket.  Quantized buckets have their own
+    # RS+AG lowering (for them the decomposition IS the allreduce),
+    # so both sched modes run quantized end-to-end.
+    rs_ok = (
+        cfg.mode == "reduce_scatter"
+        and op in (Average, Sum)
+        and (process_set is None or process_set.process_set_id == 0)
+        and isinstance(axis, str)
+    )
+    return _DensePlan(schedule, hier_ok, adasum_ok, rs_ok)
+
+
+def _bucket_reducer(
+    plan: _DensePlan, res_out, *, quantized, compression, axis, op,
+    prescale_factor, postscale_factor, process_set,
+):
+    """``reduce_flat(flat, bucket)`` for :func:`sched.exchange`: which
+    collective a bucket gets, by its ``lowering`` and ``wire``.
+    ``res_out`` (the error-feedback residual leaves, or None) is
+    updated in place by quantized buckets."""
+    from .. import sched
+
+    scale = dict(
+        prescale_factor=prescale_factor, postscale_factor=postscale_factor,
+    )
+
+    def allreduce_flat(f):
+        # Quantized compressor: the quantization lives inside the
+        # two-phase reduction, so the bucket dispatches to the
+        # quantized primitives instead of cast-allreduce-cast.
+        # Pre/postscale fold into the fp32 accumulation outside the
+        # quantizer.
+        if quantized and jnp.issubdtype(f.dtype, jnp.floating):
+            from ..ops.quantized import quantized_allreduce
+
+            g = f if prescale_factor == 1.0 else f * prescale_factor
+            g = quantized_allreduce(
+                g, axis=axis, op=op, process_set=process_set,
+                wire=getattr(compression, "wire_format", "int8"),
+            )
+            return g if postscale_factor == 1.0 else g * postscale_factor
+        return traced.allreduce(
+            f, axis=axis, op=op, process_set=process_set, **scale
+        )
+
+    def dense_flat(f):
+        if plan.rs_ok and jnp.issubdtype(f.dtype, jnp.floating):
+            return sched.execute.reduce_scatter_flat(
+                f, axis=axis, average=(op == Average), **scale
+            )
+        return allreduce_flat(f)
+
+    def reduce_bucket_flat(f, bucket):
+        if bucket.lowering == "hier_adasum" and (
+            plan.hier_ok or plan.adasum_ok
+        ):
+            # Hierarchical Adasum (both sched modes — the staged
+            # allreduce IS the RS+AG composition): intra-slice sum,
+            # adaptive combination on the 1/k DCN shard, ICI
+            # gather.  The bucket's wire compresses only the DCN
+            # leg; EF does not apply (hier semantics).
+            return sched.execute.hier_adasum_flat(
+                f, axis=axis, average=(op != Sum), wire=bucket.wire,
+                **scale
+            )
+        if bucket.lowering == "hier" and plan.hier_ok:
+            # Two-level ICI/DCN staging (topo/): the bucket's wire
+            # compresses only the cross-slice hop.  EF residuals
+            # don't apply on this lowering (the quantization error
+            # lives on the slice-summed shard, not the gradient) —
+            # hier quantized buckets run EF-free.
+            if plan.rs_ok and jnp.issubdtype(f.dtype, jnp.floating):
+                return sched.execute.hier_reduce_scatter_flat(
+                    f, axis=axis, average=(op == Average),
+                    wire=bucket.wire, **scale
+                )
+            return sched.execute.hier_allreduce_flat(
+                f, axis=axis, average=(op == Average), wire=bucket.wire,
+                **scale
+            )
+        if bucket.wire in ("int8", "fp8"):
+            res_flat, rmeta = None, None
+            if res_out is not None:
+                rf, rmeta = fusion.flatten_group(
+                    [res_out[i] for i in bucket.indices]
+                )
+                res_flat = rf[0]
+            red, r_new = sched.execute.quantized_exchange_flat(
+                f, axis=axis, average=(op == Average), wire=bucket.wire,
+                residual=res_flat, process_set=process_set, **scale
+            )
+            if r_new is not None:
+                for i, r in zip(
+                    bucket.indices, fusion.unflatten_group([r_new], rmeta)
+                ):
+                    res_out[i] = r.astype(res_out[i].dtype)
+            return red
+        if bucket.wire == "bf16":
+            return sched.execute.bf16_wire(dense_flat)(f)
+        return dense_flat(f)
+
+    return reduce_bucket_flat
+
+
 def _reduce_gradients(
     grads: Any,
     *,
@@ -127,10 +402,13 @@ def _reduce_gradients(
     sparse_as_dense: bool = False,
     residuals: Any = None,
     lowering: Optional[str] = None,
-    update: Optional[Callable[[Any], Any]] = None,
+    update_follows: bool = False,
 ) -> Any:
     """Bucket, compress, and allreduce a gradient pytree as few fused
-    collectives (the FuseResponses + fusion-buffer path, compiled).
+    collectives (the FuseResponses + fusion-buffer path, compiled) —
+    the bucketed overlap scheduler (``sched/``): plan in
+    reverse-backward order, emit barrier-sequenced per-bucket
+    collectives XLA can overlap with the remaining backward.
 
     ``IndexedSlices`` leaves take the sparse path — allgather of slices
     (reference ``tensorflow/__init__.py:95-162``) — then densify locally
@@ -147,64 +425,24 @@ def _reduce_gradients(
     ``SchedConfig.lowering``) — the Adasum optimizer preset passes
     ``"hier_adasum"``.
 
-    ``update`` (a closure over the *reduced* gradient tree) engages
-    whole-step emission (``HVD_TPU_ONESTEP``): on the scheduler path
-    the decompress+update epilogue is handed to
-    :func:`~horovod_tpu.sched.execute.exchange` and — when the fold is
-    engaged — stitched into the exchange emission itself, so XLA
-    compiles reduce + update as one program.  The call then returns
-    ``update(reduced_tree)`` (with residuals:
-    ``(update_result, new_residuals)``) instead of the reduced tree.
-    Paths the fold does not cover (legacy single-pass, sparse leaves,
-    ``HVD_TPU_ONESTEP=off``) apply ``update`` after the reduction —
-    value-identical, the fold is ordering-only.
+    ``update_follows`` says the caller applies the inner optimizer to
+    the result right away (``update_fn`` without local aggregation); a
+    dense tree's reduced leaves are then tied together before they are
+    decompressed.
     """
-    from ..ops.sparse import IndexedSlices, densify, sparse_allreduce
+    from ..ops.sparse import IndexedSlices, densify
 
-    # Quantized wire (Compression.int8/fp8 or a HVD_TPU_SCHED_WIRE
-    # request) validation happens up front so it also covers all-sparse
-    # trees and sparse leaves (which would otherwise silently ship fp32
-    # through the identity compressor).  The autotune probe can force
-    # the quantized wire on at trace time (third explored knob,
-    # utils/autotune.py) — only ever on, never off: an explicit
-    # Compression.int8 is a user numerics choice.
-    quantized = getattr(compression, "quantized_wire", False)
-    if _quantized_override:
-        quantized = True
+    # --- wire validation.  The autotune probe can force the quantized
+    # wire on at trace time (third explored knob, utils/autotune.py) —
+    # only ever on, never off: an explicit Compression.int8 is a user
+    # numerics choice.
+    quantized = bool(
+        getattr(compression, "quantized_wire", False) or _quantized_override
+    )
     if quantized:
-        if op not in (Average, Sum):
-            from .. import sched as _sched_mod
+        _check_quantized_compressor(op, axis, process_set)
 
-            # Narrowed raise (PR 10): hierarchical Adasum quantizes only
-            # the DCN hop (the intra-slice sum stays dense), so a
-            # cross-slice topology serves Compression.int8/fp8 + Adasum
-            # through the hier_adasum lowering.  Flat Adasum (single
-            # slice, process subsets, multi-axis) still raises — the
-            # VHDD tree has no quantized form.
-            if not (op == Adasum and _sched_mod.current_config().enabled
-                    and _adasum_hier_eligible(axis, process_set)):
-                raise QuantizedWireError(
-                    "the quantized wire requires op=Average/Sum "
-                    "(ops/quantized.py); flat Adasum has no quantized "
-                    "lowering — on a cross-slice topology hier_adasum "
-                    "quantizes just the DCN hop (docs/adasum.md)"
-                )
-        if process_set is not None and process_set.process_set_id != 0:
-            # v2 serves sets that tile the axis into equal replica
-            # groups (the phase collectives ride replica_groups);
-            # anything else raises rather than silently going dense.
-            from ..runtime import get_runtime
-
-            table = get_runtime().process_set_table
-            if table.partition_groups(process_set) is None and \
-                    len(process_set.ranks) != table.world_size:
-                raise QuantizedWireError(
-                    f"the quantized wire serves the global set or sets "
-                    f"that tile the axis into equal replica groups; "
-                    f"{process_set!r} does neither — use the dense "
-                    "path for arbitrary subsets"
-                )
-
+    # --- the sparse split
     is_sparse = lambda x: isinstance(x, IndexedSlices)
     if sparse_as_dense:
         grads = jax.tree.map(
@@ -213,7 +451,7 @@ def _reduce_gradients(
         )
     leaves, treedef = jax.tree.flatten(grads, is_leaf=is_sparse)
     if not leaves:
-        return update(grads) if update is not None else grads
+        return grads
     sparse_idx = [i for i, g in enumerate(leaves) if is_sparse(g)]
     if sparse_idx:
         if quantized:
@@ -230,35 +468,6 @@ def _reduce_gradients(
                 "no Adasum variant); pass sparse_as_dense=True to adasum "
                 "embedding gradients as dense tensors"
             )
-
-        def reduce_sparse(s: IndexedSlices) -> jax.Array:
-            # Same wire semantics as the dense path: compress the
-            # payload, prescale before the collective, postscale after.
-            wire, ctx = compression.compress(s.values)
-            if prescale_factor != 1.0:
-                wire = wire * jnp.asarray(prescale_factor, wire.dtype)
-            out = sparse_allreduce(
-                IndexedSlices(s.indices, wire, s.dense_shape),
-                axis=axis, op=op, process_set=process_set,
-            )
-            vals = compression.decompress(out.values, ctx)
-            if postscale_factor != 1.0:
-                vals = vals * jnp.asarray(postscale_factor, vals.dtype)
-            reduced = densify(
-                IndexedSlices(out.indices, vals, s.dense_shape)
-            )
-            if process_set is not None:
-                # Non-members keep their own local gradient (the dense
-                # path's jnp.where(mask, y, x) pass-through,
-                # traced.py:236); allgather hands them zeros or foreign
-                # slices instead, so mask at the densified level.
-                from ..ops.traced import _set_info
-
-                _, mask, _, _ = _set_info(axis, process_set)
-                if mask is not None:
-                    reduced = jnp.where(mask, reduced, densify(s))
-            return reduced
-
         sparse_set = set(sparse_idx)
         dense_pos = [i for i in range(len(leaves)) if i not in sparse_set]
         if groups is not None:
@@ -272,7 +481,13 @@ def _reduce_gradients(
                     "allgather-of-slices, not fused allreduce)"
                 )
             groups = [[old_to_new[i] for i in g] for g in groups]
-        reduced_sparse = {i: reduce_sparse(leaves[i]) for i in sparse_idx}
+        out = list(leaves)
+        for i in sparse_idx:
+            out[i] = _reduce_sparse_leaf(
+                leaves[i], axis=axis, op=op, compression=compression,
+                prescale_factor=prescale_factor,
+                postscale_factor=postscale_factor, process_set=process_set,
+            )
         dense_reduced = _reduce_gradients(
             [leaves[i] for i in dense_pos],
             axis=axis, op=op, compression=compression,
@@ -281,334 +496,68 @@ def _reduce_gradients(
             fusion_threshold_bytes=fusion_threshold_bytes, groups=groups,
             lowering=lowering,
         )
-        out = list(leaves)
         for i, t in zip(dense_pos, dense_reduced):
             out[i] = t
-        for i, t in reduced_sparse.items():
-            out[i] = t
-        tree = jax.tree.unflatten(treedef, out)
-        # Sparse leaves never fold (allgather-of-slices has no fused
-        # emission); the update applies after, value-identical.
-        return update(tree) if update is not None else tree
+        return jax.tree.unflatten(treedef, out)
 
+    # --- plan
     compressed = [compression.compress(g) for g in leaves]
     wire = [c[0] for c in compressed]
     ctxs = [c[1] for c in compressed]
-
-    # Wire bytes per element: 1 on the int8 path (the in-memory
-    # tensors stay fp32 there — compress() is identity), so buckets
-    # fill to the intended wire-size threshold.
-    wire_itemsize = (
-        (lambda t: 1) if quantized else (lambda t: t.dtype.itemsize)
+    plan = _plan_dense(
+        wire, quantized=quantized, compression=compression, axis=axis,
+        op=op, process_set=process_set,
+        fusion_threshold_bytes=fusion_threshold_bytes, groups=groups,
+        lowering=lowering,
     )
-    sizes = [w.size * wire_itemsize(w) for w in wire]
-    wire_dtypes = [str(w.dtype) for w in wire]
-    if groups is not None:
-        # Explicit tensor groups (reference optimizer.py:128-162 `groups`):
-        # each listed group fuses atomically; ungrouped tensors bucket by
-        # threshold.
-        grouped_idx = set(i for g in groups for i in g)
-        pinned = [list(g) for g in groups]
-        rest = [i for i in range(len(wire)) if i not in grouped_idx]
-    else:
-        pinned = []
-        rest = list(range(len(wire)))
+    res_out = None
+    if residuals is not None:
+        res_out = list(jax.tree.flatten(residuals)[0])
+        if len(res_out) != len(wire):
+            raise ValueError("residuals structure does not match gradients")
 
-    # Quantized wire (Compression.int8/fp8): the quantization lives
-    # inside the two-phase reduction, so the bucket dispatches to the
-    # quantized primitives instead of cast-allreduce-cast.  Pre/postscale
-    # fold into the fp32 accumulation outside the quantizer.
-    def reduce_flat(f):
-        if quantized:
-            from ..ops.quantized import quantized_allreduce
-
-            if not jnp.issubdtype(f.dtype, jnp.floating):
-                return traced.allreduce(
-                    f, axis=axis, op=op,
-                    prescale_factor=prescale_factor,
-                    postscale_factor=postscale_factor,
-                    process_set=process_set,
-                )
-            g = f if prescale_factor == 1.0 else f * prescale_factor
-            g = quantized_allreduce(
-                g, axis=axis, op=op, process_set=process_set,
-                wire=getattr(compression, "wire_format", "int8"),
-            )
-            return g if postscale_factor == 1.0 else g * postscale_factor
-        return traced.allreduce(
-            f, axis=axis, op=op,
-            prescale_factor=prescale_factor,
-            postscale_factor=postscale_factor,
-            process_set=process_set,
-        )
-
-    # Per-bucket hot-path lanes (reference per-tensor activity lanes,
-    # common.h:73-105): a named_scope per bucket lands in the compiled
-    # program's op metadata — the device profiler attributes each fused
-    # collective to its bucket — and, when a timeline is active, the
-    # plan records one event per bucket at trace time so a slow bucket
-    # is identifiable without a full profiler trace.
+    # --- emit.  Per-bucket hot-path lanes (reference per-tensor
+    # activity lanes, common.h:73-105): the exchange puts a named_scope
+    # per bucket into the compiled program's op metadata — the device
+    # profiler attributes each fused collective to its bucket — and,
+    # when a timeline is active, records one event per bucket at trace
+    # time so a slow bucket is identifiable without a full profiler
+    # trace.
+    from .. import sched
     from ..runtime import get_runtime_or_none
 
-    _rt = get_runtime_or_none()
-    tl = _rt.timeline if _rt is not None else None
-
-    from .. import sched as _sched
-
-    cfg = _sched.current_config()
-    if cfg.enabled:
-        # Bucketed overlap scheduler (sched/, default engine): plan in
-        # reverse-backward order (observed by the grad-boundary taps
-        # when TrainStep armed them), emit barrier-sequenced per-bucket
-        # collectives XLA can overlap with the remaining backward.
-        import dataclasses as _dc
-
-        if cfg.bucket_bytes is None and fusion_threshold_bytes is not None:
-            cfg = _dc.replace(cfg, bucket_bytes=fusion_threshold_bytes)
-        # Per-bucket wire request: an explicit quantized compressor
-        # wins; otherwise the HVD_TPU_SCHED_WIRE / tuner choice rides.
-        wire_req = (
-            getattr(compression, "wire_format", "int8") if quantized
-            else cfg.wire
-        )
-        if wire_req in ("int8", "fp8"):
-            # Satellite contract: the quantized wire raises instead of
-            # silently degrading when the reduction shape cannot carry
-            # it (non-Sum/Average ops, multi-axis reductions; process
-            # sets were validated above, non-tiling ones at trace
-            # time).  Adasum is the narrowed exception: on a
-            # cross-slice topology the hier_adasum lowering quantizes
-            # just the DCN hop, so only *flat* Adasum still raises.
-            if op not in (Average, Sum) and not (
-                op == Adasum
-                and _adasum_hier_eligible(axis, process_set)
-            ):
-                raise QuantizedWireError(
-                    f"quantized wire {wire_req!r} requires op=Average/"
-                    "Sum; flat Adasum and min/max reductions have no "
-                    "quantized lowering — unset HVD_TPU_SCHED_WIRE or "
-                    "use a cast compressor (cross-slice topologies "
-                    "quantize Adasum's DCN hop via hier_adasum)"
-                )
-            if not isinstance(axis, str):
-                raise QuantizedWireError(
-                    f"quantized wire {wire_req!r} needs one named mesh "
-                    f"axis (got {axis!r}); the all_to_all phase has no "
-                    "multi-axis form"
-                )
-        # Hierarchical (ICI/DCN) lowering eligibility: one named axis,
-        # plain sum/average, the global set (topology groups factor the
-        # whole axis).  The plan stamps the cost model's per-bucket
-        # choice; ineligible shapes stay flat.
-        from ..parallel.tensor import _axis_present
-
-        hier_ok = (
-            op in (Average, Sum)
-            and isinstance(axis, str)
-            and _axis_present(axis)
-            and (process_set is None or process_set.process_set_id == 0)
-        )
-        # op=Adasum rides the hierarchical machinery too (ROADMAP 5a):
-        # eligible buckets lower hier_adasum — the reference's
-        # AdasumGpuAllreduceOp schedule (sum inside the slice, adaptive
-        # summation across) — unless the lowering is forced flat, in
-        # which case (and on single-slice topologies, where the plan
-        # resolves flat anyway) the flat VHDD tree serves the bucket.
-        adasum_ok = (
-            op == Adasum
-            and isinstance(axis, str)
-            and _axis_present(axis)
-            and (process_set is None or process_set.process_set_id == 0)
-        )
-        req_lowering = cfg.lowering if lowering is None else lowering
-        if hier_ok:
-            lower_req = req_lowering
-        elif adasum_ok:
-            lower_req = "flat" if req_lowering == "flat" \
-                else "hier_adasum"
-        else:
-            lower_req = "flat"
-        schedule = _sched.build_schedule(
-            sizes, wire_dtypes, cfg,
-            order=_sched.hooks.consume_order(len(wire)),
-            pinned=pinned,
-            wire=wire_req,
-            lowering=lower_req,
-            axis_size=(
-                jax.lax.axis_size(axis) if (hier_ok or adasum_ok)
-                else None
-            ),
-        )
-        # reduce_scatter+all_gather exchange (arXiv:2004.13336) needs a
-        # plain sum/average over one whole-world axis; anything else
-        # (Adasum, process sets, multi-axis) keeps the allreduce
-        # lowering per dense bucket.  Quantized buckets have their own
-        # RS+AG lowering below (for them the decomposition IS the
-        # allreduce), so both sched modes run quantized end-to-end.
-        rs_ok = (
-            cfg.mode == "reduce_scatter"
-            and op in (Average, Sum)
-            and (process_set is None or process_set.process_set_id == 0)
-            and isinstance(axis, str)
-        )
-
-        def dense_flat(f):
-            if rs_ok and jnp.issubdtype(f.dtype, jnp.floating):
-                return _sched.execute.reduce_scatter_flat(
-                    f, axis=axis, average=(op == Average),
-                    prescale_factor=prescale_factor,
-                    postscale_factor=postscale_factor,
-                )
-            return reduce_flat(f)
-
-        res_out = None
-        if residuals is not None:
-            res_out = list(jax.tree.flatten(residuals)[0])
-            if len(res_out) != len(wire):
-                raise ValueError(
-                    "residuals structure does not match gradients"
-                )
-
-        def reduce_bucket_flat(f, bucket):
-            if bucket.lowering == "hier_adasum" and (hier_ok or adasum_ok):
-                # Hierarchical Adasum (both sched modes — the staged
-                # allreduce IS the RS+AG composition): intra-slice sum,
-                # adaptive combination on the 1/k DCN shard, ICI
-                # gather.  The bucket's wire compresses only the DCN
-                # leg; EF does not apply (hier semantics).
-                return _sched.execute.hier_adasum_flat(
-                    f, axis=axis, average=(op != Sum),
-                    wire=bucket.wire,
-                    prescale_factor=prescale_factor,
-                    postscale_factor=postscale_factor,
-                )
-            if bucket.lowering == "hier" and hier_ok:
-                # Two-level ICI/DCN staging (topo/): the bucket's wire
-                # compresses only the cross-slice hop.  EF residuals
-                # don't apply on this lowering (the quantization error
-                # lives on the slice-summed shard, not the gradient) —
-                # hier quantized buckets run EF-free.
-                if rs_ok and jnp.issubdtype(f.dtype, jnp.floating):
-                    return _sched.execute.hier_reduce_scatter_flat(
-                        f, axis=axis, average=(op == Average),
-                        wire=bucket.wire,
-                        prescale_factor=prescale_factor,
-                        postscale_factor=postscale_factor,
-                    )
-                return _sched.execute.hier_allreduce_flat(
-                    f, axis=axis, average=(op == Average),
-                    wire=bucket.wire,
-                    prescale_factor=prescale_factor,
-                    postscale_factor=postscale_factor,
-                )
-            if bucket.wire in ("int8", "fp8"):
-                res_flat, rmeta = None, None
-                if res_out is not None:
-                    rf, rmeta = fusion.flatten_group(
-                        [res_out[i] for i in bucket.indices]
-                    )
-                    res_flat = rf[0]
-                red, r_new = _sched.execute.quantized_exchange_flat(
-                    f, axis=axis, average=(op == Average),
-                    wire=bucket.wire,
-                    prescale_factor=prescale_factor,
-                    postscale_factor=postscale_factor,
-                    residual=res_flat, process_set=process_set,
-                )
-                if r_new is not None:
-                    for i, r in zip(
-                        bucket.indices,
-                        fusion.unflatten_group([r_new], rmeta),
-                    ):
-                        res_out[i] = r.astype(res_out[i].dtype)
-                return red
-            if bucket.wire == "bf16":
-                return _sched.execute.bf16_wire(dense_flat)(f)
-            return dense_flat(f)
-
+    rt = get_runtime_or_none()
+    reduced = sched.exchange(
+        wire, plan.schedule,
+        _bucket_reducer(
+            plan, res_out, quantized=quantized, compression=compression,
+            axis=axis, op=op, prescale_factor=prescale_factor,
+            postscale_factor=postscale_factor, process_set=process_set,
+        ),
+        timeline=rt.timeline if rt is not None else None, axis=axis,
         # Rail pipeliner (xir/pipeline.py): hier buckets may emit as
         # per-rail phase chains — the factory mirrors the serialized
-        # hier reducers above op for op, so pipeline on/off/auto is
+        # hier reducers op for op, so pipeline on/off/auto is
         # bitwise-identical on the f32 dense wire.
-        phase_factory = (
-            _sched.execute.hier_phase_factory(
-                axis=axis, average=(op == Average), rs_mode=rs_ok,
+        phases=(
+            sched.execute.hier_phase_factory(
+                axis=axis, average=(op == Average), rs_mode=plan.rs_ok,
                 prescale_factor=prescale_factor,
                 postscale_factor=postscale_factor,
             )
-            if hier_ok else None
-        )
-        if update is None:
-            reduced = _sched.exchange(
-                wire, schedule, reduce_bucket_flat,
-                barriers=cfg.barriers, timeline=tl, axis=axis,
-                phases=phase_factory,
-            )
-            out = [
-                compression.decompress(t, c)
-                for t, c in zip(reduced, ctxs)
-            ]
-            tree = jax.tree.unflatten(treedef, out)
-            if residuals is not None:
-                return tree, jax.tree.unflatten(treedef, res_out)
-            return tree
+            if plan.hier_ok else None
+        ),
+    )
+    if update_follows:
+        # Orders every leaf's decompress and update after the last
+        # bucket's collective (ROADMAP D4 asks what that costs).
+        reduced = lax.optimization_barrier(tuple(reduced))
 
-        # Whole-step emission (HVD_TPU_ONESTEP): hand the decompress +
-        # optimizer-update closure to the exchange so an engaged fold
-        # stitches it INTO the traced emission (one dispatch unit for
-        # reduce + update).  A None result means the fold did not
-        # engage — the epilogue then applies right here, on the exact
-        # jaxpr the epilogue-free path would have built.
-        def _epilogue(red_leaves):
-            out_ = [
-                compression.decompress(t, c)
-                for t, c in zip(red_leaves, ctxs)
-            ]
-            return update(jax.tree.unflatten(treedef, out_))
-
-        reduced, update_result = _sched.exchange(
-            wire, schedule, reduce_bucket_flat,
-            barriers=cfg.barriers, timeline=tl, axis=axis,
-            phases=phase_factory, epilogue=_epilogue,
-        )
-        if update_result is None:
-            update_result = _epilogue(reduced)
-        if residuals is not None:
-            return update_result, jax.tree.unflatten(treedef, res_out)
-        return update_result
-
-    # Legacy single-pass path (HVD_TPU_SCHED=off): in-order buckets, no
-    # sequencing barriers — one monolithic fused exchange per dtype run.
-    buckets = list(pinned)
-    if rest:
-        for b in fusion.bucket_plan(
-            [sizes[i] for i in rest], [wire_dtypes[i] for i in rest],
-            fusion_threshold_bytes,
-        ):
-            buckets.append([rest[i] for i in b])
-    reduced = list(wire)
-    for bi, bucket in enumerate(buckets):
-        nbytes = sum(sizes[i] for i in bucket)
-        if tl is not None:
-            tl.record_op(
-                f"bucket{bi}[n={len(bucket)}]", "FUSION_PLAN", nbytes
-            )
-        with jax.named_scope(f"hvd_bucket{bi}_{nbytes}B"):
-            flats, meta = fusion.flatten_group([wire[i] for i in bucket])
-            out_flats = [reduce_flat(f) for f in flats]
-        for i, t in zip(bucket, fusion.unflatten_group(out_flats, meta)):
-            reduced[i] = t
-
+    # --- decompress
     out = [compression.decompress(t, c) for t, c in zip(reduced, ctxs)]
     tree = jax.tree.unflatten(treedef, out)
-    if update is not None:
-        # Legacy single-pass engine: no fold (the path has no program
-        # emission to stitch into); the update applies after.
-        tree = update(tree)
     if residuals is not None:
-        # Legacy engine: EF rides the scheduler; residuals pass through
-        # untouched (zeros behave as plain quantization).
-        return tree, residuals
+        return tree, jax.tree.unflatten(treedef, res_out)
     return tree
 
 
@@ -659,7 +608,7 @@ def DistributedOptimizer(
     if k < 1:
         raise ValueError("backward_passes_per_step must be >= 1")
 
-    def reduce_fn(grads, residuals=None, update=None):
+    def reduce_fn(grads, residuals=None, update_follows=False):
         return _reduce_gradients(
             grads,
             axis=axis,
@@ -673,18 +622,18 @@ def DistributedOptimizer(
             sparse_as_dense=sparse_as_dense,
             residuals=residuals,
             lowering=lowering,
-            update=update,
+            update_follows=update_follows,
         )
 
     def _ef_active() -> bool:
-        # Error-feedback residuals ride the scheduler engine with a
-        # quantized wire — either an explicit Compression.int8/fp8 or a
-        # HVD_TPU_SCHED_WIRE=int8/fp8 request at init time (the state
-        # must exist before the first trace).
+        # Error-feedback residuals ride a quantized wire — either an
+        # explicit Compression.int8/fp8 or a HVD_TPU_SCHED_WIRE=int8/fp8
+        # request at init time (the state must exist before the first
+        # trace).
         from .. import sched as _sched
 
         cfg = _sched.current_config()
-        if not (cfg.enabled and cfg.wire_ef):
+        if not cfg.wire_ef:
             return False
         if getattr(compression, "quantized_wire", False):
             return True
@@ -709,33 +658,12 @@ def DistributedOptimizer(
     def update_fn(grads, state: DistributedOptimizerState, params=None):
         residual = getattr(state, "residual", None)
         if k == 1:
-            from ..xir import interp as _xinterp
-
-            if _xinterp.onestep_mode() != "off":
-                # Whole-step emission (HVD_TPU_ONESTEP): the inner
-                # update rides into the reduction as a closure, so an
-                # engaged fold compiles exchange + update as ONE
-                # dispatch unit.  Identical math in identical order —
-                # the closure body is the exact two lines below.
-                def _apply(reduced_tree):
-                    return optimizer.update(
-                        reduced_tree, state.inner, params
-                    )
-
-                if residual is not None:
-                    (updates, inner), residual = reduce_fn(
-                        grads, residual, update=_apply
-                    )
-                else:
-                    updates, inner = reduce_fn(grads, update=_apply)
-                return updates, DistributedOptimizerState(
-                    counter=state.counter + 1, acc=None, inner=inner,
-                    residual=residual,
-                )
             if residual is not None:
-                reduced, residual = reduce_fn(grads, residual)
+                reduced, residual = reduce_fn(
+                    grads, residual, update_follows=True
+                )
             else:
-                reduced = reduce_fn(grads)
+                reduced = reduce_fn(grads, update_follows=True)
             updates, inner = optimizer.update(reduced, state.inner, params)
             return updates, DistributedOptimizerState(
                 counter=state.counter + 1, acc=None, inner=inner,
@@ -889,23 +817,17 @@ class TrainStep:
         def init_body(params):
             return _stack_local(optimizer.init(params))
 
-        # Grad-boundary taps (sched/hooks.py): when the overlap
-        # scheduler drives a DistributedOptimizer (marker present), the
-        # backward trace records per-leaf readiness order so the plan
-        # stage buckets in true reverse-backward order.  Gated on the
-        # marker — a plain optax transform never consumes the capture.
-        _is_hvd_opt = hasattr(optimizer.update, "_hvd_fusion_threshold")
+        # Grad-boundary taps (sched/hooks.py): under a
+        # DistributedOptimizer (marker present) the backward trace
+        # records per-leaf readiness order so the plan stage buckets in
+        # true reverse-backward order.  Gated on the marker — a plain
+        # optax transform never consumes the capture.
+        if hasattr(optimizer.update, "_hvd_fusion_threshold"):
+            from ..sched import hooks as _sched_hooks
 
-        def _loss_for_trace():
-            from .. import sched as _sched
-
-            _cfg = _sched.current_config()
-            if _is_hvd_opt and _cfg.enabled and _cfg.capture_order:
-                return _sched.hooks.capturing_loss(loss_fn)
-            return loss_fn
+            loss_fn = _sched_hooks.capturing_loss(loss_fn)
 
         def compute_grads(params, model_state, batch):
-            loss_fn = _loss_for_trace()
             if stateful:
                 (loss, out_state), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                     params, model_state, batch
@@ -1067,7 +989,6 @@ class TrainStep:
     def _call(self, params, args, span):
         from .. import prof, trace as _trace
         from ..prof.introspect import ProfiledExecutor
-        from ..xir import interp as _xinterp
 
         def profiled(fn):
             # HVD_TPU_PROF flipped off mid-run calls the raw fn again.
@@ -1080,12 +1001,6 @@ class TrainStep:
                 opt_state, batch = args
                 model_state = None
             specs = self._state_specs(opt_state)
-            # Whole-step emission mode is a trace-time constant (the
-            # update closure either folds into the exchange or runs
-            # after it), so each resolved mode is its own compiled
-            # variant — flipping HVD_TPU_ONESTEP mid-run retraces
-            # instead of silently running the stale shape.
-            onestep = _xinterp.onestep_mode()
             threshold = None
             hier = None
             quant = None
@@ -1096,7 +1011,7 @@ class TrainStep:
             key = (
                 jax.tree.structure(opt_state),
                 jax.tree.structure(model_state),
-                threshold, hier, quant, onestep,
+                threshold, hier, quant,
             )
             if (self._autotune is not None and self._autotune.converged
                     and len(self._step_cache) > 1):
@@ -1115,13 +1030,7 @@ class TrainStep:
             if profiled(fn):
                 sig, compiled = fn.lookup(call_args)
         if span is not None:
-            # The onestep attr rides the step span so prof/hostgap.py
-            # counts the folded step as exactly one dispatch (the exec
-            # span covers exchange + update; without the attr a
-            # fallback-demoted wrapper would read 0 and the epilogue
-            # could double-count).
             span.attrs["compiled"] = not built_here
-            span.attrs["onestep"] = 1 if onestep == "on" else 0
 
         rt = get_runtime()
         tl = rt.timeline

@@ -325,8 +325,7 @@ def _fsdp_exchange(op_name: str, x: jax.Array, axis, bucket: int = 0
                    ) -> jax.Array:
     """One FSDP exchange phase through the exchange IR (``xir``): the
     per-step parameter ``all_gather`` or gradient ``reduce_scatter``.
-    The interpreter emits the identical flat ``lax`` collective
-    (``HVD_TPU_XIR=off`` calls it directly — bitwise either way); the
+    The interpreter emits the flat ``lax`` collective; the
     wire stays dense here (FSDP's wire compression is its own
     ``compression=`` kwarg, applied by the caller around this hop) and
     the lowering stays flat (the 1/N shard layout is the optimizer-
@@ -335,10 +334,6 @@ def _fsdp_exchange(op_name: str, x: jax.Array, axis, bucket: int = 0
     byte gauges, and a persistent-store key for its program."""
     from .. import xir
 
-    if not xir.enabled():
-        if op_name == "all_gather":
-            return lax.all_gather(x, axis, tiled=True)
-        return lax.psum_scatter(x, axis, scatter_dimension=0, tiled=True)
     if op_name == "all_gather":
         op = xir.all_gather(
             axis, lowering="flat", bucket=bucket,

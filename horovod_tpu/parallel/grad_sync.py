@@ -30,11 +30,7 @@ from __future__ import annotations
 
 from typing import Sequence, Tuple
 
-import jax
-from jax import lax
-
 from .mesh import DP_AXIS, EP_AXIS, SP_AXIS, TP_AXIS
-from .tensor import _axis_present
 
 
 def _parse(axes: str) -> Tuple[str, ...]:
@@ -45,7 +41,6 @@ def sync_gradients(
     grads,
     param_shard_axes=None,
     axes: Sequence[str] = (DP_AXIS, SP_AXIS, TP_AXIS, EP_AXIS),
-    scheduled: bool | None = None,
 ):
     """Synchronize a gradient pytree inside shard_map.
 
@@ -58,36 +53,12 @@ def sync_gradients(
     current shard_map are skipped, so one call site works across mesh
     shapes.
 
-    ``scheduled``: route the pmeans through the bucketed overlap
-    scheduler (``sched/``) — per-parameter semantics are unchanged
-    (pmean is elementwise, so bucketing never moves a value), but the
-    exchange becomes reverse-backward ordered fused buckets XLA can
-    overlap with compute.  ``None`` follows the ``HVD_TPU_SCHED`` knob
-    (default on).
+    The pmeans go through the bucketed overlap scheduler (``sched/``):
+    per-parameter semantics are those of the rule above (pmean is
+    elementwise, so bucketing never moves a value), exchanged as
+    reverse-backward ordered fused buckets XLA can overlap with
+    compute.
     """
-    if scheduled is None:
-        from ..sched import current_config
+    from ..sched import sync_gradients_bucketed
 
-        scheduled = current_config().enabled
-    if scheduled:
-        from ..sched import sync_gradients_bucketed
-
-        return sync_gradients_bucketed(grads, param_shard_axes, axes)
-    present = tuple(a for a in axes if _axis_present(a))
-
-    def sync(g, sharded_str):
-        sharded = _parse(sharded_str)
-        mean_over = tuple(a for a in present if a not in sharded)
-        if mean_over:
-            g = lax.pmean(g, mean_over)
-        scale = 1
-        for a in present:
-            if a in sharded:
-                scale *= lax.axis_size(a)
-        if scale != 1:
-            g = g / scale
-        return g
-
-    if param_shard_axes is None:
-        return jax.tree.map(lambda g: sync(g, ""), grads)
-    return jax.tree.map(sync, grads, param_shard_axes)
+    return sync_gradients_bucketed(grads, param_shard_axes, axes)

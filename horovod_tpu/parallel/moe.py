@@ -75,18 +75,12 @@ def _routed_all_to_all(x: jax.Array, axis: str, split_axis: int,
                        concat_axis: int, bucket: int = 0) -> jax.Array:
     """One MoE all_to_all through the exchange IR (``xir``): the op
     carries the payload metadata the tuner/store key and the byte
-    gauges need, and the interpreter emits the identical
-    ``lax.all_to_all`` on the dense wire (``HVD_TPU_XIR=off`` calls it
-    directly — bitwise-equal either way).  Wire requests
+    gauges need, and the interpreter emits ``lax.all_to_all``
+    on the dense wire.  Wire requests
     (``HVD_TPU_XIR_WIRE`` / ``HVD_TPU_SCHED_WIRE``) gate through
     shuffle-op eligibility: bf16 casts the wire, int8/fp8 stay off."""
     from .. import xir
 
-    if not xir.enabled():
-        return lax.all_to_all(
-            x, axis, split_axis=split_axis, concat_axis=concat_axis,
-            tiled=True,
-        )
     op = xir.all_to_all(
         axis, split_axis=split_axis, concat_axis=concat_axis,
         wire=xir.wire_request(), bucket=bucket,

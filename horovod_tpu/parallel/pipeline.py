@@ -61,13 +61,10 @@ def pipeline_apply(
 
     def _hop(y):
         # The stage-to-stage activation hop through the exchange IR:
-        # the interpreter emits the identical lax.ppermute on the
-        # dense wire (HVD_TPU_XIR=off calls it directly); the hop's
-        # bytes land in the PIPELINE_EXCHANGE lane + kind-labeled
+        # the interpreter emits lax.ppermute on the dense wire; the
+        # hop's bytes land in the PIPELINE_EXCHANGE lane + kind-labeled
         # gauges, with the DCN share computed from which (src, dst)
         # pairs cross a slice boundary.
-        if not xir.enabled():
-            return lax.ppermute(y, axis, shift)
         op = xir.permute(
             axis, shift, wire=xir.wire_request(),
             nbytes=y.size * y.dtype.itemsize, dtype=y.dtype,

@@ -44,17 +44,11 @@ def ulysses_attention(
 
     def _flip(x, split_axis, concat_axis, bucket):
         # One head/sequence re-shard through the exchange IR: the
-        # interpreter emits the identical lax.all_to_all on the dense
-        # wire (HVD_TPU_XIR=off calls it directly), bf16 wire requests
-        # cast around it, and the flip's bytes land in the
+        # interpreter emits lax.all_to_all on the dense wire, bf16
+        # wire requests cast around it, and the flip's bytes land in the
         # ULYSSES_EXCHANGE lane + kind-labeled gauges.
         from .. import xir
 
-        if not xir.enabled():
-            return lax.all_to_all(
-                x, axis, split_axis=split_axis, concat_axis=concat_axis,
-                tiled=True,
-            )
         op = xir.all_to_all(
             axis, split_axis=split_axis, concat_axis=concat_axis,
             wire=xir.wire_request(), bucket=bucket,

@@ -26,9 +26,8 @@ its trace-time replacement, three stages over one gradient pytree:
                 windows and the elastic driver serves entries
                 fleet-wide (``/schedules``).  See docs/autotune.md.
 
-``DistributedOptimizer`` uses this pipeline by default; set
-``HVD_TPU_SCHED=off`` for the legacy single-fused-exchange path.  See
-docs/scheduler.md.
+``DistributedOptimizer`` and ``parallel.sync_gradients`` exchange
+through this pipeline.  See docs/scheduler.md.
 """
 
 from . import execute, hooks, plan, tune, zero1  # noqa: F401

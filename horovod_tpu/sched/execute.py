@@ -271,10 +271,9 @@ def _bucket_timeline(timeline, bi: int, bucket: Bucket) -> None:
 
 def _exchange_pipelined(
     wire: Sequence[jax.Array],
-    schedule: BucketSchedule,
+    buckets: Sequence[Bucket],
     reduce_flat: Callable[[jax.Array, Bucket], jax.Array],
     phases: Callable[[Bucket], Optional[_PhasedBucket]],
-    program: Any,
     timeline: Any,
 ) -> List[jax.Array]:
     """Rail-chained emission (``HVD_TPU_XIR_PIPELINE``): decomposable
@@ -286,8 +285,6 @@ def _exchange_pipelined(
     BOTH rails (full ordering, exactly their serialized behavior).
     Values are bitwise identical to the serialized emission: every
     barrier is identity and per-bucket op order never changes."""
-    import dataclasses as _dc
-
     from .. import trace
     from ..xir import pipeline as railpipe
 
@@ -319,12 +316,7 @@ def _exchange_pipelined(
         ):
             reduced[i] = t
 
-    for bi, bucket in enumerate(schedule.buckets):
-        if program is not None:
-            op = program.ops[bi]
-            bucket = _dc.replace(
-                bucket, wire=op.wire, lowering=op.lowering
-            )
+    for bi, bucket in enumerate(buckets):
         pb = phases(bucket)
         ins = [wire[i] for i in bucket.indices]
         if timeline is not None:
@@ -394,50 +386,28 @@ def exchange(
     schedule: BucketSchedule,
     reduce_flat: Callable[[jax.Array, Bucket], jax.Array],
     *,
-    barriers: bool = True,
     timeline: Any = None,
     kind: str = "dense_grad",
     axis: Any = None,
     phases: Optional[Callable[[Bucket], Optional[_PhasedBucket]]] = None,
-    epilogue: Optional[Callable[[List[jax.Array]], Any]] = None,
-) -> Any:
+) -> List[jax.Array]:
     """Run ``schedule`` over the ``wire`` leaves: per bucket, flatten ->
     one collective per dtype (via ``reduce_flat(flat, bucket)``) ->
     slice back out.  Returns the reduced leaves in original flatten
     order.
 
-    Under ``HVD_TPU_XIR=on`` (the default) the schedule is first
-    expressed as an explicit exchange program
+    The schedule is first expressed as an explicit exchange program
     (:func:`~horovod_tpu.xir.from_schedule` — one op per bucket
-    carrying the (wire, lowering, bucket, ef) tuple that used to be
-    implicit in ``Bucket`` fields), and this loop interprets that
-    program: the op record is authoritative for the per-bucket
-    dispatch.  The ops are constructed from the very bucket fields
-    they replace, so the emitted collectives — and therefore f32
-    dense losses — are bitwise identical with the IR on or off
-    (tests/test_xir.py pins this).
+    carrying the (wire, lowering, bucket, ef) tuple), and this loop
+    interprets that program: the op record is authoritative for the
+    per-bucket dispatch.
 
     Values are independent of bucketing: XLA collectives are
     elementwise over the buffer, so concat order never changes a sum —
-    with a dense wire the scheduler is numerics-identical to the
-    single-fused-exchange legacy path by construction.  A bucket whose
-    ``wire`` is quantized trades that identity for compressed wire
-    bytes (the reducer routes it through ops/quantized.py).
-
-    ``epilogue`` opts the schedule into whole-step emission
-    (``HVD_TPU_ONESTEP``, docs/exchange_ir.md "Whole-step emission"):
-    when :func:`~horovod_tpu.xir.interp.onestep_engaged` folds, the
-    caller's post-exchange closure (decompress + optimizer update) is
-    stitched onto the reduced leaves *inside* this traced emission via
-    :func:`~horovod_tpu.xir.interp.emit_step`, so XLA compiles
-    exchange + update as ONE program instead of two dispatch units.
-    With ``epilogue`` the return value is ``(reduced, result)`` where
-    ``result`` is the closure's output when the fold engaged and
-    ``None`` when it did not — a ``None`` result means the caller must
-    apply the epilogue itself, which keeps the ``off`` path's jaxpr
-    construction literally identical to the epilogue-free call.  The
-    fold is ordering-only (optimization_barrier ties), so f32 dense
-    losses stay bitwise identical in every mode.
+    with a dense wire the scheduler is numerics-identical to a per-leaf
+    reduction by construction.  A bucket whose ``wire`` is quantized
+    trades that identity for compressed wire bytes (the reducer routes
+    it through ops/quantized.py).
 
     ``phases`` (a :func:`hier_phase_factory`) opts the schedule into
     the rail pipeliner: when ``HVD_TPU_XIR_PIPELINE`` engages
@@ -448,16 +418,12 @@ def exchange(
     f32 dense losses are bitwise identical to the serialized emission
     in every mode.
     """
-    from .. import trace, xir
-    from ..xir import interp as _xinterp
+    from .. import prof, svc, trace, xir
     from ..xir import pipeline as railpipe
 
     t0 = time.perf_counter()
-    program = (
-        xir.from_schedule(schedule, kind=kind, axis=axis)
-        if xir.enabled() else None
-    )
-    if program is not None and program.trace is None and trace.enabled():
+    program = xir.from_schedule(schedule, kind=kind, axis=axis)
+    if program.trace is None and trace.enabled():
         # Trace correlation for the whole submission: the context rides
         # the program into the service (queue/negotiation/cache spans)
         # and back out to the rail-phase spans emitted below.  A caller
@@ -468,95 +434,64 @@ def exchange(
         if not ctx.tenant:
             default = trace.context.default_tenant()
             if default:
-                import dataclasses as _dc
-
-                ctx = _dc.replace(ctx, tenant=default)
+                ctx = dataclasses.replace(ctx, tenant=default)
         program = program.with_trace(ctx)
-    if program is not None:
-        # Async exchange service (svc/): the bucketed pipeline is a
-        # *producer* — the program is submitted to the service at
-        # trace time and the (ResponseCache-resolved) copy it hands
-        # back drives the emission below.  A repeat signature costs
-        # zero re-lowering; a dead service falls back to the local
-        # program (svc.fallback_sync).  The ops are equal either way,
-        # so HVD_TPU_SVC on/off stays bitwise identical on this path.
-        from .. import svc as _svc
-
-        if _svc.enabled():
-            axis_size_hint = None
-            if isinstance(axis, str):
-                try:
-                    axis_size_hint = lax.axis_size(axis)
-                except Exception:
-                    axis_size_hint = None
-            program = _svc.get_service().submit_traced(
-                program, producer=f"sched.{kind}",
-                axis_size=axis_size_hint, store=False,
-            )
-        metrics.inc_counter("xir.programs")
-        metrics.inc_counter(f"xir.programs.{kind}")
-        metrics.inc_counter("xir.ops", len(program.ops))
-        # Emission accounting for the profiling plane (trace-time, like
-        # the counters above): how many collective programs — and ops —
-        # one step's schedule emits, per source.
-        from .. import prof
-
-        prof.note_emission(f"sched.{kind}", len(program.ops))
     axis_size = None
     if isinstance(axis, str):
         try:
             axis_size = lax.axis_size(axis)
         except Exception:
             axis_size = None
-    # Rail pipelining (xir/pipeline.py): needs barriers (the rails ARE
-    # barrier chains), a phase factory from the caller, and an engaged
-    # knob/cost-model verdict.  Values are bitwise identical either
-    # way; the branch only changes ordering edges.
+    # Async exchange service (svc/): the bucketed pipeline is a
+    # *producer* — the program is submitted to the service at trace
+    # time and the (ResponseCache-resolved) copy it hands back drives
+    # the emission below.  A repeat signature costs zero re-lowering; a
+    # dead service falls back to the local program
+    # (svc.fallback_sync).  The ops are equal either way, so
+    # HVD_TPU_SVC on/off stays bitwise identical on this path.
+    if svc.enabled():
+        program = svc.get_service().submit_traced(
+            program, producer=f"sched.{kind}",
+            axis_size=axis_size, store=False,
+        )
+    metrics.inc_counter("xir.programs")
+    metrics.inc_counter(f"xir.programs.{kind}")
+    metrics.inc_counter("xir.ops", len(program.ops))
+    # Emission accounting for the profiling plane (trace-time, like
+    # the counters above): how many collective programs — and ops —
+    # one step's schedule emits, per source.
+    prof.note_emission(f"sched.{kind}", len(program.ops))
+    # Rail pipelining (xir/pipeline.py): needs a phase factory from the
+    # caller and an engaged knob/cost-model verdict.  Values are
+    # bitwise identical either way; the branch only changes ordering
+    # edges.
     pipelined = bool(
-        barriers and phases is not None
-        and railpipe.engaged(schedule, axis_size)
+        phases is not None and railpipe.engaged(schedule, axis_size)
     )
     metrics.set_gauge(
         "sched.pipeline.engaged", 1.0 if pipelined else 0.0,
         {"mode": railpipe.mode()},
     )
-    # Whole-step fold (xir/interp.py onestep): the update closure
-    # counts as one more dispatch unit on top of the bucket chain, so
-    # auto engages whenever there is anything to stitch it to.
-    onestep_fold = bool(
-        epilogue is not None
-        and _xinterp.onestep_engaged(len(schedule) + 1)
-    )
-    metrics.set_gauge(
-        "sched.onestep.engaged", 1.0 if onestep_fold else 0.0,
-        {"mode": _xinterp.onestep_mode()},
-    )
-    epilogue_result = None
+    # Interpret the program: the op record drives each bucket's dispatch.
+    buckets = [
+        dataclasses.replace(bucket, wire=op.wire, lowering=op.lowering)
+        for bucket, op in zip(schedule.buckets, program.ops)
+    ]
     with trace.span(
-        f"exchange.{kind}", "exchange",
-        ctx=program.trace if program is not None else None,
+        f"exchange.{kind}", "exchange", ctx=program.trace,
         kind=kind, buckets=len(schedule), pipelined=pipelined,
-        onestep=int(onestep_fold),
     ):
         if pipelined:
             reduced = _exchange_pipelined(
-                wire, schedule, reduce_flat, phases, program, timeline
+                wire, buckets, reduce_flat, phases, timeline
             )
         else:
             reduced = list(wire)
             token: Optional[jax.Array] = None
-            for bi, bucket in enumerate(schedule.buckets):
-                if program is not None:
-                    # Interpret the program: the op record drives the
-                    # bucket's dispatch (equal to the plan's fields by
-                    # construction).
-                    op = program.ops[bi]
-                    bucket = dataclasses.replace(
-                        bucket, wire=op.wire, lowering=op.lowering
-                    )
-                ins = [wire[i] for i in bucket.indices]
-                if barriers:
-                    ins, token = _chain(ins, token)
+            for bi, bucket in enumerate(buckets):
+                ins, token = _chain(
+                    [wire[i] for i in bucket.indices], token
+                )
                 if timeline is not None:
                     _bucket_timeline(timeline, bi, bucket)
                 with trace.span(
@@ -569,11 +504,10 @@ def exchange(
                 ):
                     flats, meta = fusion.flatten_group(ins)
                     outs = [reduce_flat(f, bucket) for f in flats]
-                if barriers:
-                    # Scalar carried out of this bucket's collective:
-                    # the next bucket's inputs are barrier-tied to it,
-                    # enforcing issue order without touching values.
-                    token = outs[0].reshape(-1)[0]
+                # Scalar carried out of this bucket's collective: the
+                # next bucket's inputs are barrier-tied to it,
+                # enforcing issue order without touching values.
+                token = outs[0].reshape(-1)[0]
                 for i, t in zip(
                     bucket.indices, fusion.unflatten_group(outs, meta)
                 ):
@@ -582,14 +516,6 @@ def exchange(
                     "sched.bytes_per_bucket", bucket.nbytes,
                     buckets=metrics.BYTES_BUCKETS,
                 )
-        if onestep_fold:
-            # Stitch the caller's decompress+update closure onto the
-            # reduced leaves INSIDE this emission: one traced region,
-            # one dispatch unit (the exec span prof/hostgap.py counts
-            # once under onestep).
-            epilogue_result = _xinterp.emit_step(
-                reduced, epilogue, src=f"sched.{kind}"
-            )
     metrics.inc_counter("sched.plans")
     metrics.inc_counter("sched.buckets", len(schedule))
     metrics.inc_counter("sched.exchange_bytes", schedule.total_bytes)
@@ -599,8 +525,6 @@ def exchange(
     # Emission cost of the exchange subgraph (trace-time under jit; the
     # device-side wire time is the profiler's/timeline's to attribute).
     metrics.observe("sched.exchange_seconds", time.perf_counter() - t0)
-    if epilogue is not None:
-        return reduced, epilogue_result
     return reduced
 
 
@@ -935,7 +859,6 @@ def sync_gradients_bucketed(
 
         reduced = exchange(
             [leaves[i] for i in idxs], schedule, reduce_flat,
-            barriers=cfg.barriers,
             axis=mean_over[0] if len(mean_over) == 1 else tuple(mean_over),
             # Rail pipelining for hier pmean buckets: the factory's
             # pmean flavor replicates hierarchical_all_reduce(Average)

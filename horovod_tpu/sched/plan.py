@@ -77,14 +77,11 @@ def _canon_wire_choice(wire: str) -> str:
 
 @dataclasses.dataclass(frozen=True)
 class SchedConfig:
-    """Knobs of the bucketed overlap scheduler (``HVD_TPU_SCHED*``)."""
+    """Knobs of the bucketed overlap scheduler (``HVD_TPU_SCHED_*``)."""
 
-    enabled: bool = True
     mode: str = "allreduce"  # "allreduce" | "reduce_scatter"
     bucket_bytes: Optional[int] = None  # None -> fusion threshold knob
     look_ahead: int = 3
-    barriers: bool = True
-    capture_order: bool = True
     wire: str = "off"  # "off" | "bf16" | "int8" | "fp8"
     wire_ef: bool = True  # error-feedback residuals for quantized wires
     # "auto" | "flat" | "hier" | "hier_adasum" (HVD_TPU_TOPO_LOWER)
@@ -101,17 +98,12 @@ class SchedConfig:
 
     @classmethod
     def from_env(cls) -> "SchedConfig":
-        raw = (env.get_env(env.SCHED, "on") or "on").strip().lower()
-        enabled = raw not in ("off", "0", "false", "no")
         bucket_bytes = env.get_int(env.SCHED_BUCKET_BYTES, -1)
         return cls(
-            enabled=enabled,
             mode=(env.get_env(env.SCHED_MODE, "allreduce") or "allreduce")
             .strip().lower(),
             bucket_bytes=None if bucket_bytes < 0 else bucket_bytes,
             look_ahead=env.get_int(env.SCHED_LOOK_AHEAD, 3),
-            barriers=env.get_bool(env.SCHED_BARRIERS, True),
-            capture_order=env.get_bool(env.SCHED_CAPTURE_ORDER, True),
             wire=env.get_env(env.SCHED_WIRE, "off") or "off",
             wire_ef=env.get_bool(env.SCHED_WIRE_EF, True),
             lowering=env.get_env(env.TOPO_LOWER, "auto") or "auto",

@@ -6,7 +6,7 @@ scratch every run — exploration cost is paid per *job*, even for the
 a JSON file (``HVD_TPU_TUNE_DB``) mapping
 
     key = sha256(schedule ``signature()``, topology spec, jax version,
-                 ``HVD_TPU_SCHED*/WIRE*/TOPO*`` knob fingerprint)
+                 ``HVD_TPU_SCHED_*/WIRE*/TOPO*`` knob fingerprint)
 
 to the winning ``(bucket_bytes, wire, lowering)`` tuple and its window
 score.  :class:`~horovod_tpu.sched.tune.ScheduleTuner` warm-starts
@@ -55,7 +55,7 @@ _warn_lock = threading.Lock()
 
 
 def knob_fingerprint(include_svc: bool = True) -> str:
-    """Stable digest of every ``HVD_TPU_SCHED*/WIRE*/TOPO*/QUANT*``
+    """Stable digest of every ``HVD_TPU_SCHED_*/WIRE*/TOPO*/QUANT*``
     env knob (and its legacy ``HOROVOD_`` spelling): two processes with
     the same fingerprint plan identical schedules from identical
     metadata, so stored winners are only shared between them.

@@ -253,9 +253,7 @@ def bucketed_zero_step(
         gshards = []
         new_residuals = []
         rails = railpipe.RailChain()
-        use_rails = cfg.barriers and railpipe.engaged(
-            meta["schedule"], world
-        )
+        use_rails = railpipe.engaged(meta["schedule"], world)
         pipe_overlaps = 0
         token = None
         intra = (
@@ -271,7 +269,7 @@ def bucketed_zero_step(
                     else ("ici", "dcn")
                 )
                 (g,) = rails.tie([g], bucket_rails)
-            elif cfg.barriers and token is not None:
+            elif token is not None:
                 g, token = lax.optimization_barrier((g, token))
             if lay.lowering in ("hier", "hier_adasum"):
                 # ICI reduce_scatter to the slice-local 1/k shard, then
@@ -325,7 +323,7 @@ def bucketed_zero_step(
                     ("dcn",) if lay.lowering == "hier"
                     else ("ici", "dcn"),
                 )
-            elif cfg.barriers:
+            else:
                 token = shard.reshape(-1)[0]
             gshards.append(shard)
         if use_rails:

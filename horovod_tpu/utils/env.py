@@ -50,14 +50,10 @@ PROCESS_SETS = "PROCESS_SETS"
 BATCH_D2D_MEMCOPIES = "BATCH_D2D_MEMCOPIES"
 NUM_STREAMS = "NUM_STREAMS"
 # Bucketed overlap scheduler (sched/): the gradient-exchange pipeline
-# behind DistributedOptimizer.  SCHED=off restores the single-fused-
-# exchange legacy path; see docs/scheduler.md.
-SCHED = "SCHED"  # on (default) | off
+# behind DistributedOptimizer; see docs/scheduler.md.
 SCHED_MODE = "SCHED_MODE"  # allreduce (default) | reduce_scatter
 SCHED_BUCKET_BYTES = "SCHED_BUCKET_BYTES"  # default: fusion threshold
 SCHED_LOOK_AHEAD = "SCHED_LOOK_AHEAD"  # bucket-close look-ahead, default 3
-SCHED_BARRIERS = "SCHED_BARRIERS"  # optimization_barrier sequencing, default on
-SCHED_CAPTURE_ORDER = "SCHED_CAPTURE_ORDER"  # backward-order hooks, default on
 # Quantized wire v2 (ops/quantized.py + sched/): per-bucket wire format
 # for the scheduler's exchange — off (default; dense/compressor wire) |
 # bf16 | int8 | fp8.  See docs/quantization.md.
@@ -111,9 +107,7 @@ TOPO_FIT_REFIT_EVERY = "TOPO_FIT_REFIT_EVERY"  # new obs between refits
 # Unified exchange IR (xir/): route every collective-shaped workload
 # (dense DP buckets, MoE all_to_all, Ulysses flips, sparse embedding
 # exchange, pipeline ppermute, FSDP RS+AG) through the explicit
-# plan->lower->execute pipeline.  off restores the direct-lax call
-# paths (bitwise identical).  See docs/exchange_ir.md.
-XIR = "XIR"  # on (default) | off
+# plan->lower->execute pipeline.  See docs/exchange_ir.md.
 # Wire format non-gradient IR workloads request (default off — an
 # explicit numerics opt-in, NOT inherited from HVD_TPU_SCHED_WIRE:
 # these ops move activations/embedding rows, not EF-compensated

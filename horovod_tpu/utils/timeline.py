@@ -411,9 +411,8 @@ def _bucket_re():
     if _BUCKET_RE is None:
         import re
 
-        # both engines' scopes: hvd_bucket* (legacy HVD_TPU_SCHED=off)
-        # and hvd_sched_bucket* (the bucketed overlap scheduler)
-        _BUCKET_RE = re.compile(r"hvd_(?:sched_)?bucket(\d+)_(\d+)B")
+        # the bucketed overlap scheduler's per-bucket scopes
+        _BUCKET_RE = re.compile(r"hvd_sched_bucket(\d+)_(\d+)B")
     return _BUCKET_RE
 
 

@@ -26,18 +26,15 @@ rail pipeliner) — phase-interleaves the ICI and DCN rails across
 buckets and merges co-scheduled programs with disjoint rails
 (``HVD_TPU_XIR_PIPELINE``; ordering-only, losses bitwise-identical).
 
-``HVD_TPU_XIR=off`` restores every direct call path (bitwise-identical
-by the interpreter's parity contract).  See docs/exchange_ir.md.
+See docs/exchange_ir.md.
 """
 
 from . import interp, ir, lower, pipeline  # noqa: F401
 from .interp import (  # noqa: F401
     account,
-    enabled,
     execute,
     execute_merged,
     run_op,
-    set_enabled_override,
     wire_request,
 )
 from .ir import (  # noqa: F401

@@ -6,8 +6,9 @@ pre-IR call sites used —
 
 * ``wire="off"``, ``lowering="flat"`` → the stock ``lax`` collective
   with identical arguments, so an IR-routed exchange is **bitwise
-  identical** to the direct call it replaced (the parity contract
-  tests/test_collective_matrix.py's XIR column pins);
+  identical** to the direct call (the parity contract
+  tests/test_collective_matrix.py's XIR column pins against plain
+  ``lax``);
 * ``wire="bf16"`` → the cast-around-the-wire scheme
   (``sched/execute.bf16_wire``'s semantics, applied per op);
 * ``wire="int8"/"fp8"`` on reduce-shaped ops → the
@@ -39,27 +40,6 @@ from .. import metrics
 from ..exceptions import HorovodTpuError
 from ..utils import env
 from . import ir, lower as lower_mod
-
-# Trace-time enable override (the sched config-override pattern):
-# tests and in-script parity checks pin the engine without touching
-# the environment.
-_enabled_override: Optional[bool] = None
-
-
-def set_enabled_override(value: Optional[bool]) -> None:
-    global _enabled_override
-    _enabled_override = value
-
-
-def enabled() -> bool:
-    """Whether exchanges route through the IR (``HVD_TPU_XIR``, default
-    on).  Off restores every workload's direct-``lax`` call path —
-    bitwise identical by the interpreter's own contract, so the knob is
-    a triage lever, not a numerics one."""
-    if _enabled_override is not None:
-        return _enabled_override
-    return env.get_bool("XIR", True)
-
 
 # ----------------------------------------------------- onestep knob
 #

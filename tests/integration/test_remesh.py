@@ -232,7 +232,7 @@ class TestPlanReshard:
             "b": jnp.zeros((7,), jnp.float32),
             "c": jnp.zeros((4, 4), jnp.float32),
         }
-        cfg = sched.SchedConfig(enabled=True, bucket_bytes=128,
+        cfg = sched.SchedConfig(bucket_bytes=128,
                                 lowering="flat")
         return bucket_layouts(params, world, cfg)
 
@@ -562,7 +562,7 @@ def test_in_process_resize_matches_restart_path():
     from horovod_tpu.topo import model as topo_model
 
     loss_fn, fresh_params, batch = _quadratic_setup()
-    cfg = sched.SchedConfig(enabled=True, bucket_bytes=48,
+    cfg = sched.SchedConfig(bucket_bytes=48,
                             lowering="flat")
     tx = optax.adam(0.05)
     steps = 3
@@ -656,7 +656,7 @@ def test_in_process_grow_matches_restart_path():
     from horovod_tpu.topo import model as topo_model
 
     loss_fn, fresh_params, batch = _quadratic_setup()
-    cfg = sched.SchedConfig(enabled=True, bucket_bytes=48,
+    cfg = sched.SchedConfig(bucket_bytes=48,
                             lowering="flat")
     tx = optax.adam(0.05)
     try:
@@ -1088,7 +1088,7 @@ RESIZE_WORKER = textwrap.dedent("""
         x, y = b
         return jnp.mean((x @ p["w"] - y) ** 2)
 
-    cfg = sched.SchedConfig(enabled=True, bucket_bytes=32,
+    cfg = sched.SchedConfig(bucket_bytes=32,
                             lowering="flat")
     params = {"w": jnp.full((2, 1), 0.1, jnp.float32)}
     state = ArrayState(params=params, opt_state=None, epoch=0)

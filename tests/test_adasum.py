@@ -363,7 +363,7 @@ def test_large_batch_stability_property(hvd_module, monkeypatch):
         def run(lowering, steps=40):
             params = {"w": jnp.zeros((d,))}
             sched.set_config_override(sched.SchedConfig(
-                enabled=True, bucket_bytes=4096, lowering=lowering))
+                bucket_bytes=4096, lowering=lowering))
             try:
                 tx = hvd.DistributedOptimizer(optax.sgd(lr), op=hvd.Sum)
                 step = hvd.distributed_train_step(loss_fn, tx)

@@ -512,7 +512,7 @@ class TestHierAdasumColumn:
         params = {"w1": jnp.full((4, 4), 0.2),
                   "w2": jnp.full((4, 2), 0.5), "b": jnp.zeros((2,))}
         sched.set_config_override(sched.SchedConfig(
-            enabled=True, bucket_bytes=64, lowering=lowering))
+            bucket_bytes=64, lowering=lowering))
         try:
             kw = {}
             if op is not None:
@@ -740,7 +740,7 @@ class TestHierAdasumColumn:
         params = {"w1": jnp.full((4, 4), 0.2),
                   "w2": jnp.full((4, 2), 0.5), "b": jnp.zeros((2,))}
         cfg = sched.SchedConfig(
-            enabled=True, bucket_bytes=64, mode="reduce_scatter",
+            bucket_bytes=64, mode="reduce_scatter",
             lowering="hier_adasum",
         )
         lays = bucket_layouts(params, 8, cfg)
@@ -820,13 +820,6 @@ class TestXirColumn:
     cast round trip is exact) — on a 2x2 hybrid mesh, a simulated
     2-slice topology, and process-set subgroups."""
 
-    @pytest.fixture(autouse=True)
-    def _clean(self):
-        from horovod_tpu import xir
-
-        yield
-        xir.set_enabled_override(None)
-
     def _bf16_exact(self, shape, seed):
         # integer-valued f32: exactly representable in bf16, so the
         # bf16 wire's cast round trip changes nothing.
@@ -838,7 +831,6 @@ class TestXirColumn:
         import jax
         from jax.sharding import PartitionSpec as P
 
-        from horovod_tpu import xir
         from horovod_tpu.parallel import make_mesh
         from horovod_tpu.parallel.moe import (
             moe_alltoall_combine,
@@ -864,19 +856,12 @@ class TestXirColumn:
                 out_specs=P("dp", "ep"), check_vma=False,
             ))(x))
 
-        xir.set_enabled_override(True)
-        on = run(roundtrip)
-        xir.set_enabled_override(False)
-        off = run(roundtrip)
-        want = run(direct)
-        np.testing.assert_array_equal(on, want)
-        np.testing.assert_array_equal(off, want)
+        np.testing.assert_array_equal(run(roundtrip), run(direct))
 
     def test_moe_bf16_wire_2x2_mesh(self, hvd_module, monkeypatch):
         import jax
         from jax.sharding import PartitionSpec as P
 
-        from horovod_tpu import xir
         from horovod_tpu.parallel import make_mesh
         from horovod_tpu.parallel.moe import moe_alltoall_dispatch
 
@@ -892,7 +877,6 @@ class TestXirColumn:
         want = run(lambda a: jax.lax.all_to_all(
             a, "ep", split_axis=0, concat_axis=1, tiled=True))
         monkeypatch.setenv("HVD_TPU_XIR_WIRE", "bf16")
-        xir.set_enabled_override(True)
         got = run(lambda a: moe_alltoall_dispatch(a, "ep"))
         np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
 
@@ -901,7 +885,7 @@ class TestXirColumn:
         import jax
         from jax.sharding import PartitionSpec as P
 
-        from horovod_tpu import metrics, topo, xir
+        from horovod_tpu import metrics, topo
         from horovod_tpu.parallel.moe import moe_alltoall_dispatch
         from horovod_tpu.runtime import WORLD_AXIS
 
@@ -918,7 +902,6 @@ class TestXirColumn:
 
             want = run(lambda a: jax.lax.all_to_all(
                 a, WORLD_AXIS, split_axis=0, concat_axis=1, tiled=True))
-            xir.set_enabled_override(True)
             got = run(lambda a: moe_alltoall_dispatch(a, WORLD_AXIS))
             np.testing.assert_array_equal(got, want)
             # the previously-invisible a2a traffic, split by network
@@ -936,7 +919,6 @@ class TestXirColumn:
         import jax
         from jax.sharding import PartitionSpec as P
 
-        from horovod_tpu import xir
         from horovod_tpu.parallel import make_mesh
         from horovod_tpu.parallel.ulysses import ulysses_attention
 
@@ -964,22 +946,19 @@ class TestXirColumn:
 
         want = run(direct)
         monkeypatch.setenv("HVD_TPU_XIR_WIRE", wire)
-        xir.set_enabled_override(True)
         on = run(ul)
-        xir.set_enabled_override(False)
-        off = run(ul)
         if wire == "off":
             np.testing.assert_array_equal(on, want)
         else:
             np.testing.assert_allclose(on, want, rtol=1e-6, atol=1e-6)
-        np.testing.assert_array_equal(off, want)
 
     def test_ulysses_two_slice_full_attention(self, hvd_module,
                                               monkeypatch):
         import jax
         from jax.sharding import PartitionSpec as P
 
-        from horovod_tpu import topo, xir
+        from horovod_tpu import topo
+        from horovod_tpu.parallel.ring_attention import full_attention
         from horovod_tpu.parallel.ulysses import ulysses_attention
         from horovod_tpu.runtime import WORLD_AXIS
 
@@ -991,17 +970,20 @@ class TestXirColumn:
             def ul(a):
                 return ulysses_attention(a, a, a, axis=WORLD_AXIS)
 
-            def run():
+            def direct(a):
+                h = jax.lax.all_to_all(a, WORLD_AXIS, split_axis=2,
+                                       concat_axis=1, tiled=True)
+                return jax.lax.all_to_all(
+                    full_attention(h, h, h, causal=False), WORLD_AXIS,
+                    split_axis=1, concat_axis=2, tiled=True)
+
+            def run(fn):
                 return np.asarray(jax.jit(jax.shard_map(
-                    ul, mesh=hvd.mesh(), in_specs=(P(WORLD_AXIS),),
+                    fn, mesh=hvd.mesh(), in_specs=(P(WORLD_AXIS),),
                     out_specs=P(WORLD_AXIS), check_vma=False,
                 ))(q))
 
-            xir.set_enabled_override(True)
-            on = run()
-            xir.set_enabled_override(False)
-            off = run()
-            np.testing.assert_array_equal(on, off)
+            np.testing.assert_array_equal(run(ul), run(direct))
         finally:
             topo.reset()
 
@@ -1045,7 +1027,7 @@ class TestXirColumn:
         import jax
         from jax.sharding import PartitionSpec as P
 
-        from horovod_tpu import xir
+        from horovod_tpu.ops import traced
         from horovod_tpu.ops.sparse import IndexedSlices, sparse_allreduce
         from horovod_tpu.runtime import WORLD_AXIS
 
@@ -1062,20 +1044,19 @@ class TestXirColumn:
                 )
                 return out.values
 
-            def run():
+            def direct(i, v):
+                return traced.allgather(
+                    v, axis=WORLD_AXIS, process_set=ps) / len(ps.ranks)
+
+            def run(fn):
                 return np.asarray(jax.jit(jax.shard_map(
-                    sp, mesh=hvd.mesh(),
+                    fn, mesh=hvd.mesh(),
                     in_specs=(P(WORLD_AXIS), P(WORLD_AXIS)),
                     out_specs=P(WORLD_AXIS), check_vma=False,
                 ))(idx, vals))
 
-            xir.set_enabled_override(True)
-            on = run()
-            xir.set_enabled_override(False)
-            off = run()
-            np.testing.assert_array_equal(on, off)
+            np.testing.assert_array_equal(run(sp), run(direct))
         finally:
-            xir.set_enabled_override(None)
             hvd.remove_process_set(ps)
 
 
@@ -1122,7 +1103,7 @@ class TestPipelineColumn:
         }
         railpipe.set_mode_override(mode)
         sched.set_config_override(sched.SchedConfig(
-            enabled=True, bucket_bytes=16 * 1024, lowering=lowering,
+            bucket_bytes=16 * 1024, lowering=lowering,
             wire=wire,
         ))
         overlap0 = metrics.get_counter("sched.pipeline.overlap_windows")
@@ -1278,17 +1259,15 @@ class TestPipelineColumn:
         ) < 1e-12
 
 
-@pytest.mark.onestep
-class TestOnestepColumn:
-    """Whole-step emission column of the matrix (``HVD_TPU_ONESTEP``,
-    xir/interp.py): the single-dispatch fold — exchange schedule plus
-    optimizer update traced into one jitted program — against the
-    per-bucket dispatch chain.  Bitwise on the f32 dense wire in every
-    mode (the fold is function composition at trace time: same ops in
-    the same order, the barrier is value-identity), 1e-3 on int8+EF,
-    composed with the hier lowering and the rail-pipelined ordering,
-    plus donation parity for both step classes with ``donate=False``
-    as the numerics hook."""
+class TestCompiledStepColumn:
+    """Compiled-step column of the matrix: ``TrainStep``'s one jitted
+    program — exchange schedule, the exchange→update barrier and the
+    optimizer update — against the plain reference, a ``pmean`` a leaf
+    and the optax update.  Bitwise on the flat f32 dense wire (a
+    barrier is value-identity and bucketing moves no value), 1e-6 with
+    the hier lowering (another order of summation), 1e-3 on int8+EF,
+    the same under the rail-pipelined ordering, plus donation parity
+    for both step classes with ``donate=False`` as the numerics hook."""
 
     @pytest.fixture(autouse=True)
     def _forced_two_slice(self, monkeypatch):
@@ -1304,14 +1283,7 @@ class TestOnestepColumn:
         sched.set_config_override(None)
         topo.reset()
 
-    def _train(self, mode, wire="off", pipeline="off", iters=5,
-               lowering="hier", donate=True):
-        import optax
-
-        from horovod_tpu import metrics, sched
-        from horovod_tpu.xir import interp as xinterp
-        from horovod_tpu.xir import pipeline as railpipe
-
+    def _problem(self):
         rng = np.random.RandomState(7)
         X = rng.randn(32, 64).astype(np.float32)
         Y = (X @ rng.randn(64, 8).astype(np.float32)).astype(np.float32)
@@ -1327,72 +1299,99 @@ class TestOnestepColumn:
             "b1": jnp.zeros((256,)),
             "w2": jnp.asarray(r.randn(256, 8).astype(np.float32) * 0.05),
         }
-        xinterp.set_onestep_override(mode)
+        return loss_fn, p, (jnp.asarray(X), jnp.asarray(Y))
+
+    def _train(self, wire="off", pipeline="off", iters=5,
+               lowering="hier", donate=True):
+        import optax
+
+        from horovod_tpu import sched
+        from horovod_tpu.xir import pipeline as railpipe
+
+        loss_fn, p, batch = self._problem()
         railpipe.set_mode_override(pipeline)
         sched.set_config_override(sched.SchedConfig(
-            enabled=True, bucket_bytes=16 * 1024, lowering=lowering,
+            bucket_bytes=16 * 1024, lowering=lowering,
             wire=wire,
         ))
-        folds0 = metrics.get_counter("xir.onestep.steps")
         try:
             tx = hvd.DistributedOptimizer(optax.sgd(0.05))
             step = hvd.distributed_train_step(loss_fn, tx, donate=donate)
             st = step.init(p)
-            batch = (jnp.asarray(X), jnp.asarray(Y))
             losses = []
             for _ in range(iters):
                 p, st, loss = step(p, st, batch)
                 losses.append(float(loss))
-            folds = metrics.get_counter("xir.onestep.steps") - folds0
-            return losses, folds
+            return losses
         finally:
-            from horovod_tpu import sched as _s
-
-            _s.set_config_override(None)
+            sched.set_config_override(None)
             railpipe.set_mode_override(None)
-            xinterp.set_onestep_override(None)
 
-    def test_onestep_vs_off_bitwise_f32(self, hvd_module):
-        off, n_off = self._train("off")
-        on, n_on = self._train("on")
-        assert off == on  # bitwise: the fold is trace-time composition
-        assert n_off == 0
-        assert n_on > 0  # the whole-step emission actually engaged
+    def _reference(self, iters=5):
+        """Plain jax: a pmean a leaf and the optax update."""
+        import jax
+        import optax
+        from jax import lax
+        from jax.sharding import PartitionSpec as P
 
-    def test_auto_mode_bitwise_and_engaged(self, hvd_module):
-        off, _ = self._train("off")
-        auto, n_auto = self._train("auto")
-        assert off == auto
-        assert n_auto > 0  # multi-unit schedule: auto folds
+        from horovod_tpu.runtime import WORLD_AXIS
+
+        loss_fn, p, batch = self._problem()
+        tx = optax.sgd(0.05)
+
+        def body(p, st, b):
+            loss, g = jax.value_and_grad(loss_fn)(p, b)
+            g = jax.tree.map(lambda x: lax.pmean(x, WORLD_AXIS), g)
+            updates, st = tx.update(g, st, p)
+            return (optax.apply_updates(p, updates), st,
+                    lax.pmean(loss, WORLD_AXIS))
+
+        step = jax.jit(jax.shard_map(
+            body, mesh=hvd.mesh(), in_specs=(P(), P(), P(WORLD_AXIS)),
+            out_specs=(P(), P(), P()), check_vma=False,
+        ))
+        st = tx.init(p)
+        losses = []
+        for _ in range(iters):
+            p, st, loss = step(p, st, batch)
+            losses.append(float(loss))
+        return losses
+
+    def test_flat_bitwise_f32(self, hvd_module):
+        # bitwise: buckets, barriers and the update tie move no value
+        assert self._train(lowering="flat") == self._reference()
+
+    def test_hier_lowering_matches_reference(self, hvd_module):
+        np.testing.assert_allclose(
+            self._train(lowering="hier"), self._reference(),
+            rtol=1e-6, atol=1e-6)
 
     def test_int8_ef_within_tolerance(self, hvd_module):
-        """The quantize/dequantize phases fold along with everything
-        else, so onestep == off holds to the wire's own tolerance and
-        both stay close to dense."""
-        dense, _ = self._train("off")
-        off, _ = self._train("off", wire="int8")
-        on, n_on = self._train("on", wire="int8")
-        assert n_on > 0
-        np.testing.assert_allclose(off, on, rtol=1e-3, atol=1e-3)
-        np.testing.assert_allclose(dense, on, rtol=1e-3, atol=1e-3)
+        """The quantize/dequantize phases sit in the same program as
+        everything else; the step stays within the wire's own
+        tolerance of the dense reference."""
+        np.testing.assert_allclose(
+            self._train(wire="int8"), self._reference(),
+            rtol=1e-3, atol=1e-3)
 
     def test_composes_with_pipelined_ordering(self, hvd_module):
-        """The fold stitches the update onto whatever ordering the
-        rail pipeliner emitted: onestep+pipelined == both off,
-        bitwise (ordering and stitching are both value-identity)."""
-        base, _ = self._train("off", pipeline="off")
-        both, n_both = self._train("on", pipeline="on")
-        assert base == both
-        assert n_both > 0
+        """The update follows whatever ordering the rail pipeliner
+        emitted: pipelined == serialized, bitwise (ordering is
+        value-identity), and both match the reference."""
+        serial = self._train(pipeline="off")
+        piped = self._train(pipeline="on")
+        assert serial == piped
+        np.testing.assert_allclose(
+            piped, self._reference(), rtol=1e-6, atol=1e-6)
 
-    def test_train_step_donation_parity_under_onestep(self, hvd_module):
-        """Donated whole-step program == undonated, bitwise —
-        ``donate=False`` is the numerics hook when in-place buffer
-        reuse is suspected."""
-        donated, _ = self._train("on", donate=True)
-        undonated, _ = self._train("on", donate=False)
-        assert donated == undonated
+    def test_train_step_donation_parity(self, hvd_module):
+        """Donated program == undonated, bitwise — ``donate=False`` is
+        the numerics hook when in-place buffer reuse is suspected."""
+        donated = self._train(lowering="flat", donate=True)
+        undonated = self._train(lowering="flat", donate=False)
+        assert donated == undonated == self._reference()
 
+    @pytest.mark.onestep
     def test_stale_step_donation_parity_under_onestep(self, hvd_module):
         import optax
 

@@ -7,7 +7,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.models import gpt_tiny
@@ -116,13 +116,31 @@ def test_hybrid_dp_tp_training_matches_single_device():
 class TestRule2x2Mesh:
     """pmean-vs-divide rule pinned on a 2×2 dp×tp mesh: replicated,
     tp-sharded, and mixed ``param_shard_axes`` pytrees — and the
-    scheduler-mode exchange must match the reference per-leaf path
-    bit-for-bit in f32."""
+    bucketed exchange must match the per-leaf rule written out in
+    plain ``lax`` bit-for-bit in f32."""
 
     def _mesh(self):
         return make_mesh(dp=2, tp=2, devices=jax.devices()[:4])
 
-    def _run(self, scheduled):
+    @staticmethod
+    def _per_leaf_rule(grads, shard_axes, axes):
+        """The rule of grad_sync's docstring, a leaf at a time: pmean
+        over the axes the parameter is not sharded over, divide by the
+        size of those it is."""
+        def sync(g, sharded_str):
+            sharded = sharded_str.split()
+            mean_over = tuple(a for a in axes if a not in sharded)
+            if mean_over:
+                g = lax.pmean(g, mean_over)
+            scale = 1
+            for a in axes:
+                if a in sharded:
+                    scale *= lax.axis_size(a)
+            return g / scale if scale != 1 else g
+
+        return jax.tree.map(sync, grads, shard_axes)
+
+    def _run(self, sync):
         mesh = self._mesh()
         # distinct per-device blocks: x is sharded over (dp, tp)
         x = jnp.arange(16.0, dtype=jnp.float32).reshape(4, 4)
@@ -130,9 +148,7 @@ class TestRule2x2Mesh:
 
         def fn(x):
             g = {"rep": x, "tp": x * 2.0, "mix": x + 1.0}
-            return sync_gradients(
-                g, axes_tree, axes=("dp", "tp"), scheduled=scheduled
-            )
+            return sync(g, axes_tree, axes=("dp", "tp"))
 
         spec = {"rep": P("dp", "tp"), "tp": P("dp", "tp"),
                 "mix": P("dp", "tp")}
@@ -174,21 +190,21 @@ class TestRule2x2Mesh:
         return out
 
     def test_rule_replicated_tp_sharded_mixed(self):
-        got = self._run(scheduled=False)
+        got = self._run(self._per_leaf_rule)
         want = self._expected()
         for k in want:
             np.testing.assert_allclose(got[k], want[k], rtol=1e-6)
 
     def test_scheduler_mode_bit_for_bit(self):
-        """Scheduler-mode exchange == reference per-leaf path, exact
+        """Bucketed exchange == the per-leaf rule in plain lax, exact
         f32 equality (pmean is elementwise; bucketing moves no value)."""
-        ref = self._run(scheduled=False)
-        got = self._run(scheduled=True)
+        ref = self._run(self._per_leaf_rule)
+        got = self._run(sync_gradients)
         for k in ref:
             np.testing.assert_array_equal(got[k], ref[k])
 
     def test_scheduler_mode_matches_rule(self):
-        got = self._run(scheduled=True)
+        got = self._run(sync_gradients)
         want = self._expected()
         for k in want:
             np.testing.assert_allclose(got[k], want[k], rtol=1e-6)

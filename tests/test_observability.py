@@ -140,27 +140,6 @@ class TestTrainStepTimeline:
         assert all(e["args"]["bytes"] > 0 for e in plans)
         assert any(e["name"].startswith("bucket0") for e in plans)
 
-    def test_timeline_records_bucket_lanes_legacy_engine(
-        self, monkeypatch, tmp_path
-    ):
-        """HVD_TPU_SCHED=off keeps the legacy FUSION_PLAN lanes."""
-        path = tmp_path / "timeline.json"
-        monkeypatch.setenv("HVD_TPU_TIMELINE", str(path))
-        monkeypatch.setenv("HVD_TPU_FUSION_THRESHOLD", "600")
-        monkeypatch.setenv("HVD_TPU_SCHED", "off")
-        hvd.init()
-        try:
-            step, params, opt_state, batch = _tiny_step(hvd)
-            params, opt_state, loss = step(params, opt_state, batch)
-            float(loss)
-        finally:
-            hvd.shutdown()
-        events = json.loads(path.read_text())
-        plans = [e for e in events if e.get("cat") == "FUSION_PLAN"]
-        assert len(plans) >= 2, plans
-        assert all(e["args"]["bytes"] > 0 for e in plans)
-        assert any(e["name"].startswith("bucket0") for e in plans)
-
     def test_compiled_step_hlo_names_buckets(self, monkeypatch):
         import jax
 
@@ -175,25 +154,6 @@ class TestTrainStepTimeline:
             hlo = fn.lower(params, None, opt_state, batch).compile().as_text()
             assert "hvd_sched_bucket0" in hlo
             assert "hvd_sched_bucket1" in hlo
-        finally:
-            hvd.shutdown()
-
-    def test_compiled_step_hlo_names_buckets_legacy_engine(
-        self, monkeypatch
-    ):
-        import jax
-
-        monkeypatch.setenv("HVD_TPU_FUSION_THRESHOLD", "600")
-        monkeypatch.setenv("HVD_TPU_SCHED", "off")
-        hvd.init()
-        try:
-            step, params, opt_state, batch = _tiny_step(hvd)
-            params, opt_state, loss = step(params, opt_state, batch)
-            float(loss)
-            fn = next(iter(step._step_cache.values()))
-            hlo = fn.lower(params, None, opt_state, batch).compile().as_text()
-            assert "hvd_bucket0" in hlo
-            assert "hvd_bucket1" in hlo
         finally:
             hvd.shutdown()
 

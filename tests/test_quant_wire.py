@@ -185,22 +185,43 @@ def test_bf16_wire_rides_per_bucket(hvd_module):
 
 
 def test_wire_bytes_gauges_and_ratio(hvd_module):
-    """Acceptance: sched.wire_bytes{wire=int8} shows >= 3x reduction vs
-    the fp32 wire on the same schedule."""
+    """sched.wire_bytes{wire=int8} reads the bytes the wire format
+    defines, n + 4 * ceil(n / block) a bucket; against the fp32 wire on
+    the same schedule that is >= 3x once a bucket fills its blocks (a
+    26-element problem pays 12 bytes of scales on 26 of payload)."""
+    from horovod_tpu.ops.quantized import quant_block
+
+    def wire_gauges(params, batch, loss_fn, bucket_bytes):
+        metrics.reset_counters("sched.")
+        _run_steps(loss_fn, params, batch,
+                   SchedConfig(bucket_bytes=bucket_bytes))
+        dense = metrics.get_gauge("sched.wire_bytes", {"wire": "off"})
+        metrics.reset_counters("sched.")
+        _run_steps(loss_fn, params, batch,
+                   SchedConfig(bucket_bytes=bucket_bytes, wire="int8"))
+        return dense, metrics.get_gauge("sched.wire_bytes",
+                                        {"wire": "int8"})
+
+    block = quant_block()
     params, batch, loss_fn = _problem()
-    metrics.reset_counters("sched.")
-    _run_steps(loss_fn, params, batch, SchedConfig(bucket_bytes=64))
-    dense_bytes = metrics.get_gauge("sched.wire_bytes",
-                                    {"wire": "off"})
-    assert dense_bytes and dense_bytes > 0
-    metrics.reset_counters("sched.")
-    _run_steps(loss_fn, params, batch,
-               SchedConfig(bucket_bytes=64, wire="int8"))
-    int8_bytes = metrics.get_gauge("sched.wire_bytes", {"wire": "int8"})
-    assert int8_bytes and int8_bytes > 0
+    elems = [int(np.prod(v.shape)) for v in params.values()]
+    assert max(elems) * F32 <= 64 and sum(elems) < block
+    dense_bytes, int8_bytes = wire_gauges(params, batch, loss_fn, 64)
+    assert dense_bytes == sum(elems) * F32
+    # every bucket is smaller than one block: one fp32 scale each
+    buckets = metrics.get_gauge("sched.buckets_per_step")
+    assert int8_bytes == sum(elems) + 4 * buckets
+    assert metrics.get_counter("sched.wire_bytes.int8") > 0
+
+    n = 8 * block  # one bucket of whole blocks
+    wide = {"w": jnp.full((n,), 0.01)}
+    x = jnp.ones((16, n))
+    dense_bytes, int8_bytes = wire_gauges(
+        wide, x, lambda p, b: jnp.mean((b @ p["w"]) ** 2), n * F32)
+    assert dense_bytes == n * F32
+    assert int8_bytes == n + 4 * (n // block)
     assert dense_bytes / int8_bytes >= 3.0
     assert metrics.get_gauge("sched.compression_ratio") >= 3.0
-    assert metrics.get_counter("sched.wire_bytes.int8") > 0
 
 
 def test_gradient_accumulation_threads_residual(hvd_module):

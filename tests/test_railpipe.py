@@ -261,7 +261,7 @@ class TestRailParity:
         import optax
 
         railpipe.set_mode_override(mode)
-        cfg = sched.SchedConfig(enabled=True, bucket_bytes=16 * 1024,
+        cfg = sched.SchedConfig(bucket_bytes=16 * 1024,
                                 lowering="hier")
         rng = np.random.RandomState(5)
         X = rng.randn(16, 32).astype(np.float32)
@@ -296,7 +296,7 @@ class TestRailParity:
 
         g = {"a": np.random.RandomState(9).randn(8, 64)
              .astype(np.float32)}
-        cfg = sched.SchedConfig(enabled=True, bucket_bytes=64,
+        cfg = sched.SchedConfig(bucket_bytes=64,
                                 lowering="hier")
 
         def f(grads):

@@ -2,11 +2,15 @@
 reverse-backward order, exchange-mode equivalence, bucketed ZeRO-1,
 per-bucket compression, and registry-fed bucket-size tuning."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax import lax
+from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
 from horovod_tpu import metrics, sched
@@ -170,29 +174,61 @@ def _run_steps(loss_fn, params, batch, cfg, n=3, **opt_kwargs):
         sched.set_config_override(None)
 
 
-def test_sched_on_off_losses_identical_f32(hvd_module):
-    """The scheduler engine is numerics-identical (f32, rtol=0) to the
-    legacy single-fused-exchange path."""
+def _reference_steps(loss_fn, params, batch, n=3, wire_dtype=None):
+    """The plain reference: a ``pmean`` a leaf (in ``wire_dtype`` when
+    given) and the optax update, nothing of the scheduler."""
+    tx = optax.sgd(0.1)
+
+    def body(p, st, b):
+        loss, g = jax.value_and_grad(loss_fn)(p, b)
+        if wire_dtype is not None:
+            g = jax.tree.map(lambda x: x.astype(wire_dtype), g)
+        g = jax.tree.map(lambda x: lax.pmean(x, hvd.WORLD_AXIS), g)
+        g = jax.tree.map(lambda x, q: x.astype(q.dtype), g, p)
+        updates, st = tx.update(g, st, p)
+        return (optax.apply_updates(p, updates), st,
+                lax.pmean(loss, hvd.WORLD_AXIS))
+
+    step = jax.jit(jax.shard_map(
+        body, mesh=hvd.mesh(), in_specs=(P(), P(), P(hvd.WORLD_AXIS)),
+        out_specs=(P(), P(), P()), check_vma=False,
+    ))
+    p, st = fresh(params), tx.init(params)
+    losses = []
+    for _ in range(n):
+        p, st, loss = step(p, st, batch)
+        losses.append(float(loss))
+    return p, losses
+
+
+def test_sched_losses_identical_to_per_leaf_reference_f32(hvd_module):
+    """The scheduler engine is numerics-identical (f32, rtol=0) to a
+    per-leaf pmean and the plain optax update."""
     params, batch, loss_fn = _problem()
     # tiny buckets: the three grads exchange as separate buckets
-    on = SchedConfig(enabled=True, bucket_bytes=64)
-    off = SchedConfig(enabled=False)
-    p_on, l_on = _run_steps(loss_fn, params, batch, on)
-    p_off, l_off = _run_steps(loss_fn, params, batch, off)
-    assert l_on == l_off  # bitwise: same floats through repr round-trip
+    p_on, l_on = _run_steps(
+        loss_fn, params, batch, SchedConfig(bucket_bytes=64))
+    p_ref, l_ref = _reference_steps(loss_fn, params, batch)
+    assert l_on == l_ref  # bitwise: same floats through repr round-trip
     for k in params:
         np.testing.assert_array_equal(
-            np.asarray(p_on[k]), np.asarray(p_off[k])
+            np.asarray(p_on[k]), np.asarray(p_ref[k])
         )
     assert metrics.get_gauge("sched.buckets_per_step") >= 2
 
 
-def test_sched_no_barriers_identical(hvd_module):
+def test_sched_barrier_chain_is_ordering_only(hvd_module):
+    """Three barrier-chained buckets and one bucket (no chain) give the
+    reference's losses alike: the barriers touch no value."""
     params, batch, loss_fn = _problem()
-    a = _run_steps(loss_fn, params, batch,
-                   SchedConfig(bucket_bytes=64, barriers=False))
-    b = _run_steps(loss_fn, params, batch, SchedConfig(enabled=False))
-    assert a[1] == b[1]
+    chained = _run_steps(
+        loss_fn, params, batch, SchedConfig(bucket_bytes=64))
+    assert metrics.get_gauge("sched.buckets_per_step") >= 2
+    single = _run_steps(
+        loss_fn, params, batch, SchedConfig(bucket_bytes=1 << 20))
+    assert metrics.get_gauge("sched.buckets_per_step") == 1
+    ref = _reference_steps(loss_fn, params, batch)
+    assert chained[1] == single[1] == ref[1]
 
 
 def test_reduce_scatter_mode_matches_allreduce(hvd_module):
@@ -243,8 +279,7 @@ def test_explicit_groups_ride_as_pinned_buckets(hvd_module):
     params, batch, loss_fn = _problem()
     a = _run_steps(loss_fn, params, batch, SchedConfig(bucket_bytes=64),
                    groups=[[0, 2]])
-    b = _run_steps(loss_fn, params, batch, SchedConfig(enabled=False),
-                   groups=[[0, 2]])
+    b = _reference_steps(loss_fn, params, batch)
     assert a[1] == b[1]
 
 
@@ -253,14 +288,13 @@ def test_explicit_groups_ride_as_pinned_buckets(hvd_module):
 def test_compression_round_trip_per_bucket(hvd_module):
     """bf16 wire: the plan carries the bucket's wire dtype, the
     exchange casts per leaf, and the decompressed output restores f32
-    — identical between scheduler and legacy engines."""
+    — identical to a per-leaf pmean of the bf16 casts."""
     params, batch, loss_fn = _problem()
     on = SchedConfig(bucket_bytes=64)
     p_on, l_on = _run_steps(loss_fn, params, batch, on,
                             compression=hvd.Compression.bf16)
-    p_off, l_off = _run_steps(loss_fn, params, batch,
-                              SchedConfig(enabled=False),
-                              compression=hvd.Compression.bf16)
+    p_off, l_off = _reference_steps(loss_fn, params, batch,
+                                    wire_dtype=jnp.bfloat16)
     assert l_on == l_off
     for k in params:
         assert p_on[k].dtype == jnp.float32
@@ -407,14 +441,84 @@ def test_exchange_metrics_and_gauges(hvd_module):
 
 
 def test_sched_config_from_env(monkeypatch):
-    monkeypatch.setenv("HVD_TPU_SCHED", "off")
     monkeypatch.setenv("HVD_TPU_SCHED_MODE", "reduce_scatter")
     monkeypatch.setenv("HVD_TPU_SCHED_BUCKET_BYTES", "4096")
     monkeypatch.setenv("HVD_TPU_SCHED_LOOK_AHEAD", "7")
     cfg = SchedConfig.from_env()
-    assert not cfg.enabled
     assert cfg.mode == "reduce_scatter"
     assert cfg.bucket_bytes == 4096
     assert cfg.look_ahead == 7
-    monkeypatch.setenv("HVD_TPU_SCHED", "on")
-    assert SchedConfig.from_env().enabled
+    # every field is a plan or wire choice: the engine has no off switch
+    assert {f.name for f in dataclasses.fields(SchedConfig)} == {
+        "mode", "bucket_bytes", "look_ahead", "wire", "wire_ef", "lowering"}
+
+
+# ------------------------------------------- the compiled step's program
+
+_CHAIN = {"a": (4, 4), "b": (4, 6), "c": (6, 2)}  # applied a, b, c
+
+
+def _lowered_step_hlo(case, k):
+    """The HLO ``TrainStep`` hands the compiler (the CPU backend expands
+    barriers away, so the compiled text no longer shows them) for a
+    three-matmul chain whose gradients, one bucket each, become ready
+    c, b, a."""
+    params = {n: jnp.full(s, 0.1) for n, s in _CHAIN.items()}
+    x = jnp.ones((16, 4))
+
+    def chain(p, b):
+        return jnp.sum(b @ p["a"] @ p["b"] @ p["c"])
+
+    compression = hvd.Compression.none
+    stateful = False
+    loss_fn = chain
+    if case == "bf16_compression":
+        compression = hvd.Compression.bf16
+    elif case == "stateful":
+        stateful = True
+
+        def loss_fn(p, state, b):
+            return chain(p, b), {"seen": state["seen"] + 1.0}
+
+    sched.set_config_override(SchedConfig(bucket_bytes=8))
+    tx = hvd.DistributedOptimizer(
+        optax.sgd(0.1), compression=compression,
+        backward_passes_per_step=k)
+    step = hvd.distributed_train_step(loss_fn, tx, stateful=stateful)
+    st = step.init(params)
+    model_state = {"seen": jnp.zeros((3,))} if stateful else None
+    fn = step._build_step(step._state_specs(st))
+    return fn.lower(params, model_state, st, x).as_text(dialect="hlo")
+
+
+@pytest.mark.parametrize("k", [1, 2], ids=["k1", "k2"])
+@pytest.mark.parametrize(
+    "case", ["dense_f32", "bf16_compression", "stateful"])
+def test_step_program_orders_exchange_before_update(hvd_module, case, k):
+    """What the step's program holds of the exchange: one all-reduce a
+    bucket in reverse-backward order, chained by barriers, and — only
+    where the update follows at once (``backward_passes_per_step=1``) —
+    exactly one barrier over all reduced wire-dtype leaves after the
+    last of them."""
+    import re
+
+    hlo = _lowered_step_hlo(case, k).splitlines()
+    dtype = "bf16" if case == "bf16_compression" else "f32"
+    sizes = {int(np.prod(s)): n for n, s in _CHAIN.items()}
+    reduces = []  # (line number, leaf name) of the gradients' all-reduces
+    for i, line in enumerate(hlo):
+        m = re.search(r"= (\w+)\[(\d+)\]\{0\} all-reduce\(", line)
+        if m and m.group(1) == dtype and int(m.group(2)) in sizes:
+            reduces.append((i, sizes[int(m.group(2))]))
+    assert [name for _, name in reduces] == ["c", "b", "a"]
+    barriers = [i for i, line in enumerate(hlo) if " opt-barrier(" in line]
+    first, last = reduces[0][0], reduces[-1][0]
+    # the chain: a barrier with the carried token before buckets 1 and 2
+    assert len([i for i in barriers if first < i < last]) >= 2
+    after = [hlo[i] for i in barriers if i > last]
+    if k == 1:
+        (tie,) = after
+        operands = re.search(r"= \((.*)\) opt-barrier\(", tie).group(1)
+        assert re.findall(r"(\w+)\[", operands) == [dtype] * 3, tie
+    else:
+        assert after == []

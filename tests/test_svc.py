@@ -536,7 +536,7 @@ class TestNegotiationStallInspector:
 def _train(svc_on, iters=6, lr=0.05):
     svc.set_enabled_override(svc_on)
     sched.set_config_override(
-        sched.SchedConfig(enabled=True, bucket_bytes=2048)
+        sched.SchedConfig(bucket_bytes=2048)
     )
     try:
         rng = np.random.RandomState(0)

@@ -341,7 +341,7 @@ class TestStepNesting:
     def _hier_train(self, iters=3):
         topo.set_topology_override(T24)
         sched.set_config_override(sched.SchedConfig(
-            enabled=True, bucket_bytes=2048, lowering="hier",
+            bucket_bytes=2048, lowering="hier",
         ))
         rng = np.random.RandomState(0)
         X = rng.randn(16, 32).astype(np.float32)

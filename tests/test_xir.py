@@ -14,6 +14,7 @@ surface (kind-labeled gauges, XIR counters, timeline lanes).
 import json
 
 import jax
+from jax import lax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -32,7 +33,6 @@ N = 8
 @pytest.fixture(autouse=True)
 def _clean_overrides():
     yield
-    xir.set_enabled_override(None)
     sched.set_config_override(None)
 
 
@@ -262,12 +262,12 @@ class TestStoreKeying:
 
 class TestDenseGradParity:
     """The tentpole acceptance: f32 dense DP programs through the IR
-    are bitwise-identical to the PR 7 direct path."""
+    are bitwise-identical to a per-leaf ``pmean`` and the optax
+    update."""
 
-    def _losses(self, xir_on):
+    def _losses(self, through_ir):
         import optax
 
-        xir.set_enabled_override(xir_on)
         X = np.random.RandomState(1).randn(16, 4).astype(np.float32)
         Y = (X @ np.full((4, 2), 0.7)).astype(np.float32)
 
@@ -277,22 +277,35 @@ class TestDenseGradParity:
 
         params = {"w1": jnp.full((4, 4), 0.2),
                   "w2": jnp.full((4, 2), 0.5), "b": jnp.zeros((2,))}
-        sched.set_config_override(
-            sched.SchedConfig(enabled=True, bucket_bytes=64)
-        )
-        try:
+        batch = (jnp.asarray(X), jnp.asarray(Y))
+        if through_ir:
+            sched.set_config_override(
+                sched.SchedConfig(bucket_bytes=64)
+            )
             tx = hvd.DistributedOptimizer(optax.sgd(0.1))
             step = hvd.distributed_train_step(loss_fn, tx)
             st = step.init(params)
-            batch = (jnp.asarray(X), jnp.asarray(Y))
-            out = []
-            for _ in range(8):
-                params, st, loss = step(params, st, batch)
-                out.append(float(loss))
-            return out
-        finally:
-            sched.set_config_override(None)
-            xir.set_enabled_override(None)
+        else:
+            tx = optax.sgd(0.1)
+
+            def plain(p, st, b):
+                loss, g = jax.value_and_grad(loss_fn)(p, b)
+                g = jax.tree.map(lambda x: lax.pmean(x, WORLD_AXIS), g)
+                updates, st = tx.update(g, st, p)
+                return (optax.apply_updates(p, updates), st,
+                        lax.pmean(loss, WORLD_AXIS))
+
+            step = jax.jit(jax.shard_map(
+                plain, mesh=hvd.mesh(),
+                in_specs=(P(), P(), P(WORLD_AXIS)),
+                out_specs=(P(), P(), P()), check_vma=False,
+            ))
+            st = tx.init(params)
+        out = []
+        for _ in range(8):
+            params, st, loss = step(params, st, batch)
+            out.append(float(loss))
+        return out
 
     def test_f32_dense_losses_bitwise(self, hvd_module):
         assert self._losses(True) == self._losses(False)
@@ -317,17 +330,30 @@ class TestWorkloadParity:
                 axis=WORLD_AXIS,
             )
 
-        def run():
+        def plain(wstack, m):
+            # the same schedule unrolled, its hops lax.ppermute itself
+            stage = lax.axis_index(WORLD_AXIS)
+            shift = [(j, (j + 1) % N) for j in range(N)]
+            act, out = jnp.zeros_like(m[0]), jnp.zeros_like(m)
+            for s in range(m.shape[0] + N - 1):
+                x_in = m[min(s, m.shape[0] - 1)]
+                y = jnp.tanh(jnp.where(stage == 0, x_in, act) @ wstack[0])
+                if s >= N - 1:
+                    k = s - (N - 1)
+                    out = out.at[k].set(
+                        jnp.where(stage == N - 1, y, out[k]))
+                act = lax.ppermute(y, WORLD_AXIS, shift)
+            return lax.psum(
+                jnp.where(stage == N - 1, out, jnp.zeros_like(out)),
+                WORLD_AXIS)
+
+        def run(fn):
             return np.asarray(jax.jit(jax.shard_map(
-                pp, mesh=hvd.mesh(), in_specs=(P(WORLD_AXIS), P()),
+                fn, mesh=hvd.mesh(), in_specs=(P(WORLD_AXIS), P()),
                 out_specs=P(), check_vma=False,
             ))(w, mb))
 
-        xir.set_enabled_override(True)
-        on = run()
-        xir.set_enabled_override(False)
-        off = run()
-        np.testing.assert_array_equal(on, off)
+        np.testing.assert_array_equal(run(pp), run(plain))
 
     def test_fsdp_step_bitwise(self, hvd_module):
         import optax
@@ -341,17 +367,38 @@ class TestWorkloadParity:
         def loss_fn(p, b):
             return jnp.mean((b @ p["w"]) ** 2)
 
-        losses = {}
-        for flag in (True, False):
-            xir.set_enabled_override(flag)
-            step = fsdp_train_step(loss_fn, optax.sgd(0.1))
-            ps, st = step.init(params)
-            ls = []
-            for _ in range(3):
-                ps, st, loss = step(ps, st, jnp.asarray(X))
-                ls.append(float(loss))
-            losses[flag] = ls
-        assert losses[True] == losses[False]
+        step = fsdp_train_step(loss_fn, optax.sgd(0.1))
+        ps, st = step.init(params)
+        got = []
+        for _ in range(3):
+            ps, st, loss = step(ps, st, jnp.asarray(X))
+            got.append(float(loss))
+
+        # the plain reference: the same step on the raveled vector, one
+        # element a rank, written with lax's own collectives
+        tx = optax.sgd(0.1)
+
+        def plain(shard, st, b):
+            full = lax.all_gather(shard, WORLD_AXIS, tiled=True)
+            loss, g = jax.value_and_grad(
+                lambda f: loss_fn({"w": f.reshape(4, 2)}, b))(full)
+            g = lax.psum_scatter(
+                g, WORLD_AXIS, scatter_dimension=0, tiled=True) / N
+            updates, st = tx.update(g, st, shard)
+            return (optax.apply_updates(shard, updates), st,
+                    lax.pmean(loss, WORLD_AXIS))
+
+        ref_step = jax.jit(jax.shard_map(
+            plain, mesh=hvd.mesh(),
+            in_specs=(P(WORLD_AXIS), P(), P(WORLD_AXIS)),
+            out_specs=(P(WORLD_AXIS), P(), P()), check_vma=False,
+        ))
+        shard, ref_st = params["w"].reshape(-1), tx.init(jnp.zeros((1,)))
+        want = []
+        for _ in range(3):
+            shard, ref_st, loss = ref_step(shard, ref_st, jnp.asarray(X))
+            want.append(float(loss))
+        assert got == want
 
     def test_sparse_exchange_bitwise_and_observable(self, hvd_module):
         from horovod_tpu.ops.sparse import IndexedSlices, sparse_allreduce
@@ -365,19 +412,18 @@ class TestWorkloadParity:
             )
             return out.values
 
-        def run():
+        def plain(i, v):
+            return lax.all_gather(v, WORLD_AXIS, tiled=True) / N
+
+        def run(fn):
             return np.asarray(jax.jit(jax.shard_map(
-                sp, mesh=hvd.mesh(),
+                fn, mesh=hvd.mesh(),
                 in_specs=(P(WORLD_AXIS), P(WORLD_AXIS)),
                 out_specs=P(WORLD_AXIS), check_vma=False,
             ))(idx, vals))
 
         metrics.reset_counters("xir.programs.sparse_embed")
-        xir.set_enabled_override(True)
-        on = run()
-        xir.set_enabled_override(False)
-        off = run()
-        np.testing.assert_array_equal(on, off)
+        np.testing.assert_array_equal(run(sp), run(plain))
         assert metrics.get_counter("xir.programs.sparse_embed") == 1
         assert metrics.get_gauge(
             "sched.wire_bytes", {"wire": "off", "kind": "sparse_embed"}
@@ -430,18 +476,7 @@ class TestInterpReduceOps:
             xir.execute(prog, [1, 2], store=False)
 
 
-class TestEnableKnob:
-    def test_env_default_on(self, monkeypatch):
-        monkeypatch.delenv("HVD_TPU_XIR", raising=False)
-        assert xir.enabled()
-        monkeypatch.setenv("HVD_TPU_XIR", "off")
-        assert not xir.enabled()
-
-    def test_override_wins(self, monkeypatch):
-        monkeypatch.setenv("HVD_TPU_XIR", "off")
-        xir.set_enabled_override(True)
-        assert xir.enabled()
-
+class TestWireKnob:
     def test_wire_request_default_off_and_validated(self, monkeypatch):
         monkeypatch.delenv("HVD_TPU_XIR_WIRE", raising=False)
         monkeypatch.setenv("HVD_TPU_SCHED_WIRE", "int8")

@@ -66,10 +66,10 @@ def run(cfg):
         sched.set_config_override(None)
 
 
-hier = run(sched.SchedConfig(enabled=True, bucket_bytes=64,
+hier = run(sched.SchedConfig(bucket_bytes=64,
                              lowering="hier"))
 dcn_hier = metrics.get_gauge("topo.dcn_bytes")
-adasum = run(sched.SchedConfig(enabled=True, bucket_bytes=64,
+adasum = run(sched.SchedConfig(bucket_bytes=64,
                                lowering="hier_adasum"))
 dcn_adasum = metrics.get_gauge("topo.dcn_bytes")
 buckets = metrics.get_gauge("topo.buckets", {"lowering": "hier_adasum"})
@@ -134,7 +134,7 @@ def losses(lowering):
         "b": jnp.zeros((2,)),
     }
     sched.set_config_override(sched.SchedConfig(
-        enabled=True, bucket_bytes=64, lowering=lowering))
+        bucket_bytes=64, lowering=lowering))
     try:
         tx = hvd.DistributedOptimizer(optax.sgd(0.1))
         step = hvd.distributed_train_step(loss_fn, tx)

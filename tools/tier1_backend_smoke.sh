@@ -88,8 +88,8 @@ def run(cfg, fam):
         set_family(None)
 
 
-dense_cfg = sched.SchedConfig(enabled=True, bucket_bytes=64)
-quant_cfg = sched.SchedConfig(enabled=True, bucket_bytes=64,
+dense_cfg = sched.SchedConfig(bucket_bytes=64)
+quant_cfg = sched.SchedConfig(bucket_bytes=64,
                               wire="int8", wire_ef=True)
 
 # 1. dense f32: gpu family bitwise == tpu family

@@ -1,13 +1,12 @@
 #!/usr/bin/env bash
-# Whole-step single-dispatch smoke: a 4-process CPU run on a forced
-# 2x4 topology must produce HVD_TPU_ONESTEP=on losses bitwise equal
-# to =off (and =auto) for a hier multi-bucket training loop — the
-# fold is trace-time composition, never a numerics change — with the
-# xir.onestep.steps counter proving the emission actually engaged.
-# On the N-small-programs-across-several-fusion-classes service burst
-# (the ROADMAP item 4 workload), the folded run must pay exactly ONE
-# svc dispatch per cycle (prof.dispatches_per_step p50 == 1 where the
-# off run pays one per class) and show a measured host-gap reduction
+# Whole-step single-dispatch smoke (the exchange service's fold; the
+# compiled train step is one program already and has no such knob): a
+# 4-process CPU run on a forced 2x4 topology.  On the
+# N-small-programs-across-several-fusion-classes service burst (the
+# ROADMAP item 4 workload), the folded run must be bitwise equal to
+# the per-unit one, pay exactly ONE svc dispatch per cycle
+# (prof.dispatches_per_step p50 == 1 where the off run pays one per
+# class) and show a measured host-gap reduction
 # (prof.host_gap_seconds mean, off/on > 1.05; tools/topo_bench.py
 # --onestep records the >= 1.15 solo-process number).  A
 # ScheduleTuner(explore_onestep=True) explores off -> on -> auto,
@@ -17,9 +16,8 @@
 # Each of the 4 worker processes runs its own 8-virtual-device SPMD
 # world (this jax build's CPU backend rejects cross-process
 # computations, so the processes are independent replicas of the same
-# seeded loop): the assertions cover onestep on==off inside every
-# process AND bitwise agreement of the folded trajectories across all
-# 4 processes (the fold re-emits the same ops in the same order).
+# seeded burst): the assertions cover onestep on==off inside every
+# process.
 set -euo pipefail
 
 export JAX_PLATFORMS=cpu
@@ -44,7 +42,6 @@ import sys
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 
 import horovod_tpu as hvd
 from horovod_tpu import metrics, sched, svc, trace, xir
@@ -54,54 +51,6 @@ from horovod_tpu.xir import interp as xinterp
 hvd.init()
 
 rng = np.random.RandomState(7)
-X = rng.randn(32, 64).astype(np.float32)
-Y = (X @ rng.randn(64, 8).astype(np.float32)).astype(np.float32)
-
-
-def loss_fn(p, b):
-    x, y = b
-    h = jnp.tanh(x @ p["w1"] + p["b1"])
-    return jnp.mean((h @ p["w2"] - y) ** 2)
-
-
-def params():
-    r = np.random.RandomState(3)
-    return {
-        "w1": jnp.asarray(r.randn(64, 256).astype(np.float32) * 0.05),
-        "b1": jnp.zeros((256,)),
-        "w2": jnp.asarray(r.randn(256, 8).astype(np.float32) * 0.05),
-    }
-
-
-def train(mode, iters=8):
-    xinterp.set_onestep_override(mode)
-    sched.set_config_override(sched.SchedConfig(
-        enabled=True, bucket_bytes=16 * 1024, lowering="hier",
-    ))
-    f0 = metrics.get_counter("xir.onestep.steps")
-    try:
-        p = params()
-        tx = hvd.DistributedOptimizer(optax.sgd(0.05))
-        step = hvd.distributed_train_step(loss_fn, tx)
-        st = step.init(p)
-        batch = (jnp.asarray(X), jnp.asarray(Y))
-        losses = []
-        for _ in range(iters):
-            p, st, loss = step(p, st, batch)
-            losses.append(float(loss))
-        return losses, metrics.get_counter("xir.onestep.steps") - f0
-    finally:
-        sched.set_config_override(None)
-        xinterp.set_onestep_override(None)
-
-
-off, n_off = train("off")
-on, n_on = train("on")
-auto, n_auto = train("auto")
-assert off == on, f"onestep on != off (bitwise): {off} vs {on}"
-assert off == auto, f"onestep auto != off (bitwise): {off} vs {auto}"
-assert n_off == 0, f"off run emitted a fold: {n_off}"
-assert n_on > 0 and n_auto > 0, "fold never engaged under on/auto"
 
 # --- service burst: one dispatch per cycle, measured host gap -------
 rows, per_class = 64, 3
@@ -199,7 +148,7 @@ t2 = sched.ScheduleTuner(explore_onestep=True, store="env",
 assert t2.converged, "warm start did not converge at window 0"
 assert t2.onestep() == "on", "warm start lost the onestep winner"
 
-json.dump({"losses": on, "folds": n_on, "disp_p50": b_on["disp_p50"],
+json.dump({"disp_p50": b_on["disp_p50"],
            "gap_ratio": round(gap_ratio, 3),
            "winner": t1.onestep()}, sys.stdout)
 EOF
@@ -219,14 +168,9 @@ import sys
 
 worker = sys.argv[1]
 results = [json.load(open(f"{worker}.out.{i}")) for i in range(4)]
-vals = [r["losses"] for r in results]
-assert all(v == vals[0] for v in vals), \
-    f"folded trajectories diverged across processes: {vals}"
-assert all(r["folds"] > 0 for r in results), results
 assert all(r["disp_p50"] == 1.0 for r in results), results
 assert all(r["winner"] == "on" for r in results), results
-print(f"onestep smoke OK x 4 procs: final loss "
-      f"{results[0]['losses'][-1]:.6f}, dispatches/step p50 == 1, "
+print(f"onestep smoke OK x 4 procs: dispatches/step p50 == 1, "
       f"host-gap off/on {min(r['gap_ratio'] for r in results):.2f}-"
       f"{max(r['gap_ratio'] for r in results):.2f}x, "
       f"tuner winner '{results[0]['winner']}' persisted + warm-started")

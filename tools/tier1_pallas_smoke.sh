@@ -77,8 +77,8 @@ def run(cfg, backend=None):
         os.environ.pop("HVD_TPU_QUANT_BACKEND", None)
 
 
-dense_cfg = sched.SchedConfig(enabled=True, bucket_bytes=64)
-quant_cfg = sched.SchedConfig(enabled=True, bucket_bytes=64,
+dense_cfg = sched.SchedConfig(bucket_bytes=64)
+quant_cfg = sched.SchedConfig(bucket_bytes=64,
                               wire="int8", wire_ef=True)
 
 dense = run(dense_cfg)

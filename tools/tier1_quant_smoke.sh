@@ -66,10 +66,10 @@ def run(cfg):
 
 # small buckets so the scheduler emits several per step
 metrics.reset_counters("sched.")
-dense = run(sched.SchedConfig(enabled=True, bucket_bytes=64))
+dense = run(sched.SchedConfig(bucket_bytes=64))
 dense_bytes = metrics.get_gauge("sched.wire_bytes", {"wire": "off"})
 metrics.reset_counters("sched.")
-quant = run(sched.SchedConfig(enabled=True, bucket_bytes=64,
+quant = run(sched.SchedConfig(bucket_bytes=64,
                               wire="int8", wire_ef=True))
 int8_bytes = metrics.get_gauge("sched.wire_bytes", {"wire": "int8"})
 

@@ -66,7 +66,7 @@ def params():
 def train(mode, iters=8):
     railpipe.set_mode_override(mode)
     sched.set_config_override(sched.SchedConfig(
-        enabled=True, bucket_bytes=16 * 1024, lowering="hier",
+        bucket_bytes=16 * 1024, lowering="hier",
     ))
     o0 = metrics.get_counter("sched.pipeline.overlap_windows")
     try:

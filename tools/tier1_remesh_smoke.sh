@@ -74,7 +74,7 @@ def fresh_params():
     }
 
 
-cfg = sched.SchedConfig(enabled=True, bucket_bytes=48, lowering="flat")
+cfg = sched.SchedConfig(bucket_bytes=48, lowering="flat")
 tx = optax.adam(0.05)
 batch = (jnp.asarray(X), jnp.asarray(Y))
 

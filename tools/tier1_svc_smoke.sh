@@ -66,7 +66,7 @@ def train(svc_on, iters=8):
     svc.set_enabled_override(svc_on)
     svc.set_staleness_override(0)
     sched.set_config_override(sched.SchedConfig(
-        enabled=True, bucket_bytes=16 * 1024,
+        bucket_bytes=16 * 1024,
     ))
     try:
         p = params()

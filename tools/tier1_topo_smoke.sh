@@ -65,10 +65,10 @@ def run(cfg):
 
 
 # small buckets so the scheduler emits several per step
-flat = run(sched.SchedConfig(enabled=True, bucket_bytes=64,
+flat = run(sched.SchedConfig(bucket_bytes=64,
                              lowering="flat"))
 dcn_flat = metrics.get_gauge("topo.dcn_bytes")
-hier = run(sched.SchedConfig(enabled=True, bucket_bytes=64,
+hier = run(sched.SchedConfig(bucket_bytes=64,
                              lowering="hier"))
 dcn_hier = metrics.get_gauge("topo.dcn_bytes")
 
@@ -133,7 +133,7 @@ def losses(lowering):
         "b": jnp.zeros((2,)),
     }
     sched.set_config_override(sched.SchedConfig(
-        enabled=True, bucket_bytes=64, lowering=lowering))
+        bucket_bytes=64, lowering=lowering))
     try:
         tx = hvd.DistributedOptimizer(optax.sgd(0.1))
         step = hvd.distributed_train_step(loss_fn, tx)

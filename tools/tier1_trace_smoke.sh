@@ -75,7 +75,7 @@ def params(extra=False):
 def train(level, iters=8, extra=False):
     trace.set_level_override(level)
     sched.set_config_override(sched.SchedConfig(
-        enabled=True, bucket_bytes=16 * 1024, lowering="hier",
+        bucket_bytes=16 * 1024, lowering="hier",
     ))
     try:
         p = params(extra)

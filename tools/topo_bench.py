@@ -137,7 +137,7 @@ def main() -> dict:
 
     def run(lowering, iters=30, warmup=5):
         cfg = sched.SchedConfig(
-            enabled=True, bucket_bytes=16 * 1024, lowering=lowering
+            bucket_bytes=16 * 1024, lowering=lowering
         )
         sched.set_config_override(cfg)
         try:
@@ -234,7 +234,7 @@ def main_quant() -> dict:
         # lowering pinned flat so the record isolates the wire backend
         # (hier would move the quantizer onto the DCN-hop groups)
         cfg = sched.SchedConfig(
-            enabled=True, bucket_bytes=16 * 1024, wire="int8",
+            bucket_bytes=16 * 1024, wire="int8",
             wire_ef=True, lowering="flat",
         )
         sched.set_config_override(cfg)
@@ -389,7 +389,7 @@ def main_adasum() -> dict:
     def run(lowering):
         params = {"w": jnp.zeros((d,))}
         sched.set_config_override(sched.SchedConfig(
-            enabled=True, bucket_bytes=4096, lowering=lowering,
+            bucket_bytes=4096, lowering=lowering,
         ))
         try:
             tx = hvd.DistributedOptimizer(optax.sgd(lr), op=hvd.Sum)
@@ -495,7 +495,7 @@ def main_pipeline() -> dict:
     def run(mode, bucket_bytes, iters=30, warmup=5):
         railpipe.set_mode_override(mode)
         cfg = sched.SchedConfig(
-            enabled=True, bucket_bytes=bucket_bytes, lowering="hier"
+            bucket_bytes=bucket_bytes, lowering="hier"
         )
         sched.set_config_override(cfg)
         overlap0 = metrics.get_counter("sched.pipeline.overlap_windows")
