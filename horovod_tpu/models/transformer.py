@@ -51,7 +51,11 @@ from ..parallel.tensor import (
     _axis_present,
 )
 from ..parallel.ulysses import ulysses_attention
-from ..ops.pallas_kernels import flash_attention
+from ..ops.pallas_kernels import (
+    flash_attention,
+    flash_attention_qkv,
+    rope as rope_kernel,
+)
 
 Dtype = Any
 
@@ -74,7 +78,8 @@ class TransformerConfig:
     remat: bool = False          # jax.checkpoint each block (long-context)
     # What a rematerialised block keeps for its backward pass besides its
     # input: any of "flash_qkv" (q, k, v as the attention kernel read
-    # them) and "flash_out" (its output and row logsumexp), the names
+    # them: [B, T, H·D], the projections' own layout, after rope) and
+    # "flash_out" (its output, [B, T, H·D], and row logsumexp), the names
     # ``ops/pallas_kernels.py`` gives its residuals.  With both the
     # backward re-runs neither the kernel nor the q/k/v projections.
     remat_save: Tuple[str, ...] = ()
@@ -130,11 +135,12 @@ def rope_tables(positions: jax.Array, head_dim: int,
 
 def apply_rope(x: jax.Array, rope: Tuple[jax.Array, jax.Array]) -> jax.Array:
     """Rotate [B, T, H, D] by the tables of :func:`rope_tables` ([T, D/2]
-    or [B, T, D/2]), rotate-half pairing: element i pairs with i + D/2."""
-    cos, sin = (r[..., None, :] for r in rope)  # over the heads
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+    or [B, T, D/2]), rotate-half pairing: element i pairs with i + D/2.
+    The kernel works on [B, T, H·D], where the projections leave q and k
+    and the attention kernels take them."""
+    b, t, h, d = x.shape
+    return rope_kernel(
+        x.reshape(b, t, h * d), *rope, heads=h).reshape(x.shape)
 
 
 class Attention(nn.Module):
@@ -169,8 +175,9 @@ class Attention(nn.Module):
             )(x)
 
         if cfg.fused_qkv:
-            qkv = column(3, "qkv").reshape(b, t, 3, h_local, cfg.head_dim)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            qkv = column(3, "qkv")
+            parts = qkv.reshape(b, t, 3, h_local, cfg.head_dim)
+            q, k, v = parts[:, :, 0], parts[:, :, 1], parts[:, :, 2]
         else:
             q, k, v = (
                 column(1, name).reshape(b, t, h_local, cfg.head_dim)
@@ -203,6 +210,10 @@ class Attention(nn.Module):
                 f"sequence axis {cfg.sp_axis!r} is present in the mesh; "
                 "use attn_impl='ring' or 'ulysses' for sequence parallelism"
             )
+        elif cfg.attn_impl == "flash" and cfg.fused_qkv and rope is None:
+            # the kernels read q, k and v out of the projection in place
+            out = flash_attention_qkv(qkv, h_local, cfg.causal,
+                                      segment_ids=segment_ids)
         elif cfg.attn_impl == "flash":
             out = flash_attention(q, k, v, cfg.causal,
                                   segment_ids=segment_ids)

@@ -56,6 +56,30 @@ def test_flash_attention_rejects_unequal_seq_lens():
         flash_attention(q, kv, kv)
 
 
+# name -> (H, D, heads a grid step owns): the kernels cut [B, T, H·D] into
+# column slabs of whole 128-lane columns where the heads allow it.
+_SLABS = {
+    "pair_d64": (12, 64, 2),      # gpt2s
+    "one_d128": (16, 128, 1),     # ouro26b
+    "four_d32": (4, 32, 4),
+    "all_d16": (2, 16, 2),        # 8 heads would fill a column: all of them
+    "all_odd_d64": (3, 64, 3),    # a pair does not divide 3: all of them
+}
+
+
+@pytest.mark.parametrize("name", list(_SLABS))
+def test_slab_rule(name):
+    h, d, g = _SLABS[name]
+    assert pallas_kernels._slab_heads(h, d) == g
+    q = jnp.zeros((2, 64, h, d), jnp.float32)
+    jaxpr = jax.make_jaxpr(
+        lambda q: _grads(flash_attention, q, q, q, causal=True))(q)
+    calls = [e for e in _eqns(jaxpr.jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert [c.params["grid_mapping"].grid for c in calls] == [
+        (2, h // g), (2, h // g, 1)]
+
+
 # (b, t, h, d), causal, the caller's (block_q, block_k).  Both kernels take
 # their tile from T and the smaller cap: 64 here, else a multiple of 128.
 _FORWARD_CASES = {
@@ -73,6 +97,10 @@ _FORWARD_CASES = {
     # blocks capped under 128 by the caller, and unequal: tiles of 32
     "capped_blocks": ((2, 200, 2, 32), True, (48, 32)),
     "capped_blocks_noncausal": ((2, 200, 2, 32), False, (32, 48)),
+    # the slab rule (_SLABS): T = 200 is four tiles of 64, 56 rows padded
+    **{f"slab_{name}{'' if causal else '_noncausal'}":
+       ((1, 200, h, d), causal, (64, 64))
+       for name, (h, d, _) in _SLABS.items() for causal in (True, False)},
 }
 
 
@@ -114,6 +142,8 @@ _LSE_CASES = {
     "d128_tiles": ((1, 640, 2, 128), None, (512, 512)),
     "packed_capped_blocks": ((2, 70, 2, 8), (23, 41), (16, 16)),
     "packed_tiles": ((1, 300, 2, 32), (77, 130, 260), (128, 128)),
+    **{f"slab_{name}": ((1, 200, h, d), (70, 150) if h < 12 else None,
+                        (64, 64)) for name, (h, d, _) in _SLABS.items()},
 }
 
 
@@ -125,9 +155,10 @@ def test_flash_forward_logsumexp(case, causal):
     shape, cuts, (block_q, block_k) = _LSE_CASES[case]
     q, k, v = jax.random.normal(jax.random.PRNGKey(4), (3,) + shape)
     seg = None if cuts is None else _segments(shape[0], shape[1], cuts)
-    _, (_, _, _, _, lse) = pallas_kernels._flash_fwd_res(
-        q, k, v, causal, None, block_q, block_k, seg)
-    b, t, h, _ = shape
+    b, t, h, d = shape
+    _, (_, _, lse) = pallas_kernels._flash_fwd_res(
+        tuple(x.reshape(b, t, h * d) for x in (q, k, v)), h, causal,
+        d ** -0.5, block_q, block_k, seg)
     assert lse.shape == (b, h, t) and lse.dtype == jnp.float32
     np.testing.assert_allclose(
         np.asarray(lse), np.asarray(_logsumexp_reference(q, k, causal, seg)),
@@ -163,11 +194,12 @@ def test_flash_packed_padding_rows(causal, monkeypatch):
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
     (out_raw, lse_raw), = raw
-    # the kernel's own layouts: oᵀ [B, H, n, D, block], lse [B, H, n, 1, block]
-    out_raw = np.asarray(out_raw).transpose(0, 1, 2, 4, 3).reshape(b, h, 384, d)
+    # the kernel's own layouts: o [B, T, H·D], lse [B, H, n, 1, block]
+    out_raw = np.asarray(out_raw)
+    assert out_raw.shape == (b, 384, h * d)
     lse_raw = np.asarray(lse_raw).reshape(b, h, 384)
     assert np.isfinite(lse_raw[:, :, :t]).all()
-    assert (out_raw[:, :, t:] == 0).all() and (lse_raw[:, :, t:] <= -1e30).all()
+    assert (out_raw[:, t:] == 0).all() and (lse_raw[:, :, t:] <= -1e30).all()
 
 
 def _grads(attn, q, k, v, **kw):
@@ -204,6 +236,13 @@ _GRAD_CASES = {
         ((1, 384, 2, 128), True, (90, 200), jnp.float32, (512, 512), 2e-4),
     "d128_bf16_packed":
         ((1, 256, 2, 128), True, (100,), jnp.bfloat16, (512, 512), 2e-2),
+    # the slab rule (_SLABS), four tiles of 64 with 56 rows padded: causal,
+    # not causal, and packed
+    **{f"slab_{name}_{kind}":
+       ((1, 200, h, d), kind != "noncausal",
+        (70, 150) if kind == "packed" else None, jnp.float32, (64, 64), 2e-4)
+       for name, (h, d, _) in _SLABS.items()
+       for kind in ("causal", "noncausal", "packed")},
 }
 
 
@@ -280,29 +319,140 @@ def test_flash_attention_grad_keeps_scores_in_the_kernels(packed):
             assert shape[-2:] != (t, t), (eqn.primitive.name, shape)
 
 
+@pytest.mark.parametrize("packed", [False, True])
+def test_flash_attention_grad_turns_nothing_outside_the_kernels(packed):
+    """q, k, v, o and their gradients pass between the caller's
+    [B, T, H, D] and the kernels by reshapes alone: no ``transpose``
+    equation outside the two ``pallas_call``s, forward or backward."""
+    q = jnp.zeros((2, 256, 12, 64), jnp.bfloat16)
+    seg = _segments(2, 256, (100,)) if packed else None
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, segment_ids=seg)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
+        q, q, q)
+    names = [e.primitive.name for e in _eqns(jaxpr.jaxpr)]
+    assert names.count("pallas_call") == 2
+    assert "transpose" not in names and "dot_general" not in names
+
+
+# (b, t, h, d), causal, segment cuts: the fused projection read in place
+# (slabs of whole 128-lane columns) and cut up first (heads that fill none)
+_FUSED_CASES = {
+    "pair_d64": ((1, 200, 4, 64), True, None),
+    "pair_d64_packed": ((1, 200, 4, 64), True, (70, 150)),
+    "one_d128_noncausal": ((1, 160, 2, 128), False, None),
+    "four_d32_packed": ((2, 96, 4, 32), True, (40,)),
+    "all_d16": ((1, 100, 2, 16), True, None),
+}
+
+
+@pytest.mark.parametrize("case", list(_FUSED_CASES))
+def test_flash_attention_of_a_fused_projection(case):
+    """``flash_attention_qkv`` on [B, T, 3·H·D] against ``full_attention``
+    on the three parts: the value and the one fused gradient."""
+    from horovod_tpu.ops.pallas_kernels import flash_attention_qkv
+
+    (b, t, h, d), causal, cuts = _FUSED_CASES[case]
+    qkv = jax.random.normal(jax.random.PRNGKey(6), (b, t, 3 * h * d))
+    seg = None if cuts is None else _segments(b, t, cuts)
+
+    def parts(qkv):
+        return (qkv.reshape(b, t, 3, h, d)[:, :, i] for i in range(3))
+
+    def fused(qkv):
+        return flash_attention_qkv(qkv, h, causal, segment_ids=seg)
+
+    def reference(qkv):
+        return full_attention(
+            *parts(qkv), causal=causal, segment_ids=seg).reshape(b, t, h * d)
+
+    np.testing.assert_allclose(
+        np.asarray(fused(qkv)), np.asarray(reference(qkv)),
+        atol=2e-5, rtol=2e-5)
+    got = jax.grad(lambda x: (fused(x) ** 2).sum())(qkv)
+    want = jax.grad(lambda x: (reference(x) ** 2).sum())(qkv)
+    assert got.shape == qkv.shape
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), atol=2e-4, rtol=2e-4)
+
+
+def test_fused_projection_is_read_in_place():
+    """With slabs of whole 128-lane columns both kernels take the one
+    [B, T, 3·H·D] array three times and nothing cuts it up; heads that
+    fill no column are cut out first."""
+    from horovod_tpu.ops.pallas_kernels import flash_attention_qkv
+
+    def operands(h, d):
+        qkv = jnp.zeros((1, 128, 3 * h * d), jnp.float32)
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda x: flash_attention_qkv(x, h, True).sum()))(qkv)
+        eqns = list(_eqns(jaxpr.jaxpr))
+        calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+        return {e.primitive.name for e in eqns}, [
+            [v.aval.shape for v in c.invars[:3]] for c in calls]
+
+    names, shapes = operands(4, 64)
+    assert "slice" not in names
+    assert shapes == [[(1, 128, 768)] * 3] * 2
+    names, shapes = operands(2, 16)
+    assert "slice" in names
+    assert shapes == [[(1, 128, 32)] * 3] * 2
+
+
+def _kernel_scopes(model, tokens):
+    """The scope paths of the kernels in the compiled gradient of a model,
+    which is what the device trace's events carry.  (A jitted wrapper's
+    path is put together from its call site's when the module is compiled:
+    the lowered text has only the callee's part.  Interpreted, a kernel is
+    a ``while`` over its grid where the Mosaic call would be, which reads
+    ``<this path>/pallas_call``: tests/test_tpu_compile.py.)"""
+    params = model.init(jax.random.PRNGKey(0), tokens)
+
+    def loss(p):
+        return model.apply(p, tokens)[0].astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss)).lower(params).compile().as_text()
+    return set(re.findall(
+        r'op_name="(jit\([^"]*?/attn(?:/[^"/]+)*?)/while/', text))
+
+
 def test_flash_backward_scope_is_not_the_forwards():
     """The benchmark's ``flash_fwd_roofline`` sums the Mosaic calls whose
     scope path holds "attn/pallas_call"; the backward's call, traced from
     the same ``attn`` module, must not read so."""
     from horovod_tpu.models.transformer import Transformer, TransformerConfig
 
-    model = Transformer(TransformerConfig(
+    calls = _kernel_scopes(Transformer(TransformerConfig(
         vocab_size=64, num_layers=1, model_dim=32, num_heads=2, head_dim=16,
-        ff_dim=64, max_len=32))
-    tokens = jnp.zeros((2, 32), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), tokens)
-
-    def loss(p):
-        return model.apply(p, tokens)[0].astype(jnp.float32).sum()
-
-    text = jax.jit(jax.grad(loss)).lower(params).as_text(debug_info=True)
-    calls = set(re.findall(r'"(jit\([^"]*/pallas_call)[^"]*"', text))
-    forward = {c for c in calls if "attn/pallas_call" in c}
+        ff_dim=64, max_len=32)), jnp.zeros((2, 32), jnp.int32))
+    forward = {c for c in calls if c.endswith("/attn")}
     backward = calls - forward
     assert len(forward) == 1 and "transpose(" not in next(iter(forward))
     assert len(backward) == 1
     path = next(iter(backward))
     assert "transpose(" in path and "/attn/flash_bwd/" in path
+
+
+def test_rope_kernels_scope_is_neither_flash_kernels():
+    """A model with rotary positions calls the rotary kernel from the
+    same ``attn`` module; its calls read ".../attn/jit(_rope_call)/rope/
+    pallas_call", forward and backward, so neither roofline's anchor
+    ("attn/pallas_call", "flash_bwd") finds them."""
+    from horovod_tpu.models.transformer import Transformer, TransformerConfig
+
+    calls = _kernel_scopes(Transformer(TransformerConfig(
+        vocab_size=64, num_layers=1, model_dim=32, num_heads=2, head_dim=16,
+        ff_dim=64, max_len=32, positions="rope", fused_qkv=False)),
+        jnp.zeros((2, 32), jnp.int32))
+    rope = {c for c in calls if c.endswith("/attn/jit(_rope_call)/rope")}
+    assert len(rope) == 2  # q's and k's share a path; forward, backward
+    flash = calls - rope
+    assert len({c for c in flash if c.endswith("/attn")}) == 1
+    assert len({c for c in flash if "/attn/flash_bwd/" in c}) == 1
+    assert len(flash) == 2
 
 
 def test_flash_attention_bf16():
@@ -361,3 +511,53 @@ def test_flash_packed_multiblock_matches_full():
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b_), rtol=1e-4, atol=1e-4
         )
+
+
+def _rope_reference(x, cos, sin):
+    """Rotate-half on [B, T, H, D] as plain jnp, float32 inside."""
+    cos, sin = cos[..., None, :], sin[..., None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
+# (b, t, h, d), a table a row (packed positions), dtype
+_ROPE_CASES = {
+    "one_d128": ((2, 96, 2, 128), False, jnp.float32),
+    "one_d128_bf16_long": ((1, 1100, 2, 128), False, jnp.bfloat16),
+    "pair_d64": ((1, 100, 4, 64), False, jnp.float32),
+    "four_d32_tables_per_row": ((2, 64, 4, 32), True, jnp.float32),
+    "all_d16": ((2, 40, 2, 16), True, jnp.float32),
+    "all_odd_d64": ((1, 50, 3, 64), False, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", list(_ROPE_CASES))
+def test_rope_kernel_matches_rotate_half(case):
+    """The kernel on [B, T, H·D] against rotate-half written on
+    [B, T, H, D]: the value and the gradient (the turn the other way)."""
+    (b, t, h, d), per_row, dtype = _ROPE_CASES[case]
+    x = jax.random.normal(jax.random.PRNGKey(7), (b, t, h, d), dtype)
+    pos = jnp.arange(t) if not per_row else (
+        jnp.arange(b * t).reshape(b, t) * 3 % 17)
+    angles = pos[..., None] * (
+        1.0 / 1e4 ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+
+    def kernel(x):
+        return pallas_kernels.rope(
+            x.reshape(b, t, h * d), cos, sin, h).reshape(b, t, h, d)
+
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    got, want = kernel(x), _rope_reference(x, cos, sin)
+    assert got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+    w = jax.random.normal(jax.random.PRNGKey(8), (b, t, h, d), jnp.float32)
+    grad = lambda f: jax.grad(
+        lambda x: (f(x).astype(jnp.float32) * w).sum())(x)
+    np.testing.assert_allclose(
+        np.asarray(grad(kernel), np.float32),
+        np.asarray(grad(lambda x: _rope_reference(x, cos, sin)), np.float32),
+        atol=tol, rtol=tol)

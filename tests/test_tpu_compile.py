@@ -39,12 +39,17 @@ def mosaic(monkeypatch):
     from jax.experimental.compilation_cache import compilation_cache
 
     monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
+    # the jitted wrappers (_flash_backward, _rope_call) keep what they
+    # traced by shapes alone: nothing interpreted may be found again here,
+    # and nothing of Mosaic's by the interpreted tests after
+    jax.clear_caches()
     # an executable for a described chip cannot be read back from the
     # persistent cache without one
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     yield
+    jax.clear_caches()
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
 
@@ -93,6 +98,49 @@ def test_flash_attention_grad_compiles_for_the_chip(
     assert compiled.memory_analysis().temp_size_in_bytes < scores / 2
 
 
+def _entry(text):
+    """The instructions of a compiled module's entry computation."""
+    return text[text.index("\nENTRY "):].splitlines()
+
+
+def _result_and_opcode(line):
+    """("bf16[2,8]{1,0}", "copy") of "%copy.1 = bf16[2,8]{1,0} copy(%p)"."""
+    head = line.split(" = ", 1)[-1].split("(")[0].split(" ")
+    return head[0], head[-1]
+
+
+@pytest.mark.parametrize("packed", [False, True])
+def test_fused_projection_passes_to_the_kernels_without_a_copy(
+        one_chip, mosaic, packed):
+    """gpt2s: both kernels take the [B, T, 3·H·D] projection as it is,
+    three times, and give [B, T, H·D] arrays; on the way XLA copies,
+    transposes and cuts nothing (the cotangents' concatenation is what
+    is left of the glue)."""
+    b, t, h, d = 16, 1024, 12, 64
+    qkv = jax.ShapeDtypeStruct((b, t, 3 * h * d), jnp.bfloat16,
+                               sharding=one_chip)
+    w = jax.ShapeDtypeStruct((b, t, h * d), jnp.bfloat16, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip)
+
+    def grad(qkv, w, seg):
+        return jax.grad(lambda x: jnp.sum(
+            pallas_kernels.flash_attention_qkv(
+                x, h, True, segment_ids=seg if packed else None
+            ).astype(jnp.float32) * w))(qkv)
+
+    entry = _entry(jax.jit(grad).lower(qkv, w, seg).compile().as_text())
+    calls = [ln for ln in entry if "tpu_custom_call" in ln]
+    assert len(calls) == 2
+    for call in calls:
+        operands = call.split("custom-call(")[1].split(")")[0].split(", ")
+        assert operands[0] == operands[1] == operands[2]
+        assert f"bf16[{b},{t},{3 * h * d}]{{2,1,0}}" in call.split(
+            "operand_layout_constraints=")[1]
+    for ln in entry:
+        assert _result_and_opcode(ln)[1] not in (
+            "copy", "transpose", "slice"), ln
+
+
 def _pallas_calls(jaxpr):
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
@@ -111,8 +159,8 @@ def test_flash_attention_forward_is_one_call_on_a_grid_of_heads(
         one_chip, mosaic, shape, packed):
     """What the benchmark's forward rooflines count on: the forward is
     exactly one Mosaic call, found under ".../attn/pallas_call" and not
-    under flash_bwd.  Its grid is (batch, head): no K-block axis whose
-    steps could be empty.  And the row logsumexp leaves it one float32 a
+    under flash_bwd.  Its grid is (batch, slab of heads): no K-block axis
+    whose steps could be empty.  And the row logsumexp leaves it one float32 a
     row: neither an operand nor a result is a float32 array with 128
     lanes of row statistics."""
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
@@ -124,7 +172,8 @@ def test_flash_attention_forward_is_one_call_on_a_grid_of_heads(
                 q, k, v, True, segment_ids=seg if packed else None)
 
     call, = _pallas_calls(jax.make_jaxpr(forward)(x, x, x, seg).jaxpr)
-    assert call.params["grid_mapping"].grid == (shape[0], shape[2])
+    slabs = shape[2] // pallas_kernels._slab_heads(*shape[2:])
+    assert call.params["grid_mapping"].grid == (shape[0], slabs)
 
     text = jax.jit(forward).lower(x, x, x, seg).compile().as_text()
     line, = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
@@ -136,6 +185,41 @@ def test_flash_attention_forward_is_one_call_on_a_grid_of_heads(
         assert not dims.endswith(",128"), dims
     b, t, h, _ = shape
     assert f"f32[{b},{h},{t // 512},1,512]" in signature
+
+
+@pytest.mark.parametrize("shape, per_row", [
+    ((2, 4096, 16, 128), False),  # ouro26b.ring2x4096: a head a slab
+    ((2, 4096, 16, 128), True),   # a table a row, as packed rows have
+    ((16, 1024, 12, 64), False),  # a pair of heads a slab: two turns
+    ((2, 600, 4, 32), False),     # four heads a slab, ragged T
+])
+def test_rope_compiles_for_the_chip(one_chip, mosaic, shape, per_row):
+    """The rotary kernel and its transpose on [B, T, H·D]: two Mosaic
+    calls under a scope of their own, and XLA copies nothing around
+    them."""
+    b, t, h, d = shape
+    x = jax.ShapeDtypeStruct((b, t, h * d), jnp.bfloat16, sharding=one_chip)
+    table = jax.ShapeDtypeStruct(
+        ((b,) if per_row else ()) + (t, d // 2), jnp.float32,
+        sharding=one_chip)
+
+    def grad(x, w, cos, sin):
+        with jax.named_scope("attn"):
+            return jax.value_and_grad(lambda x: jnp.sum(
+                pallas_kernels.rope(x, cos, sin, h).astype(jnp.float32)
+                * w))(x)
+
+    entry = _entry(jax.jit(grad).lower(x, x, table, table).compile().as_text())
+    calls = [ln for ln in entry if "tpu_custom_call" in ln]
+    assert len(calls) == 2
+    for call in calls:  # not the flash forward's ".../attn/pallas_call"
+        scope, = re.findall(r'op_name="([^"]*)"', call)
+        assert scope.endswith("/rope/pallas_call"), scope
+        assert "attn/pallas_call" not in scope and "flash_bwd" not in scope
+    for ln in entry:  # of x, its gradient or the result
+        result, opcode = _result_and_opcode(ln)
+        assert opcode not in ("copy", "transpose") or (
+            f"{t},{h * d}]" not in result), ln
 
 
 def test_scale_buffer_compiles_for_the_chip(one_chip, mosaic):

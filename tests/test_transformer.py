@@ -2,6 +2,8 @@
 the sp (ring/Ulysses) and tp sharded paths against the unsharded model
 with identical weights."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -487,10 +489,17 @@ def test_what_a_rematerialised_block_keeps_changes_no_value(save):
                                    rtol=1e-4, atol=1e-7)
     # the names reach jax.checkpoint's policy: with the kernel's output
     # kept, the backward runs the forward kernel once a call and not twice
+    # (the backward kernel and the rotary kernel are jitted wrappers: the
+    # jaxpr holds their calls by name and prints each kernel once)
     jaxpr = str(jax.make_jaxpr(jax.grad(lambda p: loss(remat, p)))(params))
-    forward_calls = jaxpr.count("pallas_call") - jaxpr.count(
-        "flash_bwd_dq_dkv")
+    forward_calls = len(re.findall(r"pallas_call\[", jaxpr)) - len(
+        re.findall(r"name=(flash_bwd_dq_dkv|rope)\n", jaxpr))
     assert forward_calls == (12 if not save else 6)
+    # q and k are turned once forward and their gradients once backward, 12
+    # each; with q and k kept as the kernel read them, turned already, the
+    # backward does not turn them again
+    rope_calls = len(re.findall(r"name=_rope_call\b", jaxpr))
+    assert rope_calls == (24 if "flash_qkv" in save else 36)
 
 
 def test_rope_scores_depend_on_the_offset_only():
