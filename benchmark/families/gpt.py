@@ -22,8 +22,9 @@ def build(config: Dict[str, Any], traffic: Dict[str, Any]) -> System:
     m = config["model"]
     if m["layer_norm_epsilon"] != 1e-6:
         raise ValueError(
-            "models/transformer.py fixes LayerNorm's epsilon at flax's "
-            f"1e-6; the configuration says {m['layer_norm_epsilon']}")
+            "families/gpt.py builds GPT-2 with LayerNorm's epsilon left at "
+            "flax's 1e-6 (TransformerConfig.norm_eps is not passed); the "
+            f"configuration says {m['layer_norm_epsilon']}")
     if m["n_positions"] < traffic["seq_len"]:
         raise ValueError(
             f"{traffic['seq_len']} tokens a row exceed the model's "

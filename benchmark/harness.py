@@ -1,5 +1,6 @@
-"""One run of one cell: set-up, the check against the reference, the
-measured window, and the record the metrics are read from.
+"""One run of one cell: set-up with the system's check steps, the measured
+window, then the reference alone on the chip and the comparison that
+decides ``correct``, and the record the metrics are read from.
 
 Nothing here knows a configuration, a mix or a metric by name: the cell
 names its files (``manifest.py``), the family builds the system, the
@@ -13,6 +14,8 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
+import gc
 import importlib
 import importlib.util
 import math
@@ -39,14 +42,17 @@ def say(key: str, value) -> None:
 
 
 class Laps:
-    """Seconds of each phase of set-up, on earlier lines."""
+    """Seconds of each phase, on earlier lines: of set-up
+    (``setup_seconds.``) and, once the window has closed, of the check
+    (``check_seconds.``), which ``setup_s`` does not hold."""
 
-    def __init__(self, start: float):
+    def __init__(self, start: float, prefix: str = "setup_seconds"):
         self.last = start
+        self.prefix = prefix
 
     def lap(self, phase: str) -> None:
         now = time.perf_counter()
-        say(f"setup_seconds.{phase}", round(now - self.last, 2))
+        say(f"{self.prefix}.{phase}", round(now - self.last, 2))
         self.last = now
 
 
@@ -79,6 +85,13 @@ class Run:
     step_hlo: Optional[str] = None
     trace_file: Optional[Path] = None
     failed: int = 0
+    # every chip's allocator statistics when the window had closed and the
+    # system's state was still alive: before the reference touched the chip
+    allocator_stats: List[Dict[str, Any]] = dataclasses.field(
+        default_factory=list)
+    # what decided ``correct``: name -> (number, its limit)
+    compared: Dict[str, Tuple[float, float]] = dataclasses.field(
+        default_factory=dict)
     _module: Any = None
     _reduced: Any = None
 
@@ -172,36 +185,44 @@ class Trainer:
         return loss
 
 
-def check_against_reference(cell: Cell, system, trainer: Trainer,
-                            traffic: Traffic, mesh, reference_params,
-                            laps: Laps) -> Tuple[bool, Dict[str, Any]]:
-    """Run the check of ``check.py``; its system steps are the step's
-    compilation and first warm-up.  Returns (agree, what was compared)."""
-    import jax
-
+def system_check_steps(cell: Cell, trainer: Trainer, traffic: Traffic, mesh
+                       ) -> Tuple[Any, List[float]]:
+    """The system's side of the check of ``check.py``: its first optimizer
+    steps on the seeded sample tiled to the cell's batch, which are the
+    step's compilation and first warm-up.  Returns the sample (host arrays,
+    for the reference) and the loss before each step."""
     spec = cell.config["check"]
     chips = mesh.devices.size
     sample = traffic.sample(chips * spec["sample_rows_per_chip"])
     tiled = traffic.place(
         check.tile_for_chips(sample, chips, traffic.rows // chips))
-    system_losses = [float(trainer.advance(tiled))
-                     for _ in range(spec["steps"])]
-    del tiled
-    laps.lap("step_compile_and_check_steps")
+    return sample, [float(trainer.advance(tiled))
+                    for _ in range(spec["steps"])]
+
+
+def reference_side(cell: Cell, system, make_weights, sample, chips: int,
+                   device) -> Tuple[List[float], int]:
+    """The reference's side, alone on the chip: the weights made again from
+    the seed by the program that made the system's, in float32 on
+    ``device``, and the reference's steps on each chip's sample.  Returns
+    its losses and the weights' fingerprint."""
+    laps = Laps(time.perf_counter(), "check_seconds")
+    say("check.bytes_in_use_before_reference", check.bytes_in_use(device))
+    params, _ = make_weights()
+    bits = check.fingerprint(params)
+    params = check.float32_on(params, device)
+    laps.lap("weights_again")
     reference = importlib.import_module(
         f"benchmark.reference.{cell.config['family']}")
-    reference_losses = check.reference_losses(
-        reference.loss, cell.config["model"], system.optimizer,
-        reference_params, check.chunks_for_chips(sample, chips),
-        spec["steps"], mesh.devices.flat[0])
+    trained = check.train_reference(
+        reference.loss, cell.config["model"], system.optimizer, params,
+        check.chunks_for_chips(sample, chips), cell.config["check"]["steps"],
+        device)
+    del params  # donated to the reference's first update
     laps.lap("reference")
-    agree = check.losses_agree(
-        system_losses, reference_losses, spec["loss_rtol"])
-    return agree, {
-        "system_losses": system_losses,
-        "reference_losses": reference_losses,
-        "loss_rtol": spec["loss_rtol"],
-    }
+    say("check.reference_live_bytes", trained.live_bytes)
+    say("check.reference_program_bytes", trained.program_bytes)
+    return trained.losses, bits
 
 
 def compiled_step(step):
@@ -282,15 +303,74 @@ def run_window(run: Run, trainer: Trainer, traffic: Traffic, log: CompileLog,
 
 
 # --------------------------------------------------------------------- run
+@dataclasses.dataclass
+class SystemSide:
+    """What is left of the system's run when its state is gone."""
+
+    sample: Any                 # the check's rows, on the host
+    losses: List[float]         # before each of its check steps
+    weights_bits: int           # check.fingerprint of what it started from
+    same_before: bool           # replicas identical, before the window
+    same_after: bool            # and after it
+
+
+def drive_system(run: Run, hvd, system, make_weights, seed: int,
+                 trace_dir: Optional[Path], log: CompileLog, laps: Laps
+                 ) -> SystemSide:
+    """The system's whole life on the chips: weights, the compiled step
+    with its state, the check's steps, the window.  Everything it put on a
+    chip is local to this call, so it is released when the call returns."""
+    from horovod_tpu.prof import introspect
+
+    cell = run.cell
+    mesh = hvd.mesh()
+    traffic = Traffic(cell.traffic, system.element, mesh, hvd.WORLD_AXIS,
+                      seed)
+    try:
+        params, model_state = make_weights()
+        params = hvd.broadcast_parameters(params, root_rank=0)
+        weights_bits = check.fingerprint(params)
+        laps.lap("weights")
+        trainer = Trainer(make_step(hvd, system), params, model_state,
+                          system.stateful)
+        del params, model_state
+        sample, losses = system_check_steps(cell, trainer, traffic, mesh)
+        laps.lap("step_compile_and_check_steps")
+        identical = check.replicas_identical(mesh, hvd.WORLD_AXIS)
+        same_before = identical(trainer.params)
+        traffic.start()
+        laps.lap("replica_check_and_traffic")
+        run_window(run, trainer, traffic, log, trace_dir)
+        same_after = identical(trainer.params)
+        run.allocator_stats = [
+            d.memory_stats() or {} for d in mesh.devices.flat]
+        run.step_record = introspect.get(STEP_PROGRAM) or {}
+        if trace_dir is not None:
+            run.step_hlo = compiled_step(trainer.step).as_text()
+            # beside the trace, so that it can be reduced again by hand
+            (trace_dir / "step.hlo.txt").write_text(run.step_hlo)
+            found = sorted(trace_dir.glob("plugins/profile/*/*.xplane.pb"))
+            run.trace_file = found[-1] if found else None
+        return SystemSide(sample, losses, weights_bits, same_before,
+                          same_after)
+    finally:
+        traffic.close()
+
+
 def run_cell(cell: Cell, devices, seed: int, seconds: float, trace: bool,
              process_start: float, out_dir: Path) -> Tuple[Run, bool]:
     """One run of ``cell`` on ``devices``.  Returns the record and
-    ``correct``."""
+    ``correct``.
+
+    The system and the reference are never on the chips together: the
+    system runs first, from its weights to the end of the window, the
+    allocator's peak is read, its state is released; then the reference
+    makes the same weights again and trains alone.  So a configuration is
+    sized by what its step holds, and ``setup_s`` pays for no reference."""
     import jax
 
     import horovod_tpu as hvd
     from horovod_tpu import native
-    from horovod_tpu.prof import introspect
     from horovod_tpu.utils import compile_cache
 
     laps = Laps(process_start)
@@ -303,66 +383,54 @@ def run_cell(cell: Cell, devices, seed: int, seconds: float, trace: bool,
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
-    hvd.init(devices=list(devices))
     run = Run(cell=cell, chips=len(devices), platform=devices[0].platform,
               device_kind=devices[0].device_kind, seconds_asked=seconds,
               process_start=process_start)
-    traffic = None
-    try:
-        mesh = hvd.mesh()
-        family = importlib.import_module(
-            f"benchmark.families.{cell.config['family']}")
-        system = family.build(cell.config, cell.traffic)
-        traffic = Traffic(cell.traffic, system.element, mesh,
-                          hvd.WORLD_AXIS, seed)
-        with CompileLog() as log:
-            params, model_state = jax.jit(system.init)(
-                jax.random.PRNGKey(seed))
-            params = hvd.broadcast_parameters(params, root_rank=0)
-            reference_params = check.float32_copy_on(
-                params, mesh.devices.flat[0])
-            laps.lap("weights")
-            trainer = Trainer(make_step(hvd, system), params, model_state,
-                              system.stateful)
-            del params, model_state
-            agree, compared = check_against_reference(
-                cell, system, trainer, traffic, mesh, reference_params, laps)
-            del reference_params
-            for key, value in compared.items():
-                say(f"check.{key}", value)
-            identical = check.replicas_identical(mesh, hvd.WORLD_AXIS)
-            same_before = identical(trainer.params)
-            traffic.start()
-            laps.lap("replica_check_and_traffic")
-            trace_dir = None
-            if trace:
-                trace_dir = Path(out_dir) / f"trace-{cell.name}"
-                shutil.rmtree(trace_dir, ignore_errors=True)
-            run_window(run, trainer, traffic, log, trace_dir)
-            same_after = identical(trainer.params)
-            builds = [round(s, 2) for _, s in log.builds if s >= 1.0]
-            say("compile.builds_over_1s", builds)
-            say("compile.cache_reads_writes",
-                (len(log.reads), len(log.writes)))
-        say("check.replicas_identical", (same_before, same_after))
-        run.step_record = introspect.get(STEP_PROGRAM) or {}
-        if trace:
-            run.step_hlo = compiled_step(trainer.step).as_text()
-            # beside the trace, so that it can be reduced again by hand
-            (trace_dir / "step.hlo.txt").write_text(run.step_hlo)
-            found = sorted(trace_dir.glob("plugins/profile/*/*.xplane.pb"))
-            run.trace_file = found[-1] if found else None
-        say("traffic.wait_seconds_max",
-            max(run.wait_seconds, default=0.0))
-        say("window.losses_first_last",
-            [c.loss for c in run.completions[:1] + run.completions[-1:]])
-        correct = bool(agree and same_before and same_after
-                       and run.failed == 0 and run.completions)
-        return run, correct
-    finally:
-        if traffic is not None:
-            traffic.close()
-        hvd.shutdown()
+    family = importlib.import_module(
+        f"benchmark.families.{cell.config['family']}")
+    system = family.build(cell.config, cell.traffic)
+    # one program from one key, for the system and again for the reference
+    make_weights = functools.partial(
+        jax.jit(system.init), jax.random.PRNGKey(seed))
+    trace_dir = None
+    if trace:
+        trace_dir = Path(out_dir) / f"trace-{cell.name}"
+        shutil.rmtree(trace_dir, ignore_errors=True)
+    with CompileLog() as log:
+        hvd.init(devices=list(devices))
+        try:
+            side = drive_system(run, hvd, system, make_weights, seed,
+                                trace_dir, log, laps)
+        finally:
+            hvd.shutdown()
+        gc.collect()  # the step and its state, should a cycle hold them
+        reference_losses, reference_bits = reference_side(
+            cell, system, make_weights, side.sample, run.chips, devices[0])
+        builds = [round(s, 2) for _, s in log.builds if s >= 1.0]
+        say("compile.builds_over_1s", builds)
+        say("compile.cache_reads_writes", (len(log.reads), len(log.writes)))
+    say("check.reference_peak_bytes", max(
+        (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+        for d in devices))
+    rtol = cell.config["check"]["loss_rtol"]
+    say("check.system_losses", side.losses)
+    say("check.reference_losses", reference_losses)
+    say("check.loss_rtol", rtol)
+    say("check.replicas_identical", (side.same_before, side.same_after))
+    say("traffic.wait_seconds_max", max(run.wait_seconds, default=0.0))
+    say("window.losses_first_last",
+        [c.loss for c in run.completions[:1] + run.completions[-1:]])
+    gaps = check.loss_gaps(side.losses, reference_losses)
+    run.compared = {
+        **{f"loss_gap_step_{k}": (gap, rtol) for k, gap in enumerate(gaps)},
+        "weights_differ": (float(reference_bits != side.weights_bits), 0.0),
+        "replicas_differ_before": (float(not side.same_before), 0.0),
+        "replicas_differ_after": (float(not side.same_after), 0.0),
+        "losses_not_finite": (float(run.failed), 0.0),
+        "no_step_completed": (float(not run.completions), 0.0),
+    }
+    correct = all(value <= limit for value, limit in run.compared.values())
+    return run, correct
 
 
 # ----------------------------------------------------------------- metrics

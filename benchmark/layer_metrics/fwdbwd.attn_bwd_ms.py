@@ -1,7 +1,9 @@
 """Device time per step and chip of attention's backward pass: the
 operations under ``hvd_compute_grads`` whose scope path is a transposed
-(backward) one inside a block's ``attn`` module — the chunked XLA backward
-of ``ops/pallas_kernels.py`` and the projections' gradients."""
+(backward) one inside a block's ``attn`` module — the fused backward kernel
+of ``ops/pallas_kernels.py`` (under ``attn/flash_bwd/``, delta and the turn
+of dq inside it), the joining of dq, dk and dv, and the projections'
+gradients."""
 
 
 def read(run):

@@ -16,7 +16,8 @@ def ops_and_bytes(rows: int, seq_len: int, heads: int, head_dim: int,
     ``ops/gpt.py``).  Operations: QK^T and AV, 2*head_dim each per allowed
     pair and head.  Bytes: q, k and v read and the output written once in
     the activation type, and one float32 logsumexp per position and head
-    (the kernel writes 128 lanes of it; one is required)."""
+    (what the kernel writes since PR 28: a row of positions, not 128 lanes
+    a position)."""
     ops = 4.0 * heads * head_dim * attention_pairs(units, sum_sq, causal)
     positions = rows * seq_len * heads
     return ops, positions * (4.0 * head_dim * itemsize + 4.0)

@@ -4,8 +4,10 @@ it is started on, from the root of a checkout.
 
 The last line of standard output is the result: one JSON object with
 ``correct``, ``attempted``, ``failed``, ``metrics`` and ``device`` (and,
-traced, ``breakdown``).  Without a TPU, or with fewer chips than the cell
-asks for, nothing is trained, no result is printed and the exit code is 2.
+traced, ``breakdown``), and last ``compared``: every number that decided
+``correct`` beside its limit, which are also the last lines of standard
+error.  Without a TPU, or with fewer chips than the cell asks for, nothing
+is trained, no result is printed and the exit code is 2.
 """
 
 from __future__ import annotations
@@ -28,9 +30,9 @@ def result_line(run, correct: bool, traced: bool, devices) -> dict:
     cell = run.cell
     metrics = harness.metrics_of(
         run, cell.per_layer if traced else cell.end_to_end, on_chip)
-    stats = [d.memory_stats() or {} for d in devices]
-    allocator_peak = max(
-        (s.get("peak_bytes_in_use", 0) for s in stats), default=0)
+    # read when the window had closed, before the reference used the chip
+    stats = run.allocator_stats or [{}]
+    allocator_peak = max(s.get("peak_bytes_in_use", 0) for s in stats)
     step_bytes = int(run.step_record.get("peak_hbm_bytes") or 0)
     harness.say("memory.allocator_stats_first_chip", stats[0])
     harness.say("memory.allocator_peak_bytes_in_use", allocator_peak)
@@ -58,6 +60,10 @@ def result_line(run, correct: bool, traced: bool, devices) -> dict:
         device["busy_s"] = reduced.busy_seconds()
         device["window_s"] = reduced.window_seconds()
         line["breakdown"] = reduced.breakdown()
+    # last: every number that decided ``correct``, beside its limit
+    line["compared"] = {
+        name: {"value": value, "limit": limit}
+        for name, (value, limit) in run.compared.items()}
     return line
 
 
@@ -98,8 +104,11 @@ def main(argv=None) -> int:
         cell, devices, seed=args.seed, seconds=args.seconds,
         trace=bool(args.trace), process_start=_PROCESS_START,
         out_dir=manifest.PACKAGE_DIR / "out")
-    print(json.dumps(result_line(run, correct, bool(args.trace), devices)),
-          flush=True)
+    line = result_line(run, correct, bool(args.trace), devices)
+    print(json.dumps(line), flush=True)
+    for name, pair in line["compared"].items():
+        print(f"compared {name}: {pair['value']} (limit {pair['limit']})",
+              file=sys.stderr, flush=True)
     return 0
 
 

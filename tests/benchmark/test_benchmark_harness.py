@@ -24,6 +24,9 @@ def test_tiny_cell_gives_the_contracts_last_line(
     assert {"correct", "attempted", "failed", "metrics",
             "device"} <= set(line)
     assert line["correct"] is True and line["failed"] == 0
+    # last in the line: what decided ``correct``, each beside its limit
+    assert list(line)[-1] == "compared" and len(line["compared"]) == 9
+    assert all(c["value"] <= c["limit"] for c in line["compared"].values())
     assert line["attempted"] == len(run.completions) >= 1
     assert line["device"]["platform"] == "cpu"
     assert line["device"]["count"] == cell.chips

@@ -15,24 +15,20 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import tiny_cells
-from tiny_cells import CHECKOUT, HERE, run_tiny
+from benchmark import check, harness, manifest
+from benchmark.families import looplm as family
+from benchmark.ops import looplm as ops
+from benchmark.reference import looplm as reference
+from benchmark.trace import calls, hlo, reduce, xplane
+from benchmark.traffic import Traffic
+from horovod_tpu import metrics
+from horovod_tpu.models import transformer
 
-# The stand-in of the real cell, registered here and not in tiny_cells.py:
-# the ``tiny_root`` fixture maps every cell a metric names through TINY
-# (PERF.md section 7: TINY should be read from files).
-REAL_CELL, TINY_CELL = "ouro26b.ring2x4096", "looplm_tiny.ring2x64"
-tiny_cells.TINY.setdefault(
-    REAL_CELL, (TINY_CELL, "looplm_tiny", "ring-2x64", 1))
+from tiny_cells import CHECKOUT, HERE, TINY, run_tiny
 
-from benchmark import check, harness, manifest  # noqa: E402
-from benchmark.families import looplm as family  # noqa: E402
-from benchmark.ops import looplm as ops  # noqa: E402
-from benchmark.reference import looplm as reference  # noqa: E402
-from benchmark.trace import calls, hlo, reduce, xplane  # noqa: E402
-from benchmark.traffic import Traffic  # noqa: E402
-from horovod_tpu import metrics  # noqa: E402
-from horovod_tpu.models import transformer  # noqa: E402
+# the stand-in of the real cell: fixture/cells/looplm_tiny.ring2x64.json
+REAL_CELL = "ouro26b.ring2x4096"
+TINY_CELL = TINY[REAL_CELL][0]
 
 FIXTURE = HERE / "fixture"
 LOOP_METRICS = (

@@ -1,18 +1,26 @@
 """What the benchmark's tests share: where things are, the tiny stand-in
 of every real cell, and one run of a tiny cell on the virtual CPU mesh."""
 
+import json
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
 CHECKOUT = HERE.parent.parent
 
-# real cell -> (tiny cell, configuration, traffic mix, chips)
-TINY = {
-    "gpt2s.dense": ("gpt_tiny.dense", "gpt_tiny", "ring-8x64", 1),
-    "gpt2s.packed": ("gpt_tiny.packed", "gpt_tiny", "packed-docs-8x64", 1),
-    "gpt2s.dp4": ("gpt_tiny.dp4", "gpt_tiny", "ring-8x64", 4),
-    "resnet50.b256": ("resnet_cut.b8", "resnet_cut", "ring-8-images", 1),
-}
+
+def _tiny_cells():
+    """real cell -> (tiny cell, configuration, traffic mix, chips), from
+    ``fixture/cells/<tiny cell>.json``: a new cell brings its stand-in as a
+    file, and every test file finds it whichever is run alone."""
+    found = {}
+    for path in sorted((HERE / "fixture" / "cells").glob("*.json")):
+        cell = json.loads(path.read_text())
+        found[cell["stands_for"]] = (
+            path.stem, cell["config"], cell["traffic"], cell["chips"])
+    return found
+
+
+TINY = _tiny_cells()
 
 
 def run_tiny(root, cell_name, *, seed=1, seconds=0.3, trace=False,
