@@ -34,6 +34,8 @@ through the KV store).
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import json
 import threading
 from bisect import bisect_left
@@ -60,6 +62,52 @@ BYTES_BUCKETS: Tuple[float, ...] = (
     1 << 10, 1 << 14, 1 << 18, 1 << 20, 1 << 22, 1 << 24,
     1 << 26, 1 << 28, 1 << 30,
 )
+
+
+# Gauges whose value the device computes inside a compiled step
+# (``trace_gauge``): the bags open while a step is traced, innermost
+# last, and the device arrays of finished calls not yet read.
+_trace_bags: List[Dict[str, Any]] = []
+_deferred: "collections.deque" = collections.deque(maxlen=8)
+
+
+@contextlib.contextmanager
+def traced_gauges():
+    """Collects what the function traced under it gives to
+    :func:`trace_gauge`: yields the dict, name -> traced value.
+    ``TrainStep`` opens one around the loss function and returns the
+    values with the step's outputs."""
+    bag: Dict[str, Any] = {}
+    _trace_bags.append(bag)
+    try:
+        yield bag
+    finally:
+        _trace_bags.pop()
+
+
+def trace_gauge(name: str, value) -> None:
+    """A gauge set from inside a traced step: ``value`` is a traced
+    scalar at the level of the loss (not inside a ``jax.checkpoint`` or a
+    loop), and reaches the registry once a call of the compiled step has
+    computed it.  Outside a collecting step it is dropped."""
+    if _trace_bags:
+        _trace_bags[-1][name] = value
+
+
+def defer_gauges(values: Dict[str, Any]) -> None:
+    """Device scalars of a call just enqueued, for
+    :func:`fold_ready_gauges`; only the newest few calls are kept."""
+    if values:
+        _deferred.append(values)
+
+
+def fold_ready_gauges() -> None:
+    """Sets the gauges of every deferred call the device has finished,
+    oldest first, and waits for none."""
+    while _deferred and all(v.is_ready() for v in _deferred[0].values()):
+        # one fetch for the call's scalars together
+        for name, value in jax.device_get(_deferred.popleft()).items():
+            set_gauge(name, float(value))
 
 
 class _Histogram:

@@ -13,6 +13,11 @@ on.  TPU-first choices:
 * QKV/out projections are column/row tensor-parallel over the tp axis
   (one psum per attention + one per MLP, the Megatron pairing).
 * optional MoE FFN sharded over the ep axis (parallel/moe).
+* a mixer and an FFN per layer (``layer_kinds``, ``ffn_kinds``): softmax
+  attention ("full"), latent attention ("mla": keys and values from a
+  compressed latent, DeepSeek-V2) or the delta rule with a per-channel
+  decay ("kda", ops/kda), the last two with a sigmoid gate a head; a dense MLP, the capacity MoE, or dropless
+  routed experts of which this device holds a range ("experts").
 
 * the block is GPT-2's by default (LayerNorm, learned positions, fused
   QKV with bias, GELU MLP, tied head) and a current decoder's by
@@ -42,7 +47,7 @@ from jax import lax
 
 from .. import metrics
 from ..parallel.mesh import EP_AXIS, SP_AXIS, TP_AXIS
-from ..parallel.moe import MoELayer
+from ..parallel.moe import ExpertFFN, MoELayer
 from ..parallel.ring_attention import full_attention, ring_attention
 from ..parallel.tensor import (
     ColumnParallelDense,
@@ -51,6 +56,7 @@ from ..parallel.tensor import (
     _axis_present,
 )
 from ..parallel.ulysses import ulysses_attention
+from ..ops.kda import chunk_major, kda_chunk_major
 from ..ops.pallas_kernels import (
     flash_attention,
     flash_attention_qkv,
@@ -104,6 +110,53 @@ class TransformerConfig:
     moe_k: int = 2
     moe_capacity_factor: float = 1.25
     ep_axis: str = EP_AXIS
+    # A mixer and an FFN for every layer, one name a layer; empty means
+    # "full" everywhere and what ``moe_every`` says.
+    layer_kinds: Tuple[str, ...] = ()   # "full" | "mla" | "kda"
+    ffn_kinds: Tuple[str, ...] = ()     # "dense" | "moe" | "experts"
+    # "mla" (arXiv:2405.04434 section 2.1): k and v of every head from one
+    # normed latent of ``kv_lora_rank``, and a rope key of ``rope_dim`` the
+    # heads share; q and k are qk_nope_dim + rope_dim wide, v ``head_dim``.
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    rope_dim: int = 0            # 0: the whole head turns ("full")
+    rope_interleave: bool = False  # pairs (2i, 2i+1), not (i, i + D/2)
+    # "kda" (arXiv:2510.26692 section 3): heads of ``head_dim`` keys and
+    # values, a causal depthwise convolution of ``kda_conv`` taps, and
+    # g = kda_lower_bound * sigmoid(exp(A_log) (x W_f + dt_bias)).
+    kda_conv: int = 4
+    kda_lower_bound: float = -5.0
+    # "experts": a router over ``num_experts`` with ``experts_per_token``
+    # chosen inside the best ``topk_group`` of ``n_group`` groups; this
+    # device holds ``experts_held`` = (first, past the last) of them, each
+    # a SwiGLU of ``expert_ff_dim``, and one shared expert of that width.
+    num_experts: int = 0
+    experts_held: Tuple[int, int] = (0, 0)
+    expert_ff_dim: int = 0
+    experts_per_token: int = 1
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling: float = 1.0
+
+
+KDA_CHUNK = 64  # tokens of a chunk of the delta rule (ops/kda.py)
+MIXERS = ("full", "mla", "kda")
+FFNS = ("dense", "moe", "experts")
+
+
+def layer_kind(cfg: TransformerConfig, i: int) -> Tuple[str, str]:
+    """(mixer, FFN) of layer ``i``."""
+    mixer = cfg.layer_kinds[i] if cfg.layer_kinds else "full"
+    if cfg.ffn_kinds:
+        ffn = cfg.ffn_kinds[i]
+    else:
+        ffn = ("moe" if cfg.moe_every > 0 and (i + 1) % cfg.moe_every == 0
+               else "dense")
+    if mixer not in MIXERS or ffn not in FFNS:
+        raise ValueError(
+            f"layer {i}: unknown kind {(mixer, ffn)!r}; mixers are "
+            f"{MIXERS}, FFNs {FFNS}")
+    return mixer, ffn
 
 
 def _tp_degree(axis: str) -> int:
@@ -145,9 +198,11 @@ def apply_rope(x: jax.Array, rope: Tuple[jax.Array, jax.Array]) -> jax.Array:
 
 class Attention(nn.Module):
     """Multi-head attention: tp-sharded projections + sp-sharded
-    sequence (ring or Ulysses)."""
+    sequence (ring or Ulysses).  ``latent`` makes it latent attention
+    (``cfg.kv_lora_rank`` and beside it; see ``_latent_qkv``)."""
 
     cfg: TransformerConfig
+    latent: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array,
@@ -168,13 +223,17 @@ class Attention(nn.Module):
         h_local = cfg.num_heads // tp
         b, t, _ = x.shape
 
-        def column(parts: int, name: str) -> jax.Array:
+        def column(parts: int, name: str, width: int = cfg.head_dim
+                   ) -> jax.Array:
             return ColumnParallelDense(
-                parts * cfg.num_heads * cfg.head_dim, axis=cfg.tp_axis,
+                parts * cfg.num_heads * width, axis=cfg.tp_axis,
                 use_bias=cfg.use_bias, dtype=cfg.dtype, name=name,
             )(x)
 
-        if cfg.fused_qkv:
+        scale = None
+        if self.latent:
+            q, k, v, scale = self._latent_qkv(x, column, h_local, rope)
+        elif cfg.fused_qkv:
             qkv = column(3, "qkv")
             parts = qkv.reshape(b, t, 3, h_local, cfg.head_dim)
             q, k, v = parts[:, :, 0], parts[:, :, 1], parts[:, :, 2]
@@ -182,7 +241,7 @@ class Attention(nn.Module):
             q, k, v = (
                 column(1, name).reshape(b, t, h_local, cfg.head_dim)
                 for name in ("q", "k", "v"))
-        if rope is not None:
+        if rope is not None and not self.latent:
             q, k = apply_rope(q, rope), apply_rope(k, rope)
 
         if segment_ids is not None and cfg.attn_impl not in ("flash", "full"):
@@ -210,17 +269,21 @@ class Attention(nn.Module):
                 f"sequence axis {cfg.sp_axis!r} is present in the mesh; "
                 "use attn_impl='ring' or 'ulysses' for sequence parallelism"
             )
-        elif cfg.attn_impl == "flash" and cfg.fused_qkv and rope is None:
+        elif (cfg.attn_impl == "flash" and cfg.fused_qkv and rope is None
+              and not self.latent):
             # the kernels read q, k and v out of the projection in place
             out = flash_attention_qkv(qkv, h_local, cfg.causal,
                                       segment_ids=segment_ids)
         elif cfg.attn_impl == "flash":
-            out = flash_attention(q, k, v, cfg.causal,
+            out = flash_attention(q, k, v, cfg.causal, scale=scale,
                                   segment_ids=segment_ids)
         else:
-            out = full_attention(q, k, v, causal=cfg.causal,
+            out = full_attention(q, k, v, causal=cfg.causal, scale=scale,
                                  segment_ids=segment_ids)
 
+        if self.latent:
+            # its heads come back as wide as its padded q and k
+            out = _gate_heads(cfg, x, out[..., :cfg.head_dim])
         out = out.reshape(b, t, h_local * cfg.head_dim)
         return RowParallelDense(
             cfg.model_dim, axis=cfg.tp_axis, use_bias=cfg.use_bias,
@@ -228,13 +291,181 @@ class Attention(nn.Module):
         )(out)
 
 
-class Block(nn.Module):
-    """Pre-norm transformer block; FFN is dense-TP or MoE.  With
-    ``cfg.post_norm`` each sublayer's output is normed again before it
-    joins the residual ("sandwich")."""
+    def _latent_qkv(self, x, column, h_local: int, rope):
+        """Latent attention's q, k, v as the kernels take them, heads of
+        one width, and the scale of its scores.  With c the latent and
+        k_r the rope key,  [c, k_r] = x W_dkv,  [k_n, v] = RMSNorm(c)
+        W_ukv,  q = [q_n, rope(q_r)],  k = [k_n, rope(k_r)] with k_r the
+        same for every head; scores over qk_nope_dim + rope_dim.  The
+        kernels take square heads, so q and k are padded with zeros to
+        whole 128-lane columns (192 -> 256) and v to the same width: the
+        scores and the first ``head_dim`` lanes of the output are exact,
+        the padded lanes cost the kernel ``qk_nope_dim + rope_dim`` :
+        ``width`` of its time.  The compiled kernels of the other
+        widths are untouched."""
+        cfg = self.cfg
+        b, t, _ = x.shape
+        nope, turn = cfg.qk_nope_dim, cfg.rope_dim
+        if rope is None:
+            raise ValueError("latent attention turns its rope key: set "
+                             "positions='rope'")
+        with jax.named_scope("mla"):
+            q = column(1, "q", nope + turn).reshape(b, t, h_local, -1)
+            down = nn.Dense(cfg.kv_lora_rank + turn, use_bias=False,
+                            dtype=cfg.dtype, name="kv_down")(x)
+            latent = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
+                                name="kv_norm")(down[..., :cfg.kv_lora_rank])
+            up = ColumnParallelDense(
+                cfg.num_heads * (nope + cfg.head_dim), axis=cfg.tp_axis,
+                use_bias=False, dtype=cfg.dtype, name="kv_up",
+            )(latent.astype(cfg.dtype)).reshape(b, t, h_local, -1)
+            k_rope = down[..., None, cfg.kv_lora_rank:]       # [B, T, 1, r]
+            q_rope = q[..., nope:]
+            if cfg.rope_interleave:
+                # pairs (2i, 2i+1) as the kernel's (i, i + r/2): the same
+                # permutation of q's and k's channels leaves q.k as it was
+                order = jnp.concatenate(
+                    [jnp.arange(0, turn, 2), jnp.arange(1, turn, 2)])
+                q_rope, k_rope = q_rope[..., order], k_rope[..., order]
+            q_rope, k_rope = apply_rope(q_rope, rope), apply_rope(k_rope, rope)
+            width = nope + turn
+            if width > 128:  # whole 128-lane columns
+                width = -(-width // 128) * 128
+            pad = lambda a: jnp.pad(
+                a, ((0, 0),) * 3 + ((0, width - a.shape[-1]),))
+            q = pad(jnp.concatenate([q[..., :nope], q_rope], axis=-1))
+            k = pad(jnp.concatenate(
+                [up[..., :nope],
+                 jnp.broadcast_to(k_rope, (b, t, h_local, turn))], axis=-1))
+            v = pad(up[..., nope:])
+        return q, k, v, float(nope + turn) ** -0.5
+
+
+def _gate_heads(cfg: TransformerConfig, x: jax.Array, out: jax.Array
+                ) -> jax.Array:
+    """[B, T, H, D] times sigmoid(x W_g), one gate a head: the latent
+    mixer's output gate (the delta-rule mixer applies its own by chunk)."""
+    gate = nn.Dense(cfg.num_heads, use_bias=False, dtype=jnp.float32,
+                    name="gate")(x.astype(jnp.float32))
+    return (out * jax.nn.sigmoid(gate)[..., None]).astype(out.dtype)
+
+
+def _short_conv(x: jax.Array, taps: jax.Array,
+                segment_ids: Optional[jax.Array]) -> jax.Array:
+    """Causal depthwise convolution along T: y_t = sum_j taps[j] x_{t-j},
+    [B, T, C] by [K, C], float32; a packed row's documents do not see
+    each other."""
+    b, t, _ = x.shape
+    x = x.astype(jnp.float32)
+    y = x * taps[0]
+    for j in range(1, taps.shape[0]):
+        shifted = jnp.pad(x, ((0, 0), (j, 0), (0, 0)))[:, :t]
+        if segment_ids is not None:
+            same = segment_ids == jnp.pad(
+                segment_ids, ((0, 0), (j, 0)), constant_values=-1)[:, :t]
+            shifted = jnp.where(same[..., None], shifted, 0.0)
+        y = y + shifted * taps[j]
+    return y
+
+
+class KDAMixer(nn.Module):
+    """Kimi delta attention: the delta rule with a per-channel bounded
+    decay over heads of ``head_dim`` keys and values (``ops/kda.py`` has
+    the recurrence and its chunked form).
+
+        q = L2(SiLU(Conv(x W_q))) / sqrt(d),  k = L2(SiLU(Conv(x W_k))),
+        v = SiLU(Conv(x W_v)),  beta = sigmoid(x W_b) a head,
+        g = lower_bound * sigmoid(exp(A_log) (x W_f + dt_bias)) a channel,
+        out = W_o (RMSNorm_head(o) * sigmoid(x W_g)_head)."""
 
     cfg: TransformerConfig
-    use_moe: bool = False
+
+    @nn.compact
+    def __call__(self, x: jax.Array,
+                 segment_ids: Optional[jax.Array] = None) -> jax.Array:
+        cfg = self.cfg
+        b, t, _ = x.shape
+        h, d = cfg.num_heads, cfg.head_dim
+        if _axis_present(cfg.sp_axis) and lax.axis_size(cfg.sp_axis) > 1:
+            raise ValueError(
+                "the kda mixer's state runs along the whole row: it cannot "
+                "be sequence-sharded")
+
+        def projected(name):
+            return nn.Dense(h * d, use_bias=False, dtype=cfg.dtype,
+                            name=name)(x)
+
+        # The core reads its operands chunk by chunk, [n, B, H, C, d]: the
+        # one re-laying of each (the heads leave the lanes), after the
+        # convolution, which runs along T on [B, T, H·d] as projected.
+        pad = -t % KDA_CHUNK
+        seg = (jnp.ones((b, t), jnp.int32) if segment_ids is None
+               else segment_ids.astype(jnp.int32))
+        seg = jnp.pad(seg, ((0, 0), (0, pad)), mode="edge")
+        seg = jnp.swapaxes(seg.reshape(b, -1, KDA_CHUNK), 0, 1)
+
+        def chunks(a, width):
+            a = jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
+            return chunk_major(a.reshape(b, t + pad, h, width), KDA_CHUNK)
+
+        def convolved(name):
+            taps = self.param(
+                f"conv_{name}", nn.initializers.normal(0.5),
+                (cfg.kda_conv, h * d), jnp.float32)
+            y = projected(name)
+            with jax.named_scope("conv"):
+                return chunks(nn.silu(_short_conv(y, taps, segment_ids)), d)
+
+        def unit(a):
+            return a * lax.rsqrt(
+                jnp.sum(jnp.square(a), axis=-1, keepdims=True) + 1e-6)
+
+        q, k, v = convolved("q"), convolved("k"), convolved("v")
+        raw_gate = projected("f")
+        with jax.named_scope("conv"):
+            # normed in float32, handed on as the matmuls will take them
+            q, k, v = (a.astype(cfg.dtype)
+                       for a in (unit(q) * d ** -0.5, unit(k), v))
+        with jax.named_scope("gate"):
+            a_log = self.param(
+                "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                    key, shape, jnp.float32, 1.0, 2.0)), (h,))
+            dt_bias = self.param(
+                "dt_bias", lambda key, shape: jax.random.uniform(
+                    key, shape, jnp.float32, -4.0, -1.0), (h * d,))
+            g = chunks(cfg.kda_lower_bound * jax.nn.sigmoid(
+                jnp.repeat(jnp.exp(a_log), d)
+                * (raw_gate.astype(jnp.float32) + dt_bias)), d)
+            beta = chunks(jax.nn.sigmoid(nn.Dense(
+                h, use_bias=False, dtype=jnp.float32, name="b")(
+                    x.astype(jnp.float32))), 1)
+        with jax.named_scope("core"):
+            o = kda_chunk_major(q, k, v, g, beta, seg)
+        o = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
+                       name="o_norm")(o)
+        o = o * chunks(jax.nn.sigmoid(nn.Dense(
+            h, use_bias=False, dtype=jnp.float32, name="gate")(
+                x.astype(jnp.float32))), 1)
+        # [n, B, H, C, d] -> [B, T, H·d]
+        o = jnp.transpose(o.astype(cfg.dtype), (1, 0, 3, 2, 4)).reshape(
+            b, t + pad, h * d)[:, :t]
+        return RowParallelDense(
+            cfg.model_dim, axis=cfg.tp_axis, use_bias=False,
+            dtype=cfg.dtype, name="proj",
+        )(o)
+
+
+class Block(nn.Module):
+    """Pre-norm transformer block: a mixer (``kind``: softmax attention,
+    latent attention or the delta rule) and an FFN (``ffn``: dense-TP,
+    the capacity MoE or dropless experts).  With ``cfg.post_norm`` each
+    sublayer's output is normed again before it joins the residual
+    ("sandwich").  Returns (x, the capacity MoE's auxiliary loss, the
+    held experts' loads or None)."""
+
+    cfg: TransformerConfig
+    kind: str = "full"
+    ffn: str = "dense"
 
     @nn.compact
     def __call__(
@@ -246,15 +477,27 @@ class Block(nn.Module):
             raise ValueError(
                 f"unknown mlp {cfg.mlp!r}; expected 'gelu' or 'gated_silu'")
         h = _norm(cfg, "ln_attn")(x)
-        y = Attention(cfg, name="attn")(h.astype(cfg.dtype), segment_ids,
-                                        rope)
+        if self.kind == "kda":
+            y = KDAMixer(cfg, name="kda")(h.astype(cfg.dtype), segment_ids)
+        else:
+            y = Attention(cfg, latent=self.kind == "mla", name="attn")(
+                h.astype(cfg.dtype), segment_ids, rope)
         if cfg.post_norm:
             y = _norm(cfg, "ln_attn_post")(y)
         x = x + y.astype(x.dtype)
         h = _norm(cfg, "ln_mlp")(x)
-        h = h.astype(cfg.dtype)
         aux = jnp.zeros((), jnp.float32)
-        if self.use_moe:
+        load = None
+        if self.ffn == "experts":
+            # takes the normed state in float32, the router's own type
+            y, load = ExpertFFN(
+                num_experts=cfg.num_experts, experts_held=cfg.experts_held,
+                hidden=cfg.expert_ff_dim, k=cfg.experts_per_token,
+                n_group=cfg.n_group, topk_group=cfg.topk_group,
+                routed_scaling=cfg.routed_scaling, dtype=cfg.dtype,
+                name="moe",
+            )(h)
+        elif self.ffn == "moe":
             y, aux = MoELayer(
                 num_experts_local=cfg.num_experts_local,
                 hidden=cfg.ff_dim // max(1, cfg.num_experts_local),
@@ -263,7 +506,7 @@ class Block(nn.Module):
                 axis=cfg.ep_axis,
                 dtype=cfg.dtype,
                 name="moe",
-            )(h)
+            )(h.astype(cfg.dtype))
         else:
             gated = cfg.mlp == "gated_silu"
             y = TensorParallelMLP(
@@ -275,10 +518,10 @@ class Block(nn.Module):
                 gated=gated,
                 use_bias=cfg.use_bias,
                 name="mlp",
-            )(h)
+            )(h.astype(cfg.dtype))
         if cfg.post_norm:
             y = _norm(cfg, "ln_mlp_post")(y)
-        return x + y.astype(x.dtype), aux
+        return x + y.astype(x.dtype), aux, load
 
 
 def _positions(cfg: TransformerConfig, b: int, t: int,
@@ -346,7 +589,8 @@ class Transformer(nn.Module):
                 )
                 x = x + jnp.take(wpe, pos, axis=0)
             else:
-                rope = rope_tables(pos, cfg.head_dim, cfg.rope_theta)
+                rope = rope_tables(pos, cfg.rope_dim or cfg.head_dim,
+                                   cfg.rope_theta)
             x = x.astype(cfg.dtype)
         if cfg.tie_head:
             head = emb.embedding
@@ -366,11 +610,10 @@ class Transformer(nn.Module):
                 Block, policy=jax.checkpoint_policies.save_only_these_names(
                     *cfg.remat_save))
         # Made once, called ut_steps times: one set of weights a layer.
+        kinds = [layer_kind(cfg, i) for i in range(cfg.num_layers)]
         blocks = [
-            block_cls(
-                cfg, use_moe=cfg.moe_every > 0 and (i + 1) % cfg.moe_every == 0,
-                name=f"block_{i}")
-            for i in range(cfg.num_layers)
+            block_cls(cfg, kind=mixer, ffn=ffn, name=f"block_{i}")
+            for i, (mixer, ffn) in enumerate(kinds)
         ]
         ln_f = _norm(cfg, "ln_f")
         gate = (nn.Dense(1, dtype=jnp.float32, name="exit_gate")
@@ -378,14 +621,17 @@ class Transformer(nn.Module):
 
         aux_total = jnp.zeros((), jnp.float32)
         applications = 0
+        loads = []  # of every application of a layer with held experts
         logits, exit_logits = [], []
         for step in range(cfg.ut_steps):
             with (jax.named_scope(f"ut_{step}") if cfg.ut_steps > 1
                   else contextlib.nullcontext()):
                 for block in blocks:
-                    x, aux = block(x, segment_ids, rope)
+                    x, aux, load = block(x, segment_ids, rope)
                     aux_total = aux_total + aux
                     applications += 1
+                    if load is not None:
+                        loads.append(load)
                 # the normed state is what the next pass starts from
                 x = ln_f(x)
                 if gate is not None or step == cfg.ut_steps - 1:
@@ -403,6 +649,20 @@ class Transformer(nn.Module):
                 x = x.astype(cfg.dtype)
         metrics.set_gauge("model.layer_applications", applications)
         metrics.set_gauge("model.ut_steps", cfg.ut_steps)
+        for name in MIXERS + FFNS:
+            metrics.set_gauge(
+                "model.layer_kinds", sum(name in kind for kind in kinds),
+                {"kind": name})
+        if loads:
+            held = cfg.experts_held[1] - cfg.experts_held[0]
+            metrics.set_gauge("model.moe.experts_held", held)
+            # what the batch really asked of the held experts: read from
+            # the step's outputs when they are there (metrics.trace_gauge)
+            loads = lax.stop_gradient(jnp.stack(loads))
+            metrics.trace_gauge("model.moe.pairs_per_step", jnp.sum(loads))
+            metrics.trace_gauge(
+                "model.moe.load_max_over_mean",
+                jnp.max(loads) / jnp.maximum(jnp.mean(loads), 1e-9))
         if gate is None:
             return logits[-1], aux_total
         return jnp.stack(logits), jnp.stack(exit_logits), aux_total
@@ -423,7 +683,7 @@ def param_shard_axes(params, cfg: TransformerConfig):
         joined = "/".join(str(k) for k in keys)
         leaf = keys[-1] if keys else ""
         if "/moe/" in f"/{joined}/":
-            return cfg.ep_axis if leaf in ("wi", "wo") else ""
+            return cfg.ep_axis if leaf in ("wg", "wi", "wo") else ""
         if "/attn/" in f"/{joined}/":
             if any(f"/{n}/" in f"/{joined}/" for n in ("qkv", "q", "k", "v")):
                 return cfg.tp_axis  # column shard: kernel and bias

@@ -827,26 +827,34 @@ class TrainStep:
 
             loss_fn = _sched_hooks.capturing_loss(loss_fn)
 
+        from .. import metrics as _metrics
+
+        def with_gauges(*args):
+            # What the model gives to ``metrics.trace_gauge`` while it is
+            # traced leaves the step beside the loss (nothing, and the
+            # same program, where the model gives none).
+            with _metrics.traced_gauges() as bag:
+                out = loss_fn(*args)
+            loss, aux = out if stateful or has_aux else (out, None)
+            return loss, (aux, dict(bag))
+
         def compute_grads(params, model_state, batch):
+            args = (params, model_state, batch) if stateful else (
+                params, batch)
+            (loss, (aux, gauges)), grads = jax.value_and_grad(
+                with_gauges, has_aux=True)(*args)
+            out_state = None
             if stateful:
-                (loss, out_state), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                    params, model_state, batch
-                )
                 # Cross-replica average of model state (SyncBN semantics).
-                out_state = lax.pmean(out_state, axis)
-                return loss, out_state, None, grads
-            if has_aux:
-                (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                    params, batch
-                )
-                return loss, None, lax.pmean(aux, axis), grads
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-            return loss, None, None, grads
+                out_state, aux = lax.pmean(aux, axis), None
+            elif has_aux:
+                aux = lax.pmean(aux, axis)
+            return loss, out_state, aux, grads, lax.pmean(gauges, axis)
 
         def step_body(params, model_state, opt_state, batch):
             opt_state = _stack_local(opt_state, unstack=True)
             with jax.named_scope("hvd_compute_grads"):
-                loss, model_state, aux, grads = compute_grads(
+                loss, model_state, aux, grads, gauges = compute_grads(
                     params, model_state, batch
                 )
             with jax.named_scope("hvd_reduce_and_update"):
@@ -860,7 +868,7 @@ class TrainStep:
             out += (opt_state, loss)
             if aux is not None:
                 out += (aux,)
-            return out
+            return out + (gauges,)
 
         # Build init: trace state structure to derive out specs.
         def make_init():
@@ -920,6 +928,7 @@ class TrainStep:
         out_specs += (specs, P())
         if self.has_aux and not self.stateful:
             out_specs += (P(),)
+        out_specs += (P(),)  # the traced gauges, a dict that may be empty
         # Donate params / model state / optimizer state — the pytrees
         # the step returns updated — so XLA aliases them in place
         # instead of copying the full parameter set in HBM every step.
@@ -985,9 +994,11 @@ class TrainStep:
             if interval is not None and not holds_build:
                 _metrics.observe("train.step_seconds", interval)
             _metrics.inc_counter("train.steps")
+            if span is None:  # no step tree whose finalize would
+                _metrics.fold_ready_gauges()
 
     def _call(self, params, args, span):
-        from .. import prof, trace as _trace
+        from .. import metrics as _metrics, prof, trace as _trace
         from ..prof.introspect import ProfiledExecutor
 
         def profiled(fn):
@@ -1081,6 +1092,11 @@ class TrainStep:
                 with _trace.span("train_step", "exec"):
                     out = fn(*call_args)
             self._ran = fn
+            # the traced gauges stay here: folded into the registry when
+            # the device has them (``hvd_step_finalize``), never awaited
+            *out, gauges = out
+            out = tuple(out)
+            _metrics.defer_gauges(gauges)
         except QuantizedWireError:
             if quant and built_here and self._autotune is not None \
                     and not self._autotune.converged:

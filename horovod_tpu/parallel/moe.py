@@ -19,10 +19,12 @@ from typing import Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from .mesh import EP_AXIS
-from .tensor import _axis_present
+from .tensor import TensorParallelMLP, _axis_present
 
 
 def _top_k_gating(
@@ -165,3 +167,252 @@ class MoELayer(nn.Module):
             y = y.reshape(e, capacity, d)
         out = jnp.einsum("sec,ecd->sd", combine.astype(y.dtype), y)
         return out.reshape(b, t, d), aux
+
+
+# ---------------------------------------------------------------------------
+# Dropless experts: sigmoid scores, group-limited top-k, sorted dispatch and
+# a grouped product over the experts this device holds
+# ---------------------------------------------------------------------------
+
+
+def route_group_limited(scores: jax.Array, bias: jax.Array, k: int,
+                        n_group: int, topk_group: int, scale: float
+                        ) -> Tuple[jax.Array, jax.Array]:
+    """DeepSeek-V3's router without an auxiliary loss (arXiv:2412.19437
+    section 2.1.2).  ``scores`` [S, E] are the sigmoids of the router's
+    logits, ``bias`` [E] the selection bias (a buffer: it moves the
+    choice and never the weight).  The experts lie in ``n_group`` groups
+    of E / n_group; a group's score is the sum of its two largest
+    ``scores + bias``, the best ``topk_group`` groups stay, and the ``k``
+    largest ``scores + bias`` among their experts are chosen.  Returns
+    (ids [S, k] int32, weights [S, k]): ``scale * s_i / sum of the chosen
+    s``."""
+    s, e = scores.shape
+    choice = scores + lax.stop_gradient(bias)
+    if n_group > 1:
+        grouped = choice.reshape(s, n_group, e // n_group)
+        group_score = jnp.sum(lax.top_k(grouped, 2)[0], axis=-1)
+        kept = lax.top_k(group_score, topk_group)[1]            # [S, g]
+        group_kept = jnp.any(
+            kept[..., None] == jnp.arange(n_group), axis=1)     # [S, G]
+        choice = jnp.where(
+            group_kept[..., None], grouped, -jnp.inf).reshape(s, e)
+    # a name, so that a caller's ``jax.checkpoint`` policy can keep the
+    # choice (k integers a token) and not sort for it again
+    ids = checkpoint_name(
+        lax.top_k(lax.stop_gradient(choice), k)[1], "moe_route")
+    chosen = jnp.take_along_axis(scores, ids, axis=-1)
+    weights = scale * chosen / (
+        jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return ids.astype(jnp.int32), weights
+
+
+# Rows of a grouped product's tile.  A tile costs much the same whatever
+# it holds (its expert's matrices sliced, their gradients' rows read and
+# written), so the loop's time goes by the number of tiles: at 256 rows a
+# lightly loaded expert is one tile and only a heavy one is more, and the
+# step's time moves less with the router's draw than at 128 (PERF.md, PR 32).
+_TILE = 256
+
+
+def _plan(local: jax.Array, n_held: int, tile: int):
+    """The sorted dispatch of the (token, expert) pairs ``local`` [S, k]
+    (the held expert's index, or ``n_held`` for a pair another device
+    serves): the pairs' order by expert, each held expert's count and
+    start in that order, and its tiles of ``tile`` rows.  The bound on
+    the pairs is all S x k of them, so none can be lost."""
+    flat = local.reshape(-1)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    counts = jnp.bincount(flat, length=n_held + 1)[:n_held].astype(jnp.int32)
+    starts = jnp.cumsum(counts) - counts
+    tiles = -(-counts // tile)
+    return order, counts, starts, jnp.cumsum(tiles), tiles
+
+
+def _tile_rows(j, plan, k: int, tile: int):
+    """(expert, token of every row, whether the row is a pair, the pair's
+    index) of tile ``j`` of the plan."""
+    order, counts, starts, tile_end, tiles = plan
+    e = jnp.sum(j >= tile_end).astype(jnp.int32)
+    offset = (j - (tile_end[e] - tiles[e])) * tile + jnp.arange(
+        tile, dtype=jnp.int32)
+    valid = offset < counts[e]
+    pair = order[jnp.minimum(starts[e] + offset, order.shape[0] - 1)]
+    return e, pair // k, valid, pair
+
+
+def _swiglu_tile(xt, e, wg, wu):
+    a = jnp.dot(xt, lax.dynamic_index_in_dim(wg, e, keepdims=False),
+                preferred_element_type=jnp.float32)
+    u = jnp.dot(xt, lax.dynamic_index_in_dim(wu, e, keepdims=False),
+                preferred_element_type=jnp.float32)
+    return a, u
+
+
+@jax.custom_vjp
+def grouped_experts(x, weights, wg, wu, wd, local):
+    """sum over the pairs on held experts of ``w * E_e(x_token)``: x
+    [S, d], weights [S, k] float32, the held experts' SwiGLU matrices wg,
+    wu [n, d, f] and wd [n, f, d] in the compute type, ``local`` [S, k]
+    int32 (``_plan``).  Returns [S, d] float32.
+
+    The pairs are sorted by expert and multiplied tile by tile, a tile of
+    ``_TILE`` rows of one expert; the loop runs over the tiles the batch
+    really produced (a dynamic trip count), so the work follows the load:
+    there is no capacity, no [S, E, C] tensor and no buffer of the worst
+    case's size, and the static bound is every pair."""
+    return _grouped_fwd(x, weights, wg, wu, wd, local)[0]
+
+
+def _grouped_fwd(x, weights, wg, wu, wd, local):
+    k = local.shape[1]
+    with jax.named_scope("dispatch"):
+        plan = _plan(local, wg.shape[0], _TILE)
+    flat_w = weights.reshape(-1)
+
+    def tile(j, out):
+        with jax.named_scope("dispatch"):
+            e, token, valid, pair = _tile_rows(j, plan, k, _TILE)
+            xt = x[token]
+        with jax.named_scope("experts"):
+            a, u = _swiglu_tile(xt, e, wg, wu)
+            h = (jax.nn.silu(a) * u).astype(x.dtype)
+            y = jnp.dot(h, lax.dynamic_index_in_dim(wd, e, keepdims=False),
+                        preferred_element_type=jnp.float32)
+        with jax.named_scope("combine"):
+            w = jnp.where(valid, flat_w[pair], 0.0)
+            return out.at[token].add(y * w[:, None])
+
+    out = lax.fori_loop(
+        0, plan[3][-1], tile, jnp.zeros(x.shape, jnp.float32))
+    return out, (x, weights, wg, wu, wd, local, plan)
+
+
+def _grouped_bwd(res, dout):
+    x, weights, wg, wu, wd, local, plan = res
+    k = local.shape[1]
+    flat_w = weights.reshape(-1)
+    dout = dout.astype(jnp.float32)
+
+    def tile(j, carry):
+        dx, dw, dwg, dwu, dwd = carry
+        with jax.named_scope("dispatch"):
+            e, token, valid, pair = _tile_rows(j, plan, k, _TILE)
+            xt = x[token]
+            dyt = dout[token]
+        with jax.named_scope("experts"):
+            a, u = _swiglu_tile(xt, e, wg, wu)
+            sig = jax.nn.sigmoid(a)
+            act = a * sig
+            h = (act * u).astype(x.dtype)
+            wd_e = lax.dynamic_index_in_dim(wd, e, keepdims=False)
+            y = jnp.dot(h, wd_e, preferred_element_type=jnp.float32)
+            w = jnp.where(valid, flat_w[pair], 0.0)
+            dy = (dyt * w[:, None]).astype(x.dtype)
+            dh = lax.dot_general(dy, wd_e, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+            da = (dh * u * (sig + act * (1.0 - sig))).astype(x.dtype)
+            du = (dh * act).astype(x.dtype)
+            dxt = (
+                lax.dot_general(
+                    da, lax.dynamic_index_in_dim(wg, e, keepdims=False),
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                + lax.dot_general(
+                    du, lax.dynamic_index_in_dim(wu, e, keepdims=False),
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+
+            def add(total, left, right):
+                part = lax.dot_general(
+                    left, right, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                return lax.dynamic_update_index_in_dim(
+                    total, lax.dynamic_index_in_dim(
+                        total, e, keepdims=False) + part, e, 0)
+
+            dwg, dwu, dwd = add(dwg, xt, da), add(dwu, xt, du), add(dwd, h, dy)
+        with jax.named_scope("combine"):
+            dw = dw.at[pair].add(
+                jnp.where(valid, jnp.sum(dyt * y, axis=-1), 0.0))
+            dx = dx.at[token].add(dxt)
+        return dx, dw, dwg, dwu, dwd
+
+    zeros = lambda like: jnp.zeros(like.shape, jnp.float32)
+    dx, dw, dwg, dwu, dwd = lax.fori_loop(
+        0, plan[3][-1], tile,
+        (zeros(x), zeros(flat_w), zeros(wg), zeros(wu), zeros(wd)))
+    return (dx.astype(x.dtype), dw.reshape(weights.shape).astype(
+        weights.dtype), dwg.astype(wg.dtype), dwu.astype(wu.dtype),
+        dwd.astype(wd.dtype), np.zeros(local.shape, jax.dtypes.float0))
+
+
+grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+class ExpertFFN(nn.Module):
+    """A routed-expert FFN as a device of an expert-parallel job holds it:
+    a router as wide as the layer (``num_experts``), the SwiGLU experts
+    ``experts_held`` = (first, past the last) of it, and one shared expert.
+
+        s = sigmoid(x W_r);  ids, w = route_group_limited(s, b, ...)
+        y = Shared(x) + sum over chosen i held here of w_i E_i(x)
+
+    With every expert held this is the whole layer; with a range it is
+    this device's part, which the other devices' parts complete by a sum
+    (the shared expert counted once).  No token is dropped.  Takes the
+    normed input in float32 (the router's type) and returns (y [B, T, d]
+    in ``dtype``, the held experts' loads [n_held] float32, the pairs
+    each served)."""
+
+    num_experts: int
+    experts_held: Tuple[int, int]
+    hidden: int
+    k: int
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling: float = 1.0
+    dtype: Optional[jnp.dtype] = None
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+        b, t, d = x.shape
+        lo, hi = self.experts_held
+        n_held = hi - lo
+        if not 0 <= lo < hi <= self.num_experts:
+            raise ValueError(
+                f"experts_held {self.experts_held} is no range of the "
+                f"{self.num_experts} experts")
+        dtype = self.dtype or x.dtype
+        xf = x.reshape(b * t, d)
+        with jax.named_scope("router"):
+            w_r = self.param("router", nn.initializers.lecun_normal(),
+                             (d, self.num_experts), jnp.float32)
+            bias = self.param("router_bias", nn.initializers.normal(0.01),
+                              (self.num_experts,), jnp.float32)
+            scores = jax.nn.sigmoid(jnp.dot(
+                xf.astype(jnp.float32), w_r,
+                precision=lax.Precision.HIGHEST))
+            ids, weights = route_group_limited(
+                scores, bias, self.k, self.n_group, self.topk_group,
+                self.routed_scaling)
+            held = jnp.logical_and(ids >= lo, ids < hi)
+            local = jnp.where(held, ids - lo, n_held)
+            load = jnp.sum(
+                local[..., None] == jnp.arange(n_held), axis=(0, 1))
+
+        def experts(name, shape):
+            return self.param(name, nn.initializers.lecun_normal(
+                in_axis=-2, out_axis=-1), shape, jnp.float32).astype(dtype)
+
+        wg = experts("wg", (n_held, d, self.hidden))
+        wu = experts("wi", (n_held, d, self.hidden))
+        wd = experts("wo", (n_held, self.hidden, d))
+        xc = xf.astype(dtype)
+        routed = grouped_experts(xc, weights, wg, wu, wd, local)
+        with jax.named_scope("shared"):
+            shared = TensorParallelMLP(
+                hidden=self.hidden, features=d, dtype=dtype, act=nn.silu,
+                gated=True, use_bias=False, name="shared")(xc)
+        y = routed.astype(dtype) + shared
+        return y.reshape(b, t, d), load.astype(jnp.float32)

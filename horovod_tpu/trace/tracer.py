@@ -371,6 +371,7 @@ class Tracer:
                 )
         metrics.inc_counter("trace.spans", n)
         if step:
+            metrics.fold_ready_gauges()
             self._publish_rail_utilization(span)
             # Profiling plane (prof/): step clock, host gap, MFU and
             # the sentinel all derive from the finalized step tree.

@@ -104,7 +104,8 @@ def test_full_train_step_single_allreduce(hvd_module):
         jax.shard_map(
             step._step_body, mesh=hvd.mesh(),
             in_specs=(step._param_spec, P(), specs, step._batch_spec),
-            out_specs=(step._param_spec, specs, P()),
+            # params, optimizer state, loss, and the traced gauges (none)
+            out_specs=(step._param_spec, specs, P(), P()),
             check_vma=False,
         ),
     )

@@ -1,0 +1,398 @@
+"""The layers of a hybrid linear-attention / latent-attention decoder with
+routed experts, each against a plain form at a small size: the chunked
+delta rule (``ops/kda.py``) against its recurrence, latent attention
+against materialised scores, the group-limited router against ``top_k`` on
+hand-made cases, the dropless grouped product against a dense loop, and the
+shares of an expert layer against the whole layer."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu.models import transformer
+from horovod_tpu.ops import kda
+from horovod_tpu.parallel import moe
+
+
+def _max_rel(got, want, floor=1e-6):
+    scale = max(float(jnp.max(jnp.abs(want))), floor)
+    return float(jnp.max(jnp.abs(got - want))) / scale
+
+
+# ------------------------------------------------------------- the delta rule
+def _kda_inputs(seed, decay, b=2, t=150, h=2, dk=16, dv=8):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(keys[0], (b, t, h, dk)))
+    k = unit(jax.random.normal(keys[1], (b, t, h, dk)))
+    v = jax.random.normal(keys[2], (b, t, h, dv))
+    u = jax.random.uniform(keys[3], (b, t, h, dk))
+    g = {
+        "across": -5.0 * u,
+        "strong_end": -4.9 - 0.0999 * u,             # alpha ~ e^-5
+        "weak_end": -1e-3 * u,                       # alpha ~ 1
+        # half of a head's channels at each end of (-5, 0)
+        "both_ends": jnp.where(jnp.arange(dk) % 2 == 0, -4.99, -0.01) + 0 * u,
+    }[decay]
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (b, t, h)))
+    return q, k, v, g, beta
+
+
+def _packed_rows(t):
+    # a boundary inside a chunk, one on a chunk's edge (64), padding last
+    first = np.repeat([1, 2, 3], [40, 24, t - 64])
+    second = np.repeat([1, 2, 0], [100, 30, t - 130])
+    return jnp.asarray(np.stack([first, second]), jnp.int32)
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["dense", "packed"])
+@pytest.mark.parametrize(
+    "decay", ["across", "strong_end", "weak_end", "both_ends"])
+def test_chunked_delta_rule_is_the_recurrence(decay, packed):
+    """T = 150 is two chunks of 64 and a ragged third.  Outputs and every
+    gradient; a gradient is held to 1e-4 of the largest gradient of the
+    five (at the strong end the decays' own gradient is 1e-3 of k's, and
+    float32 cancels in the chunk's running sums)."""
+    args = _kda_inputs(0, decay)
+    seg = _packed_rows(150) if packed else None
+    weight = jax.random.normal(jax.random.PRNGKey(9), (2, 150, 2, 8))
+
+    def scalar(fn):
+        return lambda *a: jnp.sum(fn(*a, segment_ids=seg) * weight)
+
+    with jax.default_matmul_precision("highest"):
+        got = kda.kda_chunked(*args, segment_ids=seg)
+        want = kda.kda_recurrent(*args, segment_ids=seg)
+        g_got = jax.grad(scalar(kda.kda_chunked), argnums=range(5))(*args)
+        g_want = jax.grad(scalar(kda.kda_recurrent), argnums=range(5))(*args)
+    assert got.shape == want.shape == (2, 150, 2, 8)
+    assert _max_rel(got, want) <= 1e-5
+    scale = max(float(jnp.max(jnp.abs(w))) for w in g_want)
+    for name, a, w in zip("q k v g beta".split(), g_got, g_want):
+        assert float(jnp.max(jnp.abs(a - w))) <= 1e-4 * scale, name
+        assert float(jnp.max(jnp.abs(w))) > 0, name
+
+
+def test_a_document_starts_from_an_empty_state():
+    """The second document of a packed row alone gives what it gives in
+    the row: nothing crosses the boundary."""
+    q, k, v, g, beta = _kda_inputs(1, "weak_end", b=1, t=100)
+    seg = jnp.asarray(np.repeat([1, 2], [37, 63])[None], jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        row = kda.kda_chunked(q, k, v, g, beta, segment_ids=seg)
+        alone = kda.kda_chunked(*(a[:, 37:] for a in (q, k, v, g, beta)))
+        unpacked = kda.kda_chunked(q, k, v, g, beta)
+    assert _max_rel(row[:, 37:], alone) <= 1e-5
+    assert _max_rel(unpacked[:, 37:], alone) > 1e-2  # the state matters
+
+
+def test_no_exponent_passes_the_bound_at_the_strongest_decay():
+    q, k, v, _, beta = _kda_inputs(2, "across", t=128)
+    g = jnp.full(q.shape, -4.999)
+    out, grads = jax.value_and_grad(
+        lambda g: jnp.sum(kda.kda_chunked(q, k, v, g, beta)))(g)
+    assert np.isfinite(float(out)) and bool(jnp.all(jnp.isfinite(grads)))
+    with pytest.raises(ValueError, match="sub-chunks"):
+        kda.kda_chunked(q, k, v, g, beta, chunk=64, sub=24)
+
+
+def test_short_convolution_keeps_to_its_document():
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 10, 3))
+    taps = jax.random.normal(jax.random.PRNGKey(1), (4, 3))
+    seg = jnp.asarray([[1, 1, 1, 1, 2, 2, 2, 2, 2, 2]])
+    y = transformer._short_conv(x, taps, seg)
+    want = np.zeros((10, 3), np.float32)
+    for t in range(10):
+        for j in range(4):
+            if t - j >= 0 and seg[0, t - j] == seg[0, t]:
+                want[t] += np.asarray(taps[j] * x[0, t - j])
+    np.testing.assert_allclose(y[0], want, rtol=1e-5, atol=1e-6)
+    # without segments the second document sees the first one's last tokens
+    assert not np.allclose(transformer._short_conv(x, taps, None)[0, 4],
+                           want[4])
+
+
+# -------------------------------------------------------- latent attention
+def _mla_config(**over):
+    cfg = transformer.TransformerConfig(
+        vocab_size=64, num_layers=1, model_dim=48, num_heads=3, head_dim=16,
+        ff_dim=64, max_len=256, dtype=jnp.float32, attn_impl="flash",
+        norm="rmsnorm", positions="rope", rope_theta=6e6, use_bias=False,
+        fused_qkv=False, mlp="gated_silu", tie_head=False,
+        layer_kinds=("mla",), kv_lora_rank=24,
+        qk_nope_dim=16, rope_dim=8, rope_interleave=True)
+    return dataclasses.replace(cfg, **over)
+
+
+def _plain_mla(p, x, cfg, pos, seg):
+    """Materialised scores, interleaved rope written out pair by pair."""
+    b, t, _ = x.shape
+    h, nope, turn = cfg.num_heads, cfg.qk_nope_dim, cfg.rope_dim
+
+    def rope(a):  # [B, T, H, turn]
+        inv = 1.0 / cfg.rope_theta ** (np.arange(0, turn, 2) / turn)
+        ang = pos[..., None, None] * inv
+        if cfg.rope_interleave:
+            even, odd = a[..., 0::2], a[..., 1::2]
+        else:
+            even, odd = a[..., :turn // 2], a[..., turn // 2:]
+        out = (even * jnp.cos(ang) - odd * jnp.sin(ang),
+               odd * jnp.cos(ang) + even * jnp.sin(ang))
+        if cfg.rope_interleave:
+            return jnp.stack(out, axis=-1).reshape(a.shape)
+        return jnp.concatenate(out, axis=-1)
+
+    q = (x @ p["q"]["Dense_0"]["kernel"]).reshape(b, t, h, nope + turn)
+    down = x @ p["kv_down"]["kernel"]
+    c = down[..., :cfg.kv_lora_rank]
+    c = c / jnp.sqrt(jnp.mean(c * c, -1, keepdims=True) + cfg.norm_eps) \
+        * p["kv_norm"]["scale"]
+    up = (c @ p["kv_up"]["Dense_0"]["kernel"]).reshape(b, t, h, -1)
+    k_r = rope(down[..., None, cfg.kv_lora_rank:])
+    scores = (jnp.einsum("bqhd,bkhd->bhqk", q[..., :nope], up[..., :nope])
+              + jnp.einsum("bqhd,bkd->bhqk", rope(q[..., nope:]),
+                           k_r[:, :, 0])) / np.sqrt(nope + turn)
+    idx = jnp.arange(t)
+    allowed = (idx[:, None] >= idx[None]) & (seg[:, :, None] == seg[:, None])
+    w = jax.nn.softmax(jnp.where(allowed[:, None], scores, -jnp.inf), -1)
+    o = jnp.einsum("bhqk,bkhd->bqhd", w, up[..., nope:])
+    o = o * jax.nn.sigmoid(x @ p["gate"]["kernel"])[..., None]
+    return o.reshape(b, t, -1) @ p["proj"]["Dense_0"]["kernel"]
+
+
+@pytest.mark.parametrize("impl, interleave, packed", [
+    ("flash", True, False), ("flash", True, True), ("flash", False, False),
+    ("full", True, True)])
+def test_latent_attention_is_the_plain_form(impl, interleave, packed):
+    cfg = _mla_config(attn_impl=impl, rope_interleave=interleave)
+    b, t = 2, 70
+    x = jax.random.normal(jax.random.PRNGKey(0), (b, t, cfg.model_dim))
+    seg = jnp.asarray(np.stack([np.repeat([1, 2], [30, 40]),
+                                np.repeat([1, 2, 3], [10, 50, 10])]))
+    if not packed:
+        seg = jnp.ones((b, t), jnp.int32)
+    idx = jnp.broadcast_to(jnp.arange(t), (b, t))
+    starts = jnp.concatenate(
+        [jnp.ones((b, 1), bool), seg[:, 1:] != seg[:, :-1]], axis=1)
+    pos = idx - jax.lax.cummax(jnp.where(starts, idx, 0), axis=1)
+    tables = transformer.rope_tables(pos, cfg.rope_dim, cfg.rope_theta)
+    attn = transformer.Attention(cfg, latent=True)
+    params = attn.init(jax.random.PRNGKey(1), x, seg, tables)
+    assert params["params"]["kv_up"]["Dense_0"]["kernel"].shape == (
+        24, 3 * 32)
+    assert params["params"]["q"]["Dense_0"]["kernel"].shape == (48, 3 * 24)
+
+    def system(p, x):
+        return attn.apply(p, x, seg if packed else None, tables)
+
+    def plain(p, x):
+        return _plain_mla(p["params"], x, cfg, pos.astype(jnp.float32), seg)
+
+    weight = jax.random.normal(jax.random.PRNGKey(2), (b, t, cfg.model_dim))
+    with jax.default_matmul_precision("highest"):
+        got, want = system(params, x), plain(params, x)
+        g_got = jax.grad(lambda p, x: jnp.sum(system(p, x) * weight),
+                         argnums=(0, 1))(params, x)
+        g_want = jax.grad(lambda p, x: jnp.sum(plain(p, x) * weight),
+                          argnums=(0, 1))(params, x)
+    assert _max_rel(got, want) <= 2e-5
+    flat = jax.tree_util.tree_flatten_with_path(g_got)[0]
+    for (path, a), w in zip(flat, jax.tree.leaves(g_want)):
+        assert _max_rel(a, w) <= 1e-4, jax.tree_util.keystr(path)
+
+
+# ------------------------------------------------------------------ router
+def _route(scores, bias, **kw):
+    args = dict(k=2, n_group=2, topk_group=1, scale=2.5)
+    args.update(kw)
+    ids, w = moe.route_group_limited(
+        jnp.asarray([scores], jnp.float32), jnp.asarray(bias, jnp.float32),
+        **args)
+    return sorted(np.asarray(ids[0]).tolist()), np.asarray(w[0])
+
+
+def test_the_group_limit_changes_the_choice():
+    """Two groups of three, one kept.  The largest score of all lies in
+    group 0; group 1's two best sum higher, so both choices come from
+    group 1: plain top-2 would take experts 0 and 3."""
+    scores = [0.9, 0.1, 0.1, 0.6, 0.5, 0.1]
+    zero = [0.0] * 6
+    assert _route(scores, zero)[0] == [3, 4]
+    assert _route(scores, zero, n_group=1)[0] == [0, 3]  # = lax.top_k
+    assert sorted(np.asarray(jax.lax.top_k(
+        jnp.asarray(scores), 2)[1]).tolist()) == [0, 3]
+    ids, w = _route(scores, zero)
+    np.testing.assert_allclose(sorted(w), [2.5 * 0.5 / 1.1, 2.5 * 0.6 / 1.1],
+                               rtol=1e-6)
+
+
+def test_the_bias_changes_the_choice_and_not_the_weight():
+    scores = [0.2, 0.1, 0.1, 0.6, 0.5, 0.4]
+    ids, w = _route(scores, [0.0] * 6)
+    assert ids == [3, 4]
+    # expert 5's bias lifts it over expert 4; its weight is from its score
+    ids_b, w_b = _route(scores, [0, 0, 0, 0, 0, 0.2])
+    assert ids_b == [3, 5]
+    np.testing.assert_allclose(sorted(w_b), [2.5 * 0.4 / 1.0, 2.5 * 0.6 / 1.0],
+                               rtol=1e-6)
+    # a bias can move the kept group too
+    assert _route(scores, [0.9, 0.9, 0, 0, 0, 0])[0] == [0, 1]
+    # and takes no gradient, the scores do
+    g_s, g_b = jax.grad(
+        lambda s, b: jnp.sum(moe.route_group_limited(
+            s, b, 2, 2, 1, 2.5)[1] * jnp.asarray([1.0, 2.0])),
+        argnums=(0, 1))(jnp.asarray([scores]), jnp.zeros(6))
+    assert not np.any(g_b) and np.any(g_s)
+
+
+def test_router_is_top_k_where_nothing_limits_it():
+    scores = jax.nn.sigmoid(
+        jax.random.normal(jax.random.PRNGKey(0), (50, 32)))
+    ids, w = moe.route_group_limited(scores, jnp.zeros(32), 4, 8, 8, 1.0)
+    vals, want = jax.lax.top_k(scores, 4)
+    np.testing.assert_array_equal(np.sort(ids, -1), np.sort(want, -1))
+    np.testing.assert_allclose(np.sort(w, -1), np.sort(
+        vals / vals.sum(-1, keepdims=True), -1), rtol=1e-6)
+
+
+# ---------------------------------------------------------------- dispatch
+def _dense_loop(x, weights, wg, wu, wd, local):
+    out = jnp.zeros(x.shape, jnp.float32)
+    for e in range(wg.shape[0]):
+        w = jnp.sum(jnp.where(local == e, weights, 0.0), axis=-1)
+        out = out + w[:, None] * (
+            (jax.nn.silu(x @ wg[e]) * (x @ wu[e])) @ wd[e])
+    return out
+
+
+@pytest.mark.parametrize("load", ["skewed", "even", "none_held"])
+def test_dropless_dispatch_is_the_dense_loop(load):
+    """600 tokens x 3 choices over 4 held experts (index 4: another
+    device's).  Skewed: expert 1 takes most pairs, more than two tiles,
+    expert 2 takes none; no pair is lost."""
+    s, k, d, f, n = 600, 3, 16, 24, 4
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
+    if load == "skewed":
+        local = jnp.stack([
+            jnp.where(jnp.arange(s) % 10 < 9, 1, 0),     # 540 on expert 1
+            jnp.where(jnp.arange(s) % 7 == 0, 3, 4),
+            jnp.where(jnp.arange(s) % 5 == 0, 0, 4)], axis=1)
+        assert int(jnp.sum(local == 1)) > 2 * moe._TILE
+        assert int(jnp.sum(local == 2)) == 0
+    elif load == "even":
+        local = jnp.stack([jax.random.permutation(k_, 5)[:3]
+                           for k_ in jax.random.split(keys[5], s)])
+    else:
+        local = jnp.full((s, k), 4)
+    local = local.astype(jnp.int32)
+    x = jax.random.normal(keys[0], (s, d))
+    weights = jax.random.uniform(keys[1], (s, k))
+    wg, wu = (jax.random.normal(kk, (n, d, f)) / 4 for kk in keys[2:4])
+    wd = jax.random.normal(keys[4], (n, f, d)) / 5
+    cot = jax.random.normal(jax.random.PRNGKey(7), (s, d))
+    with jax.default_matmul_precision("highest"):
+        got = moe.grouped_experts(x, weights, wg, wu, wd, local)
+        want = _dense_loop(x, weights, wg, wu, wd, local)
+        g_got = jax.grad(lambda *a: jnp.sum(
+            moe.grouped_experts(*a, local) * cot), argnums=range(5))(
+                x, weights, wg, wu, wd)
+        g_want = jax.grad(lambda *a: jnp.sum(
+            _dense_loop(*a, local) * cot), argnums=range(5))(
+                x, weights, wg, wu, wd)
+    assert _max_rel(got, want, 1.0) <= 1e-5
+    for name, a, w in zip("x weights wg wu wd".split(), g_got, g_want):
+        assert _max_rel(a, w, 1.0) <= 1e-5, name
+    if load == "none_held":
+        assert not np.any(got)
+
+
+def _expert_layer(experts_held, total=64):
+    return moe.ExpertFFN(
+        num_experts=total, experts_held=experts_held, hidden=12, k=8,
+        n_group=8, topk_group=4, routed_scaling=2.5, dtype=jnp.float32)
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """8 shares of 8 experts of a 64-expert layer: the routed parts summed
+    and the shared expert counted once are the uncut layer, which is the
+    plain reference's (every token through every expert)."""
+    from benchmark.reference import hybridmoe as reference
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 40, 16))
+    whole = _expert_layer((0, 64))
+    params = whole.init(jax.random.PRNGKey(1), x)["params"]
+    params["router_bias"] = 0.05 * jax.random.normal(
+        jax.random.PRNGKey(2), (64,))
+    model = dict(n_group=8, topk_group=4, num_experts_per_tok=8,
+                 routed_scaling_factor=2.5, experts_held=[0, 64])
+    with jax.default_matmul_precision("highest"):
+        uncut = reference._experts(params, x, model)
+        y_whole, load_whole = whole.apply({"params": params}, x)
+        shared = reference._swiglu(
+            x, *(params["shared"][n]["Dense_0"]["kernel"]
+                 for n in ("wg", "wi", "wo")))
+        total, loads = jnp.zeros_like(uncut), []
+        for s in range(8):
+            held = slice(8 * s, 8 * s + 8)
+            share = dict(params, **{
+                n: params[n][held] for n in ("wg", "wi", "wo")})
+            y, load = _expert_layer((8 * s, 8 * s + 8)).apply(
+                {"params": share}, x)
+            # the reference is given the same share
+            part = reference._experts(
+                share, x, dict(model, experts_held=[8 * s, 8 * s + 8]))
+            assert _max_rel(y, part) <= 1e-5, s
+            total = total + (y - shared)
+            loads.append(load)
+        total = total + shared
+    assert _max_rel(y_whole, uncut) <= 1e-5
+    assert _max_rel(total, uncut) <= 1e-5
+    # every pair is served by exactly one share
+    assert float(sum(l.sum() for l in loads)) == 2 * 40 * 8
+    np.testing.assert_array_equal(jnp.concatenate(loads), load_whole)
+    # a share that left its routed part out would miss far more than that
+    assert _max_rel(total - (y - shared), uncut) > 20 * _max_rel(total, uncut)
+
+
+def test_expert_range_must_lie_inside_the_layer():
+    x = jnp.zeros((1, 4, 16))
+    with pytest.raises(ValueError, match="experts_held"):
+        _expert_layer((60, 70)).init(jax.random.PRNGKey(0), x)
+
+
+# ------------------------------------------------------------ the model
+def test_layer_kinds_choose_mixer_and_ffn_and_the_gauges_say_so():
+    from horovod_tpu import metrics
+
+    cfg = _mla_config(
+        num_layers=3, layer_kinds=("kda", "kda", "mla"),
+        ffn_kinds=("dense", "experts", "experts"), num_experts=8,
+        experts_held=(0, 4), expert_ff_dim=12, experts_per_token=2,
+        n_group=2, topk_group=1, remat=True)
+    model = transformer.Transformer(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 20), 0, 64)
+    params = model.init(jax.random.PRNGKey(1), tokens)["params"]
+    assert set(params["block_0"]) == {"kda", "ln_attn", "ln_mlp", "mlp"}
+    assert set(params["block_1"]) == {"kda", "ln_attn", "ln_mlp", "moe"}
+    assert set(params["block_2"]) == {"attn", "ln_attn", "ln_mlp", "moe"}
+    assert params["block_1"]["moe"]["wg"].shape == (4, 48, 12)
+    assert params["block_1"]["moe"]["router"].shape == (48, 8)
+    with metrics.traced_gauges() as bag:
+        logits, _ = model.apply({"params": params}, tokens)
+    assert logits.shape == (2, 20, 64)
+    for kind, count in [("kda", 2), ("mla", 1), ("full", 0), ("dense", 1),
+                        ("experts", 2), ("moe", 0)]:
+        assert metrics.get_gauge("model.layer_kinds", {"kind": kind}) == count
+    assert metrics.get_gauge("model.moe.experts_held") == 4
+    assert 0 <= float(bag["model.moe.pairs_per_step"]) <= 2 * 2 * 20 * 2
+    assert float(bag["model.moe.load_max_over_mean"]) >= 1
+    with pytest.raises(ValueError, match="unknown kind"):
+        transformer.layer_kind(
+            dataclasses.replace(cfg, layer_kinds=("kda", "ssm", "mla")), 1)
+    # the older way of asking for the capacity MoE still reads the same
+    old = dataclasses.replace(cfg, layer_kinds=(), ffn_kinds=(), moe_every=2)
+    assert [transformer.layer_kind(old, i) for i in range(3)] == [
+        ("full", "dense"), ("full", "moe"), ("full", "dense")]

@@ -174,8 +174,10 @@ class TestTrainStepTimeline:
             totals, out = hvd.profile_bucket_step(
                 fn, params, None, opt_state, batch
             )
-            # donated inputs: the step output replaces them
-            params, opt_state = out[0], out[-2]
+            # donated inputs: the step output replaces them (the raw
+            # step's last output is its traced gauges, none here)
+            params, opt_state = out[0], out[-3]
+            assert out[-1] == {}
             assert len(totals) >= 2, totals  # 4x256B at 600B -> 2 buckets
             assert all(v > 0 for v in totals.values()), totals
             assert all(k.startswith("bucket") for k in totals)
