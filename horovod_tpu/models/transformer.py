@@ -56,7 +56,8 @@ from ..parallel.tensor import (
     _axis_present,
 )
 from ..parallel.ulysses import ulysses_attention
-from ..ops.kda import chunk_major, kda_chunk_major
+from ..ops import kda_kernels
+from ..ops.kda import kda, rms_gate_heads, unit_heads
 from ..ops.pallas_kernels import (
     flash_attention,
     flash_attention_qkv,
@@ -139,7 +140,12 @@ class TransformerConfig:
     routed_scaling: float = 1.0
 
 
-KDA_CHUNK = 64  # tokens of a chunk of the delta rule (ops/kda.py)
+# The name is the benchmark's: tests/benchmark/test_benchmark_hybridmoe_
+# faults.py patches ``kda_chunk_major`` on this module to plant its faults,
+# so the mixer's core goes through this name, looked up at call time.  It is
+# ``ops.kda.kda``, on [B, T, H·d]; nothing is chunk-major any more, and a
+# benchmark PR can rename it with its test.
+kda_chunk_major = kda
 MIXERS = ("full", "mla", "kda")
 FFNS = ("dense", "moe", "experts")
 
@@ -368,6 +374,18 @@ def _short_conv(x: jax.Array, taps: jax.Array,
     return y
 
 
+class _NormScale(nn.Module):
+    """The weight of an ``nn.RMSNorm`` over ``features`` channels, under
+    its names, for a norm computed elsewhere."""
+
+    features: int
+
+    @nn.compact
+    def __call__(self) -> jax.Array:
+        return self.param("scale", nn.initializers.ones, (self.features,),
+                          jnp.float32)
+
+
 class KDAMixer(nn.Module):
     """Kimi delta attention: the delta rule with a per-channel bounded
     decay over heads of ``head_dim`` keys and values (``ops/kda.py`` has
@@ -384,7 +402,6 @@ class KDAMixer(nn.Module):
     def __call__(self, x: jax.Array,
                  segment_ids: Optional[jax.Array] = None) -> jax.Array:
         cfg = self.cfg
-        b, t, _ = x.shape
         h, d = cfg.num_heads, cfg.head_dim
         if _axis_present(cfg.sp_axis) and lax.axis_size(cfg.sp_axis) > 1:
             raise ValueError(
@@ -395,37 +412,25 @@ class KDAMixer(nn.Module):
             return nn.Dense(h * d, use_bias=False, dtype=cfg.dtype,
                             name=name)(x)
 
-        # The core reads its operands chunk by chunk, [n, B, H, C, d]: the
-        # one re-laying of each (the heads leave the lanes), after the
-        # convolution, which runs along T on [B, T, H·d] as projected.
-        pad = -t % KDA_CHUNK
-        seg = (jnp.ones((b, t), jnp.int32) if segment_ids is None
-               else segment_ids.astype(jnp.int32))
-        seg = jnp.pad(seg, ((0, 0), (0, pad)), mode="edge")
-        seg = jnp.swapaxes(seg.reshape(b, -1, KDA_CHUNK), 0, 1)
-
-        def chunks(a, width):
-            a = jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
-            return chunk_major(a.reshape(b, t + pad, h, width), KDA_CHUNK)
-
+        # q, k, v, g stay [B, T, H·d] as projected, through convolution,
+        # SiLU, the norms and the core (ops/kda.py: its kernels read a
+        # chunk of one head as a block of that array), and o comes back
+        # the same way: no [B, T, H, d] and nothing chunk-major is made.
         def convolved(name):
             taps = self.param(
                 f"conv_{name}", nn.initializers.normal(0.5),
                 (cfg.kda_conv, h * d), jnp.float32)
             y = projected(name)
             with jax.named_scope("conv"):
-                return chunks(nn.silu(_short_conv(y, taps, segment_ids)), d)
-
-        def unit(a):
-            return a * lax.rsqrt(
-                jnp.sum(jnp.square(a), axis=-1, keepdims=True) + 1e-6)
+                return nn.silu(_short_conv(y, taps, segment_ids))
 
         q, k, v = convolved("q"), convolved("k"), convolved("v")
         raw_gate = projected("f")
         with jax.named_scope("conv"):
             # normed in float32, handed on as the matmuls will take them
-            q, k, v = (a.astype(cfg.dtype)
-                       for a in (unit(q) * d ** -0.5, unit(k), v))
+            q = unit_heads(q, h, d ** -0.5, cfg.dtype)
+            k = unit_heads(k, h, 1.0, cfg.dtype)
+            v = v.astype(cfg.dtype)
         with jax.named_scope("gate"):
             a_log = self.param(
                 "A_log", lambda key, shape: jnp.log(jax.random.uniform(
@@ -433,22 +438,20 @@ class KDAMixer(nn.Module):
             dt_bias = self.param(
                 "dt_bias", lambda key, shape: jax.random.uniform(
                     key, shape, jnp.float32, -4.0, -1.0), (h * d,))
-            g = chunks(cfg.kda_lower_bound * jax.nn.sigmoid(
+            g = cfg.kda_lower_bound * jax.nn.sigmoid(
                 jnp.repeat(jnp.exp(a_log), d)
-                * (raw_gate.astype(jnp.float32) + dt_bias)), d)
-            beta = chunks(jax.nn.sigmoid(nn.Dense(
+                * (raw_gate.astype(jnp.float32) + dt_bias))
+            beta = jax.nn.sigmoid(nn.Dense(
                 h, use_bias=False, dtype=jnp.float32, name="b")(
-                    x.astype(jnp.float32))), 1)
+                    x.astype(jnp.float32)))
         with jax.named_scope("core"):
-            o = kda_chunk_major(q, k, v, g, beta, seg)
-        o = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
-                       name="o_norm")(o)
-        o = o * chunks(jax.nn.sigmoid(nn.Dense(
-            h, use_bias=False, dtype=jnp.float32, name="gate")(
-                x.astype(jnp.float32))), 1)
-        # [n, B, H, C, d] -> [B, T, H·d]
-        o = jnp.transpose(o.astype(cfg.dtype), (1, 0, 3, 2, 4)).reshape(
-            b, t + pad, h * d)[:, :t]
+            o = kda_chunk_major(q, k, v, g, beta, segment_ids)
+        o = rms_gate_heads(
+            o, _NormScale(d, name="o_norm")(),
+            jax.nn.sigmoid(nn.Dense(
+                h, use_bias=False, dtype=jnp.float32, name="gate")(
+                    x.astype(jnp.float32))),
+            cfg.norm_eps, cfg.dtype)
         return RowParallelDense(
             cfg.model_dim, axis=cfg.tp_axis, use_bias=False,
             dtype=cfg.dtype, name="proj",
@@ -653,6 +656,11 @@ class Transformer(nn.Module):
             metrics.set_gauge(
                 "model.layer_kinds", sum(name in kind for kind in kinds),
                 {"kind": name})
+        # the delta-rule layers whose core ran as ops/kda_kernels' pair
+        metrics.set_gauge(
+            "model.kda.kernel_layers",
+            sum("kda" in kind for kind in kinds)
+            * kda_kernels.takes(cfg.head_dim))
         if loads:
             held = cfg.experts_held[1] - cfg.experts_held[0]
             metrics.set_gauge("model.moe.experts_held", held)

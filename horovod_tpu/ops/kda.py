@@ -31,10 +31,22 @@ of different documents are masked out of A and B, a token after a
 boundary inside its chunk does not see the incoming state, and the
 outgoing state holds the chunk's last document only.
 
-Everything here is ``jax.numpy``; the backward pass is jax's transpose of
-the chunked form (the matmuls' and the scan's), so it is chunked as the
-forward is and holds one state a chunk.  No [T, T] array
-and no [chunk, chunk, dk] array is made.
+Which function is what.  :func:`kda_recurrent` is the definition, token
+by token.  :func:`kda_chunk_major` (and :func:`kda_chunked`, which lays
+[B, T, H, d] operands out for it) is the plain chunked form, ``jax.numpy``
+operations and jax's transpose of them, on chunk-major copies
+[n, B, H, C, d] of its operands: what the kernels are tested against
+beside the recurrence, and what a head width the chip's kernels do not
+take falls to.  :func:`kda` is what the mixer calls: on the projections'
+own [B, T, H·d] it runs ``kda_kernels.delta_rule``, a Pallas forward
+kernel and a hand-written backward kernel under one ``jax.custom_vjp``
+that read a chunk of a head as a block of those arrays and keep the
+chunk's triangles, the inverse, U, W and the running state in VMEM.  Of
+the forward the backward keeps the state entering each chunk and the
+chunk's inverse (``kda_kernels``' docstring has the equations); everything
+else it makes again chunk by chunk.  :func:`unit_heads` and
+:func:`rms_gate_heads` are the per-head norms around the core on the same
+layout.  No [T, T] array and no [chunk, chunk, dk] array is made anywhere.
 """
 
 from __future__ import annotations
@@ -45,10 +57,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-# exp() of anything larger is never needed where the mask keeps the entry
-# (sub x |g|_max <= 80 is the caller's side of the bargain); the clamp
-# keeps the masked entries finite.
-_MAX_EXPONENT = 80.0
+from . import kda_kernels
+from .kda_kernels import MAX_EXPONENT as _MAX_EXPONENT
 
 
 def kda_recurrent(q, k, v, g, beta, segment_ids=None):
@@ -227,10 +237,11 @@ def chunk_major(a, chunk: int):
 
 def kda_chunked(q, k, v, g, beta, segment_ids: Optional[jax.Array] = None,
                 chunk: int = 64, sub: int = 16):
-    """:func:`kda_recurrent` in chunks of ``chunk`` tokens: q, k, g
-    [B, T, H, dk], v [B, T, H, dv], beta [B, T, H] -> o [B, T, H, dv]
-    float32.  ``g`` must lie above ``-80 / sub`` (the bounded gate's
-    lower bound is -5 for ``sub`` 16)."""
+    """:func:`kda_recurrent` in chunks of ``chunk`` tokens, in plain
+    ``jax.numpy``: q, k, g [B, T, H, dk], v [B, T, H, dv], beta [B, T, H]
+    -> o [B, T, H, dv] float32; q's type is the matmuls' operands'.  ``g``
+    must lie above ``-80 / sub`` (the bounded gate's lower bound is -5 for
+    ``sub`` 16)."""
     b, t, h, _ = q.shape
     pad = -t % chunk
     seg = (jnp.ones((b, t), jnp.int32) if segment_ids is None
@@ -242,9 +253,48 @@ def kda_chunked(q, k, v, g, beta, segment_ids: Optional[jax.Array] = None,
         seg = jnp.pad(seg, ((0, 0), (0, pad)), mode="edge")
     n = (t + pad) // chunk
     out = kda_chunk_major(
-        *(chunk_major(a.astype(jnp.float32), chunk)
-          for a in (q, k, v, g, beta)),
+        *(chunk_major(a, chunk) for a in (q, k, v)),
+        *(chunk_major(a.astype(jnp.float32), chunk) for a in (g, beta)),
         jnp.swapaxes(seg.reshape(b, n, chunk), 0, 1), sub)
     # [n, B, H, C, dv] -> [B, T, H, dv]
     return jnp.transpose(out, (1, 0, 3, 2, 4)).reshape(
         b, n * chunk, h, -1)[:, :t]
+
+
+def kda(q, k, v, g, beta, segment_ids: Optional[jax.Array] = None):
+    """The delta rule on the projections' layout: q, k, g [B, T, H·dk], v
+    [B, T, H·dv], beta [B, T, H] -> o [B, T, H·dv] float32, q's type the
+    matmuls' operands'.  By the kernels (``kda_kernels``) where they take
+    the heads' widths, which re-lay nothing; else by :func:`kda_chunked`,
+    whose loop reads chunk-major copies."""
+    b, t, _ = q.shape
+    heads = beta.shape[-1]
+    if all(kda_kernels.takes(a.shape[-1] // heads) for a in (q, v)):
+        return kda_kernels.delta_rule(q, k, v, g, beta, segment_ids)
+    out = kda_chunked(*(a.reshape(b, t, heads, -1) for a in (q, k, v, g)),
+                      beta, segment_ids, kda_kernels.CHUNK, kda_kernels.SUB)
+    return out.reshape(b, t, -1)
+
+
+def unit_heads(x, heads: int, scale: float, dtype):
+    """``x / |x|`` a head times ``scale``, in float32, as ``dtype``: x
+    [B, T, H·d] float32 (q's and k's L2 norm).  A kernel on that layout
+    where the kernels take the width; else through [B, T, H, d]."""
+    b, t, lanes = x.shape
+    if kda_kernels.takes(lanes // heads):
+        return kda_kernels.head_unit(x, heads, scale, dtype)
+    x = x.reshape(b, t, heads, -1)
+    x = x * lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+    return (x * scale).astype(dtype).reshape(b, t, lanes)
+
+
+def rms_gate_heads(x, weight, gate, eps: float, dtype):
+    """RMSNorm over each head's channels times ``weight`` [d] and the
+    head's ``gate`` [B, T, H], in float32, as ``dtype``: x [B, T, H·d]
+    float32 (the mixer's output norm and gate)."""
+    b, t, lanes = x.shape
+    if kda_kernels.takes(weight.shape[0]):
+        return kda_kernels.head_rms_gate(x, weight, gate, eps, dtype)
+    x = x.reshape(b, t, gate.shape[-1], -1)
+    x = x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
+    return (x * weight * gate[..., None]).astype(dtype).reshape(b, t, lanes)

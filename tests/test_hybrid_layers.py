@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from horovod_tpu.models import transformer
-from horovod_tpu.ops import kda
+from horovod_tpu.ops import kda, kda_kernels
 from horovod_tpu.parallel import moe
 
 
@@ -48,25 +48,42 @@ def _packed_rows(t):
     return jnp.asarray(np.stack([first, second]), jnp.int32)
 
 
+def _by_the_kernels(q, k, v, g, beta, segment_ids=None):
+    """The kernel pair (interpreted here) on operands laid out as the
+    recurrence takes them: [B, T, H, d] is [B, T, H·d] for nothing."""
+    b, t, h, _ = q.shape
+    assert kda_kernels.takes(q.shape[-1]) and kda_kernels.takes(v.shape[-1])
+    out = kda.kda(*(a.reshape(b, t, -1) for a in (q, k, v, g)), beta,
+                  segment_ids)
+    return out.reshape(b, t, h, -1)
+
+
+IMPLS = {"chunked": kda.kda_chunked, "kernel": _by_the_kernels}
+impls = pytest.mark.parametrize("impl", sorted(IMPLS))
+
+
+@impls
 @pytest.mark.parametrize("packed", [False, True], ids=["dense", "packed"])
 @pytest.mark.parametrize(
     "decay", ["across", "strong_end", "weak_end", "both_ends"])
-def test_chunked_delta_rule_is_the_recurrence(decay, packed):
+def test_chunked_delta_rule_is_the_recurrence(decay, packed, impl):
     """T = 150 is two chunks of 64 and a ragged third.  Outputs and every
     gradient; a gradient is held to 1e-4 of the largest gradient of the
     five (at the strong end the decays' own gradient is 1e-3 of k's, and
-    float32 cancels in the chunk's running sums)."""
+    float32 cancels in the chunk's running sums).  ``kernel`` is the
+    Pallas pair with its hand-written backward, two heads a grid step."""
     args = _kda_inputs(0, decay)
     seg = _packed_rows(150) if packed else None
     weight = jax.random.normal(jax.random.PRNGKey(9), (2, 150, 2, 8))
+    chunked = IMPLS[impl]
 
     def scalar(fn):
         return lambda *a: jnp.sum(fn(*a, segment_ids=seg) * weight)
 
     with jax.default_matmul_precision("highest"):
-        got = kda.kda_chunked(*args, segment_ids=seg)
+        got = chunked(*args, segment_ids=seg)
         want = kda.kda_recurrent(*args, segment_ids=seg)
-        g_got = jax.grad(scalar(kda.kda_chunked), argnums=range(5))(*args)
+        g_got = jax.grad(scalar(chunked), argnums=range(5))(*args)
         g_want = jax.grad(scalar(kda.kda_recurrent), argnums=range(5))(*args)
     assert got.shape == want.shape == (2, 150, 2, 8)
     assert _max_rel(got, want) <= 1e-5
@@ -76,27 +93,109 @@ def test_chunked_delta_rule_is_the_recurrence(decay, packed):
         assert float(jnp.max(jnp.abs(w))) > 0, name
 
 
-def test_a_document_starts_from_an_empty_state():
+@impls
+def test_a_document_starts_from_an_empty_state(impl):
     """The second document of a packed row alone gives what it gives in
     the row: nothing crosses the boundary."""
+    chunked = IMPLS[impl]
     q, k, v, g, beta = _kda_inputs(1, "weak_end", b=1, t=100)
     seg = jnp.asarray(np.repeat([1, 2], [37, 63])[None], jnp.int32)
     with jax.default_matmul_precision("highest"):
-        row = kda.kda_chunked(q, k, v, g, beta, segment_ids=seg)
-        alone = kda.kda_chunked(*(a[:, 37:] for a in (q, k, v, g, beta)))
-        unpacked = kda.kda_chunked(q, k, v, g, beta)
+        row = chunked(q, k, v, g, beta, segment_ids=seg)
+        alone = chunked(*(a[:, 37:] for a in (q, k, v, g, beta)))
+        unpacked = chunked(q, k, v, g, beta)
     assert _max_rel(row[:, 37:], alone) <= 1e-5
     assert _max_rel(unpacked[:, 37:], alone) > 1e-2  # the state matters
 
 
-def test_no_exponent_passes_the_bound_at_the_strongest_decay():
+@impls
+def test_no_exponent_passes_the_bound_at_the_strongest_decay(impl):
     q, k, v, _, beta = _kda_inputs(2, "across", t=128)
     g = jnp.full(q.shape, -4.999)
     out, grads = jax.value_and_grad(
-        lambda g: jnp.sum(kda.kda_chunked(q, k, v, g, beta)))(g)
+        lambda g: jnp.sum(IMPLS[impl](q, k, v, g, beta)))(g)
     assert np.isfinite(float(out)) and bool(jnp.all(jnp.isfinite(grads)))
     with pytest.raises(ValueError, match="sub-chunks"):
         kda.kda_chunked(q, k, v, g, beta, chunk=64, sub=24)
+
+
+def test_kernels_at_the_chips_block_shape_are_the_chunked_form():
+    """Heads of 128 (a head is one 128-lane slab), bfloat16 operands,
+    T = 192 with a document's boundary inside the second chunk: the pair
+    against the plain chunked form at the same types, output and every
+    gradient.  The two round in the same places, so they differ by the
+    order of float32 sums and by where a bfloat16 rounding falls."""
+    b, t, h, d = 1, 192, 2, 128
+    q, k, v, g, beta = _kda_inputs(3, "across", b=b, t=t, h=h, dk=d, dv=d)
+    q, k, v = (a.astype(jnp.bfloat16) for a in (q * d ** -0.5, k, v))
+    seg = jnp.asarray(np.repeat([1, 2], [100, 92])[None], jnp.int32)
+    weight = jax.random.normal(jax.random.PRNGKey(9), (b, t, h, d))
+
+    def scalar(fn):
+        return lambda *a: jnp.sum(fn(*a, segment_ids=seg) * weight)
+
+    got = _by_the_kernels(q, k, v, g, beta, seg)
+    want = kda.kda_chunked(q, k, v, g, beta, seg)
+    assert got.dtype == want.dtype == jnp.float32
+    assert _max_rel(got, want) <= 2e-3
+    g_got = jax.grad(scalar(_by_the_kernels), argnums=range(5))(
+        q, k, v, g, beta)
+    g_want = jax.grad(scalar(kda.kda_chunked), argnums=range(5))(
+        q, k, v, g, beta)
+    for name, a, w in zip("q k v g beta".split(), g_got, g_want):
+        assert a.dtype == w.dtype and a.shape == w.shape, name
+        assert _max_rel(a.astype(jnp.float32), w.astype(jnp.float32)
+                        ) <= 2e-2, name
+
+
+def test_a_width_the_chips_kernels_do_not_take_goes_to_the_chunked_form(
+        monkeypatch):
+    """On the chip (Pallas not interpreted) heads of 16 are no whole slab:
+    ``kda`` re-lays them for ``kda_chunk_major`` and gives the same."""
+    from horovod_tpu.ops import pallas_kernels
+
+    args = _kda_inputs(4, "across", b=1, t=70)
+    want = _by_the_kernels(*args)
+    monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
+    assert not kda_kernels.takes(16) and kda_kernels.takes(256)
+    called = []
+    real = kda.kda_chunk_major
+    monkeypatch.setattr(kda, "kda_chunk_major",
+                        lambda *a: called.append(1) or real(*a))
+    b, t, h, _ = args[0].shape
+    got = kda.kda(*(a.reshape(b, t, -1) for a in args[:4]), args[4])
+    assert called and _max_rel(got.reshape(b, t, h, -1), want) <= 1e-5
+
+
+@pytest.mark.parametrize("t", [70, 300])
+def test_head_norm_kernels_are_the_plain_norms(t, monkeypatch):
+    """q's and k's L2 norm and the output's RMSNorm and gate as kernels on
+    [B, T, H·d] (interpreted here) against the same through [B, T, H, d],
+    which a width the chip's kernels do not take falls to: values and
+    every gradient, T under and over a block of rows."""
+    from horovod_tpu.ops import pallas_kernels
+
+    b, h, d = 2, 4, 16
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    x = jax.random.normal(keys[0], (b, t, h * d))
+    weight = 1.0 + 0.1 * jax.random.normal(keys[1], (d,))
+    gate = jax.nn.sigmoid(jax.random.normal(keys[2], (b, t, h)))
+    cotangent = jax.random.normal(keys[3], (b, t, h * d))
+
+    def both(fn, *args):
+        return (fn(*args),) + jax.grad(
+            lambda *a: jnp.sum(fn(*a) * cotangent),
+            argnums=range(len(args)))(*args)
+
+    unit = lambda x: kda.unit_heads(x, h, 0.25, jnp.float32)
+    normed = lambda *a: kda.rms_gate_heads(*a, 1e-6, jnp.float32)
+    got = both(unit, x) + both(normed, x, weight, gate)
+    monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
+    want = both(unit, x) + both(normed, x, weight, gate)
+    assert len(got) == 6
+    for a, w in zip(got, want):
+        assert a.shape == w.shape and _max_rel(a, w) <= 1e-5
+    assert kda.unit_heads(x, h, 1.0, jnp.bfloat16).dtype == jnp.bfloat16
 
 
 def test_short_convolution_keeps_to_its_document():
@@ -392,7 +491,63 @@ def test_layer_kinds_choose_mixer_and_ffn_and_the_gauges_say_so():
     with pytest.raises(ValueError, match="unknown kind"):
         transformer.layer_kind(
             dataclasses.replace(cfg, layer_kinds=("kda", "ssm", "mla")), 1)
+    # heads of 16 are interpreted here, so both delta-rule layers' cores
+    # ran as the kernel pair
+    assert metrics.get_gauge("model.kda.kernel_layers") == 2
     # the older way of asking for the capacity MoE still reads the same
     old = dataclasses.replace(cfg, layer_kinds=(), ffn_kinds=(), moe_every=2)
     assert [transformer.layer_kind(old, i) for i in range(3)] == [
         ("full", "dense"), ("full", "moe"), ("full", "dense")]
+
+
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def test_the_delta_rule_mixer_stays_on_the_projections_layout():
+    """Heads of 128, the chip's: from the projections to ``proj`` nothing
+    under ``kda/`` is turned (no ``transpose`` of an activation, no
+    [B, T, H, d] and no [n, B, H, C, d] array outside a kernel); the core
+    is two kernel calls a layer in a gradient (the forward that keeps the
+    states, the backward), both under ``kda/core`` where the benchmark's
+    readers look, and no other kernel is there (the norms' are under
+    ``kda/conv`` and ``kda``); the gauge says that the layer's core took
+    the kernels."""
+    from horovod_tpu import metrics
+
+    cfg = _mla_config(num_layers=1, layer_kinds=("kda",), model_dim=32,
+                      num_heads=2, head_dim=128, ff_dim=32)
+    model = transformer.Transformer(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (1, 128), 0, 64)
+    params = model.init(jax.random.PRNGKey(1), tokens)
+
+    def loss(p):
+        return jnp.sum(model.apply(p, tokens)[0])
+
+    assert metrics.get_gauge("model.kda.kernel_layers") == 1 == \
+        metrics.get_gauge("model.layer_kinds", {"kind": "kda"})
+    under_kda = [e for e in _equations(jax.make_jaxpr(jax.grad(loss))(
+        params).jaxpr) if "/kda/" in f"/{e.source_info.name_stack}/"]
+    calls = [e for e in under_kda if e.primitive.name == "pallas_call"]
+    where = [str(e.source_info.name_stack).split("block_0/kda")[-1]
+             for e in calls]
+    # q's and k's norms, the output's norm and gate: each once each way
+    assert sorted(where) == [""] * 2 + ["/conv"] * 4 + ["/core"] * 2
+    for call in calls:
+        chunks = call.params["grid_mapping"].grid[1]
+        assert (chunks == 128 // kda_kernels.CHUNK) == (
+            "kda/core" in str(call.source_info.name_stack))
+    assert {"conv", "gate", "core"} <= {
+        part for e in under_kda
+        for part in str(e.source_info.name_stack).split("/")}
+    for eqn in under_kda:
+        for out in eqn.outvars:
+            # a matmul's gradient turns its [in, out] weight, nothing more
+            assert eqn.primitive.name != "transpose" or out.aval.ndim == 2, \
+                eqn
+            # (the documents' marks a chunk are [B, n, 1, C] integers)
+            assert (out.aval.ndim < 4 or eqn in calls
+                    or out.aval.dtype == jnp.int32), eqn

@@ -228,3 +228,82 @@ def test_scale_buffer_compiles_for_the_chip(one_chip, mosaic):
         lambda a: pallas_kernels.scale_buffer(a, 0.5, jnp.bfloat16)
     ).lower(x).compile()
     assert "scale_cast/pallas_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape, dtype, packed", [
+    # ling3flash.ring1x4096: 32 heads of 128, a head one 128-lane slab
+    ((1, 4096, 32, 128), jnp.bfloat16, False),
+    ((1, 4096, 32, 128), jnp.bfloat16, True),
+    # ragged T, heads of two slabs, float32 operands
+    ((2, 150, 4, 256), jnp.float32, True),
+])
+def test_delta_rule_grad_compiles_for_the_chip(
+        one_chip, mosaic, shape, dtype, packed):
+    """The delta rule's kernel pair (``ops/kda_kernels.py``) on the
+    projections' [B, T, H·d]: a gradient is the forward that keeps the
+    states and the backward, two Mosaic calls, with no copy or transpose
+    of an operand between the arguments and the calls, and the only
+    temporaries what the backward keeps (a state and an inverse a chunk
+    and head) beside o's cotangent."""
+    from horovod_tpu.ops import kda, kda_kernels
+
+    b, t, h, d = shape
+    wide = jax.ShapeDtypeStruct((b, t, h * d), dtype, sharding=one_chip)
+    gate = jax.ShapeDtypeStruct((b, t, h * d), jnp.float32, sharding=one_chip)
+    beta = jax.ShapeDtypeStruct((b, t, h), jnp.float32, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip)
+    assert kda_kernels.takes(d) and not kda_kernels.takes(64)
+
+    def grads(q, k, v, g, beta, w, seg):
+        def loss(*operands):
+            return jnp.sum(kda.kda(*operands, seg if packed else None) * w)
+
+        return jax.grad(loss, argnums=range(5))(q, k, v, g, beta)
+
+    compiled = jax.jit(grads).lower(
+        wide, wide, wide, gate, beta, gate, seg).compile()
+    text = compiled.as_text()
+    assert len([ln for ln in text.splitlines()
+                if "tpu_custom_call" in ln]) == 2
+    assert " while(" not in text
+    padded = -(-t // kda_kernels.CHUNK) * kda_kernels.CHUNK
+    for ln in _entry(text):
+        result, opcode = _result_and_opcode(ln)
+        if opcode in ("copy", "transpose") and padded == t:
+            assert f"{t},{h * d}]" not in result, ln
+    chunks = padded // kda_kernels.CHUNK
+    kept = b * chunks * h * (d * d + 64 * 128) * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        kept + 3 * b * padded * h * d * 4
+
+
+@pytest.mark.parametrize("shape, dtype", [
+    ((1, 4096, 32, 128), jnp.bfloat16),  # ling3flash.ring1x4096
+    ((2, 300, 4, 256), jnp.float32),     # ragged T, heads of two slabs
+])
+def test_head_norms_compile_for_the_chip(one_chip, mosaic, shape, dtype):
+    """The per-head norms around the delta rule on [B, T, H·d]: q's L2
+    norm and the output's RMSNorm and gate, each a kernel forward and one
+    backward, with no copy of a [T, H·d] array beside them."""
+    from horovod_tpu.ops import kda
+
+    b, t, h, d = shape
+    wide = jax.ShapeDtypeStruct((b, t, h * d), jnp.float32, sharding=one_chip)
+    weight = jax.ShapeDtypeStruct((d,), jnp.float32, sharding=one_chip)
+    gate = jax.ShapeDtypeStruct((b, t, h), jnp.float32, sharding=one_chip)
+
+    def grads(x, o, weight, gate):
+        def loss(x, o, weight, gate):
+            q = kda.unit_heads(x, h, d ** -0.5, dtype)
+            y = kda.rms_gate_heads(o, weight, gate, 1e-6, dtype)
+            return jnp.sum((q * y).astype(jnp.float32))
+
+        return jax.grad(loss, argnums=range(4))(x, o, weight, gate)
+
+    text = jax.jit(grads).lower(wide, wide, weight, gate).compile().as_text()
+    assert len([ln for ln in text.splitlines()
+                if "tpu_custom_call" in ln]) == 4
+    for ln in _entry(text):
+        result, opcode = _result_and_opcode(ln)
+        if opcode in ("copy", "transpose"):
+            assert f"{t},{h * d}]" not in result, ln
