@@ -1,0 +1,12 @@
+"""The flash-attention forward kernel's share of its roofline in the
+full-attention layers, in percent: one call's bound by ops/swamoe.py
+(the pairs the layer type requires at its query heads; q and o at the
+query heads, k and v once at the key/value heads) times the calls a step
+the trace shows, over those calls' device time (trace/calls.py)."""
+
+from benchmark.ops import swamoe
+
+
+def read(run):
+    return swamoe.flash_roofline(
+        run, "full", False, "swa.full_flash_fwd_roofline")

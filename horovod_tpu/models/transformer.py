@@ -14,7 +14,10 @@ on.  TPU-first choices:
   (one psum per attention + one per MLP, the Megatron pairing).
 * optional MoE FFN sharded over the ep axis (parallel/moe).
 * a mixer and an FFN per layer (``layer_kinds``, ``ffn_kinds``): softmax
-  attention ("full"), latent attention ("mla": keys and values from a
+  attention over all earlier tokens ("full") or over a window of them
+  ("window"), with a head count and a rotary rule of the layer's own
+  (``layer_heads``, ``rope_rules``) and fewer key/value heads than query
+  heads (``num_kv_heads``), latent attention ("mla": keys and values from a
   compressed latent, DeepSeek-V2) or the delta rule with a per-channel
   decay ("kda", ops/kda), the last two with a sigmoid gate a head; a dense MLP, the capacity MoE, or dropless
   routed experts of which this device holds a range ("experts").
@@ -38,6 +41,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import math
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -61,10 +65,32 @@ from ..ops.kda import kda, rms_gate_heads, unit_heads
 from ..ops.pallas_kernels import (
     flash_attention,
     flash_attention_qkv,
+    flash_tiles_per_head,
     rope as rope_kernel,
 )
 
 Dtype = Any
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeRule:
+    """Rotary positions of one kind of layer: the first ``dim`` channels
+    of every head turn (0: all of them), pair i by ``position * theta **
+    (-2 i / dim)``.  With ``factor`` > 1 the frequencies are YaRN's
+    (arXiv:2309.00071, as the ``transformers`` library computes
+    ``rope_type: yarn``): interpolated by ``factor`` below the pair that
+    turns ``beta_slow`` times in ``original_max_len`` positions, as they
+    were above the one that turns ``beta_fast`` times, a linear ramp
+    between; cos and sin times ``attention_factor`` (None: 0.1 ln factor +
+    1), at every length."""
+
+    theta: float = 10000.0
+    dim: int = 0
+    factor: float = 1.0
+    original_max_len: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -73,6 +99,9 @@ class TransformerConfig:
     num_layers: int = 12
     model_dim: int = 768
     num_heads: int = 12          # GLOBAL head count
+    # Key/value heads, each read by num_heads / num_kv_heads consecutive
+    # query heads (0: as many as the layer's query heads).
+    num_kv_heads: int = 0
     head_dim: int = 64
     ff_dim: int = 3072           # GLOBAL feed-forward width
     max_len: int = 2048
@@ -113,8 +142,19 @@ class TransformerConfig:
     ep_axis: str = EP_AXIS
     # A mixer and an FFN for every layer, one name a layer; empty means
     # "full" everywhere and what ``moe_every`` says.
-    layer_kinds: Tuple[str, ...] = ()   # "full" | "mla" | "kda"
+    layer_kinds: Tuple[str, ...] = ()   # "full" | "window" | "mla" | "kda"
     ffn_kinds: Tuple[str, ...] = ()     # "dense" | "moe" | "experts"
+    # "window": softmax attention whose queries see themselves and the
+    # ``window - 1`` tokens before them (of their own document).
+    window: int = 0
+    # Query heads of each layer where the layers differ (empty:
+    # ``num_heads`` everywhere), and the rotary rule of a kind of mixer
+    # (a kind without one turns ``rope_dim`` or the whole head by
+    # ``rope_theta``).
+    layer_heads: Tuple[int, ...] = ()
+    rope_rules: Tuple[Tuple[str, RopeRule], ...] = ()
+    # softmax attention's output times sigmoid(x W_g), one gate a head
+    attn_gate: bool = False
     # "mla" (arXiv:2405.04434 section 2.1): k and v of every head from one
     # normed latent of ``kv_lora_rank``, and a rope key of ``rope_dim`` the
     # heads share; q and k are qk_nope_dim + rope_dim wide, v ``head_dim``.
@@ -146,7 +186,7 @@ class TransformerConfig:
 # ``ops.kda.kda``, on [B, T, H·d]; nothing is chunk-major any more, and a
 # benchmark PR can rename it with its test.
 kda_chunk_major = kda
-MIXERS = ("full", "mla", "kda")
+MIXERS = ("full", "window", "mla", "kda")
 FFNS = ("dense", "moe", "experts")
 
 
@@ -182,14 +222,60 @@ def _norm(cfg: TransformerConfig, name: str) -> nn.Module:
         f"unknown norm {cfg.norm!r}; expected 'layernorm' or 'rmsnorm'")
 
 
-def rope_tables(positions: jax.Array, head_dim: int,
-                theta: float) -> Tuple[jax.Array, jax.Array]:
+def rope_tables(positions: jax.Array, head_dim: int, theta: float,
+                yarn: Optional[RopeRule] = None
+                ) -> Tuple[jax.Array, jax.Array]:
     """(cos, sin) of the rotary angles, [..., head_dim / 2] float32:
-    pair i turns by ``position * theta ** (-2 i / head_dim)``."""
+    pair i turns by ``position * theta ** (-2 i / head_dim)``, or by
+    YaRN's frequencies and times its factor where ``yarn`` scales
+    (:class:`RopeRule`; ``head_dim`` is the width that turns)."""
     inv_freq = 1.0 / theta ** (
         jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+    factor = 1.0
+    if yarn is not None and yarn.factor > 1.0:
+        low, high = yarn_ramp(head_dim, theta, yarn)
+        ramp = jnp.clip(
+            (jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+            / (high - low), 0.0, 1.0)
+        inv_freq = inv_freq / yarn.factor * ramp + inv_freq * (1.0 - ramp)
+        factor = yarn.attention_factor
+        if factor is None:
+            factor = 0.1 * math.log(yarn.factor) + 1.0
     angles = positions.astype(jnp.float32)[..., None] * inv_freq
+    if factor != 1.0:
+        return jnp.cos(angles) * factor, jnp.sin(angles) * factor
     return jnp.cos(angles), jnp.sin(angles)
+
+
+def yarn_ramp(dim: int, theta: float, rule: RopeRule) -> Tuple[float, float]:
+    """(low, high): the pairs between which YaRN's frequencies pass from
+    as they were (below ``low``) to interpolated (above ``high``): those
+    that turn ``beta_fast`` and ``beta_slow`` times in the original
+    length, rounded outwards and kept inside the table."""
+    def pair(turns):
+        return (dim * math.log(rule.original_max_len / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair(rule.beta_fast)), 0)
+    high = min(math.ceil(pair(rule.beta_slow)), dim - 1)
+    return float(low), float(high if high != low else high + 0.001)
+
+
+def _rope_by_kind(cfg: TransformerConfig, positions: jax.Array):
+    """{mixer kind: its (cos, sin)}: the kind's rule where
+    ``cfg.rope_rules`` has one, else the model's (``rope_dim`` or the whole
+    head by ``rope_theta``), each table made once."""
+    rules = dict(cfg.rope_rules)
+    kinds = set(cfg.layer_kinds) or {"full"}
+    tables = {}
+    if kinds - set(rules):
+        tables[None] = rope_tables(
+            positions, cfg.rope_dim or cfg.head_dim, cfg.rope_theta)
+    for kind in sorted(kinds & set(rules)):
+        rule = rules[kind]
+        tables[kind] = rope_tables(
+            positions, rule.dim or cfg.head_dim, rule.theta, rule)
+    return {kind: tables.get(kind, tables.get(None)) for kind in kinds}
 
 
 def apply_rope(x: jax.Array, rope: Tuple[jax.Array, jax.Array]) -> jax.Array:
@@ -205,16 +291,29 @@ def apply_rope(x: jax.Array, rope: Tuple[jax.Array, jax.Array]) -> jax.Array:
 class Attention(nn.Module):
     """Multi-head attention: tp-sharded projections + sp-sharded
     sequence (ring or Ulysses).  ``latent`` makes it latent attention
-    (``cfg.kv_lora_rank`` and beside it; see ``_latent_qkv``)."""
+    (``cfg.kv_lora_rank`` and beside it; see ``_latent_qkv``).  A layer
+    has ``heads`` query heads (0: ``cfg.num_heads``) over
+    ``cfg.num_kv_heads`` key/value heads, and with ``window`` its queries
+    see themselves and the ``window - 1`` tokens before them; the tables
+    it is called with are its kind's rotary rule.  A window layer's
+    operations lie under a ``window`` scope inside the module's."""
 
     cfg: TransformerConfig
     latent: bool = False
+    heads: int = 0
+    window: int = 0
 
     @nn.compact
     def __call__(self, x: jax.Array,
                  segment_ids: Optional[jax.Array] = None,
                  rope: Optional[Tuple[jax.Array, jax.Array]] = None
                  ) -> jax.Array:
+        with (jax.named_scope("window") if self.window
+              else contextlib.nullcontext()):
+            return self._mix(x, segment_ids, rope)
+
+    @nn.nowrap  # no scope of its own: the kernels' paths stay attn/...
+    def _mix(self, x, segment_ids, rope):
         cfg = self.cfg
         if cfg.attn_impl not in ("flash", "full", "ring", "ulysses"):
             raise ValueError(
@@ -222,17 +321,20 @@ class Attention(nn.Module):
                 "'flash', 'full', 'ring', or 'ulysses'"
             )
         tp = _tp_degree(cfg.tp_axis)
-        if cfg.num_heads % tp != 0:
+        heads, kv_heads = attention_heads(cfg, self.heads)
+        if heads % tp != 0 or kv_heads % tp != 0:
             raise ValueError(
-                f"num_heads {cfg.num_heads} not divisible by tp degree {tp}"
+                f"num_heads {heads} (key/value heads {kv_heads}) not "
+                f"divisible by tp degree {tp}"
             )
-        h_local = cfg.num_heads // tp
+        h_local = heads // tp
+        window = self.window or None
         b, t, _ = x.shape
 
-        def column(parts: int, name: str, width: int = cfg.head_dim
-                   ) -> jax.Array:
+        def column(parts: int, name: str, width: int = cfg.head_dim,
+                   heads: int = heads) -> jax.Array:
             return ColumnParallelDense(
-                parts * cfg.num_heads * width, axis=cfg.tp_axis,
+                parts * heads * width, axis=cfg.tp_axis,
                 use_bias=cfg.use_bias, dtype=cfg.dtype, name=name,
             )(x)
 
@@ -240,13 +342,19 @@ class Attention(nn.Module):
         if self.latent:
             q, k, v, scale = self._latent_qkv(x, column, h_local, rope)
         elif cfg.fused_qkv:
+            if kv_heads != heads:
+                raise ValueError(
+                    "fewer key/value heads than query heads take separate "
+                    "projections: set fused_qkv=False")
             qkv = column(3, "qkv")
             parts = qkv.reshape(b, t, 3, h_local, cfg.head_dim)
             q, k, v = parts[:, :, 0], parts[:, :, 1], parts[:, :, 2]
         else:
             q, k, v = (
-                column(1, name).reshape(b, t, h_local, cfg.head_dim)
-                for name in ("q", "k", "v"))
+                column(1, name, heads=n).reshape(
+                    b, t, n // tp, cfg.head_dim)
+                for name, n in (("q", heads), ("k", kv_heads),
+                                ("v", kv_heads)))
         if rope is not None and not self.latent:
             q, k = apply_rope(q, rope), apply_rope(k, rope)
 
@@ -255,6 +363,12 @@ class Attention(nn.Module):
                 "packed sequences (segment_ids) require attn_impl='flash' "
                 "or 'full'; sequence-parallel impls do not support packing"
             )
+        if ((window or kv_heads != heads) and _axis_present(cfg.sp_axis)
+                and cfg.attn_impl in ("ring", "ulysses")):
+            raise ValueError(
+                "a window and grouped key/value heads are the flash "
+                "kernels' and full_attention's: the sequence-parallel "
+                "impls take neither")
         # With the sp axis absent the sequence is unsharded, so plain
         # full attention is the correct lowering for every impl.
         if cfg.attn_impl == "ring" and _axis_present(cfg.sp_axis):
@@ -282,14 +396,16 @@ class Attention(nn.Module):
                                       segment_ids=segment_ids)
         elif cfg.attn_impl == "flash":
             out = flash_attention(q, k, v, cfg.causal, scale=scale,
-                                  segment_ids=segment_ids)
+                                  segment_ids=segment_ids, window=window)
         else:
             out = full_attention(q, k, v, causal=cfg.causal, scale=scale,
-                                 segment_ids=segment_ids)
+                                 segment_ids=segment_ids, window=window)
 
         if self.latent:
             # its heads come back as wide as its padded q and k
-            out = _gate_heads(cfg, x, out[..., :cfg.head_dim])
+            out = _gate_heads(x, out[..., :cfg.head_dim])
+        elif cfg.attn_gate:
+            out = _gate_heads(x, out)
         out = out.reshape(b, t, h_local * cfg.head_dim)
         return RowParallelDense(
             cfg.model_dim, axis=cfg.tp_axis, use_bias=cfg.use_bias,
@@ -322,7 +438,8 @@ class Attention(nn.Module):
             latent = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
                                 name="kv_norm")(down[..., :cfg.kv_lora_rank])
             up = ColumnParallelDense(
-                cfg.num_heads * (nope + cfg.head_dim), axis=cfg.tp_axis,
+                attention_heads(cfg, self.heads)[0] * (nope + cfg.head_dim),
+                axis=cfg.tp_axis,
                 use_bias=False, dtype=cfg.dtype, name="kv_up",
             )(latent.astype(cfg.dtype)).reshape(b, t, h_local, -1)
             k_rope = down[..., None, cfg.kv_lora_rank:]       # [B, T, 1, r]
@@ -347,11 +464,23 @@ class Attention(nn.Module):
         return q, k, v, float(nope + turn) ** -0.5
 
 
-def _gate_heads(cfg: TransformerConfig, x: jax.Array, out: jax.Array
-                ) -> jax.Array:
-    """[B, T, H, D] times sigmoid(x W_g), one gate a head: the latent
-    mixer's output gate (the delta-rule mixer applies its own by chunk)."""
-    gate = nn.Dense(cfg.num_heads, use_bias=False, dtype=jnp.float32,
+def attention_heads(cfg: TransformerConfig, heads: int = 0
+                    ) -> Tuple[int, int]:
+    """(query heads, key/value heads) of a layer of ``heads`` query heads
+    (0: the model's ``num_heads``)."""
+    heads = heads or cfg.num_heads
+    kv_heads = cfg.num_kv_heads or heads
+    if heads % kv_heads:
+        raise ValueError(
+            f"{kv_heads} key/value heads do not divide {heads} query heads")
+    return heads, kv_heads
+
+
+def _gate_heads(x: jax.Array, out: jax.Array) -> jax.Array:
+    """[B, T, H, D] times sigmoid(x W_g), one gate a head: the output gate
+    of latent attention and, with ``attn_gate``, of softmax attention
+    (the delta-rule mixer applies its own by chunk)."""
+    gate = nn.Dense(out.shape[2], use_bias=False, dtype=jnp.float32,
                     name="gate")(x.astype(jnp.float32))
     return (out * jax.nn.sigmoid(gate)[..., None]).astype(out.dtype)
 
@@ -460,7 +589,7 @@ class KDAMixer(nn.Module):
 
 class Block(nn.Module):
     """Pre-norm transformer block: a mixer (``kind``: softmax attention,
-    latent attention or the delta rule) and an FFN (``ffn``: dense-TP,
+    whole or windowed, latent attention or the delta rule) and an FFN (``ffn``: dense-TP,
     the capacity MoE or dropless experts).  With ``cfg.post_norm`` each
     sublayer's output is normed again before it joins the residual
     ("sandwich").  Returns (x, the capacity MoE's auxiliary loss, the
@@ -469,6 +598,7 @@ class Block(nn.Module):
     cfg: TransformerConfig
     kind: str = "full"
     ffn: str = "dense"
+    heads: int = 0  # query heads of this layer's attention; 0: num_heads
 
     @nn.compact
     def __call__(
@@ -483,8 +613,10 @@ class Block(nn.Module):
         if self.kind == "kda":
             y = KDAMixer(cfg, name="kda")(h.astype(cfg.dtype), segment_ids)
         else:
-            y = Attention(cfg, latent=self.kind == "mla", name="attn")(
-                h.astype(cfg.dtype), segment_ids, rope)
+            y = Attention(
+                cfg, latent=self.kind == "mla", heads=self.heads,
+                window=cfg.window if self.kind == "window" else 0,
+                name="attn")(h.astype(cfg.dtype), segment_ids, rope)
         if cfg.post_norm:
             y = _norm(cfg, "ln_attn_post")(y)
         x = x + y.astype(x.dtype)
@@ -592,8 +724,7 @@ class Transformer(nn.Module):
                 )
                 x = x + jnp.take(wpe, pos, axis=0)
             else:
-                rope = rope_tables(pos, cfg.rope_dim or cfg.head_dim,
-                                   cfg.rope_theta)
+                rope = _rope_by_kind(cfg, pos)
             x = x.astype(cfg.dtype)
         if cfg.tie_head:
             head = emb.embedding
@@ -614,8 +745,14 @@ class Transformer(nn.Module):
                     *cfg.remat_save))
         # Made once, called ut_steps times: one set of weights a layer.
         kinds = [layer_kind(cfg, i) for i in range(cfg.num_layers)]
+        layer_heads = cfg.layer_heads or (0,) * cfg.num_layers
+        if len(layer_heads) != cfg.num_layers:
+            raise ValueError(
+                f"layer_heads names {len(layer_heads)} layers of "
+                f"{cfg.num_layers}")
         blocks = [
-            block_cls(cfg, kind=mixer, ffn=ffn, name=f"block_{i}")
+            block_cls(cfg, kind=mixer, ffn=ffn, heads=layer_heads[i],
+                      name=f"block_{i}")
             for i, (mixer, ffn) in enumerate(kinds)
         ]
         ln_f = _norm(cfg, "ln_f")
@@ -630,7 +767,8 @@ class Transformer(nn.Module):
             with (jax.named_scope(f"ut_{step}") if cfg.ut_steps > 1
                   else contextlib.nullcontext()):
                 for block in blocks:
-                    x, aux, load = block(x, segment_ids, rope)
+                    x, aux, load = block(
+                        x, segment_ids, rope and rope[block.kind])
                     aux_total = aux_total + aux
                     applications += 1
                     if load is not None:
@@ -661,6 +799,17 @@ class Transformer(nn.Module):
             "model.kda.kernel_layers",
             sum("kda" in kind for kind in kinds)
             * kda_kernels.takes(cfg.head_dim))
+        for i, (name, _) in enumerate(kinds):
+            if name in ("full", "window") and cfg.attn_impl == "flash":
+                labels = {"kind": name}
+                heads, kv_heads = attention_heads(cfg, layer_heads[i])
+                metrics.set_gauge(
+                    "model.attn.kv_groups", heads // kv_heads, labels)
+                # of the flash forward kernel's walks, one head, one row
+                metrics.set_gauge(
+                    "model.attn.tiles_per_head", flash_tiles_per_head(
+                        t, cfg.causal,
+                        cfg.window if name == "window" else None), labels)
         if loads:
             held = cfg.experts_held[1] - cfg.experts_held[0]
             metrics.set_gauge("model.moe.experts_held", held)

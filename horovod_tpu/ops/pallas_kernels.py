@@ -142,6 +142,14 @@ def _and(mask, other):
     return other if mask is None else jnp.logical_and(mask, other)
 
 
+def _distance(blocks, block: int):
+    """Query position less key position over a transposed tile, [block
+    (keys), block (queries)], whose Q block lies ``blocks`` K blocks on."""
+    return (blocks * block
+            + lax.broadcasted_iota(jnp.int32, (block, block), 1)
+            - lax.broadcasted_iota(jnp.int32, (block, block), 0))
+
+
 def _block(i, block: int):
     """Rows ``[i·block, (i+1)·block)`` as an index of a ``[T, lanes]`` ref."""
     return pl.ds(pl.multiple_of(i * block, block), block)
@@ -161,6 +169,8 @@ def _flash_fwd_kernel(
     t_actual: int,
     nblocks: int,
     heads: int,
+    group: int = 1,
+    window: Optional[int] = None,
 ):
     """One (batch, slab of ``heads`` heads): every Q block's output and
     row logsumexp, each head's from one walk over the K blocks that Q
@@ -177,7 +187,14 @@ def _flash_fwd_kernel(
     at the one tile a position mask can touch (the diagonal when causal,
     else the last, which holds the padding) and goes on below it in a
     ``fori_loop``: no tile above the diagonal is visited, and no other
-    tile builds a mask beside the segments'."""
+    tile builds a mask beside the segments'.
+
+    ``group`` query heads read one key/value head (grouped heads): k and
+    v are then a slab of ``[B, T, G·D]``, head ``hd // group`` of it.
+    With ``window`` (causal) a query sees itself and the ``window - 1``
+    keys before it: the walk ends at the last tile the window touches,
+    and only the tiles its far edge cuts (the last one or two) build the
+    second position mask."""
     if packed:
         q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, lse_ref = refs
     else:
@@ -191,11 +208,12 @@ def _flash_fwd_kernel(
         out_t = []
         for hd in range(heads):
             lanes = slice(hd * d, (hd + 1) * d)
+            kv_lanes = slice(hd // group * d, (hd // group + 1) * d)
             q = q_slab[:, lanes]
 
-            def tile(kb, carry):
-                k = _rows(k_ref, kb, block)[:, lanes]
-                v = _rows(v_ref, kb, block)[:, lanes]
+            def tile(kb, carry, windowed=False):
+                k = _rows(k_ref, kb, block)[:, kv_lanes]
+                v = _rows(v_ref, kb, block)[:, kv_lanes]
                 # Both matmuls take the input dtype (bf16 fast path) and
                 # accumulate in f32; scores and statistics are f32.
                 st = lax.dot_general(
@@ -208,6 +226,8 @@ def _flash_fwd_kernel(
                             jnp.int32, (block, block), 1) >= row
                     if t_actual < nblocks * block:  # K rows past the end
                         mask = _and(mask, kb * block + row < t_actual)
+                if windowed:  # the window's far edge crosses this tile
+                    mask = _and(mask, _distance(j - kb, block) < window)
                 if packed:
                     # sk_ref[kb] is [block, 1], sq_ref[j] [1, block]
                     mask = _and(mask, sk_ref[kb] == sq_ref[j])
@@ -230,7 +250,18 @@ def _flash_fwd_kernel(
                     acc += carry[2] * corr
                 return m, l, acc
 
-            m, l, acc = lax.fori_loop(0, first, tile, tile(first, None))
+            if window is None:
+                m, l, acc = lax.fori_loop(0, first, tile, tile(first, None))
+            else:
+                # below the diagonal: the tiles the window holds whole,
+                # [clear, j), then those its far edge cuts, [lowest, clear)
+                clear = jnp.clip(j + 1 - window // block, 0, j)
+                lowest = jnp.maximum(j * block + 1 - window, 0) // block
+                carry = tile(j, None, windowed=window < block)
+                carry = lax.fori_loop(clear, j, tile, carry)
+                m, l, acc = lax.fori_loop(
+                    lowest, clear, functools.partial(tile, windowed=True),
+                    carry)
             seen = l > 0.0
             l = jnp.where(seen, l, 1.0)
             out_t.append(acc / l)
@@ -288,6 +319,25 @@ def _tile_edge(t: int, cap: int) -> int:
         u for u in range(1, min(cap, _MAX_TILE) // _LANES + 1) if n % u == 0)
 
 
+def flash_tiles_per_head(t: int, causal: bool = True,
+                         window: Optional[int] = None,
+                         block: int = _MAX_TILE) -> int:
+    """Tiles the forward kernel's walks visit for one head over a row of
+    ``t`` tokens: every Q block's, from its first tile to the last one
+    the mask leaves it (``_flash_fwd_kernel``: the whole row of tiles
+    without a causal mask, up to the diagonal with one, from the
+    window's far edge with a window).  The backward visits the same
+    tiles, by K block."""
+    block = _tile_edge(t, block)
+    n = -(-t // block)
+    if not causal:
+        return n * n
+    if window is None:
+        return n * (n + 1) // 2
+    return sum(j + 1 - max(j * block + 1 - window, 0) // block
+               for j in range(n))
+
+
 def _slab_heads(heads: int, d: int) -> int:
     """Heads a grid step owns: the fewest that fill whole 128-lane
     columns of ``[B, T, H·D]`` and divide ``heads`` (one at D = 128, a
@@ -298,23 +348,38 @@ def _slab_heads(heads: int, d: int) -> int:
 
 
 def _slabs(operands: Tuple[jax.Array, ...], heads: int):
-    """(q, k, v, the first slab of each, heads a slab, lanes a slab) of
-    the kernels' operands: three ``[B, T, H·D]`` arrays, or one
-    ``[B, T, 3·H·D]`` projection that holds q, k and v side by side and
-    is read in place, the same array from three column offsets."""
+    """(q, k, v, the first slab of each, heads a slab, lanes a slab, query
+    heads a key/value head, lanes of a slab of k and v) of the kernels'
+    operands: three ``[B, T, H·D]`` arrays, or one ``[B, T, 3·H·D]``
+    projection that holds q, k and v side by side and is read in place,
+    the same array from three column offsets.  k and v may hold fewer
+    heads than q, ``[B, T, G·D]`` with G dividing H (grouped heads): a
+    slab is then one query head and the key/value head it reads, where a
+    head fills whole 128-lane columns, else all of both."""
     fused = len(operands) == 1
     q, k, v = operands * 3 if fused else operands
     width = q.shape[-1] // (3 if fused else 1)
+    group = 1 if fused else width // k.shape[-1]
     g = _slab_heads(heads, width // heads)
+    if group > 1 and g > 1:
+        g = heads
     lanes = g * (width // heads)
     first = tuple(i * (width // lanes) if fused else 0 for i in range(3))
-    return q, k, v, first, g, lanes
+    return (q, k, v, first, g, lanes, group,
+            lanes // group if g > 1 else lanes)
 
 
-def _column(first: int):
+def _slab_of(first: int, s, group: int):
+    """Column slab of grid step ``s``: its own, or with grouped heads its
+    key/value head's, which ``group`` consecutive steps share (the
+    pipeline fetches a block again only when its index moves)."""
+    return first + s if group == 1 else first + s // group
+
+
+def _column(first: int, group: int = 1):
     """Index map of a slab of ``[B, T, ·]``: all rows, slab
     ``first + s`` of the columns, for grid steps (b, s, ...)."""
-    return lambda b_, s, *_: (b_, 0, first + s)
+    return lambda b_, s, *_: (b_, 0, _slab_of(first, s, group))
 
 
 def _flash_forward(
@@ -324,6 +389,7 @@ def _flash_forward(
     scale: float,
     block: int,
     segments: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Forward kernel on ``[B, T, H·D]`` operands (``_slabs``), the
     projections' own layout: (out [B, T, H·D], lse [B, H, T] float32).
@@ -332,7 +398,7 @@ def _flash_forward(
     slab's q, k, v and output stay in VMEM meanwhile (2 KB a token in
     bf16 at the 128 lanes of a slab, 3 KB with segment ids), which
     bounds T at some 45 000 a device, 30 000 packed."""
-    q, k, v, first, g, lanes = _slabs(operands, heads)
+    q, k, v, first, g, lanes, group, kv_lanes = _slabs(operands, heads)
     b, t = q.shape[:2]
     block = _tile_edge(t, block)
     q, k, v = (_pad_axis(x, 1, block) for x in (q, k, v))
@@ -340,8 +406,9 @@ def _flash_forward(
     n = tp // block
 
     inputs = [q, k, v]
-    in_specs = [
-        pl.BlockSpec((None, tp, lanes), _column(c)) for c in first]
+    in_specs = [pl.BlockSpec((None, tp, lanes), _column(first[0]))] + [
+        pl.BlockSpec((None, tp, kv_lanes), _column(c, group))
+        for c in first[1:]]
     if segments is not None:
         seg = _pad_seg(jnp.asarray(segments, jnp.int32), tp)
         # along lanes for the Q side, along sublanes for the K side
@@ -360,6 +427,8 @@ def _flash_forward(
         t_actual=t,
         nblocks=n,
         heads=g,
+        group=group,
+        window=window,
     )
     # VMEM: what stays per slab (q, k, v and the output twice for the
     # pipeline, lanes in whole columns of 128; the K side's segment ids
@@ -405,6 +474,8 @@ def _flash_bwd_kernel(
     t_actual: int,
     nblocks: int,
     heads: int,
+    group: int = 1,
+    window: Optional[int] = None,
 ):
     """One K/V block of one (batch, slab of ``heads`` heads): its dk and
     dv whole and its share of every dq block, each head's in one pass
@@ -420,7 +491,13 @@ def _flash_bwd_kernel(
     is, once, at the slab's last K block, where dq leaves scaled and
     rounded as rows of [B, T, H·D].  delta = rowsum(do ⊙ o) is made at
     the slab's first K block from do and o as they lie, and kept in
-    VMEM beside dqᵀ."""
+    VMEM beside dqᵀ.
+
+    With ``group`` query heads a key/value head, dk and dv are sums over
+    a slab's query heads of one key/value head (``_flash_backward``
+    sums over the slabs); with ``window`` the pass ends at the last Q
+    block that sees this K block, and every tile's mask has the window's
+    far edge beside the diagonal."""
     if packed:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref, sq_ref, sk_ref,
          dq_ref, dk_ref, dv_ref, dqt_acc, dk_acc, dv_acc, delta_ref) = refs
@@ -452,11 +529,17 @@ def _flash_bwd_kernel(
     kt_slab = k_slab.T  # a head's kᵀ is a slice of its sublanes
     k_pos = kk * block + lax.broadcasted_iota(jnp.int32, (block, block), 0)
 
+    last = nblocks  # past the last Q block that sees this K block
+    if window is not None:
+        last = jnp.minimum(nblocks, kk + ((window - 2) // block + 2))
+
     for hd in range(heads):
         lanes = slice(hd * d, (hd + 1) * d)
-        k = k_slab[:, lanes]
-        v = v_slab[:, lanes]
-        kt = kt_slab[lanes]
+        kv = hd // group
+        kv_lanes = slice(kv * d, (kv + 1) * d)
+        k = k_slab[:, kv_lanes]
+        v = v_slab[:, kv_lanes]
+        kt = kt_slab[kv_lanes]
 
         def tile(j, _):
             q = _rows(q_ref, j, block)[:, lanes]
@@ -472,27 +555,30 @@ def _flash_bwd_kernel(
                 q_pos = j * block + lax.broadcasted_iota(
                     jnp.int32, (block, block), 1)
                 mask = _and(mask, q_pos >= k_pos)
+                if window is not None:
+                    mask = _and(mask, q_pos - k_pos < window)
             if packed:
                 # sk_ref is [block, 1], sq_ref[j] [1, block]
                 mask = _and(mask, sk_ref[:] == sq_ref[j])
             pt = jnp.exp(st - lse_ref[hd, j])
             if mask is not None:
                 pt = jnp.where(mask, pt, 0.0)
-            dv_acc[hd] += jnp.dot(
+            dv_acc[kv] += jnp.dot(
                 pt.astype(do.dtype), do, preferred_element_type=jnp.float32)
             dpt = lax.dot_general(
                 v, do, _NT, preferred_element_type=jnp.float32)
             dst = (pt * (dpt - delta_ref[hd, j])).astype(q.dtype)
-            dk_acc[hd] += jnp.dot(dst, q, preferred_element_type=jnp.float32)
+            dk_acc[kv] += jnp.dot(dst, q, preferred_element_type=jnp.float32)
             dqt_acc[j, lanes] += jnp.dot(
                 kt, dst, preferred_element_type=jnp.float32)
 
         # Causal: the Q blocks above this K block see none of it and are
         # skipped, as the forward skips the K blocks above a Q block.
-        lax.fori_loop(kk if causal else 0, nblocks, tile, None)
+        lax.fori_loop(kk if causal else 0, last, tile, None)
 
     def slab(acc):  # [heads, block, D] -> [block, heads · D]
-        return jnp.concatenate([acc[hd] for hd in range(heads)], axis=1)
+        return jnp.concatenate(
+            [acc[hd] for hd in range(acc.shape[0])], axis=1)
 
     dk_ref[:] = (slab(dk_acc) * scale).astype(dk_ref.dtype)
     dv_ref[:] = slab(dv_acc).astype(dv_ref.dtype)
@@ -509,7 +595,7 @@ def _flash_bwd_kernel(
 # jitted: the layers of a model call it with the same shapes, so it is
 # traced and lowered once and called as often (the forward cannot be: a
 # ``jit(...)`` in its scope path would hide it from the benchmark's anchor).
-@functools.partial(jax.jit, static_argnums=(1, 5, 6, 7))
+@functools.partial(jax.jit, static_argnums=(1, 5, 6, 7, 9))
 def _flash_backward(
     operands: Tuple[jax.Array, ...],
     heads: int,
@@ -520,16 +606,17 @@ def _flash_backward(
     scale: float,
     block: int,
     segments: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Backward kernel on ``[B, T, H·D]`` operands (``_slabs``), the
     output's cotangent, the output and the [B, H, T] row logsumexp:
-    (dq, dk, dv), each [B, T, H·D].
+    (dq, dk, dv), each as wide as its operand.
 
     The grid is (batch, slab, K block); one slab's q, do, o, lse, delta,
     its dq and dqᵀ in float32 stay in VMEM meanwhile (about 2 KB a
     token at 128 lanes), which bounds T at some tens of thousands a
     device."""
-    q, k, v, first, g, lanes = _slabs(operands, heads)
+    q, k, v, first, g, lanes, group, kv_lanes = _slabs(operands, heads)
     b, t = q.shape[:2]
     block = _tile_edge(t, block)
     q, k, v, do, out = (_pad_axis(x, 1, block) for x in (q, k, v, do, out))
@@ -543,13 +630,14 @@ def _flash_backward(
         return _pad_axis(x, 2, block).reshape(b, heads, n, 1, block)
 
     per_slab = lambda c: pl.BlockSpec((None, tp, lanes), _column(c))
-    k_block = lambda c: pl.BlockSpec(
-        (None, block, lanes), lambda b_, s, kk: (b_, kk, c + s))
+    k_block = lambda c, group=1: pl.BlockSpec(
+        (None, block, kv_lanes),
+        lambda b_, s, kk: (b_, kk, _slab_of(c, s, group)))
     row = pl.BlockSpec(
         (None, g, n, 1, block), lambda b_, s, kk: (b_, s, 0, 0, 0))
     inputs = [q, k, v, do, rows(lse), out]
-    in_specs = [per_slab(first[0]), k_block(first[1]), k_block(first[2]),
-                per_slab(0), row, per_slab(0)]
+    in_specs = [per_slab(first[0]), k_block(first[1], group),
+                k_block(first[2], group), per_slab(0), row, per_slab(0)]
     if segments is not None:
         seg = _pad_seg(jnp.asarray(segments, jnp.int32), tp)
         inputs += [seg.reshape(b, n, 1, block), seg[:, :, None]]
@@ -567,9 +655,15 @@ def _flash_backward(
         t_actual=t,
         nblocks=n,
         heads=g,
+        group=group,
+        window=window,
     )
     d = lanes // g
     width = heads // g * lanes
+    # dk and dv of a slab are its own key/value heads': with grouped heads
+    # and a slab a query head, a query head's each, summed below
+    per_query_head = group > 1 and g == 1
+    kv_width = heads // g * kv_lanes
     # VMEM: what stays per slab (q, do, o and dq twice for the pipeline,
     # lanes in whole columns of 128; dqᵀ once, float32) and a dozen
     # float32 tiles.
@@ -580,11 +674,12 @@ def _flash_backward(
         grid=(b, heads // g, n),
         in_specs=in_specs,
         out_specs=[per_slab(0), k_block(0), k_block(0)],
-        out_shape=[_sds((b, tp, width), x.dtype, q) for x in (q, k, v)],
+        out_shape=[_sds((b, tp, w), x.dtype, q) for x, w in (
+            (q, width), (k, kv_width), (v, kv_width))],
         scratch_shapes=[
             pltpu.VMEM((n, lanes, block), jnp.float32),
-            pltpu.VMEM((g, block, d), jnp.float32),
-            pltpu.VMEM((g, block, d), jnp.float32),
+            pltpu.VMEM((kv_lanes // d, block, d), jnp.float32),
+            pltpu.VMEM((kv_lanes // d, block, d), jnp.float32),
             pltpu.VMEM((g, n, 1, block), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
@@ -594,6 +689,19 @@ def _flash_backward(
         interpret=_interpret(),
         name="flash_bwd_dq_dkv",
     )(*inputs)
+    if per_query_head:
+        # [B, T, H·D], a query head's dk or dv each: H / group key/value
+        # heads of ``group`` consecutive parts, summed in float32
+        # (as sums of lane slices: a [.., G, group, D] view of the array
+        # would be a copy of it under the (8, 128) tiling)
+        def over_groups(x):
+            heads_ = [x[..., i * d:(i + 1) * d].astype(jnp.float32)
+                      for i in range(heads)]
+            return jnp.concatenate(
+                [sum(heads_[i:i + group]) for i in range(0, heads, group)],
+                axis=-1).astype(x.dtype)
+
+        dk, dv = over_groups(dk), over_groups(dv)
     return dq[:, :t], dk[:, :t], dv[:, :t]
 
 
@@ -609,6 +717,7 @@ def _flash_bwd_chunked(
     block_q: int,
     block_k: int,
     segments: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Flash-attention backward: (dq, dk, dv) as [B, T, H·D] from the
     forward's residuals (q, k, v as the kernel read them, the output,
@@ -627,11 +736,11 @@ def _flash_bwd_chunked(
     with jax.named_scope("flash_bwd"):
         return _flash_backward(
             operands, lse.shape[1], do, lse, out, causal, scale,
-            min(block_q, block_k), segments)
+            min(block_q, block_k), segments, window)
 
 
 def _flash_fwd_res(operands, heads, causal, scale, block_q, block_k,
-                   segments=None):
+                   segments=None, window=None):
     """(out [B, T, H·D], residuals): q, k, v are kept as the kernel read
     them, [B, T, H·D] as the projections wrote them (or the one fused
     projection), so the backward copies none of them.  The residuals
@@ -641,7 +750,8 @@ def _flash_fwd_res(operands, heads, causal, scale, block_q, block_k,
     not run the forward kernel again)."""
     operands = tuple(checkpoint_name(x, "flash_qkv") for x in operands)
     out, lse = _flash_forward(
-        operands, heads, causal, scale, min(block_q, block_k), segments)
+        operands, heads, causal, scale, min(block_q, block_k), segments,
+        window)
     out = checkpoint_name(out, "flash_out")
     lse = checkpoint_name(lse, "flash_out")
     return out, (operands, out, lse)
@@ -655,7 +765,7 @@ def _cotangents(operands, grads):
     return (jnp.concatenate(grads, axis=-1),)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
 def _flash_attention_dense(
     operands: Tuple[jax.Array, ...],
     heads: int,
@@ -663,19 +773,28 @@ def _flash_attention_dense(
     scale: float,
     block_q: int,
     block_k: int,
+    window: Optional[int],
 ) -> jax.Array:
-    return _flash_fwd_res(operands, heads, causal, scale, block_q, block_k)[0]
+    return _flash_dense_fwd_rule(
+        operands, heads, causal, scale, block_q, block_k, window)[0]
 
 
-def _flash_bwd_rule(heads, causal, scale, block_q, block_k, res, do):
-    grads = _flash_bwd_chunked(res, do, causal, scale, block_q, block_k)
+def _flash_dense_fwd_rule(operands, heads, causal, scale, block_q, block_k,
+                          window):
+    return _flash_fwd_res(
+        operands, heads, causal, scale, block_q, block_k, None, window)
+
+
+def _flash_bwd_rule(heads, causal, scale, block_q, block_k, window, res, do):
+    grads = _flash_bwd_chunked(
+        res, do, causal, scale, block_q, block_k, window=window)
     return (_cotangents(res[0], grads),)
 
 
-_flash_attention_dense.defvjp(_flash_fwd_res, _flash_bwd_rule)
+_flash_attention_dense.defvjp(_flash_dense_fwd_rule, _flash_bwd_rule)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6, 7))
 def _flash_attention_packed(
     operands: Tuple[jax.Array, ...],
     segment_ids: jax.Array,
@@ -684,23 +803,26 @@ def _flash_attention_packed(
     scale: float,
     block_q: int,
     block_k: int,
+    window: Optional[int],
 ) -> jax.Array:
     return _flash_fwd_res(
-        operands, heads, causal, scale, block_q, block_k, segment_ids)[0]
+        operands, heads, causal, scale, block_q, block_k, segment_ids,
+        window)[0]
 
 
 def _flash_packed_fwd_rule(operands, seg, heads, causal, scale, block_q,
-                           block_k):
+                           block_k, window):
     out, res = _flash_fwd_res(
-        operands, heads, causal, scale, block_q, block_k, seg)
+        operands, heads, causal, scale, block_q, block_k, seg, window)
     return out, (res, seg)
 
 
-def _flash_packed_bwd_rule(heads, causal, scale, block_q, block_k, res_seg,
-                           do):
+def _flash_packed_bwd_rule(heads, causal, scale, block_q, block_k, window,
+                           res_seg, do):
     res, seg = res_seg
     grads = _flash_bwd_chunked(
-        res, do, causal, scale, block_q, block_k, segments=seg)
+        res, do, causal, scale, block_q, block_k, segments=seg,
+        window=window)
     # integer segment ids carry a float0 (empty) cotangent
     return (_cotangents(res[0], grads),
             np.zeros(seg.shape, jax.dtypes.float0))
@@ -710,12 +832,17 @@ _flash_attention_packed.defvjp(_flash_packed_fwd_rule,
                                _flash_packed_bwd_rule)
 
 
-def _flash(operands, heads, causal, scale, block_q, block_k, segment_ids):
+def _flash(operands, heads, causal, scale, block_q, block_k, segment_ids,
+           window=None):
     """[B, T, H·D] out of ``_slabs`` operands, dense or packed."""
     b, t = operands[0].shape[:2]
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"a window ({window}) is a causal mask's: a query sees itself "
+            "and the window - 1 keys before it")
     if segment_ids is None:
         return _flash_attention_dense(
-            operands, heads, causal, scale, block_q, block_k)
+            operands, heads, causal, scale, block_q, block_k, window)
     if segment_ids.shape != (b, t):
         raise ValueError(
             f"segment_ids must be [B, T] = {(b, t)}, got "
@@ -723,7 +850,7 @@ def _flash(operands, heads, causal, scale, block_q, block_k, segment_ids):
         )
     return _flash_attention_packed(
         operands, jnp.asarray(segment_ids, jnp.int32), heads, causal, scale,
-        block_q, block_k)
+        block_q, block_k, window)
 
 
 def flash_attention(
@@ -735,6 +862,7 @@ def flash_attention(
     block_q: int = 512,
     block_k: int = 512,
     segment_ids: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Fused flash attention: [B, T, H, D] → [B, T, H, D].
 
@@ -762,6 +890,14 @@ def flash_attention(
     cross-document attention).  The reference has no LM/attention story;
     this is the TPU-native throughput lever for LM pretraining.
 
+    ``k`` and ``v`` may hold fewer heads than ``q``, [B, T, G, D] with G
+    dividing H (grouped key/value heads): query head h reads head
+    ``h // (H / G)``, which is never repeated in HBM; the forward reads a
+    key/value head once for its group, and dk, dv are the sums over it.
+    ``window`` (with ``causal``) keeps of each query's keys itself and
+    the ``window - 1`` before it: neither kernel visits a tile the
+    window does not touch.
+
     Requires ``q`` and ``k``/``v`` to share sequence length: the kernel's
     padding mask and causal diagonal are derived from ``q.shape[1]``.
     For cross-attention with differing lengths use ``full_attention``
@@ -774,10 +910,15 @@ def flash_attention(
             "full_attention for unequal lengths"
         )
     b, t, heads, d = q.shape
+    if k.shape != v.shape or k.shape[3] != d or heads % k.shape[2]:
+        raise ValueError(
+            f"flash_attention takes k and v of one shape, heads as wide as "
+            f"q's and a number of them that divides q's: q {q.shape}, "
+            f"k {k.shape}, v {v.shape}")
     out = _flash(
-        tuple(x.reshape(b, t, heads * d) for x in (q, k, v)), heads, causal,
+        tuple(x.reshape(b, t, -1) for x in (q, k, v)), heads, causal,
         scale if scale is not None else d ** -0.5, block_q, block_k,
-        segment_ids)
+        segment_ids, window)
     return out.reshape(b, t, heads, d)
 
 
@@ -809,20 +950,24 @@ def flash_attention_qkv(
 # ---------------------------------------------------------------------------
 
 _ROPE_ROWS = 512
+# A block of rows is in VMEM four times (in and out, each twice for the
+# pipeline) beside the tables, under the 16 MB a kernel has without asking:
+# 512 rows up to 2048 lanes of bf16, fewer of a wider array.
+_ROPE_BLOCK_BYTES = 2 << 20
 
 
-def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref, *, heads: int):
+def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref, *, half: int):
     """A block of rows of ``[B, T, H·D]`` rotated, slab by slab: with
-    the tables doubled over a head's halves and tiled over a slab's
-    heads, ``x·cos + other·sin``, where a lane's ``other`` is its
-    partner D/2 lanes up (first half of a head) or down (second half):
-    a lane rotation of the slab, two and a select where a slab holds
-    more than one head."""
+    the tables doubled over the halves of a head's turning part (and 1
+    and 0 over the rest of it, where only part of a head turns) and
+    tiled over a slab's heads, ``x·cos + other·sin``, where a lane's
+    ``other`` is its partner ``half`` lanes up (first half) or down
+    (second half): a lane rotation of the slab, two and a select where
+    a slab holds more than one such pair of halves."""
     lanes = cos_ref.shape[-1]
-    half = lanes // heads // 2
     cos, sin = cos_ref[:], sin_ref[:]
     first = None
-    if heads > 1:
+    if lanes > 2 * half:
         first = lax.broadcasted_iota(
             jnp.int32, cos.shape, 1) % (2 * half) < half
     for s in range(x_ref.shape[-1] // lanes):
@@ -837,9 +982,11 @@ def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref, *, heads: int):
 # jitted: the q's and k's of every layer, forward and backward, are one
 # traced and lowered function called many times, not as many kernels.
 @functools.partial(jax.jit, static_argnums=(3,))
-def _rope_call(x, cos, sin, heads):
+def _rope_call(x, cos, sin, half):
     b, t, width = x.shape
-    rows = min(_ROPE_ROWS, -(-t // 16) * 16)
+    fit = _ROPE_BLOCK_BYTES // (width * x.dtype.itemsize)
+    rows = min(_ROPE_ROWS, -(-t // 16) * 16,
+               max(16, 1 << (fit.bit_length() - 1)))
     x, cos, sin = (_pad_axis(a, 1, rows) for a in (x, cos, sin))
     lanes = cos.shape[-1]
     per_row = cos.shape[0] > 1  # a table a row, or one for all
@@ -847,8 +994,7 @@ def _rope_call(x, cos, sin, heads):
     table = pl.BlockSpec(
         (None, rows, lanes), lambda i, j: (i if per_row else 0, j, 0))
     out = pl.pallas_call(
-        functools.partial(
-            _rope_kernel, heads=lanes // (width // heads)),
+        functools.partial(_rope_kernel, half=half),
         grid=(b, x.shape[1] // rows),
         in_specs=[rows_of, table, table],
         out_specs=rows_of,
@@ -864,17 +1010,17 @@ def _rope_call(x, cos, sin, heads):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _rope(x, cos, sin, heads):
-    return _rope_call(x, cos, sin, heads)
+def _rope(x, cos, sin, half):
+    return _rope_call(x, cos, sin, half)
 
 
-def _rope_fwd(x, cos, sin, heads):
-    return _rope_call(x, cos, sin, heads), (cos, sin)
+def _rope_fwd(x, cos, sin, half):
+    return _rope_call(x, cos, sin, half), (cos, sin)
 
 
-def _rope_bwd(heads, tables, g):
+def _rope_bwd(half, tables, g):
     cos, sin = tables  # the transpose of a rotation turns the other way
-    return (_rope_call(g, cos, -sin, heads), jnp.zeros_like(cos),
+    return (_rope_call(g, cos, -sin, half), jnp.zeros_like(cos),
             jnp.zeros_like(sin))
 
 
@@ -887,16 +1033,25 @@ def rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
     (element i of a head pairs with i + D/2), as one VMEM-tiled kernel
     on the layout the q and k projections write and the flash kernels
     read: ``cos``/``sin`` are the angles' tables, [T, D/2] or
-    [B, T, D/2] float32.  The arithmetic is float32, the result ``x``'s
+    [B, T, D/2] float32.  Narrower tables, [..., r/2], turn the first r
+    channels of every head (pairs i, i + r/2) and pass the rest.  The
+    arithmetic is float32, the result ``x``'s
     dtype.  Written in XLA on [B, T, H, D] the same rotation costs a
     re-layout of q and of k on the way in and on the way out, forward
     and backward: that reshape of [B, T, H·D] is no bitcast under the
     (8, 128) tiling (PERF.md, PR 30)."""
-    g = _slab_heads(heads, x.shape[-1] // heads)
+    d = x.shape[-1] // heads
+    g = _slab_heads(heads, d)
+    half = cos.shape[-1]
 
-    def table(first, second):
-        """[1 or B, T, g·D]: a head's two halves, over a slab's heads."""
-        full = jnp.tile(jnp.concatenate([first, second], axis=-1), g)
+    def table(first, second, rest):
+        """[1 or B, T, g·D]: a head's two halves (and what leaves the
+        channels that do not turn as they are), over a slab's heads."""
+        parts = [first, second]
+        if 2 * half < d:
+            parts.append(jnp.full(
+                first.shape[:-1] + (d - 2 * half,), rest, first.dtype))
+        full = jnp.tile(jnp.concatenate(parts, axis=-1), g)
         return full.reshape((-1,) + full.shape[-2:])
 
-    return _rope(x, table(cos, cos), table(-sin, sin), heads)
+    return _rope(x, table(cos, cos, 1.0), table(-sin, sin, 0.0), half)

@@ -14,6 +14,7 @@ ride the residual connection, the standard Switch behaviour.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import flax.linen as nn
@@ -215,6 +216,17 @@ def route_group_limited(scores: jax.Array, bias: jax.Array, k: int,
 _TILE = 256
 
 
+def tile_rows(expected_pairs: float) -> int:
+    """Rows of a tile where a held expert expects ``expected_pairs`` pairs
+    of a batch: the fewest multiples of ``_TILE`` (at most four) that hold
+    one and a half times that.  An expert whose expected load fills a tile
+    would take one tile or two by the routers' draw, and the step's time
+    would follow the seed (0.5% between seeds at 256 pairs an expert and
+    tiles of 256; PERF.md, PR 34); with room for the draw it is one tile
+    an expert, whatever the seed."""
+    return _TILE * min(4, max(1, -(-int(1.5 * expected_pairs) // _TILE)))
+
+
 def _plan(local: jax.Array, n_held: int, tile: int):
     """The sorted dispatch of the (token, expert) pairs ``local`` [S, k]
     (the held expert's index, or ``n_held`` for a pair another device
@@ -249,30 +261,30 @@ def _swiglu_tile(xt, e, wg, wu):
     return a, u
 
 
-@jax.custom_vjp
-def grouped_experts(x, weights, wg, wu, wd, local):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def grouped_experts(x, weights, wg, wu, wd, local, tile=_TILE):
     """sum over the pairs on held experts of ``w * E_e(x_token)``: x
     [S, d], weights [S, k] float32, the held experts' SwiGLU matrices wg,
     wu [n, d, f] and wd [n, f, d] in the compute type, ``local`` [S, k]
     int32 (``_plan``).  Returns [S, d] float32.
 
     The pairs are sorted by expert and multiplied tile by tile, a tile of
-    ``_TILE`` rows of one expert; the loop runs over the tiles the batch
+    ``tile`` rows of one expert (``tile_rows``); the loop runs over the tiles the batch
     really produced (a dynamic trip count), so the work follows the load:
     there is no capacity, no [S, E, C] tensor and no buffer of the worst
     case's size, and the static bound is every pair."""
-    return _grouped_fwd(x, weights, wg, wu, wd, local)[0]
+    return _grouped_fwd(x, weights, wg, wu, wd, local, tile)[0]
 
 
-def _grouped_fwd(x, weights, wg, wu, wd, local):
+def _grouped_fwd(x, weights, wg, wu, wd, local, tile):
     k = local.shape[1]
     with jax.named_scope("dispatch"):
-        plan = _plan(local, wg.shape[0], _TILE)
+        plan = _plan(local, wg.shape[0], tile)
     flat_w = weights.reshape(-1)
 
-    def tile(j, out):
+    def one_tile(j, out):
         with jax.named_scope("dispatch"):
-            e, token, valid, pair = _tile_rows(j, plan, k, _TILE)
+            e, token, valid, pair = _tile_rows(j, plan, k, tile)
             xt = x[token]
         with jax.named_scope("experts"):
             a, u = _swiglu_tile(xt, e, wg, wu)
@@ -284,20 +296,20 @@ def _grouped_fwd(x, weights, wg, wu, wd, local):
             return out.at[token].add(y * w[:, None])
 
     out = lax.fori_loop(
-        0, plan[3][-1], tile, jnp.zeros(x.shape, jnp.float32))
+        0, plan[3][-1], one_tile, jnp.zeros(x.shape, jnp.float32))
     return out, (x, weights, wg, wu, wd, local, plan)
 
 
-def _grouped_bwd(res, dout):
+def _grouped_bwd(tile, res, dout):
     x, weights, wg, wu, wd, local, plan = res
     k = local.shape[1]
     flat_w = weights.reshape(-1)
     dout = dout.astype(jnp.float32)
 
-    def tile(j, carry):
+    def one_tile(j, carry):
         dx, dw, dwg, dwu, dwd = carry
         with jax.named_scope("dispatch"):
-            e, token, valid, pair = _tile_rows(j, plan, k, _TILE)
+            e, token, valid, pair = _tile_rows(j, plan, k, tile)
             xt = x[token]
             dyt = dout[token]
         with jax.named_scope("experts"):
@@ -340,7 +352,7 @@ def _grouped_bwd(res, dout):
 
     zeros = lambda like: jnp.zeros(like.shape, jnp.float32)
     dx, dw, dwg, dwu, dwd = lax.fori_loop(
-        0, plan[3][-1], tile,
+        0, plan[3][-1], one_tile,
         (zeros(x), zeros(flat_w), zeros(wg), zeros(wu), zeros(wd)))
     return (dx.astype(x.dtype), dw.reshape(weights.shape).astype(
         weights.dtype), dwg.astype(wg.dtype), dwu.astype(wu.dtype),
@@ -409,7 +421,9 @@ class ExpertFFN(nn.Module):
         wu = experts("wi", (n_held, d, self.hidden))
         wd = experts("wo", (n_held, self.hidden, d))
         xc = xf.astype(dtype)
-        routed = grouped_experts(xc, weights, wg, wu, wd, local)
+        routed = grouped_experts(
+            xc, weights, wg, wu, wd, local,
+            tile_rows(b * t * self.k / self.num_experts))
         with jax.named_scope("shared"):
             shared = TensorParallelMLP(
                 hidden=self.hidden, features=d, dtype=dtype, act=nn.silu,

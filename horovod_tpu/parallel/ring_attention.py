@@ -34,22 +34,32 @@ def full_attention(
     causal: bool = False,
     scale: Optional[float] = None,
     segment_ids: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Plain softmax attention, f32 accumulation: [B, T, H, D] → same.
 
     The single-device reference semantics that ``ring_attention`` and
     ``ulysses_attention`` must match bit-for-bit up to fp error.
     ``segment_ids`` ([B, T]) restricts attention to same-segment keys
-    (packed sequences).
+    (packed sequences).  ``k`` and ``v`` may hold fewer heads than ``q``
+    (query head h reads head ``h // (H / G)``); ``window`` keeps of a
+    query's keys under the causal mask itself and the ``window - 1``
+    before it.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if k.shape[2] != q.shape[2]:
+        k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], axis=2)
+                for x in (k, v))
     s = jnp.einsum(
         "bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale, k.astype(jnp.float32)
     )
     if causal:
         tq, tk = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((tq, tk), dtype=bool), k=tk - tq)
+        if window is not None:
+            mask = jnp.logical_and(mask, jnp.triu(
+                jnp.ones((tq, tk), dtype=bool), k=tk - tq - window + 1))
         s = jnp.where(mask, s, _NEG_INF)
     if segment_ids is not None:
         segmask = (

@@ -551,3 +551,110 @@ def test_the_delta_rule_mixer_stays_on_the_projections_layout():
             # (the documents' marks a chunk are [B, n, 1, C] integers)
             assert (out.aval.ndim < 4 or eqn in calls
                     or out.aval.dtype == jnp.int32), eqn
+
+
+# ------------------------------------------- one group: a 256-wide router's way
+def test_expert_layer_of_one_group_is_plain_top_k():
+    """``n_group`` = ``topk_group`` = 1 (Laguna-XS.2's router): the 8
+    largest s + b over the router's whole width, weights 2.5 s_i / sum of
+    the chosen s, through ``ExpertFFN`` against ``lax.top_k`` and a dense
+    loop."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 50, 16))
+    layer = moe.ExpertFFN(
+        num_experts=32, experts_held=(0, 32), hidden=12, k=8, n_group=1,
+        topk_group=1, routed_scaling=2.5, dtype=jnp.float32)
+    params = layer.init(jax.random.PRNGKey(1), x)["params"]
+    params["router_bias"] = 0.05 * jax.random.normal(
+        jax.random.PRNGKey(2), (32,))
+    xf = x.reshape(-1, 16)
+    with jax.default_matmul_precision("highest"):
+        y, load = layer.apply({"params": params}, x)
+        scores = jax.nn.sigmoid(xf @ params["router"])
+        _, ids = jax.lax.top_k(scores + params["router_bias"], 8)
+        chosen = jnp.take_along_axis(scores, ids, axis=-1)
+        weights = 2.5 * chosen / chosen.sum(-1, keepdims=True)
+        shared = (jax.nn.silu(xf @ params["shared"]["wg"]["Dense_0"]["kernel"])
+                  * (xf @ params["shared"]["wi"]["Dense_0"]["kernel"])
+                  ) @ params["shared"]["wo"]["Dense_0"]["kernel"]
+        want = shared + _dense_loop(
+            xf, weights, params["wg"], params["wi"], params["wo"], ids)
+    assert _max_rel(y.reshape(-1, 16), want) <= 1e-5
+    np.testing.assert_array_equal(
+        load, np.bincount(np.asarray(ids).ravel(), minlength=32))
+    assert float(load.sum()) == 100 * 8
+
+
+def test_the_shares_of_a_one_group_layer_add_up():
+    """8 shares of 4 experts of a 32-expert layer routed as one group: the
+    routed parts summed and the shared expert counted once are the uncut
+    layer, which is the plain reference's of the window/full-attention
+    family (every token through every expert)."""
+    from benchmark.reference import swamoe as reference
+
+    def layer(held):
+        return moe.ExpertFFN(
+            num_experts=32, experts_held=held, hidden=12, k=8, n_group=1,
+            topk_group=1, routed_scaling=2.5, dtype=jnp.float32)
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 40, 16))
+    params = layer((0, 32)).init(jax.random.PRNGKey(1), x)["params"]
+    params["router_bias"] = 0.05 * jax.random.normal(
+        jax.random.PRNGKey(2), (32,))
+    model = dict(num_experts_per_tok=8, moe_routed_scaling_factor=2.5,
+                 experts_held=[0, 32])
+    with jax.default_matmul_precision("highest"):
+        uncut = reference._experts(params, x, model)
+        y_whole, load_whole = layer((0, 32)).apply({"params": params}, x)
+        shared = reference._swiglu(
+            x, *(params["shared"][n]["Dense_0"]["kernel"]
+                 for n in ("wg", "wi", "wo")))
+        total, loads = jnp.zeros_like(uncut), []
+        for s in range(8):
+            held = slice(4 * s, 4 * s + 4)
+            share = dict(params, **{
+                n: params[n][held] for n in ("wg", "wi", "wo")})
+            y, load = layer((4 * s, 4 * s + 4)).apply({"params": share}, x)
+            # the reference is given the same share
+            part = reference._experts(
+                share, x, dict(model, experts_held=[4 * s, 4 * s + 4]))
+            assert _max_rel(y, part) <= 1e-5, s
+            total = total + (y - shared)
+            loads.append(load)
+        total = total + shared
+    assert _max_rel(y_whole, uncut) <= 1e-5
+    assert _max_rel(total, uncut) <= 1e-5
+    # every pair is served by exactly one share
+    assert float(sum(l.sum() for l in loads)) == 2 * 40 * 8
+    np.testing.assert_array_equal(jnp.concatenate(loads), load_whole)
+    # the shared expert counted in every share would be seven too many
+    assert _max_rel(total + 7 * shared, uncut) > 100 * _max_rel(total, uncut)
+
+
+@pytest.mark.parametrize("expected, rows", [
+    (64, 256), (170, 256), (256, 512), (512, 768), (2048, 1024)])
+def test_a_tile_leaves_room_for_the_routers_draw(expected, rows):
+    """One and a half times an expert's expected pairs, in multiples of
+    256 up to four: 64 pairs an expert keep the tile of 256, 256 pairs an
+    expert take 512 (one tile an expert whatever the seed)."""
+    assert moe.tile_rows(expected) == rows
+
+
+def test_a_wider_tile_is_the_same_product():
+    s, k, d, f, n = 700, 2, 16, 24, 3
+    keys = jax.random.split(jax.random.PRNGKey(3), 6)
+    local = jax.random.randint(keys[5], (s, k), 0, n + 1).astype(jnp.int32)
+    x = jax.random.normal(keys[0], (s, d))
+    weights = jax.random.uniform(keys[1], (s, k))
+    wg, wu = (jax.random.normal(kk, (n, d, f)) / 4 for kk in keys[2:4])
+    wd = jax.random.normal(keys[4], (n, f, d)) / 5
+    with jax.default_matmul_precision("highest"):
+        want, g_want = jax.value_and_grad(lambda *a: jnp.sum(
+            _dense_loop(*a, local) ** 2), argnums=range(5))(
+                x, weights, wg, wu, wd)
+        for tile in (256, 512):
+            got, g_got = jax.value_and_grad(lambda *a: jnp.sum(
+                moe.grouped_experts(*a, local, tile) ** 2),
+                argnums=range(5))(x, weights, wg, wu, wd)
+            assert float(got) == pytest.approx(float(want), rel=1e-5)
+            for a, w in zip(g_got, g_want):
+                assert _max_rel(a, w, 1.0) <= 1e-5, tile

@@ -561,3 +561,181 @@ def test_rope_kernel_matches_rotate_half(case):
         np.asarray(grad(kernel), np.float32),
         np.asarray(grad(lambda x: _rope_reference(x, cos, sin)), np.float32),
         atol=tol, rtol=tol)
+
+
+# ---------------------------------------------------------------------------
+# A window and grouped key/value heads
+# ---------------------------------------------------------------------------
+
+# name -> (B, T, H, G, D, window, tile, packed): windows smaller than,
+# equal to and larger than the tile, T that is no multiple of it, groups
+# of 1, 6 and 8 query heads a key/value head, packed rows with a window.
+_WINDOW_CASES = {
+    "window_under_the_tile": (1, 256, 4, 4, 32, 64, 128, False),
+    "window_is_the_tile": (1, 256, 4, 4, 32, 128, 128, False),
+    "window_over_the_tile": (1, 256, 4, 4, 32, 200, 128, False),
+    "window_of_two_tiles": (1, 384, 2, 2, 32, 256, 128, False),
+    "window_of_one": (1, 200, 2, 2, 16, 1, 64, False),
+    "window_over_the_row": (1, 200, 2, 2, 16, 500, 64, False),
+    "ragged_rows": (2, 200, 6, 1, 32, 64, 128, False),
+    "group_of_6": (1, 256, 6, 1, 128, None, 128, False),
+    "group_of_6_window": (1, 256, 6, 1, 128, 64, 128, False),
+    "group_of_8_window": (1, 256, 8, 1, 128, 128, 128, False),
+    "group_of_6_of_2": (1, 256, 12, 2, 128, None, 128, False),
+    "group_of_3_narrow_heads": (2, 150, 6, 2, 16, None, 64, False),
+    "packed_window": (2, 256, 4, 4, 32, 100, 128, True),
+    "packed_window_groups": (1, 256, 6, 2, 16, 100, 64, True),
+}
+
+
+def _window_inputs(case):
+    b, t, h, g, d, window, tile, packed = _WINDOW_CASES[case]
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(keys[0], (b, t, h, d))
+    k = jax.random.normal(keys[1], (b, t, g, d))
+    v = jax.random.normal(keys[2], (b, t, g, d))
+    w = jax.random.normal(keys[3], (b, t, h, d))
+    seg = None
+    if packed:
+        seg = jnp.asarray(np.sort(
+            np.random.RandomState(0).randint(1, 4, (b, t)), axis=1))
+    kw = dict(segment_ids=seg, window=window)
+    return (q, k, v, w), dict(kw, block_q=tile, block_k=tile), kw
+
+
+@pytest.mark.parametrize("case", list(_WINDOW_CASES))
+def test_flash_attention_window_and_groups(case):
+    """Both kernels against ``full_attention``: the output and dq, dk, dv
+    (dk and dv the sums over a group's query heads)."""
+    (q, k, v, w), flash_kw, full_kw = _window_inputs(case)
+    got = flash_attention(q, k, v, True, **flash_kw)
+    want = full_attention(q, k, v, True, **full_kw)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    g_got = jax.grad(lambda *a: jnp.sum(
+        flash_attention(*a, True, **flash_kw) * w), (0, 1, 2))(q, k, v)
+    g_want = jax.grad(lambda *a: jnp.sum(
+        full_attention(*a, True, **full_kw) * w), (0, 1, 2))(q, k, v)
+    for name, a, b_ in zip("dq dk dv".split(), g_got, g_want):
+        assert a.shape == b_.shape, name
+        np.testing.assert_allclose(a, b_, atol=5e-5, rtol=5e-5, err_msg=name)
+
+
+def test_a_window_is_not_the_whole_triangle():
+    """The comparison above would pass a kernel and a reference that both
+    ignored the window: the window moves the output."""
+    (q, k, v, _), flash_kw, _ = _window_inputs("window_under_the_tile")
+    windowed = flash_attention(q, k, v, True, **flash_kw)
+    whole = flash_attention(q, k, v, True, **dict(flash_kw, window=None))
+    np.testing.assert_array_equal(windowed[:, :64], whole[:, :64])
+    assert float(jnp.max(jnp.abs(windowed[:, 64:] - whole[:, 64:]))) > 0.1
+
+
+def test_grouped_heads_read_consecutive_query_heads():
+    """Query head h reads key/value head h // group, not h % G."""
+    (q, k, v, _), flash_kw, _ = _window_inputs("group_of_6_of_2")
+    got = flash_attention(q, k, v, True, **flash_kw)
+    repeated = flash_attention(
+        q, jnp.repeat(k, 6, axis=2), jnp.repeat(v, 6, axis=2), True,
+        **flash_kw)
+    np.testing.assert_allclose(got, repeated, atol=1e-6)
+    tiled = flash_attention(
+        q, jnp.tile(k, (1, 1, 6, 1)), jnp.tile(v, (1, 1, 6, 1)), True,
+        **flash_kw)
+    assert float(jnp.max(jnp.abs(got - tiled))) > 0.1
+
+
+def test_a_window_needs_the_causal_mask():
+    q = jnp.zeros((1, 64, 2, 32))
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, q, q, False, window=16)
+    with pytest.raises(ValueError, match="divides"):
+        flash_attention(jnp.zeros((1, 64, 3, 32)), q, q, True)
+
+
+@pytest.mark.parametrize("t, window, want", [
+    (8192, None, 136), (8192, 512, 31), (8192, 513, 31), (8192, 514, 45),
+    (8192, 1024, 45), (4096, 512, 15), (1024, 512, 3), (300, 64, 1),
+    (100, 512, 1)])
+def test_tiles_a_head_visits(t, window, want):
+    """The count the ``model.attn.tiles_per_head`` gauge gives: tiles of
+    512 (``_tile_edge``), a Q block's walk from the diagonal to the last
+    tile its window touches."""
+    assert pallas_kernels.flash_tiles_per_head(t, True, window) == want
+
+
+# sha256 of the bytes of (out, dq, dk, dv), of seeded inputs, computed by
+# the kernels as they were before they took a window and grouped heads
+# (commit 8df907a, interpreted on the CPU): the causal, equal-heads case
+# is the same arithmetic in the same order.
+_BEFORE = {
+    "dense": ((2, 256, 4, 32), jnp.float32, False, "f9f535415fbb2a6a"),
+    "packed_ragged": ((2, 200, 4, 32), jnp.float32, True,
+                      "10fe4e5a66eb3bd4"),
+    "bf16_head_of_128": ((1, 256, 2, 128), jnp.bfloat16, False,
+                         "730a473a8a198dfb"),
+}
+
+
+def _digest(*arrays):
+    import hashlib
+
+    h = hashlib.sha256()
+    for a in arrays:
+        h.update(np.asarray(a).tobytes())
+    return h.hexdigest()[:16]
+
+
+@pytest.mark.parametrize("case", list(_BEFORE))
+def test_equal_heads_causal_case_is_bit_equal_to_what_it_was(case):
+    shape, dtype, packed, want = _BEFORE[case]
+    b, t, _, _ = shape
+    q, k, v, w = (
+        jax.random.normal(key, shape, jnp.float32).astype(dtype)
+        for key in jax.random.split(jax.random.PRNGKey(42), 4))
+    seg = None
+    if packed:
+        seg = jnp.asarray(np.sort(
+            np.random.RandomState(3).randint(1, 4, (b, t)), axis=1))
+
+    def f(q, k, v):
+        return flash_attention(q, k, v, True, block_q=128, block_k=128,
+                               segment_ids=seg)
+
+    grads = jax.grad(lambda *a: jnp.sum(
+        f(*a).astype(jnp.float32) * w.astype(jnp.float32)), (0, 1, 2))(
+            q, k, v)
+    assert _digest(f(q, k, v), *grads) == want
+
+
+def test_fused_projection_is_bit_equal_to_what_it_was():
+    qkv = jax.random.normal(jax.random.PRNGKey(5), (2, 256, 3 * 4 * 64))
+    g = jax.grad(lambda x: jnp.sum(
+        pallas_kernels.flash_attention_qkv(x, 4, True) ** 2))(qkv)
+    assert _digest(
+        pallas_kernels.flash_attention_qkv(qkv, 4, True), g
+    ) == "7b257bd8ad5fcadc"
+
+
+@pytest.mark.parametrize("heads, d, turning", [
+    (4, 128, 64), (2, 64, 16), (4, 32, 8), (3, 128, 128)])
+def test_rope_kernel_turns_part_of_a_head(heads, d, turning):
+    """Tables narrower than half a head turn the first ``turning``
+    channels of every head, pair (i, i + turning/2), and leave the rest
+    as they were, bit for bit; the transpose turns back."""
+    b, t = 2, 70
+    x = jax.random.normal(jax.random.PRNGKey(1), (b, t, heads * d))
+    angles = jnp.arange(t)[:, None] * (
+        1.0 / 10000 ** (jnp.arange(0, turning, 2) / turning))
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    got = pallas_kernels.rope(x, cos, sin, heads).reshape(b, t, heads, d)
+    xs = x.reshape(b, t, heads, d)
+    first, second = xs[..., :turning // 2], xs[..., turning // 2:turning]
+    c, s = cos[:, None], sin[:, None]
+    np.testing.assert_allclose(
+        got[..., :turning], jnp.concatenate(
+            [first * c - second * s, second * c + first * s], axis=-1),
+        atol=1e-5)
+    np.testing.assert_array_equal(got[..., turning:], xs[..., turning:])
+    back = jax.grad(lambda a: jnp.sum(
+        pallas_kernels.rope(a, cos, sin, heads) ** 2))(x)
+    np.testing.assert_allclose(back, 2 * x, atol=1e-5)

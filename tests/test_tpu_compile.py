@@ -307,3 +307,74 @@ def test_head_norms_compile_for_the_chip(one_chip, mosaic, shape, dtype):
         result, opcode = _result_and_opcode(ln)
         if opcode in ("copy", "transpose"):
             assert f"{t},{h * d}]" not in result, ln
+
+
+@pytest.mark.parametrize("heads, kv_heads, window, packed", [
+    # laguna_xs2.ring1x8192: a sliding layer, 64 query heads over 8
+    (64, 8, 512, False),
+    # its full layers, 48 over 8
+    (48, 8, None, False),
+    # packed rows under a window
+    (64, 8, 512, True),
+])
+def test_window_and_grouped_heads_compile_for_the_chip(
+        one_chip, mosaic, heads, kv_heads, window, packed):
+    """Both kernels at the window/full-attention cell's widths, one row of
+    8192 tokens: k and v enter at their own 8 heads (no array of them at
+    the query heads' width is an operand), a grid step owns one query
+    head, and the forward's grid has no K-block axis."""
+    b, t, d = 1, 8192, 128
+    q = jax.ShapeDtypeStruct((b, t, heads, d), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, t, kv_heads, d), jnp.bfloat16,
+                              sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip)
+
+    def grads(q, k, v, w, seg):
+        def loss(q, k, v):
+            out = pallas_kernels.flash_attention(
+                q, k, v, True, segment_ids=seg if packed else None,
+                window=window)
+            return jnp.sum(out.astype(jnp.float32) * w)
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(grads).lower(q, kv, kv, q, seg).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 2
+    assert sum("flash_bwd_dq_dkv/pallas_call" in c for c in calls) == 1
+    for call in calls:
+        operands = call.split("custom-call(")[1].split(")")[0].split(", ")
+        assert len(operands) >= 3
+        constraints = call.split("operand_layout_constraints=")[1]
+        assert constraints.count(f"bf16[{b},{t},{kv_heads * d}]") >= 2
+    assert " while(" not in text
+    scores = b * heads * t * t * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < scores / 16
+
+
+@pytest.mark.parametrize("heads, turning", [(48, 64), (64, 128), (8, 64)])
+def test_rope_of_a_wide_array_fits_the_kernels_memory(
+        one_chip, mosaic, heads, turning):
+    """The window/full-attention cell's q (6144 and 8192 lanes) and k
+    (1024): a block of rows is cut to what a kernel's VMEM holds, half a
+    head turns where the tables are narrower than it."""
+    b, t, d = 1, 8192, 128
+    x = jax.ShapeDtypeStruct((b, t, heads * d), jnp.bfloat16,
+                             sharding=one_chip)
+    table = jax.ShapeDtypeStruct((t, turning // 2), jnp.float32,
+                                 sharding=one_chip)
+
+    def grad(x, w, cos, sin):
+        return jax.value_and_grad(lambda x: jnp.sum(
+            pallas_kernels.rope(x, cos, sin, heads).astype(jnp.float32)
+            * w))(x)
+
+    text = jax.jit(grad).lower(x, x, table, table).compile().as_text()
+    calls = [ln for ln in _entry(text) if "tpu_custom_call" in ln]
+    assert len(calls) == 2
+    for ln in _entry(text):  # of x, its gradient or the result
+        result, opcode = _result_and_opcode(ln)
+        assert opcode not in ("copy", "transpose") or (
+            f"{t},{heads * d}]" not in result), ln

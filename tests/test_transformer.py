@@ -564,3 +564,165 @@ def test_unknown_settings_are_errors():
                 dict(mlp="relu")):
         with pytest.raises(ValueError, match="unknown"):
             _looped(**bad).init(jax.random.PRNGKey(0), toks)
+
+
+# ------------------------------------------------ per-layer attention shapes
+def _laguna_full_rule():
+    from horovod_tpu.models.transformer import RopeRule
+
+    return RopeRule(theta=500000.0, dim=64, factor=64.0,
+                    original_max_len=4096, beta_fast=64.0, beta_slow=1.0,
+                    attention_factor=1.4158883083359672)
+
+
+def test_yarn_tables_against_hand_computed_values():
+    """Laguna-XS.2's full-attention rule: 64 channels turn, theta 500 000,
+    factor 64 over 4096 positions, beta_fast 64, beta_slow 1.  By hand:
+    low = floor(64 ln(4096 / (64 2 pi)) / (2 ln 500000)) = floor(5.66) = 5,
+    high = ceil(64 ln(4096 / (2 pi)) / (2 ln 500000)) = ceil(15.80) = 16;
+    pairs up to 5 keep their frequency, pairs from 16 on have it divided
+    by 64, pair 10 lies 5/11 of the way; cos and sin carry the factor."""
+    import math
+
+    from horovod_tpu.models.transformer import rope_tables, yarn_ramp
+
+    rule = _laguna_full_rule()
+    assert yarn_ramp(64, 500000.0, rule) == (5.0, 16.0)
+    assert rule.attention_factor == pytest.approx(0.1 * math.log(64) + 1)
+    pos = jnp.asarray([0, 1, 100, 8191])
+    cos, sin = rope_tables(pos, 64, 500000.0, rule)
+    assert cos.shape == sin.shape == (4, 32)
+    plain = [500000.0 ** (-2 * j / 64) for j in range(32)]
+    want = []
+    for j, f in enumerate(plain):
+        ramp = min(max((j - 5) / 11, 0.0), 1.0)
+        want.append(f / 64 * ramp + f * (1 - ramp))
+    assert want[5] == plain[5] and want[16] == plain[16] / 64
+    assert want[10] == pytest.approx(
+        plain[10] * (6 / 11 + 5 / 11 / 64), rel=1e-12)
+    angles = np.asarray(pos, np.float64)[:, None] * np.asarray(want)
+    np.testing.assert_allclose(
+        cos, rule.attention_factor * np.cos(angles), atol=2e-3)
+    np.testing.assert_allclose(  # float32 angles: 1e-3 at 8191 radians
+        sin, rule.attention_factor * np.sin(angles), atol=2e-3)
+    # position 0: cos is the factor itself, so a score's turning part
+    # carries its square
+    np.testing.assert_allclose(cos[0], 1.4158883, rtol=1e-6)
+    # the factor left to its default is 0.1 ln(factor) + 1
+    import dataclasses
+    again = rope_tables(pos, 64, 500000.0, dataclasses.replace(
+        rule, attention_factor=None))
+    np.testing.assert_allclose(again[0], cos, rtol=1e-6)
+    # and an unscaled rule is the plain table
+    from horovod_tpu.models.transformer import RopeRule
+    np.testing.assert_array_equal(
+        rope_tables(pos, 128, 10000.0, RopeRule())[0],
+        rope_tables(pos, 128, 10000.0)[0])
+
+
+def test_partial_rotation_leaves_the_other_channels_alone():
+    """Half of a head of 128 turns (pairs i, i + 32); channels 64-127 of
+    every head pass bit for bit, and q.k of the turned part depends on the
+    offset only."""
+    from horovod_tpu.models.transformer import apply_rope, rope_tables
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 24, 3, 128))
+    tables = rope_tables(jnp.arange(24), 64, 500000.0, _laguna_full_rule())
+    y = apply_rope(x, tables)
+    np.testing.assert_array_equal(y[..., 64:], x[..., 64:])
+    assert float(jnp.max(jnp.abs(y[:, 1:, :, :64] - x[:, 1:, :, :64]))) > 0.1
+    same = jnp.broadcast_to(x[:, :1], x.shape)  # one vector everywhere
+    t = apply_rope(same, tables)[0, :, 0, :64]
+    scores = t @ t.T
+    np.testing.assert_allclose(scores[3, 1], scores[12, 10], rtol=1e-4)
+    np.testing.assert_allclose(scores[20, 5], scores[15, 0], rtol=1e-4)
+
+
+def _mixed_config(**over):
+    from horovod_tpu.models.transformer import RopeRule
+
+    cfg = dict(
+        vocab_size=64, num_layers=3, model_dim=32, num_heads=4,
+        num_kv_heads=2, head_dim=16, ff_dim=64, max_len=64,
+        dtype=jnp.float32, norm="rmsnorm", positions="rope", use_bias=False,
+        fused_qkv=False, mlp="gated_silu", tie_head=False,
+        layer_kinds=("full", "window", "window"), window=8,
+        layer_heads=(4, 6, 6), attn_gate=True,
+        rope_rules=(
+            ("full", RopeRule(theta=100.0, dim=8, factor=8.0,
+                              original_max_len=64, beta_fast=4.0)),
+            ("window", RopeRule(theta=10000.0)),
+        ))
+    cfg.update(over)
+    return TransformerConfig(**cfg)
+
+
+def test_a_head_count_and_a_rotary_rule_per_layer():
+    from horovod_tpu import metrics
+
+    model = Transformer(_mixed_config())
+    toks = _tokens(t=32, vocab=64)
+    params = model.init(jax.random.PRNGKey(1), toks)["params"]
+    shapes = jax.tree.map(lambda a: a.shape, params)
+    for i, heads in enumerate((4, 6, 6)):
+        attn = shapes[f"block_{i}"]["attn"]
+        assert attn["q"]["Dense_0"]["kernel"] == (32, heads * 16)
+        assert attn["k"]["Dense_0"]["kernel"] == (32, 2 * 16)
+        assert attn["v"]["Dense_0"]["kernel"] == (32, 2 * 16)
+        assert attn["proj"]["Dense_0"]["kernel"] == (heads * 16, 32)
+        assert attn["gate"]["kernel"] == (32, heads)
+    logits, _ = model.apply({"params": params}, toks)
+    assert np.isfinite(np.asarray(logits)).all()
+    for kind, (count, groups, tiles) in {
+            "full": (1, 2, 1), "window": (2, 3, 1)}.items():
+        labels = {"kind": kind}
+        assert metrics.get_gauge("model.layer_kinds", labels) == count
+        assert metrics.get_gauge("model.attn.kv_groups", labels) == groups
+        assert metrics.get_gauge(
+            "model.attn.tiles_per_head", labels) == tiles
+    # the flash kernels and materialised scores agree, gradients too
+    plain = Transformer(_mixed_config(attn_impl="full"))
+
+    def loss(net):
+        return lambda p: jnp.sum(
+            net.apply({"params": p}, toks)[0] ** 2) / toks.size
+
+    got, g_got = jax.value_and_grad(loss(model))(params)
+    want, g_want = jax.value_and_grad(loss(plain))(params)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-3)
+    # the window, the gate and the rules each move the output
+    ungated = {name: dict(block, attn={
+        k: v for k, v in block["attn"].items() if k != "gate"})
+        if name.startswith("block_") else block
+        for name, block in params.items()}
+    for change, p in ((dict(window=32), params), (dict(rope_rules=()), params),
+                      (dict(attn_gate=False), ungated)):
+        moved = Transformer(_mixed_config(**change)).apply(
+            {"params": p}, toks)[0]
+        assert float(jnp.max(jnp.abs(moved - logits))) > 1e-3, change
+
+
+def test_param_shard_axes_follow_the_layers_heads():
+    from horovod_tpu.models.transformer import param_shard_axes
+
+    cfg = _mixed_config()
+    params = Transformer(cfg).init(
+        jax.random.PRNGKey(0), _tokens(t=16, vocab=64))
+    axes = param_shard_axes(params, cfg)["params"]
+    for i in range(3):
+        attn = axes[f"block_{i}"]["attn"]
+        for name in ("q", "k", "v", "proj"):
+            assert attn[name]["Dense_0"]["kernel"] == cfg.tp_axis
+        assert attn["gate"]["kernel"] == ""
+
+
+def test_settings_the_grouped_layers_refuse():
+    toks = _tokens(t=16, vocab=64)
+    for change, match in ((dict(fused_qkv=True), "separate"),
+                          (dict(num_kv_heads=3), "divide"),
+                          (dict(layer_heads=(4, 6)), "layer_heads")):
+        model = Transformer(_mixed_config(**change))
+        with pytest.raises(ValueError, match=match):
+            model.init(jax.random.PRNGKey(0), toks)
