@@ -813,6 +813,10 @@ class Transformer(nn.Module):
         if loads:
             held = cfg.experts_held[1] - cfg.experts_held[0]
             metrics.set_gauge("model.moe.experts_held", held)
+            # the expert layers whose grouped product ran as
+            # ops/expert_kernels' pair: all of them (a width the chip's
+            # kernels do not take is refused where the layer is traced)
+            metrics.set_gauge("model.moe.kernel_layers", len(loads))
             # what the batch really asked of the held experts: read from
             # the step's outputs when they are there (metrics.trace_gauge)
             loads = lax.stop_gradient(jnp.stack(loads))

@@ -24,6 +24,7 @@ import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from ..ops import expert_kernels
 from .mesh import EP_AXIS
 from .tensor import TensorParallelMLP, _axis_present
 
@@ -209,7 +210,7 @@ def route_group_limited(scores: jax.Array, bias: jax.Array, k: int,
 
 
 # Rows of a grouped product's tile.  A tile costs much the same whatever
-# it holds (its expert's matrices sliced, their gradients' rows read and
+# it holds (its expert's matrices read, their gradients' blocks read and
 # written), so the loop's time goes by the number of tiles: at 256 rows a
 # lightly loaded expert is one tile and only a heavy one is more, and the
 # step's time moves less with the router's draw than at 128 (PERF.md, PR 32).
@@ -253,110 +254,114 @@ def _tile_rows(j, plan, k: int, tile: int):
     return e, pair // k, valid, pair
 
 
-def _swiglu_tile(xt, e, wg, wu):
-    a = jnp.dot(xt, lax.dynamic_index_in_dim(wg, e, keepdims=False),
-                preferred_element_type=jnp.float32)
-    u = jnp.dot(xt, lax.dynamic_index_in_dim(wu, e, keepdims=False),
-                preferred_element_type=jnp.float32)
-    return a, u
+def _real_rows(j, plan, flat_w, k: int, tile: int):
+    """Tile ``j`` as the rows XLA moves around its kernel: (expert; whether
+    the tile is its expert's first; where each row lies in [S, d], a
+    padding row past the end, so that a gather fills it with zeros and a
+    scatter drops it; where its pair lies in [S * k], likewise; the rows'
+    routing weights [tile, 1], 0 on padding)."""
+    e, token, valid, pair = _tile_rows(j, plan, k, tile)
+    past = jnp.arange(tile, dtype=jnp.int32) + flat_w.shape[0]
+    first = j == plan[3][e] - plan[4][e]
+    return (e, first, jnp.where(valid, token, past),
+            jnp.where(valid, pair, past),
+            jnp.where(valid, flat_w[pair], 0.0)[:, None])
+
+
+def _slabs(a):
+    """[S, d] as [S, d / 128, 128] where d is whole 128-lane slabs: under
+    the (8, 128) tiling a token's row is then tiles of its own (whole ones
+    where d is a multiple of 1024), and a scatter-add of rows adds tiles
+    where on [S, d] it picks a sublane out of eight tokens' tiles: 47 us
+    for 126 at 512 rows of 2048 (PERF.md, PR 35)."""
+    lanes = expert_kernels.LANES
+    return a.reshape(a.shape[0], -1, lanes) if a.shape[1] % lanes == 0 else a
+
+
+def _take(x, rows):
+    """Rows of ``_slabs(x)`` as [tile, d]."""
+    return x.at[rows].get(mode="fill", fill_value=0).reshape(
+        rows.shape[0], -1)
+
+
+def _add(total, rows, part):
+    """``part`` [tile, d] added to rows of ``total``, ``_slabs``' layout
+    or scalars."""
+    return total.at[rows].add(
+        part.reshape(part.shape[:1] + total.shape[1:]), mode="drop")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def grouped_experts(x, weights, wg, wu, wd, local, tile=_TILE):
     """sum over the pairs on held experts of ``w * E_e(x_token)``: x
-    [S, d], weights [S, k] float32, the held experts' SwiGLU matrices wg,
-    wu [n, d, f] and wd [n, f, d] in the compute type, ``local`` [S, k]
-    int32 (``_plan``).  Returns [S, d] float32.
+    [S, d] in the compute type, weights [S, k] float32, the held experts'
+    SwiGLU matrices wg, wu [n, d, f] and wd [n, f, d] **as the parameters
+    are, float32**, ``local`` [S, k] int32 (``_plan``).  Returns [S, d]
+    float32.
 
     The pairs are sorted by expert and multiplied tile by tile, a tile of
-    ``tile`` rows of one expert (``tile_rows``); the loop runs over the tiles the batch
-    really produced (a dynamic trip count), so the work follows the load:
-    there is no capacity, no [S, E, C] tensor and no buffer of the worst
-    case's size, and the static bound is every pair."""
+    ``tile`` rows of one expert (``tile_rows``) by one call of
+    ``ops/expert_kernels``' kernel, which reads the expert's matrices where
+    they lie and casts them block by block in VMEM; the loop runs over the
+    tiles the batch really produced (a dynamic trip count), so the work
+    follows the load: there is no capacity, no [S, E, C] tensor and no
+    buffer of the worst case's size, and the static bound is every pair.
+    XLA moves a tile's real rows only: a padding row is gathered as zeros
+    and its product is dropped.  A token may name an expert twice."""
     return _grouped_fwd(x, weights, wg, wu, wd, local, tile)[0]
 
 
 def _grouped_fwd(x, weights, wg, wu, wd, local, tile):
     k = local.shape[1]
+    if not expert_kernels.takes(x.shape[1], wg.shape[2]):
+        raise ValueError(
+            f"experts {x.shape[1]} wide with {wg.shape[2]} hidden channels: "
+            "the chip's kernels take whole 128-lane slabs of both")
+    flat_w = weights.reshape(-1)
     with jax.named_scope("dispatch"):
         plan = _plan(local, wg.shape[0], tile)
-    flat_w = weights.reshape(-1)
+    slabs = _slabs(x)
 
     def one_tile(j, out):
         with jax.named_scope("dispatch"):
-            e, token, valid, pair = _tile_rows(j, plan, k, tile)
-            xt = x[token]
+            e, _, rows, _, w = _real_rows(j, plan, flat_w, k, tile)
+            xt = _take(slabs, rows)
         with jax.named_scope("experts"):
-            a, u = _swiglu_tile(xt, e, wg, wu)
-            h = (jax.nn.silu(a) * u).astype(x.dtype)
-            y = jnp.dot(h, lax.dynamic_index_in_dim(wd, e, keepdims=False),
-                        preferred_element_type=jnp.float32)
+            y = expert_kernels.tile_forward(e, xt, w, wg, wu, wd)
         with jax.named_scope("combine"):
-            w = jnp.where(valid, flat_w[pair], 0.0)
-            return out.at[token].add(y * w[:, None])
+            return _add(out, rows, y)
 
     out = lax.fori_loop(
-        0, plan[3][-1], one_tile, jnp.zeros(x.shape, jnp.float32))
-    return out, (x, weights, wg, wu, wd, local, plan)
+        0, plan[3][-1], one_tile, jnp.zeros(slabs.shape, jnp.float32))
+    return out.reshape(x.shape), (x, weights, wg, wu, wd, local, plan)
 
 
 def _grouped_bwd(tile, res, dout):
     x, weights, wg, wu, wd, local, plan = res
     k = local.shape[1]
     flat_w = weights.reshape(-1)
-    dout = dout.astype(jnp.float32)
+    slabs, dslabs = _slabs(x), _slabs(dout.astype(x.dtype))
 
     def one_tile(j, carry):
-        dx, dw, dwg, dwu, dwd = carry
+        dx, dw, grads = carry
         with jax.named_scope("dispatch"):
-            e, token, valid, pair = _tile_rows(j, plan, k, tile)
-            xt = x[token]
-            dyt = dout[token]
+            e, first, rows, pairs, w = _real_rows(j, plan, flat_w, k, tile)
+            xt, dyt = _take(slabs, rows), _take(dslabs, rows)
         with jax.named_scope("experts"):
-            a, u = _swiglu_tile(xt, e, wg, wu)
-            sig = jax.nn.sigmoid(a)
-            act = a * sig
-            h = (act * u).astype(x.dtype)
-            wd_e = lax.dynamic_index_in_dim(wd, e, keepdims=False)
-            y = jnp.dot(h, wd_e, preferred_element_type=jnp.float32)
-            w = jnp.where(valid, flat_w[pair], 0.0)
-            dy = (dyt * w[:, None]).astype(x.dtype)
-            dh = lax.dot_general(dy, wd_e, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-            da = (dh * u * (sig + act * (1.0 - sig))).astype(x.dtype)
-            du = (dh * act).astype(x.dtype)
-            dxt = (
-                lax.dot_general(
-                    da, lax.dynamic_index_in_dim(wg, e, keepdims=False),
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                + lax.dot_general(
-                    du, lax.dynamic_index_in_dim(wu, e, keepdims=False),
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32))
-
-            def add(total, left, right):
-                part = lax.dot_general(
-                    left, right, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                return lax.dynamic_update_index_in_dim(
-                    total, lax.dynamic_index_in_dim(
-                        total, e, keepdims=False) + part, e, 0)
-
-            dwg, dwu, dwd = add(dwg, xt, da), add(dwu, xt, du), add(dwd, h, dy)
+            dxt, dwt, *grads = expert_kernels.tile_backward(
+                e, first, xt, dyt, w, wg, wu, wd, *grads)
         with jax.named_scope("combine"):
-            dw = dw.at[pair].add(
-                jnp.where(valid, jnp.sum(dyt * y, axis=-1), 0.0))
-            dx = dx.at[token].add(dxt)
-        return dx, dw, dwg, dwu, dwd
+            return (_add(dx, rows, dxt), _add(dw, pairs, dwt[:, 0]),
+                    tuple(grads))
 
     zeros = lambda like: jnp.zeros(like.shape, jnp.float32)
-    dx, dw, dwg, dwu, dwd = lax.fori_loop(
+    dx, dw, grads = lax.fori_loop(
         0, plan[3][-1], one_tile,
-        (zeros(x), zeros(flat_w), zeros(wg), zeros(wu), zeros(wd)))
-    return (dx.astype(x.dtype), dw.reshape(weights.shape).astype(
-        weights.dtype), dwg.astype(wg.dtype), dwu.astype(wu.dtype),
-        dwd.astype(wd.dtype), np.zeros(local.shape, jax.dtypes.float0))
+        (zeros(slabs), zeros(flat_w), (zeros(wg), zeros(wu), zeros(wd))))
+    return (dx.reshape(x.shape).astype(x.dtype),
+            dw.reshape(weights.shape).astype(weights.dtype),
+            *(g.astype(m.dtype) for g, m in zip(grads, (wg, wu, wd))),
+            np.zeros(local.shape, jax.dtypes.float0))
 
 
 grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)
@@ -413,9 +418,10 @@ class ExpertFFN(nn.Module):
             load = jnp.sum(
                 local[..., None] == jnp.arange(n_held), axis=(0, 1))
 
+        # float32, as they are: the tile's kernel casts the block it reads
         def experts(name, shape):
             return self.param(name, nn.initializers.lecun_normal(
-                in_axis=-2, out_axis=-1), shape, jnp.float32).astype(dtype)
+                in_axis=-2, out_axis=-1), shape, jnp.float32)
 
         wg = experts("wg", (n_held, d, self.hidden))
         wu = experts("wi", (n_held, d, self.hidden))

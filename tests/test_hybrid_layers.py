@@ -367,12 +367,10 @@ def _dense_loop(x, weights, wg, wu, wd, local):
     return out
 
 
-@pytest.mark.parametrize("load", ["skewed", "even", "none_held"])
-def test_dropless_dispatch_is_the_dense_loop(load):
-    """600 tokens x 3 choices over 4 held experts (index 4: another
-    device's).  Skewed: expert 1 takes most pairs, more than two tiles,
-    expert 2 takes none; no pair is lost."""
-    s, k, d, f, n = 600, 3, 16, 24, 4
+def _dispatch_case(load, s=600, k=3, d=16, f=24, n=4, dtype=jnp.float32):
+    """(x, weights, wg, wu, wd, local, cotangent): ``s`` tokens x 3 choices
+    over 4 held experts (index 4: another device's).  Skewed: expert 1
+    takes most pairs, more than two tiles, expert 2 takes none."""
     keys = jax.random.split(jax.random.PRNGKey(0), 6)
     if load == "skewed":
         local = jnp.stack([
@@ -386,26 +384,165 @@ def test_dropless_dispatch_is_the_dense_loop(load):
                            for k_ in jax.random.split(keys[5], s)])
     else:
         local = jnp.full((s, k), 4)
-    local = local.astype(jnp.int32)
-    x = jax.random.normal(keys[0], (s, d))
-    weights = jax.random.uniform(keys[1], (s, k))
-    wg, wu = (jax.random.normal(kk, (n, d, f)) / 4 for kk in keys[2:4])
-    wd = jax.random.normal(keys[4], (n, f, d)) / 5
+    x = jax.random.normal(keys[0], (s, d)).astype(dtype)
+    weights = jax.random.uniform(keys[1], (s, k), minval=0.1)
+    wg, wu = (jax.random.normal(kk, (n, d, f)) / d ** 0.5 for kk in keys[2:4])
+    wd = jax.random.normal(keys[4], (n, f, d)) / f ** 0.5
     cot = jax.random.normal(jax.random.PRNGKey(7), (s, d))
+    return x, weights, wg, wu, wd, local.astype(jnp.int32), cot
+
+
+@pytest.mark.parametrize("load, d, f", [
+    ("skewed", 16, 24), ("even", 16, 24), ("none_held", 16, 24),
+    # a block shape the chip uses: d and f whole 128-lane slabs, three
+    # blocks of f a tile
+    ("skewed", 128, 384), ("none_held", 128, 384)])
+def test_dropless_dispatch_is_the_dense_loop(load, d, f):
+    """The kernel pair (interpreted here), a call a tile, against every
+    token through every expert: the output and all five gradients, those
+    of the float32 matrices float32; no pair is lost."""
+    from horovod_tpu.ops import expert_kernels
+
+    assert f // expert_kernels._f_block(f) == (3 if f == 384 else 1)
+    *args, local, cot = _dispatch_case(load, d=d, f=f)
     with jax.default_matmul_precision("highest"):
-        got = moe.grouped_experts(x, weights, wg, wu, wd, local)
-        want = _dense_loop(x, weights, wg, wu, wd, local)
+        got = moe.grouped_experts(*args, local)
+        want = _dense_loop(*args, local)
         g_got = jax.grad(lambda *a: jnp.sum(
-            moe.grouped_experts(*a, local) * cot), argnums=range(5))(
-                x, weights, wg, wu, wd)
+            moe.grouped_experts(*a, local) * cot), argnums=range(5))(*args)
         g_want = jax.grad(lambda *a: jnp.sum(
-            _dense_loop(*a, local) * cot), argnums=range(5))(
-                x, weights, wg, wu, wd)
+            _dense_loop(*a, local) * cot), argnums=range(5))(*args)
     assert _max_rel(got, want, 1.0) <= 1e-5
     for name, a, w in zip("x weights wg wu wd".split(), g_got, g_want):
+        assert a.dtype == jnp.float32, name
         assert _max_rel(a, w, 1.0) <= 1e-5, name
     if load == "none_held":
         assert not np.any(got)
+        assert not any(np.any(g) for g in g_got)
+
+
+def test_no_bfloat16_between_the_tiles_and_a_float32_parameter():
+    """Rows in bfloat16, the experts' matrices float32 as the parameters
+    are: their gradients come back float32 with more than bfloat16's bits
+    (the float32 sums of a tile's products, never rounded on the way), for
+    every expert a pair reached, and zeros for the one none did."""
+    *args, local, cot = _dispatch_case("skewed", dtype=jnp.bfloat16)
+    grads = jax.grad(lambda *a: jnp.sum(
+        moe.grouped_experts(*a, local) * cot), argnums=(2, 3, 4))(*args)
+    for g in grads:
+        assert g.dtype == jnp.float32
+        rounded = g.astype(jnp.bfloat16).astype(jnp.float32)
+        for e in (0, 1, 3):
+            assert float(jnp.mean(g[e] != rounded[e])) > 0.9, e
+        assert not np.any(g[2])
+    # and they are the float32 program's to bfloat16's rounding of the rows
+    want = jax.grad(lambda *a: jnp.sum(
+        _dense_loop(*a, local) * cot), argnums=(2, 3, 4))(
+            args[0].astype(jnp.float32), *args[1:])
+    for g, w in zip(grads, want):
+        assert _max_rel(g, w, 1.0) <= 3e-2
+
+
+def test_padding_rows_are_not_scattered(monkeypatch):
+    """A tile's rows past its expert's pairs are gathered as zeros and
+    dropped on the way back: a NaN planted in every such row of the
+    kernels' results (the rows whose routing weight is 0; the case's real
+    weights are 0.1 and more) reaches neither the output nor a gradient
+    of x or of the routing weights."""
+    from horovod_tpu.ops import expert_kernels
+
+    forward, backward = (
+        expert_kernels.tile_forward, expert_kernels.tile_backward)
+    planted = []
+
+    def nan_rows(w, rows):
+        return jnp.where(w == 0.0, jnp.nan, rows)
+
+    def tile_forward(e, x, w, *matrices):
+        planted.append("forward")
+        return nan_rows(w, forward(e, x, w, *matrices))
+
+    def tile_backward(e, first, x, dy, w, *matrices):
+        planted.append("backward")
+        dx, dw, *grads = backward(e, first, x, dy, w, *matrices)
+        return (nan_rows(w, dx), nan_rows(w, dw), *grads)
+
+    monkeypatch.setattr(expert_kernels, "tile_forward", tile_forward)
+    monkeypatch.setattr(expert_kernels, "tile_backward", tile_backward)
+    *args, local, cot = _dispatch_case("skewed")
+    with jax.default_matmul_precision("highest"):
+        got, grads = jax.value_and_grad(lambda *a: jnp.sum(
+            moe.grouped_experts(*a, local) * cot), argnums=range(5))(*args)
+        want = jnp.sum(_dense_loop(*args, local) * cot)
+    assert set(planted) == {"forward", "backward"}
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for g in grads:
+        assert np.isfinite(np.asarray(g)).all()
+
+
+def test_an_experts_first_tile_writes_its_sums_and_a_later_one_adds():
+    """The backward kernel on one tile: where the tile is its expert's
+    first, the expert's blocks of the accumulators are written whatever
+    they held (NaN here); a later tile fetches them and adds; no other
+    expert's block is touched either way."""
+    from horovod_tpu.ops import expert_kernels
+
+    tile, d, f, n, e = 256, 128, 256, 3, 1
+    keys = jax.random.split(jax.random.PRNGKey(5), 6)
+    x, dy = (jax.random.normal(k_, (tile, d)) for k_ in keys[:2])
+    w = jax.random.uniform(keys[2], (tile, 1), minval=0.1)
+    wg, wu = (jax.random.normal(k_, (n, d, f)) / d ** 0.5 for k_ in keys[3:5])
+    wd = jax.random.normal(keys[5], (n, f, d)) / f ** 0.5
+    assert f // expert_kernels._f_block(f) == 2
+
+    def tile_sums(first, start):
+        held = [jnp.full(m.shape, start) for m in (wg, wu, wd)]
+        with jax.default_matmul_precision("highest"):
+            return expert_kernels.tile_backward(
+                jnp.int32(e), jnp.asarray(first), x, dy, w, wg, wu, wd,
+                *held)[2:]
+
+    def plain(wg_e, wu_e, wd_e):
+        return jnp.sum(w * ((jax.nn.silu(x @ wg_e) * (x @ wu_e)) @ wd_e) * dy)
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(plain, argnums=(0, 1, 2))(wg[e], wu[e], wd[e])
+    for got, later, exact in zip(
+            tile_sums(True, jnp.nan), tile_sums(False, 1.0), want):
+        assert _max_rel(got[e], exact, 1.0) <= 1e-5
+        assert _max_rel(later[e], exact + 1.0, 1.0) <= 1e-5
+        for other in (0, 2):
+            assert np.isnan(np.asarray(got[other])).all()
+            assert np.all(np.asarray(later[other]) == 1.0)
+
+
+def test_a_token_may_name_one_expert_twice():
+    """``lax.top_k`` gives a token distinct experts, a direct caller's
+    ``local`` need not: two choices of one token on one expert are two
+    rows of its tile, both summed (the scatter-add is given no hint about
+    its indices)."""
+    *args, local, cot = _dispatch_case("even")
+    local = local.at[:, 1].set(local[:, 0])
+    assert int(jnp.sum(local[:, 0] < 4)) > 300
+    with jax.default_matmul_precision("highest"):
+        got, g_got = jax.value_and_grad(lambda *a: jnp.sum(
+            moe.grouped_experts(*a, local) * cot), argnums=range(5))(*args)
+        want, g_want = jax.value_and_grad(lambda *a: jnp.sum(
+            _dense_loop(*a, local) * cot), argnums=range(5))(*args)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for a, w in zip(g_got, g_want):
+        assert _max_rel(a, w, 1.0) <= 1e-5
+
+
+def test_a_width_the_chips_expert_kernels_do_not_take_is_refused(
+        monkeypatch):
+    from horovod_tpu.ops import expert_kernels, pallas_kernels
+
+    *args, local, _ = _dispatch_case("even", s=8)
+    monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
+    assert expert_kernels.takes(2048, 512) and expert_kernels.takes(2560, 768)
+    with pytest.raises(ValueError, match="whole 128-lane slabs"):
+        moe.grouped_experts(*args, local)
 
 
 def _expert_layer(experts_held, total=64):
@@ -498,6 +635,48 @@ def test_layer_kinds_choose_mixer_and_ffn_and_the_gauges_say_so():
     old = dataclasses.replace(cfg, layer_kinds=(), ffn_kinds=(), moe_every=2)
     assert [transformer.layer_kind(old, i) for i in range(3)] == [
         ("full", "dense"), ("full", "moe"), ("full", "dense")]
+
+
+@pytest.mark.parametrize("family, layers", [
+    ("hybrid", 2), ("swa", 3), ("dense", None)])
+def test_the_gauge_counts_the_layers_whose_product_took_the_kernels(
+        family, layers):
+    """Stand-ins of the two families with routed experts (delta rule and
+    latent attention; window and full attention over grouped heads): every
+    expert layer's grouped product runs as ``ops/expert_kernels``' pair,
+    and ``model.moe.kernel_layers`` says so; a dense model sets no such
+    gauge."""
+    from horovod_tpu import metrics
+
+    experts = dict(num_experts=8, experts_held=(0, 4), expert_ff_dim=12,
+                   experts_per_token=2, n_group=2, topk_group=1)
+    cfg = {
+        "hybrid": lambda: _mla_config(
+            num_layers=3, layer_kinds=("kda", "kda", "mla"),
+            ffn_kinds=("dense", "experts", "experts"), **experts),
+        "swa": lambda: dataclasses.replace(
+            _mla_config(), layer_kinds=("full", "window", "window", "full"),
+            num_layers=4, window=8, num_kv_heads=1,
+            ffn_kinds=("dense", "experts", "experts", "experts"), **experts),
+        "dense": lambda: dataclasses.replace(
+            _mla_config(), layer_kinds=("full",)),
+    }[family]()
+    model = transformer.Transformer(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 20), 0, 64)
+    params = model.init(jax.random.PRNGKey(1), tokens)["params"]
+    metrics.clear_gauge("model.moe.kernel_layers")
+    calls = []
+    forward = moe.expert_kernels.tile_forward
+    try:
+        moe.expert_kernels.tile_forward = lambda *a: (
+            calls.append(1), forward(*a))[1]
+        with metrics.traced_gauges():
+            logits, _ = model.apply({"params": params}, tokens)
+    finally:
+        moe.expert_kernels.tile_forward = forward
+    assert np.isfinite(np.asarray(logits)).all()
+    assert metrics.get_gauge("model.moe.kernel_layers") == layers
+    assert len(calls) == (layers or 0)      # a loop's body a layer
 
 
 def _equations(jaxpr):

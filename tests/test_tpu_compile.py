@@ -378,3 +378,50 @@ def test_rope_of_a_wide_array_fits_the_kernels_memory(
         result, opcode = _result_and_opcode(ln)
         assert opcode not in ("copy", "transpose") or (
             f"{t},{heads * d}]" not in result), ln
+
+
+@pytest.mark.parametrize("tokens, router, held, d, f", [
+    # laguna_xs2.ring1x8192: 32 of 256 experts held, tiles of 512 rows
+    (8192, 256, 32, 2048, 512),
+    # ling3flash.ring1x4096: 8 of 512, tiles of 256 rows
+    (4096, 512, 8, 2560, 768),
+])
+def test_routed_experts_tile_compiles_for_the_chip(
+        one_chip, mosaic, tokens, router, held, d, f):
+    """The grouped product's kernel pair (``ops/expert_kernels.py``) at
+    the cells' widths, rows in bfloat16 and the experts' matrices float32
+    as the parameters are: a gradient is two loops of one Mosaic call
+    each; no whole ``[n, d, f]`` matrix is converted or copied on the way
+    in (the kernel casts the block it reads) or out (the float32 sums are
+    the gradients), and the loops' carried sums are updated in place."""
+    from horovod_tpu.ops import expert_kernels
+    from horovod_tpu.parallel import moe
+
+    k = 8
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    up, down = sds((held, d, f), jnp.float32), sds((held, f, d), jnp.float32)
+    assert expert_kernels.takes(d, f) and not expert_kernels.takes(d, 24)
+    tile = moe.tile_rows(tokens * k / router)
+
+    def grads(x, weights, wg, wu, wd, local, cot):
+        return jax.value_and_grad(lambda *a: jnp.sum(moe.grouped_experts(
+            *a, local, tile) * cot), argnums=range(5))(x, weights, wg, wu, wd)
+
+    compiled = jax.jit(grads).lower(
+        sds((tokens, d), jnp.bfloat16), sds((tokens, k), jnp.float32),
+        up, up, down, sds((tokens, k), jnp.int32),
+        sds((tokens, d), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert len([ln for ln in text.splitlines()
+                if "tpu_custom_call" in ln]) == 2
+    assert len([ln for ln in text.splitlines() if " while(" in ln]) == 2
+    whole = (f"[{held},{d},{f}]", f"[{held},{f},{d}]")
+    for ln in text.splitlines():
+        if " = " not in ln:
+            continue
+        result, opcode = _result_and_opcode(ln)
+        if opcode in ("copy", "convert", "transpose"):
+            assert not any(shape in result for shape in whole), ln
+    # the three gradients (results) and nothing else of their size
+    assert compiled.memory_analysis().temp_size_in_bytes < held * d * f * 4
