@@ -78,30 +78,49 @@ def pack_batches(
 ):
     """Yield ``(tokens, segment_ids)`` batches of shape
     ``(batch_size, seq_len)`` from a document stream (static shapes for
-    jit).  Rows pack greedily within a window of documents."""
-    window: List[np.ndarray] = []
-    # Pack in windows big enough to fill ~2 batches so first-fit has
-    # material to work with, then emit full batches.
-    rows_t: List[np.ndarray] = []
+    jit).  Rows pack greedily within a window of documents.
+
+    The packer's own work on a window — :func:`pack_documents` and the
+    stacking of the full batches its rows complete — is one
+    ``pack_window`` span (``hvd_pack_window`` in a profile, on the
+    thread that packs; docs/tracing.md).  It is closed before the
+    batches are yielded, so neither the pull of a document from
+    ``docs`` nor the time the generator stands suspended is in it."""
+    from .. import trace
+
+    rows_t: List[np.ndarray] = []  # packed rows no batch has taken yet
     rows_s: List[np.ndarray] = []
-    for d in docs:
-        window.append(np.asarray(d).reshape(-1))
-        if sum(len(w) for w in window) >= 2 * batch_size * seq_len:
+
+    def pack_window(window, tokens) -> List[Tuple[np.ndarray, np.ndarray]]:
+        """Pack ``window`` behind the rows left over and cut off the
+        full batches."""
+        with trace.span(
+            "pack_window", "input", docs=len(window), tokens=tokens,
+        ) as span:
             t, s = pack_documents(window, seq_len, pad_id)
+            if span is not None:  # known only now; None at level off
+                span.attrs["rows"] = len(t)
             rows_t.extend(t)
             rows_s.extend(s)
-            window = []
-        while len(rows_t) >= batch_size:
-            yield (np.stack(rows_t[:batch_size]),
-                   np.stack(rows_s[:batch_size]))
-            rows_t, rows_s = rows_t[batch_size:], rows_s[batch_size:]
+            batches = []
+            while len(rows_t) >= batch_size:
+                batches.append((np.stack(rows_t[:batch_size]),
+                                np.stack(rows_s[:batch_size])))
+                del rows_t[:batch_size], rows_s[:batch_size]
+        return batches
+
+    # Pack in windows big enough to fill ~2 batches so first-fit has
+    # material to work with, then emit full batches.
+    window: List[np.ndarray] = []
+    held = 0  # tokens of the window's documents
+    for d in docs:
+        window.append(np.asarray(d).reshape(-1))
+        held += len(window[-1])
+        if held >= 2 * batch_size * seq_len:
+            yield from pack_window(window, held)
+            window, held = [], 0
     if window:
-        t, s = pack_documents(window, seq_len, pad_id)
-        rows_t.extend(t)
-        rows_s.extend(s)
-    while len(rows_t) >= batch_size:
-        yield (np.stack(rows_t[:batch_size]), np.stack(rows_s[:batch_size]))
-        rows_t, rows_s = rows_t[batch_size:], rows_s[batch_size:]
+        yield from pack_window(window, held)
     if rows_t and not drop_remainder:
         pad_rows = batch_size - len(rows_t)
         t = np.concatenate(

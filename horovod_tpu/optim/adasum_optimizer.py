@@ -24,6 +24,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import optax
 
 from ..compression import Compression, Compressor
@@ -52,7 +53,8 @@ def DistributedAdasumOptimizer(
     def update_fn(grads, state, params=None):
         from .distributed_optimizer import _reduce_gradients
 
-        updates, state = optimizer.update(grads, state, params)
+        with jax.named_scope("hvd_update"):
+            updates, state = optimizer.update(grads, state, params)
         reduced = _reduce_gradients(
             updates,
             axis=axis,

@@ -186,14 +186,16 @@ def _reduce_sparse_leaf(
     prescale before the collective, postscale after."""
     from ..ops.sparse import IndexedSlices, densify, sparse_allreduce
 
-    wire, ctx = compression.compress(s.values)
+    with jax.named_scope("wire_out"):
+        wire, ctx = compression.compress(s.values)
     if prescale_factor != 1.0:
         wire = wire * jnp.asarray(prescale_factor, wire.dtype)
     out = sparse_allreduce(
         IndexedSlices(s.indices, wire, s.dense_shape),
         axis=axis, op=op, process_set=process_set,
     )
-    vals = compression.decompress(out.values, ctx)
+    with jax.named_scope("wire_in"):
+        vals = compression.decompress(out.values, ctx)
     if postscale_factor != 1.0:
         vals = vals * jnp.asarray(postscale_factor, vals.dtype)
     reduced = densify(IndexedSlices(out.indices, vals, s.dense_shape))
@@ -388,7 +390,19 @@ def _bucket_reducer(
     return reduce_bucket_flat
 
 
-def _reduce_gradients(
+def _reduce_gradients(grads: Any, **how) -> Any:
+    """:func:`_exchange_leaves` under the ``hvd_exchange`` scope of the
+    compiled step: everything between the backward's gradients and the
+    reduced tree the inner optimizer is given.  Inside it ``wire_out``
+    (the leaves cast to the wire), one ``hvd_sched_bucket<i>_...`` a
+    bucket (``sched/execute.py``: flatten, collective, unflatten) and
+    ``wire_in`` (the barrier and the cast back); docs/tracing.md has the
+    table."""
+    with jax.named_scope("hvd_exchange"):
+        return _exchange_leaves(grads, **how)
+
+
+def _exchange_leaves(
     grads: Any,
     *,
     axis,
@@ -488,7 +502,7 @@ def _reduce_gradients(
                 prescale_factor=prescale_factor,
                 postscale_factor=postscale_factor, process_set=process_set,
             )
-        dense_reduced = _reduce_gradients(
+        dense_reduced = _exchange_leaves(
             [leaves[i] for i in dense_pos],
             axis=axis, op=op, compression=compression,
             prescale_factor=prescale_factor,
@@ -501,7 +515,8 @@ def _reduce_gradients(
         return jax.tree.unflatten(treedef, out)
 
     # --- plan
-    compressed = [compression.compress(g) for g in leaves]
+    with jax.named_scope("wire_out"):
+        compressed = [compression.compress(g) for g in leaves]
     wire = [c[0] for c in compressed]
     ctxs = [c[1] for c in compressed]
     plan = _plan_dense(
@@ -517,12 +532,16 @@ def _reduce_gradients(
             raise ValueError("residuals structure does not match gradients")
 
     # --- emit.  Per-bucket hot-path lanes (reference per-tensor
-    # activity lanes, common.h:73-105): the exchange puts a named_scope
-    # per bucket into the compiled program's op metadata — the device
-    # profiler attributes each fused collective to its bucket — and,
-    # when a timeline is active, records one event per bucket at trace
-    # time so a slow bucket is identifiable without a full profiler
-    # trace.
+    # activity lanes, common.h:73-105), by two mechanisms.  A scope of
+    # the compiled step: the exchange puts a named_scope per bucket
+    # (``hvd_sched_bucket<i>_<bytes>B_<wire>_<lowering>``) into the
+    # program's op metadata, so a device profile attributes each
+    # bucket's flatten, collective and unflatten to it on every step.
+    # Host events at trace time: the ``bucket<i>`` / ``exchange.*``
+    # spans and, when a timeline is active, one timeline event per
+    # bucket are made while jax traces the step, once a build — they
+    # say which buckets the plan made and time the tracing, never a
+    # bucket's exchange on the device.
     from .. import sched
     from ..runtime import get_runtime_or_none
 
@@ -548,13 +567,14 @@ def _reduce_gradients(
             if plan.hier_ok else None
         ),
     )
-    if update_follows:
-        # Orders every leaf's decompress and update after the last
-        # bucket's collective (ROADMAP D4 asks what that costs).
-        reduced = lax.optimization_barrier(tuple(reduced))
+    with jax.named_scope("wire_in"):
+        if update_follows:
+            # Orders every leaf's decompress and update after the last
+            # bucket's collective (ROADMAP D4 asks what that costs).
+            reduced = lax.optimization_barrier(tuple(reduced))
 
-    # --- decompress
-    out = [compression.decompress(t, c) for t, c in zip(reduced, ctxs)]
+        # --- decompress
+        out = [compression.decompress(t, c) for t, c in zip(reduced, ctxs)]
     tree = jax.tree.unflatten(treedef, out)
     if residuals is not None:
         return tree, jax.tree.unflatten(treedef, res_out)
@@ -664,10 +684,12 @@ def DistributedOptimizer(
                 )
             else:
                 reduced = reduce_fn(grads, update_follows=True)
-            updates, inner = optimizer.update(reduced, state.inner, params)
+            with jax.named_scope("hvd_update"):
+                updates, inner = optimizer.update(
+                    reduced, state.inner, params)
+                counter = state.counter + 1
             return updates, DistributedOptimizerState(
-                counter=state.counter + 1, acc=None, inner=inner,
-                residual=residual,
+                counter=counter, acc=None, inner=inner, residual=residual,
             )
 
         # Local gradient aggregation (reference
@@ -678,29 +700,37 @@ def DistributedOptimizer(
         # aggregation helper which only handles dense buffers.
         from ..ops.sparse import IndexedSlices as _IS, densify as _densify
 
-        grads = jax.tree.map(
-            lambda g: _densify(g) if isinstance(g, _IS) else g, grads,
-            is_leaf=lambda x: isinstance(x, _IS),
-        )
-        acc = jax.tree.map(lambda a, g: a + g, state.acc, grads)
-        counter = state.counter + 1
-        boundary = (counter % k) == 0
+        # ``hvd_accumulate``: the local sum, its scaling and zeroing, and
+        # the call count that decides which branch runs.
+        with jax.named_scope("hvd_accumulate"):
+            grads = jax.tree.map(
+                lambda g: _densify(g) if isinstance(g, _IS) else g, grads,
+                is_leaf=lambda x: isinstance(x, _IS),
+            )
+            acc = jax.tree.map(lambda a, g: a + g, state.acc, grads)
+            counter = state.counter + 1
+            boundary = (counter % k) == 0
 
         def do_step(operand):
             acc_, inner_, res_ = operand
             scale = 1.0 / k if average_aggregated_gradients else 1.0
-            scaled = jax.tree.map(lambda a: a * scale, acc_)
+            with jax.named_scope("hvd_accumulate"):
+                scaled = jax.tree.map(lambda a: a * scale, acc_)
             if res_ is not None:
                 reduced, res_ = reduce_fn(scaled, res_)
             else:
                 reduced = reduce_fn(scaled)
-            updates, new_inner = optimizer.update(reduced, inner_, params)
-            zeroed = jax.tree.map(jnp.zeros_like, acc_)
+            with jax.named_scope("hvd_update"):
+                updates, new_inner = optimizer.update(
+                    reduced, inner_, params)
+            with jax.named_scope("hvd_accumulate"):
+                zeroed = jax.tree.map(jnp.zeros_like, acc_)
             return updates, zeroed, new_inner, res_
 
         def no_step(operand):
             acc_, inner_, res_ = operand
-            updates = jax.tree.map(jnp.zeros_like, acc_)
+            with jax.named_scope("hvd_update"):
+                updates = jax.tree.map(jnp.zeros_like, acc_)
             return updates, acc_, inner_, res_
 
         updates, acc, inner, residual = lax.cond(
@@ -859,7 +889,8 @@ class TrainStep:
                 )
             with jax.named_scope("hvd_reduce_and_update"):
                 updates, opt_state = optimizer.update(grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
+                with jax.named_scope("hvd_update"):
+                    params = optax.apply_updates(params, updates)
             loss = lax.pmean(loss, axis)
             opt_state = _stack_local(opt_state)
             out = (params,)

@@ -310,10 +310,9 @@ def _exchange_pipelined(
             f"_{bucket_.lowering}_ag"
         ):
             out = pb_.ag(mid_)
-        rail.bump(out, ("ici",))
-        for i, t in zip(
-            bucket_.indices, fusion.unflatten_group([out], meta_)
-        ):
+            rail.bump(out, ("ici",))
+            leaves = fusion.unflatten_group([out], meta_)
+        for i, t in zip(bucket_.indices, leaves):
             reduced[i] = t
 
     for bi, bucket in enumerate(buckets):
@@ -337,14 +336,12 @@ def _exchange_pipelined(
             ):
                 flats, meta = fusion.flatten_group(ins)
                 outs = [reduce_flat(f, bucket) for f in flats]
-            rail.bump(outs[0], ("ici", "dcn"))
-            for i, t in zip(
-                bucket.indices, fusion.unflatten_group(outs, meta)
-            ):
+                rail.bump(outs[0], ("ici", "dcn"))
+                leaves = fusion.unflatten_group(outs, meta)
+            for i, t in zip(bucket.indices, leaves):
                 reduced[i] = t
         else:
             ins = rail.tie(ins, ("ici",))
-            flats, meta = fusion.flatten_group(ins)
             with trace.span(
                 f"bucket{bi}.rs", "bucket", bucket=bi,
                 nbytes=bucket.nbytes,
@@ -352,6 +349,7 @@ def _exchange_pipelined(
                 f"hvd_sched_bucket{bi}_{bucket.nbytes}B_{bucket.wire}"
                 f"_{bucket.lowering}_rs"
             ):
+                flats, meta = fusion.flatten_group(ins)
                 shard = pb.rs(flats[0])
             rail.bump(shard, ("ici",))
             if deferred is not None:
@@ -489,11 +487,11 @@ def exchange(
             reduced = list(wire)
             token: Optional[jax.Array] = None
             for bi, bucket in enumerate(buckets):
-                ins, token = _chain(
-                    [wire[i] for i in bucket.indices], token
-                )
                 if timeline is not None:
                     _bucket_timeline(timeline, bi, bucket)
+                # The scope holds all the device does for the bucket:
+                # the tie to the bucket before, packing the leaves, the
+                # collective, and cutting the leaves out again.
                 with trace.span(
                     f"bucket{bi}", "bucket", bucket=bi,
                     nbytes=bucket.nbytes, wire=bucket.wire,
@@ -502,15 +500,17 @@ def exchange(
                     f"hvd_sched_bucket{bi}_{bucket.nbytes}B_{bucket.wire}"
                     f"_{bucket.lowering}"
                 ):
+                    ins, token = _chain(
+                        [wire[i] for i in bucket.indices], token
+                    )
                     flats, meta = fusion.flatten_group(ins)
                     outs = [reduce_flat(f, bucket) for f in flats]
-                # Scalar carried out of this bucket's collective: the
-                # next bucket's inputs are barrier-tied to it,
-                # enforcing issue order without touching values.
-                token = outs[0].reshape(-1)[0]
-                for i, t in zip(
-                    bucket.indices, fusion.unflatten_group(outs, meta)
-                ):
+                    # Scalar carried out of this bucket's collective: the
+                    # next bucket's inputs are barrier-tied to it,
+                    # enforcing issue order without touching values.
+                    token = outs[0].reshape(-1)[0]
+                    leaves = fusion.unflatten_group(outs, meta)
+                for i, t in zip(bucket.indices, leaves):
                     reduced[i] = t
                 metrics.observe(
                     "sched.bytes_per_bucket", bucket.nbytes,

@@ -237,3 +237,96 @@ class TestParquetStreamLoader:
                 np.testing.assert_allclose(xs, xa)
         finally:
             asyn.close_async_loader()
+
+
+# ------------------------------------------ the packer's own span (PR 36)
+def _pack_docs(seed=0, n=120):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, 50, int(k), dtype=np.int32)
+            for k in rng.integers(3, 40, n)]
+
+
+def _packed(level, *, on_suspend=None, **kw):
+    """Every batch ``pack_batches`` gives at trace level ``level`` and the
+    ``pack_window`` spans it left in the flight recorder."""
+    from horovod_tpu import trace
+    from horovod_tpu.data.packing import pack_batches
+
+    trace.reset()
+    trace.set_level_override(level)
+    try:
+        batches = []
+        for batch in pack_batches(iter(_pack_docs()), seq_len=32,
+                                  batch_size=4, **kw):
+            if on_suspend is not None:
+                on_suspend()
+            batches.append(batch)
+        spans = [r["spans"] for r in trace.get_recorder()._background
+                 if r["spans"]["name"] == "pack_window"]
+        return batches, spans
+    finally:
+        trace.set_level_override(None)
+        trace.reset()
+
+
+@pytest.mark.parametrize("drop_remainder", [True, False])
+def test_pack_batches_spans_its_own_work_a_window(drop_remainder):
+    from horovod_tpu import trace
+
+    open_at_yield = []
+    batches, spans = _packed(
+        "summary", drop_remainder=drop_remainder,
+        # the generator stands suspended: nothing is open on this thread
+        on_suspend=lambda: open_at_yield.append(
+            len(trace.get_tracer()._stack())))
+    assert batches and open_at_yield == [0] * len(batches)
+    # a window closes when it holds two batches' tokens; the last is
+    # what is left
+    docs, windows, held = _pack_docs(), 0, 0
+    for d in docs:
+        held += len(d)
+        if held >= 2 * 4 * 32:
+            windows, held = windows + 1, 0
+    assert len(spans) == windows + (1 if held else 0)
+    assert sum(s["attrs"]["docs"] for s in spans) == len(docs)
+    assert sum(s["attrs"]["tokens"] for s in spans) == sum(
+        len(d) for d in docs)
+    rows = sum(s["attrs"]["rows"] for s in spans)
+    full = 4 * (len(batches) - (0 if drop_remainder else 1))
+    assert full <= rows < full + 4 + (0 if drop_remainder else 4)
+    for s in spans:
+        assert s["phase"] == "input" and s["dur"] >= 0.0
+        assert set(s["attrs"]) == {"docs", "rows", "tokens"}
+        assert "children" not in s
+
+
+def test_pack_batches_makes_no_span_at_level_off_and_the_same_batches():
+    on, spans_on = _packed("summary")
+    off, spans_off = _packed("off")
+    assert spans_on and not spans_off
+    assert len(on) == len(off)
+    for (t1, s1), (t2, s2) in zip(on, off):
+        assert t1.dtype == t2.dtype == np.int32
+        np.testing.assert_array_equal(t1, t2)
+        np.testing.assert_array_equal(s1, s2)
+
+
+def test_pack_batches_is_what_packing_each_window_by_hand_gives():
+    """The reference: windows cut where the running total reaches two
+    batches, each packed by ``pack_documents``, rows dealt out in order."""
+    from horovod_tpu.data.packing import pack_documents
+
+    docs, rows_t, rows_s, window = _pack_docs(), [], [], []
+    for d in docs:
+        window.append(d)
+        if sum(len(w) for w in window) >= 2 * 4 * 32:
+            t, s = pack_documents(window, 32)
+            rows_t.extend(t), rows_s.extend(s)
+            window = []
+    t, s = pack_documents(window, 32)
+    rows_t.extend(t), rows_s.extend(s)
+    batches, _ = _packed("summary")
+    assert len(batches) == len(rows_t) // 4
+    for i, (bt, bs) in enumerate(batches):
+        np.testing.assert_array_equal(bt, np.stack(rows_t[4 * i:4 * i + 4]))
+        np.testing.assert_array_equal(bs, np.stack(rows_s[4 * i:4 * i + 4]))
