@@ -19,14 +19,17 @@ on.  TPU-first choices:
   (``layer_heads``, ``rope_rules``) and fewer key/value heads than query
   heads (``num_kv_heads``), latent attention ("mla": keys and values from a
   compressed latent, DeepSeek-V2) or the delta rule with a per-channel
-  decay ("kda", ops/kda), the last two with a sigmoid gate a head; a dense MLP, the capacity MoE, or dropless
-  routed experts of which this device holds a range ("experts").
+  decay ("kda", ops/kda), the last two with a sigmoid gate a head, or with
+  one decay a head ("gdn", Gated DeltaNet, with a SiLU gate a channel); a
+  dense MLP, the capacity MoE, or dropless routed experts of which this
+  device holds a range ("experts").
 
 * the block is GPT-2's by default (LayerNorm, learned positions, fused
   QKV with bias, GELU MLP, tied head) and a current decoder's by
   settings of :class:`TransformerConfig`: RMSNorm, rotary positions,
   separate bias-free projections, a gated SiLU MLP, norms after each
-  sublayer as well as before it, an untied head.
+  sublayer as well as before it or instead of it (OLMo 2), q and k normed
+  over their whole projections, no positions at all, an untied head.
 * ``ut_steps`` > 1 makes it a looped LM (Universal-Transformer loop,
   arXiv:2510.25741): the blocks are made once and the whole stack is
   applied ``ut_steps`` times with the same weights, with the final norm,
@@ -122,12 +125,16 @@ class TransformerConfig:
     # The block (defaults are GPT-2's):
     norm: str = "layernorm"      # "layernorm" | "rmsnorm"
     norm_eps: float = 1e-6
-    positions: str = "learned"   # "learned" (wpe) | "rope"
+    positions: str = "learned"   # "learned" (wpe) | "rope" | "none"
     rope_theta: float = 10000.0
     use_bias: bool = True        # biases of the projections and the MLP
     fused_qkv: bool = True       # one qkv matmul, or separate q, k, v
     mlp: str = "gelu"            # "gelu" | "gated_silu" (SwiGLU)
-    post_norm: bool = False      # a norm after each sublayer too
+    post_norm: bool = False      # a norm on each sublayer's output
+    pre_norm: bool = True        # and one on its input (OLMo 2 has none)
+    # softmax attention's q and k each RMSNormed over the whole projection,
+    # all heads at once (OLMo 2's), before the heads are split
+    qk_norm: bool = False
     tie_head: bool = True        # logits through wte's transpose
     # The loop: the stack is applied ut_steps times with the same weights;
     # with exit_gate the head and a per-token gate follow every pass and
@@ -143,6 +150,7 @@ class TransformerConfig:
     # A mixer and an FFN for every layer, one name a layer; empty means
     # "full" everywhere and what ``moe_every`` says.
     layer_kinds: Tuple[str, ...] = ()   # "full" | "window" | "mla" | "kda"
+    #                                     | "gdn"
     ffn_kinds: Tuple[str, ...] = ()     # "dense" | "moe" | "experts"
     # "window": softmax attention whose queries see themselves and the
     # ``window - 1`` tokens before them (of their own document).
@@ -165,8 +173,15 @@ class TransformerConfig:
     # "kda" (arXiv:2510.26692 section 3): heads of ``head_dim`` keys and
     # values, a causal depthwise convolution of ``kda_conv`` taps, and
     # g = kda_lower_bound * sigmoid(exp(A_log) (x W_f + dt_bias)).
-    kda_conv: int = 4
+    kda_conv: int = 4            # the taps of both delta-rule mixers
     kda_lower_bound: float = -5.0
+    # "gdn" (Gated DeltaNet, arXiv:2412.06464): ``num_heads`` heads of
+    # ``gdn_key_dim`` keys and ``gdn_value_dim`` values, one decay a head
+    # g = -exp(A_log) softplus(x W_a + dt_bias), beta = 2 sigmoid(x W_b)
+    # (eigenvalues down to -1, arXiv:2411.12537), the output RMSNormed a
+    # head times SiLU(x W_z).
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
     # "experts": a router over ``num_experts`` with ``experts_per_token``
     # chosen inside the best ``topk_group`` of ``n_group`` groups; this
     # device holds ``experts_held`` = (first, past the last) of them, each
@@ -186,7 +201,7 @@ class TransformerConfig:
 # ``ops.kda.kda``, on [B, T, H·d]; nothing is chunk-major any more, and a
 # benchmark PR can rename it with its test.
 kda_chunk_major = kda
-MIXERS = ("full", "window", "mla", "kda")
+MIXERS = ("full", "window", "mla", "kda", "gdn")
 FFNS = ("dense", "moe", "experts")
 
 
@@ -349,6 +364,17 @@ class Attention(nn.Module):
             qkv = column(3, "qkv")
             parts = qkv.reshape(b, t, 3, h_local, cfg.head_dim)
             q, k, v = parts[:, :, 0], parts[:, :, 1], parts[:, :, 2]
+        elif cfg.qk_norm:
+            if tp > 1:
+                raise ValueError(
+                    "qk_norm normalises the whole q and k projections: "
+                    "they cannot be split over the tp axis")
+            q, k, v = (column(1, name, heads=n) for name, n in (
+                ("q", heads), ("k", kv_heads), ("v", kv_heads)))
+            q, k = (nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
+                               name=f"{name}_norm")(a).astype(cfg.dtype)
+                    for name, a in (("q", q), ("k", k)))
+            q, k, v = (a.reshape(b, t, -1, cfg.head_dim) for a in (q, k, v))
         else:
             q, k, v = (
                 column(1, name, heads=n).reshape(
@@ -587,13 +613,92 @@ class KDAMixer(nn.Module):
         )(o)
 
 
+def _a_log_init(key, shape):
+    """log A, A uniform in [1, 16] (fla's GatedDeltaNet draws (0, 16])."""
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+
+
+def _dt_bias_init(key, shape):
+    """softplus^-1(dt), dt log-uniform in [1e-3, 0.1] (fla's, Mamba 2's)."""
+    dt = jnp.exp(jax.random.uniform(
+        key, shape, jnp.float32, math.log(1e-3), math.log(0.1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class GDNMixer(nn.Module):
+    """Gated DeltaNet (arXiv:2412.06464; fla's ``GatedDeltaNet``): the
+    delta rule with one decay a head over heads of ``gdn_key_dim`` keys
+    and ``gdn_value_dim`` values (``ops/kda.py``, its kernels take both
+    widths where they lie in the projections).
+
+        q = L2(SiLU(Conv(x W_q))) / sqrt(dk),  k = L2(SiLU(Conv(x W_k))),
+        v = SiLU(Conv(x W_v)),  beta = 2 sigmoid(x W_b) a head (the
+        transition's eigenvalues reach -1),  g = -exp(A_log) softplus(x W_a
+        + dt_bias) a head,  out = W_o (RMSNorm_head(o) * SiLU(x W_z)) a
+        channel.
+
+    Scopes: ``conv`` (the convolutions, SiLU and the L2 norms), ``gate``
+    (g and beta), ``core`` (the delta rule), ``norm`` (the output's norm
+    and gate)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array,
+                 segment_ids: Optional[jax.Array] = None) -> jax.Array:
+        cfg = self.cfg
+        h, dk, dv = cfg.num_heads, cfg.gdn_key_dim, cfg.gdn_value_dim
+        if _axis_present(cfg.sp_axis) and lax.axis_size(cfg.sp_axis) > 1:
+            raise ValueError(
+                "the gdn mixer's state runs along the whole row: it cannot "
+                "be sequence-sharded")
+
+        def projected(name, width, dtype=cfg.dtype):
+            return nn.Dense(width, use_bias=False, dtype=dtype,
+                            name=name)(x.astype(dtype))
+
+        # [B, T, H·d] throughout, as the projections leave them
+        def convolved(name, width):
+            taps = self.param(f"conv_{name}", nn.initializers.normal(0.5),
+                              (cfg.kda_conv, width), jnp.float32)
+            y = projected(name, width)
+            with jax.named_scope("conv"):
+                return nn.silu(_short_conv(y, taps, segment_ids))
+
+        q, k, v = (convolved("q", h * dk), convolved("k", h * dk),
+                   convolved("v", h * dv))
+        with jax.named_scope("conv"):
+            q = unit_heads(q, h, dk ** -0.5, cfg.dtype)
+            k = unit_heads(k, h, 1.0, cfg.dtype)
+            v = v.astype(cfg.dtype)
+        with jax.named_scope("gate"):
+            a_log = self.param("A_log", _a_log_init, (h,))
+            dt_bias = self.param("dt_bias", _dt_bias_init, (h,))
+            g = -jnp.exp(a_log) * jax.nn.softplus(
+                projected("a", h, jnp.float32) + dt_bias)
+            beta = 2.0 * jax.nn.sigmoid(projected("b", h, jnp.float32))
+        with jax.named_scope("core"):
+            o = kda(q, k, v, g, beta, segment_ids)
+        z = projected("z", h * dv)
+        with jax.named_scope("norm"):
+            o = rms_gate_heads(
+                o, _NormScale(dv, name="o_norm")(),
+                nn.silu(z.astype(jnp.float32)), cfg.norm_eps, cfg.dtype)
+        return RowParallelDense(
+            cfg.model_dim, axis=cfg.tp_axis, use_bias=False,
+            dtype=cfg.dtype, name="proj",
+        )(o)
+
+
 class Block(nn.Module):
     """Pre-norm transformer block: a mixer (``kind``: softmax attention,
-    whole or windowed, latent attention or the delta rule) and an FFN (``ffn``: dense-TP,
-    the capacity MoE or dropless experts).  With ``cfg.post_norm`` each
-    sublayer's output is normed again before it joins the residual
-    ("sandwich").  Returns (x, the capacity MoE's auxiliary loss, the
-    held experts' loads or None)."""
+    whole or windowed, latent attention or the delta rule a channel or a
+    head) and an FFN (``ffn``: dense-TP, the capacity MoE or dropless
+    experts).  With ``cfg.post_norm`` each sublayer's output is normed
+    again before it joins the residual ("sandwich"), and without
+    ``cfg.pre_norm`` only there (OLMo 2: h = x + Norm(Mixer(x))).
+    Returns (x, the capacity MoE's auxiliary loss, the held experts' loads
+    or None)."""
 
     cfg: TransformerConfig
     kind: str = "full"
@@ -609,9 +714,13 @@ class Block(nn.Module):
         if cfg.mlp not in ("gelu", "gated_silu"):
             raise ValueError(
                 f"unknown mlp {cfg.mlp!r}; expected 'gelu' or 'gated_silu'")
-        h = _norm(cfg, "ln_attn")(x)
+        if not (cfg.pre_norm or cfg.post_norm):
+            raise ValueError("a block needs pre_norm, post_norm or both")
+        h = _norm(cfg, "ln_attn")(x) if cfg.pre_norm else x
         if self.kind == "kda":
             y = KDAMixer(cfg, name="kda")(h.astype(cfg.dtype), segment_ids)
+        elif self.kind == "gdn":
+            y = GDNMixer(cfg, name="gdn")(h.astype(cfg.dtype), segment_ids)
         else:
             y = Attention(
                 cfg, latent=self.kind == "mla", heads=self.heads,
@@ -620,7 +729,7 @@ class Block(nn.Module):
         if cfg.post_norm:
             y = _norm(cfg, "ln_attn_post")(y)
         x = x + y.astype(x.dtype)
-        h = _norm(cfg, "ln_mlp")(x)
+        h = _norm(cfg, "ln_mlp")(x) if cfg.pre_norm else x
         aux = jnp.zeros((), jnp.float32)
         load = None
         if self.ffn == "experts":
@@ -704,10 +813,10 @@ class Transformer(nn.Module):
     def __call__(self, tokens: jax.Array,
                  segment_ids: Optional[jax.Array] = None):
         cfg = self.cfg
-        if cfg.positions not in ("learned", "rope"):
+        if cfg.positions not in ("learned", "rope", "none"):
             raise ValueError(
-                f"unknown positions {cfg.positions!r}; expected 'learned' "
-                "or 'rope'")
+                f"unknown positions {cfg.positions!r}; expected 'learned', "
+                "'rope' or 'none'")
         b, t = tokens.shape
         emb = nn.Embed(
             cfg.vocab_size, cfg.model_dim,
@@ -723,7 +832,7 @@ class Transformer(nn.Module):
                     (cfg.max_len, cfg.model_dim), jnp.float32,
                 )
                 x = x + jnp.take(wpe, pos, axis=0)
-            else:
+            elif cfg.positions == "rope":
                 rope = _rope_by_kind(cfg, pos)
             x = x.astype(cfg.dtype)
         if cfg.tie_head:
@@ -799,6 +908,12 @@ class Transformer(nn.Module):
             "model.kda.kernel_layers",
             sum("kda" in kind for kind in kinds)
             * kda_kernels.takes(cfg.head_dim))
+        # and those with one decay a head (both widths taken, or neither)
+        metrics.set_gauge(
+            "model.gdn.kernel_layers",
+            sum("gdn" in kind for kind in kinds)
+            * (kda_kernels.takes(cfg.gdn_key_dim)
+               and kda_kernels.takes(cfg.gdn_value_dim)))
         for i, (name, _) in enumerate(kinds):
             if name in ("full", "window") and cfg.attn_impl == "flash":
                 labels = {"kind": name}

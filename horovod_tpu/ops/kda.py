@@ -62,9 +62,10 @@ from .kda_kernels import MAX_EXPONENT as _MAX_EXPONENT
 
 
 def kda_recurrent(q, k, v, g, beta, segment_ids=None):
-    """The recurrence, token by token: q, k, g [B, T, H, dk], v
-    [B, T, H, dv], beta [B, T, H] -> o [B, T, H, dv], all float32.  The
-    definition the chunked form is tested against."""
+    """The recurrence, token by token: q, k, g [B, T, H, dk] (g [B, T, H,
+    1]: one decay a head), v [B, T, H, dv], beta [B, T, H] -> o [B, T, H,
+    dv], all float32.  The definition the chunked form is tested
+    against."""
     b, t, h, dk = q.shape
     if segment_ids is None:
         fresh = jnp.zeros((b, t), bool)
@@ -161,12 +162,15 @@ def _inverse_unit_lower(m):
 
 def kda_chunk_major(q, k, v, g, beta, seg, sub: int = 16):
     """The chunked form on operands laid out as its loop over the chunks
-    reads them: q, k [n, B, H, C, dk], v [n, B, H, C, dv], g as q and beta
-    [n, B, H, C, 1] float32, ``seg`` [n, B, C] int32 -> o [n, B, H, C, dv]
-    float32.  n chunks of C tokens; the caller pads.  q's type is the
-    type of every matmul's operands (the decays' factors and the state are
-    made in float32 and rounded to it, the sums are float32): float32 is
-    the recurrence to rounding, bfloat16 what the MXU multiplies anyway."""
+    reads them: q, k [n, B, H, C, dk], v [n, B, H, C, dv], g as q (or
+    [n, B, H, C, 1]: one decay a head) and beta [n, B, H, C, 1] float32,
+    ``seg`` [n, B, C] int32 -> o [n, B, H, C, dv] float32.  n chunks of C
+    tokens; the caller pads.  q's type is the type of every matmul's
+    operands (the decays' factors and the state are made in float32 and
+    rounded to it, the sums are float32): float32 is the recurrence to
+    rounding, bfloat16 what the MXU multiplies anyway.  With one decay a
+    head, exp(G_r - G_i) is one [C, C] matrix, made exactly: no sub-chunks
+    and no bound on g."""
     n, b, h, chunk, dk = q.shape
     dv = v.shape[-1]
     dtype = q.dtype
@@ -190,10 +194,19 @@ def kda_chunk_major(q, k, v, g, beta, seg, sub: int = 16):
     lower = jnp.logical_and(same, row >= col)[:, :, None]
     in_last = same[:, :, -1, :][:, :, None, :, None]        # [n, B, 1, C, 1]
 
-    a_mat = jnp.where(
-        strict, _decayed_products(k, k, g_cum, sub, dtype), 0.0)
-    b_mat = jnp.where(
-        lower, _decayed_products(q, k, g_cum, sub, dtype), 0.0).astype(dtype)
+    if g.shape[-1] == 1:
+        # exp(G_r - G_i) on the triangle, 0 above it: a clamp at 0 there
+        # would stop the gradient of a difference that rounds to >= 0
+        decay = jnp.exp(jnp.where(
+            row >= col, g_cum - jnp.swapaxes(g_cum, -1, -2), -1e30))
+        products = lambda left, right: jnp.einsum(
+            "...rd,...id->...ri", left.astype(dtype), right.astype(dtype),
+            preferred_element_type=jnp.float32) * decay
+    else:
+        products = lambda left, right: _decayed_products(
+            left, right, g_cum, sub, dtype)
+    a_mat = jnp.where(strict, products(k, k), 0.0)
+    b_mat = jnp.where(lower, products(q, k), 0.0).astype(dtype)
     decay_in = jnp.exp(g_cum)                               # <= 1
     k_in = jnp.where(sees_in, k * decay_in, 0.0)
     q_in = jnp.where(sees_in, q * decay_in, 0.0).astype(dtype)
@@ -238,10 +251,11 @@ def chunk_major(a, chunk: int):
 def kda_chunked(q, k, v, g, beta, segment_ids: Optional[jax.Array] = None,
                 chunk: int = 64, sub: int = 16):
     """:func:`kda_recurrent` in chunks of ``chunk`` tokens, in plain
-    ``jax.numpy``: q, k, g [B, T, H, dk], v [B, T, H, dv], beta [B, T, H]
-    -> o [B, T, H, dv] float32; q's type is the matmuls' operands'.  ``g``
-    must lie above ``-80 / sub`` (the bounded gate's lower bound is -5 for
-    ``sub`` 16)."""
+    ``jax.numpy``: q, k, g [B, T, H, dk] (g [B, T, H, 1]: one decay a
+    head), v [B, T, H, dv], beta [B, T, H] -> o [B, T, H, dv] float32; q's
+    type is the matmuls' operands'.  A decay a channel must lie above ``-80
+    / sub`` (the bounded gate's lower bound is -5 for ``sub`` 16); one a
+    head has no bound."""
     b, t, h, _ = q.shape
     pad = -t % chunk
     seg = (jnp.ones((b, t), jnp.int32) if segment_ids is None
@@ -262,11 +276,12 @@ def kda_chunked(q, k, v, g, beta, segment_ids: Optional[jax.Array] = None,
 
 
 def kda(q, k, v, g, beta, segment_ids: Optional[jax.Array] = None):
-    """The delta rule on the projections' layout: q, k, g [B, T, H·dk], v
-    [B, T, H·dv], beta [B, T, H] -> o [B, T, H·dv] float32, q's type the
-    matmuls' operands'.  By the kernels (``kda_kernels``) where they take
-    the heads' widths, which re-lay nothing; else by :func:`kda_chunked`,
-    whose loop reads chunk-major copies."""
+    """The delta rule on the projections' layout: q, k [B, T, H·dk], v
+    [B, T, H·dv], g [B, T, H·dk] (a decay a channel) or [B, T, H] (one a
+    head, Gated DeltaNet's), beta [B, T, H] -> o [B, T, H·dv] float32, q's
+    type the matmuls' operands'.  By the kernels (``kda_kernels``) where
+    they take the heads' widths, which re-lay nothing; else by
+    :func:`kda_chunked`, whose loop reads chunk-major copies."""
     b, t, _ = q.shape
     heads = beta.shape[-1]
     if all(kda_kernels.takes(a.shape[-1] // heads) for a in (q, v)):
@@ -290,11 +305,15 @@ def unit_heads(x, heads: int, scale: float, dtype):
 
 def rms_gate_heads(x, weight, gate, eps: float, dtype):
     """RMSNorm over each head's channels times ``weight`` [d] and the
-    head's ``gate`` [B, T, H], in float32, as ``dtype``: x [B, T, H·d]
-    float32 (the mixer's output norm and gate)."""
+    ``gate``, one a head [B, T, H] or one a channel [B, T, H·d], in
+    float32, as ``dtype``: x [B, T, H·d] float32 (the mixer's output norm
+    and gate)."""
     b, t, lanes = x.shape
-    if kda_kernels.takes(weight.shape[0]):
+    d = weight.shape[0]
+    if kda_kernels.takes(d):
         return kda_kernels.head_rms_gate(x, weight, gate, eps, dtype)
-    x = x.reshape(b, t, gate.shape[-1], -1)
+    x = x.reshape(b, t, lanes // d, d)
     x = x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
-    return (x * weight * gate[..., None]).astype(dtype).reshape(b, t, lanes)
+    gate = (gate.reshape(x.shape) if gate.shape[-1] == lanes
+            else gate[..., None])
+    return (x * weight * gate).astype(dtype).reshape(b, t, lanes)

@@ -3,10 +3,14 @@ forward and a hand-written backward under one ``jax.custom_vjp``, on the
 projections' own layout: q, k, g ``[B, T, H·dk]``, v and o ``[B, T, H·dv]``,
 beta ``[B, T, H]``.
 
-A grid step owns one chunk of ``CHUNK`` tokens by one slab of
-``_heads_a_step`` heads' lanes; the chunks are walked in order (in reverse
-by the backward) with every head's state, ``[dv, dk]`` float32, in a VMEM
-scratch.  A chunk's triangles, the inverse, U, W and what the tokens wrote
+A grid step owns one chunk of ``CHUNK`` tokens by one slab of heads'
+lanes (``_slab``: ``_heads_a_step`` heads where their keys and values are
+whole 128-lane slabs, else every head, the block as wide as the array, so
+that heads of 96 or 192 lanes are read where the projection left them);
+inside a slab the heads are taken ``_heads_a_step`` at a time, each
+group's lanes a static slice of the block.  The chunks are walked in order
+(in reverse by the backward) with every head's state, ``[dv, dk]``
+float32, in a VMEM scratch.  A chunk's triangles, the inverse, U, W and what the tokens wrote
 never leave VMEM.  The state is kept transposed so that its decay, one
 factor a key channel, runs along the lanes.
 
@@ -37,6 +41,16 @@ S0 + K_out^T wrote`` and ``[U | W] = X Diag(beta) [V | K_in]``,
 
 then through A, B and the decays' exponentials into q, k and g, the
 gradient of the running sum being the reversed running sum.
+
+**One decay a head** (``g`` ``[B, T, H]``, Gated DeltaNet's, arXiv:
+2412.06464): ``exp(G_r - G_i)`` is then one ``[C, C]`` matrix D, formed
+exactly and at most 1 on the triangle, so ``A = (K K^T) * D`` and ``B =
+(Q K^T) * D`` are one matmul each and g has no lower bound (no sub-chunk
+reference points).  Backward: with ``P_A = dA * D``, ``P_B = dB * D``,
+``dQ = P_B K``, ``dK = P_A K + P_A^T K + P_B^T Q`` and ``dG = rowsum(E) -
+colsum(E)``, ``E = (dA * K K^T + dB * Q K^T) * D``; the decays of the
+state, of K_in, Q_in and K_out are those of the per-channel case summed
+over the channels.
 """
 
 from __future__ import annotations
@@ -68,8 +82,11 @@ _TN = (((1,), (1,)), ((0,), (0,)))
 
 def takes(width: int) -> bool:
     """Whether the kernels take heads ``width`` wide: any width where
-    Pallas is interpreted, whole 128-lane slabs on the chip."""
-    return pk._interpret() or width % pk._LANES == 0
+    Pallas is interpreted, a multiple of 32 lanes on the chip (compiled
+    for a v5e at 64, 96, 128, 192 and 256: ``tests/test_tpu_compile.py``);
+    a width that is no whole 128-lane slab is a static slice of a block
+    as wide as all the heads (``_slab``)."""
+    return pk._interpret() or width % 32 == 0
 
 
 def _heads_a_step(heads: int) -> int:
@@ -80,6 +97,17 @@ def _heads_a_step(heads: int) -> int:
     (PERF.md, PR 33: 4.7 ms a layer forward at one head, 2.1 at four; eight
     need more VMEM than a kernel gets unasked)."""
     return next(n for n in (4, 3, 2, 1) if heads % n == 0)
+
+
+def _slab(heads: int, *widths: int) -> int:
+    """Heads whose lanes a grid step's block holds: ``_heads_a_step``
+    where that many heads of each width are whole 128-lane slabs, else all
+    of them (a block as wide as the array is the one Mosaic takes for any
+    width)."""
+    n = _heads_a_step(heads)
+    if all(n * w % pk._LANES == 0 for w in widths):
+        return n
+    return heads
 
 
 def _parts(x):
@@ -200,6 +228,47 @@ def _chunk(q, k, v, g, beta, strict, lower, sees, last, sub: int):
         rhs_plain=rhs_plain, rhs=(beta * rhs_plain).astype(dtype))
 
 
+def _row(x):
+    """[h, C, 1] float32 as [h, 1, C], exactly: its bfloat16 parts times
+    the identity, each sum one exact term."""
+    h, c, _ = x.shape
+    row, col = _iotas(c)
+    eye = jnp.broadcast_to(
+        jnp.where(row == col, 1.0, 0.0).astype(jnp.bfloat16), (h, c, c))
+    hi, mid, low = _parts(x)
+    parts = _dot(jnp.concatenate([low, mid, hi], axis=2), eye, _TN)
+    return parts[:, 0:1] + parts[:, 1:2] + parts[:, 2:3]
+
+
+def _chunk_per_head(q, k, v, g, beta, strict, lower, sees, last):
+    """:func:`_chunk` for one decay a head, g [h, C, 1]: the decays'
+    matrix D = exp(G_r - G_i) [h, C, C] (exact, <= 1 where it is kept),
+    A and B from one matmul each.  The running sum is made as wide as a
+    key, so that the decays of K_in, Q_in, K_out and of the state are
+    the per-channel case's arrays (Mosaic cannot broadcast [h, 1, 1] over
+    a state's sublanes and lanes at once)."""
+    h, c, dk = q.shape
+    dtype = q.dtype
+    kf, qf = k.astype(jnp.float32), q.astype(jnp.float32)
+    g_cum = _running_sum(jnp.broadcast_to(g, (h, c, dk)))   # [h, C, dk]
+    column = g_cum[:, :, :1]
+    decay = jnp.exp(jnp.minimum(column - _row(column), 0.0))
+    kk, qk = _dot(k, k, _NT), _dot(q, k, _NT)
+    decay_in = jnp.exp(g_cum)                                # <= 1
+    decay_out = jnp.exp(g_cum[:, c - 1:] - g_cum)
+    k_in = jnp.where(sees, kf * decay_in, 0.0)
+    rhs_plain = jnp.concatenate([v.astype(jnp.float32), k_in], axis=2)
+    return dict(
+        q=q, k=k, kf=kf, qf=qf, decay=decay, kk=kk, qk=qk,
+        a_mat=jnp.where(strict, kk * decay, 0.0),
+        b_mat=jnp.where(lower, qk * decay, 0.0).astype(dtype),
+        decay_in=decay_in, decay_out=decay_out,
+        q_in=jnp.where(sees, qf * decay_in, 0.0).astype(dtype),
+        k_out=jnp.where(last, kf * decay_out, 0.0).astype(dtype),
+        carry=jnp.where(sees[c - 1:], decay_in[:, c - 1:], 0.0),  # [h, 1, dk]
+        rhs_plain=rhs_plain, rhs=(beta * rhs_plain).astype(dtype))
+
+
 def _wrote(parts, inverse, state, dv: int):
     """(what the chunk's tokens write [h, C, dv] float32, W [h, C, dk], the
     state as the matmuls take it, Q_in S0 [h, C, dv]) from the chunk's
@@ -219,25 +288,46 @@ def _column(block, lane, head):
     return jnp.sum(jnp.where(lane == head, block, 0.0), axis=1, keepdims=True)
 
 
-def _load(ref, width: int, heads: int):
-    """[h, C, width] of a [1, C, h·width] block."""
-    return jnp.stack(
-        [ref[0, :, j * width:(j + 1) * width] for j in range(heads)])
+def _load(ref, width: int, heads: int, offset: int = 0):
+    """[h, C, width] of heads ``offset`` to ``offset + h`` of a
+    [1, C, slab·width] block."""
+    return jnp.stack([ref[0, :, j * width:(j + 1) * width]
+                      for j in range(offset, offset + heads)])
 
 
-def _store(ref, value):
+def _store(ref, value, offset: int = 0):
     """The reverse of :func:`_load`, in the block's type."""
     width = value.shape[-1]
     for j in range(value.shape[0]):
-        ref[0, :, j * width:(j + 1) * width] = value[j].astype(ref.dtype)
+        at = (offset + j) * width
+        ref[0, :, at:at + width] = value[j].astype(ref.dtype)
+
+
+def _put(ref, value, offset: int):
+    """A group's [h, ...] into a [1, 1, slab, ...] block."""
+    if ref.shape[2] == value.shape[0]:
+        ref[0, 0] = value
+    else:
+        ref[0, 0, offset:offset + value.shape[0]] = value
+
+
+def _take(ref, heads: int, offset: int):
+    """The reverse of :func:`_put`."""
+    if ref.shape[2] == heads:
+        return ref[0, 0]
+    return ref[0, 0, offset:offset + heads]
 
 
 def _step(q_ref, k_ref, v_ref, g_ref, beta_ref, seg_col_ref, seg_row_ref,
-          sees_ref, last_ref, heads: int, dk: int, dv: int):
-    """(the first head of this grid step, its lane mask over a [C, H]
-    block, the heads' betas [h, C, 1], the documents' masks, the chunk's
-    parts)."""
-    first = pl.program_id(2) * heads
+          sees_ref, last_ref, heads: int, dk: int, dv: int, slab: int,
+          offset: int, per_head: bool):
+    """(the first head of the group of ``heads`` that starts ``offset``
+    heads into this grid step's slab, its lane mask over a [C, H] block,
+    the heads' betas [h, C, 1], the documents' masks, the chunk's parts);
+    ``per_head``: g is a [C, H] block too, one decay a head."""
+    first = pl.program_id(2) * slab
+    if offset:
+        first = first + offset
     c = q_ref.shape[1]
     row, col = _iotas(c)
     same = seg_col_ref[0] == seg_row_ref[0, 0]
@@ -247,43 +337,60 @@ def _step(q_ref, k_ref, v_ref, g_ref, beta_ref, seg_col_ref, seg_row_ref,
     masks = (jnp.logical_and(same, row > col),
              jnp.logical_and(same, row >= col),
              sees_ref[0] != 0, last_ref[0] != 0)
-    parts = _chunk(
-        _load(q_ref, dk, heads), _load(k_ref, dk, heads),
-        _load(v_ref, dv, heads), _load(g_ref, dk, heads), beta, *masks, SUB)
+    q, k, v = (_load(ref, width, heads, offset) for ref, width in (
+        (q_ref, dk), (k_ref, dk), (v_ref, dv)))
+    if per_head:
+        gates = g_ref[0]
+        g = jnp.stack([_column(gates, lane, first + j) for j in range(heads)])
+        parts = _chunk_per_head(q, k, v, g, beta, *masks)
+    else:
+        parts = _chunk(q, k, v, _load(g_ref, dk, heads, offset), beta,
+                       *masks, SUB)
     return first, lane, beta, masks, parts
 
 
-def _forward_kernel(*refs, heads: int, dk: int, dv: int, keep: bool):
+def _forward_kernel(*refs, slab: int, heads: int, dk: int, dv: int,
+                    keep: bool, per_head: bool):
     """refs: the nine operands of ``_specs``, o, with ``keep`` the states
-    and the inverses, the scratch of every head's running state."""
+    and the inverses, the scratch of every head's running state.  The
+    slab's heads are taken ``heads`` at a time."""
     o_ref, state_ref = refs[9], refs[-1]
-    first, _, beta, _, parts = _step(*refs[:9], heads, dk, dv)
-    mine = pl.ds(first, heads)
+    for offset in range(0, slab, heads):
+        first, _, beta, _, parts = _step(
+            *refs[:9], heads, dk, dv, slab, offset, per_head)
+        mine = pl.ds(first, heads)
 
-    @pl.when(pl.program_id(1) == 0)
-    def _():
-        state_ref[mine] = jnp.zeros((heads, dv, dk), jnp.float32)
+        @pl.when(pl.program_id(1) == 0)
+        def _():
+            state_ref[mine] = jnp.zeros((heads, dv, dk), jnp.float32)
 
-    dtype = parts["rhs"].dtype
-    inverse = _inverse_unit_lower(beta * parts["a_mat"], SUB)
-    state = state_ref[mine]
-    wrote, _, _, read = _wrote(parts, inverse, state, dv)
-    wrote = wrote.astype(dtype)
-    _store(o_ref, read + _dot(parts["b_mat"], wrote))
-    state_ref[mine] = state * parts["carry"] + _dot(
-        wrote, parts["k_out"], _TN)
-    if keep:
-        refs[10][0, 0] = state
-        refs[11][0, 0] = inverse
+        dtype = parts["rhs"].dtype
+        inverse = _inverse_unit_lower(beta * parts["a_mat"], SUB)
+        state = state_ref[mine]
+        wrote, _, _, read = _wrote(parts, inverse, state, dv)
+        wrote = wrote.astype(dtype)
+        _store(o_ref, read + _dot(parts["b_mat"], wrote), offset)
+        state_ref[mine] = state * parts["carry"] + _dot(
+            wrote, parts["k_out"], _TN)
+        if keep:
+            _put(refs[10], state, offset)
+            _put(refs[11], inverse, offset)
 
 
-def _backward_kernel(*refs, heads: int, dk: int, dv: int):
+def _backward_kernel(*refs, slab: int, heads: int, dk: int, dv: int,
+                     per_head: bool):
     """refs: the nine operands of ``_specs``, the states, the inverses and
     o's cotangent, the five gradients, the scratch of every head's dS."""
+    for offset in range(0, slab, heads):
+        _backward_group(refs, slab, heads, dk, dv, offset, per_head)
+
+
+def _backward_group(refs, slab: int, heads: int, dk: int, dv: int,
+                    offset: int, per_head: bool):
     states_ref, inverse_ref, do_ref = refs[9:12]
     dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate_ref = refs[12:]
     first, lane, beta, (strict, lower, sees, last), p = _step(
-        *refs[:9], heads, dk, dv)
+        *refs[:9], heads, dk, dv, slab, offset, per_head)
     mine = pl.ds(first, heads)
     c, sub = refs[0].shape[1], SUB
     n = c // sub
@@ -292,18 +399,21 @@ def _backward_kernel(*refs, heads: int, dk: int, dv: int):
     def _():
         dstate_ref[mine] = jnp.zeros((heads, dv, dk), jnp.float32)
 
-    @pl.when(pl.program_id(2) == 0)
-    def _():
-        dbeta_ref[0] = jnp.zeros(dbeta_ref.shape[1:], jnp.float32)
+    if not offset:
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            dbeta_ref[0] = jnp.zeros(dbeta_ref.shape[1:], jnp.float32)
+            if per_head:
+                dg_ref[0] = jnp.zeros(dg_ref.shape[1:], jnp.float32)
 
     token = lax.broadcasted_iota(jnp.int32, (c, dk), 0)
     dtype = p["rhs"].dtype
     kf, qf = p["kf"], p["qf"]
-    inverse = inverse_ref[0, 0]
-    state_in = states_ref[0, 0]
+    inverse = _take(inverse_ref, heads, offset)
+    state_in = _take(states_ref, heads, offset)
     wrote, w, state, _ = _wrote(p, inverse, state_in, dv)
     wrote = wrote.astype(dtype)
-    do = _load(do_ref, dv, heads).astype(dtype)
+    do = _load(do_ref, dv, heads, offset).astype(dtype)
     dstate = dstate_ref[mine]                               # d S1, [h, dv, dk]
     dstate_op = dstate.astype(dtype)
 
@@ -333,8 +443,13 @@ def _backward_kernel(*refs, heads: int, dk: int, dv: int):
         dbetas = jnp.where(lane == first + j, dbeta[j], dbetas)
     dbeta_ref[0] = dbetas
     drhs = beta * drhs
-    _store(dv_ref, drhs[..., :dv])
+    _store(dv_ref, drhs[..., :dv], offset)
     dk_in = drhs[..., dv:]
+    if per_head:
+        _per_head_grads(p, da_mat, db_mat, dq_in, dk_in, dk_out, dcarry,
+                        sees, last, dq_ref, dk_ref, dg_ref, lane, first,
+                        offset)
+        return
 
     # the triangles: rows of sub-chunk a are lefts[a] k_cols[a]^T
     dlefts, dstarts = [], []
@@ -368,12 +483,51 @@ def _backward_kernel(*refs, heads: int, dk: int, dv: int):
         token == c - 1,
         jnp.sum(out_exponent, axis=1, keepdims=True) + dcarry * p["carry"],
         0.0)
-    _store(dg_ref, _running_sum(dg_cum, reverse=True))
+    _store(dg_ref, _running_sum(dg_cum, reverse=True), offset)
     _store(dq_ref, dq_rows * p["row_decay"]
-           + jnp.where(sees, dq_in * p["decay_in"], 0.0))
+           + jnp.where(sees, dq_in * p["decay_in"], 0.0), offset)
     _store(dk_ref, dk_rows * p["row_decay"] + dk_total
            + jnp.where(sees, dk_in * p["decay_in"], 0.0)
-           + jnp.where(last, dk_out * p["decay_out"], 0.0))
+           + jnp.where(last, dk_out * p["decay_out"], 0.0), offset)
+
+
+def _per_head_grads(p, da_mat, db_mat, dq_in, dk_in, dk_out, dcarry, sees,
+                    last, dq_ref, dk_ref, dg_ref, lane, first, offset: int):
+    """The backward's last part for one decay a head (the module's
+    docstring): q's and k's gradients through A, B and the decays, and
+    g's, [C, 1] a head, into its column of the [C, H] block."""
+    h, c, _ = da_mat.shape
+    dtype = p["q"].dtype
+    kf, qf = p["kf"], p["qf"]
+    # P_A = dA * D and P_B = dB * D, stacked along the rows
+    both = jnp.concatenate(
+        [da_mat * p["decay"], db_mat * p["decay"]], axis=1).astype(dtype)
+    rows = _dot(both, p["k"])                               # [P_A K; P_B K]
+    cols = _dot(both, jnp.concatenate([p["k"], p["q"]], axis=1), _TN)
+    exponent = (da_mat * p["kk"] + db_mat * p["qk"]) * p["decay"]
+    ones = jnp.ones((h, c, 1), jnp.float32)
+    dg_cum = (jnp.sum(exponent, axis=2, keepdims=True)
+              - _dot(exponent, ones, _TN, exact=True))       # [h, C, 1]
+    out_exponent = jnp.where(last, dk_out * kf, 0.0) * p["decay_out"]
+    dg_cum = dg_cum + jnp.sum(
+        jnp.where(sees, dq_in * qf + dk_in * kf, 0.0) * p["decay_in"]
+        - out_exponent, axis=2, keepdims=True)
+    token = lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    dg_cum = dg_cum + jnp.where(
+        token == c - 1,
+        jnp.sum(jnp.sum(out_exponent, axis=2, keepdims=True), axis=1,
+                keepdims=True)
+        + jnp.sum(dcarry * p["carry"], axis=2, keepdims=True), 0.0)
+    dg = _running_sum(dg_cum, reverse=True)
+    dgs = dg_ref[0]
+    for j in range(h):
+        dgs = jnp.where(lane == first + j, dg[j], dgs)
+    dg_ref[0] = dgs
+    _store(dq_ref, rows[:, c:]
+           + jnp.where(sees, dq_in * p["decay_in"], 0.0), offset)
+    _store(dk_ref, rows[:, :c] + cols
+           + jnp.where(sees, dk_in * p["decay_in"], 0.0)
+           + jnp.where(last, dk_out * p["decay_out"], 0.0), offset)
 
 
 def _marks(seg):
@@ -392,17 +546,17 @@ def _marks(seg):
     return column(seg), chunks[:, :, None, :], column(sees), column(last)
 
 
-def _specs(heads: int, dk: int, dv: int, at):
+def _specs(heads: int, dk: int, dv: int, at, per_head: bool):
     """(block specs of q, k, v, g, beta and the four marks; the spec of a
     [B, T, H·dv] array), for a grid (row, chunk, head slab) whose chunk
-    ``at(c)`` is."""
-    n = _heads_a_step(heads)
+    ``at(c)`` is; g is a [B, T, H] array where ``per_head``."""
+    n = _slab(heads, dk, dv)
     keys = pl.BlockSpec((1, CHUNK, n * dk), lambda b, c, h: (b, at(c), h))
     values = pl.BlockSpec((1, CHUNK, n * dv), lambda b, c, h: (b, at(c), h))
     column = pl.BlockSpec((1, CHUNK, 1), lambda b, c, h: (b, at(c), 0))
+    by_head = pl.BlockSpec((1, CHUNK, heads), lambda b, c, h: (b, at(c), 0))
     return [
-        keys, keys, values, keys,
-        pl.BlockSpec((1, CHUNK, heads), lambda b, c, h: (b, at(c), 0)),
+        keys, keys, values, by_head if per_head else keys, by_head,
         column,
         pl.BlockSpec((1, 1, 1, CHUNK), lambda b, c, h: (b, at(c), 0, 0)),
         column, column], keys, values
@@ -411,7 +565,7 @@ def _specs(heads: int, dk: int, dv: int, at):
 def _kept_specs(heads: int, dk: int, dv: int, at):
     """Block specs of what the backward keeps: a state and an inverse a
     chunk and head."""
-    n = _heads_a_step(heads)
+    n = _slab(heads, dk, dv)
     whole = lambda b, c, h: (b, at(c), h, 0, 0)
     return [pl.BlockSpec((1, 1, n, dv, dk), whole),
             pl.BlockSpec((1, 1, n, CHUNK, CHUNK), whole)]
@@ -419,14 +573,27 @@ def _kept_specs(heads: int, dk: int, dv: int, at):
 
 _ORDER = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary", "arbitrary"))
+# A block of every head (``_slab``) holds ten times a slab's operands and
+# each head's state twice, in and kept: more than a kernel gets unasked.
+_WHOLE = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+    vmem_limit_bytes=96 * 2 ** 20)
+
+
+def _layout(q, v, g, heads: int):
+    """(dk, dv, the slab, the heads a group, whether g is one a head, the
+    compiler's parameters)."""
+    dk, dv = q.shape[-1] // heads, v.shape[-1] // heads
+    slab = _slab(heads, dk, dv)
+    return (dk, dv, slab, _heads_a_step(slab), g.shape[-1] != q.shape[-1],
+            _ORDER if slab == _heads_a_step(heads) else _WHOLE)
 
 
 def _forward(q, k, v, g, beta, seg, heads: int, keep: bool):
     b, t, _ = q.shape
-    dk, dv = q.shape[-1] // heads, v.shape[-1] // heads
+    dk, dv, slab, group, per_head, params = _layout(q, v, g, heads)
     n = t // CHUNK
-    step = _heads_a_step(heads)
-    specs, _, values = _specs(heads, dk, dv, lambda c: c)
+    specs, _, values = _specs(heads, dk, dv, lambda c: c, per_head)
     out_shape = [pk._sds((b, t, heads * dv), jnp.float32, q)]
     out_specs = [values]
     if keep:
@@ -434,33 +601,33 @@ def _forward(q, k, v, g, beta, seg, heads: int, keep: bool):
                       pk._sds((b, n, heads, CHUNK, CHUNK), jnp.float32, q)]
         out_specs += _kept_specs(heads, dk, dv, lambda c: c)
     return pl.pallas_call(
-        functools.partial(_forward_kernel, heads=step, dk=dk, dv=dv,
-                          keep=keep),
-        grid=(b, n, heads // step),
+        functools.partial(_forward_kernel, slab=slab, heads=group, dk=dk,
+                          dv=dv, keep=keep, per_head=per_head),
+        grid=(b, n, heads // slab),
         in_specs=specs, out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((heads, dv, dk), jnp.float32)],
-        compiler_params=_ORDER, interpret=pk._interpret(),
+        compiler_params=params, interpret=pk._interpret(),
     )(q, k, v, g, beta, *_marks(seg))
 
 
 def _backward(q, k, v, g, beta, seg, states, inverses, do, heads: int):
     b, t, _ = q.shape
-    dk, dv = q.shape[-1] // heads, v.shape[-1] // heads
+    dk, dv, slab, group, per_head, params = _layout(q, v, g, heads)
     n = t // CHUNK
-    step = _heads_a_step(heads)
     at = lambda c: n - 1 - c
-    specs, keys, values = _specs(heads, dk, dv, at)
+    specs, keys, values = _specs(heads, dk, dv, at, per_head)
     return pl.pallas_call(
-        functools.partial(_backward_kernel, heads=step, dk=dk, dv=dv),
-        grid=(b, n, heads // step),
+        functools.partial(_backward_kernel, slab=slab, heads=group, dk=dk,
+                          dv=dv, per_head=per_head),
+        grid=(b, n, heads // slab),
         in_specs=specs + _kept_specs(heads, dk, dv, at) + [values],
-        out_specs=[keys, keys, values, keys, specs[4]],
+        out_specs=[keys, keys, values, specs[3], specs[4]],
         out_shape=[pk._sds(q.shape, q.dtype, q), pk._sds(k.shape, k.dtype, q),
                    pk._sds(v.shape, v.dtype, q),
                    pk._sds(g.shape, jnp.float32, q),
                    pk._sds(beta.shape, jnp.float32, q)],
         scratch_shapes=[pltpu.VMEM((heads, dv, dk), jnp.float32)],
-        compiler_params=_ORDER, interpret=pk._interpret(),
+        compiler_params=params, interpret=pk._interpret(),
     )(q, k, v, g, beta, *_marks(seg), states, inverses, do)
 
 
@@ -485,10 +652,10 @@ _delta_rule.defvjp(_delta_rule_fwd, _delta_rule_bwd)
 
 def delta_rule(q, k, v, g, beta, segment_ids=None):
     """The chunked delta rule by the kernels: q, k [B, T, H·dk] and v
-    [B, T, H·dv] of one type (the matmuls' operands'), g as q and beta
-    [B, T, H] float32, ``segment_ids`` [B, T] or None -> o [B, T, H·dv]
-    float32.  T is padded to whole chunks with tokens that write nothing
-    and decay nothing."""
+    [B, T, H·dv] of one type (the matmuls' operands'), g as q (a decay a
+    channel) or as beta (one a head) and beta [B, T, H] float32,
+    ``segment_ids`` [B, T] or None -> o [B, T, H·dv] float32.  T is padded
+    to whole chunks with tokens that write nothing and decay nothing."""
     b, t, _ = q.shape
     pad = -t % CHUNK
     seg = (jnp.ones((b, t), jnp.int32) if segment_ids is None
@@ -508,7 +675,20 @@ def delta_rule(q, k, v, g, beta, segment_ids=None):
 # (8, 128) tiling is another array (a 67 MB copy each way, each pass).
 # ---------------------------------------------------------------------------
 
-_NORM_ROWS = 256  # tokens of a norm kernel's block
+_NORM_ROWS = 256  # tokens of a norm kernel's block, at most
+_NORM_BLOCK = _NORM_ROWS * 4 * pk._LANES  # lanes x rows of a block, at most
+
+
+def _norm_rows(t: int, lanes: int, heads: int) -> int:
+    """Tokens of a norm kernel's block: ``_NORM_ROWS``, or half as many
+    until the block is no wider in all than four 128-lane heads' (a block
+    of 256 x 5760 float32 arrays asked 28 MB of VMEM); a power of two, so
+    that a row of 4096 tokens needs no padding."""
+    slab = _slab(heads, lanes // heads) * (lanes // heads)
+    rows = _NORM_ROWS
+    while rows > 8 and rows * slab > _NORM_BLOCK:
+        rows //= 2
+    return min(t, rows)
 
 
 def _norm_grid(x, heads: int):
@@ -516,8 +696,8 @@ def _norm_grid(x, heads: int):
     heads a block, tokens a block) of the norm kernels: T padded to whole
     blocks by the caller."""
     b, t, lanes = x.shape
-    step = _heads_a_step(heads)
-    rows = min(_NORM_ROWS, t)
+    step = _slab(heads, lanes // heads)
+    rows = _norm_rows(t, lanes, heads)
     wide = pl.BlockSpec((1, rows, step * (lanes // heads)),
                         lambda b_, r, h: (b_, r, h))
     narrow = pl.BlockSpec((1, rows, heads), lambda b_, r, h: (b_, r, 0))
@@ -553,7 +733,7 @@ def _rows_padded(arrays, rows: int):
 
 def _head_unit(x, heads: int, scale: float, dtype):
     t = x.shape[1]
-    (x,) = _rows_padded((x,), min(_NORM_ROWS, t))
+    (x,) = _rows_padded((x,), _norm_rows(t, x.shape[-1], heads))
     grid, wide, _, step = _norm_grid(x, heads)
     return pl.pallas_call(
         functools.partial(_unit_kernel, heads=step, scale=scale),
@@ -576,7 +756,7 @@ def _head_unit_fwd(x, heads, scale, dtype):
 
 def _head_unit_bwd(heads, scale, dtype, x, dy):
     t = x.shape[1]
-    x, dy = _rows_padded((x, dy), min(_NORM_ROWS, t))
+    x, dy = _rows_padded((x, dy), _norm_rows(t, x.shape[-1], heads))
     grid, wide, _, step = _norm_grid(x, heads)
     return (pl.pallas_call(
         functools.partial(_unit_grad_kernel, heads=step, scale=scale),
@@ -591,17 +771,24 @@ head_unit.defvjp(_head_unit_fwd, _head_unit_bwd)
 
 def _gated(x_ref, weight_ref, gate_ref, heads: int, eps: float):
     """Per head of the block: (lanes, x / rms(x), 1 / rms(x), weight, the
-    head's gate [rows, 1], its lane mask over the [rows, H] block)."""
+    head's gate [rows, 1] or, a gate a channel, [rows, d], its lane mask
+    over the [rows, H] block or None)."""
     width = x_ref.shape[-1] // heads
-    first = pl.program_id(2) * heads
-    gates = gate_ref[0]
-    lane = lax.broadcasted_iota(jnp.int32, gates.shape, 1)
+    per_channel = gate_ref.shape[-1] == x_ref.shape[-1]
+    if not per_channel:
+        first = pl.program_id(2) * heads
+        gates = gate_ref[0]
+        lane = lax.broadcasted_iota(jnp.int32, gates.shape, 1)
     for j in range(heads):
         lanes = slice(j * width, (j + 1) * width)
         x = x_ref[0, :, lanes]
         inverse = lax.rsqrt(jnp.mean(x * x, axis=1, keepdims=True) + eps)
-        yield (lanes, x * inverse, inverse, weight_ref[...],
-               _column(gates, lane, first + j), lane == first + j)
+        if per_channel:
+            yield (lanes, x * inverse, inverse, weight_ref[...],
+                   gate_ref[0, :, lanes], None)
+        else:
+            yield (lanes, x * inverse, inverse, weight_ref[...],
+                   _column(gates, lane, first + j), lane == first + j)
 
 
 def _rms_gate_kernel(x_ref, weight_ref, gate_ref, y_ref, *, heads: int,
@@ -619,35 +806,53 @@ def _rms_gate_grad_kernel(x_ref, weight_ref, gate_ref, dy_ref, dx_ref,
     def _():
         dweight_ref[...] = jnp.zeros(dweight_ref.shape, jnp.float32)
 
-    @pl.when(pl.program_id(2) == 0)
-    def _():
-        dgate_ref[0] = jnp.zeros(dgate_ref.shape[1:], jnp.float32)
+    per_channel = gate_ref.shape[-1] == x_ref.shape[-1]
+    if not per_channel:
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            dgate_ref[0] = jnp.zeros(dgate_ref.shape[1:], jnp.float32)
 
-    dgates, dweight = dgate_ref[0], dweight_ref[...]
+        dgates = dgate_ref[0]
+    dweight = dweight_ref[...]
     for lanes, normed, inverse, weight, gate, mask in _gated(
             x_ref, weight_ref, gate_ref, heads, eps):
         dy = dy_ref[0, :, lanes].astype(jnp.float32)
         through = dy * normed
         dweight = dweight + jnp.sum(through * gate, axis=0, keepdims=True)
-        dgates = jnp.where(
-            mask, jnp.sum(through * weight, axis=1, keepdims=True), dgates)
+        if per_channel:
+            dgate_ref[0, :, lanes] = through * weight
+        else:
+            dgates = jnp.where(
+                mask, jnp.sum(through * weight, axis=1, keepdims=True),
+                dgates)
         dnormed = dy * weight * gate
         dx_ref[0, :, lanes] = inverse * (dnormed - normed * jnp.mean(
             dnormed * normed, axis=1, keepdims=True))
-    dgate_ref[0], dweight_ref[...] = dgates, dweight
+    if not per_channel:
+        dgate_ref[0] = dgates
+    dweight_ref[...] = dweight
 
 
 def _weight_spec(weight):
     return pl.BlockSpec((1, weight.shape[0]), lambda b_, r, h: (0, 0))
 
 
+def _gate_heads(x, weight, gate):
+    """(heads, whether ``gate`` is one a channel) of head_rms_gate."""
+    if gate.shape[-1] == x.shape[-1]:
+        return x.shape[-1] // weight.shape[0], True
+    return gate.shape[-1], False
+
+
 def _head_rms_gate(x, weight, gate, eps: float, dtype):
-    t, heads = x.shape[1], gate.shape[-1]
-    x, gate = _rows_padded((x, gate), min(_NORM_ROWS, t))
+    t = x.shape[1]
+    heads, per_channel = _gate_heads(x, weight, gate)
+    x, gate = _rows_padded((x, gate), _norm_rows(t, x.shape[-1], heads))
     grid, wide, narrow, step = _norm_grid(x, heads)
     return pl.pallas_call(
         functools.partial(_rms_gate_kernel, heads=step, eps=eps),
-        grid=grid, in_specs=[wide, _weight_spec(weight), narrow],
+        grid=grid,
+        in_specs=[wide, _weight_spec(weight), wide if per_channel else narrow],
         out_specs=wide, out_shape=pk._sds(x.shape, dtype, x),
         interpret=pk._interpret(),
     )(x, weight[None], gate)[:, :t]
@@ -656,8 +861,8 @@ def _head_rms_gate(x, weight, gate, eps: float, dtype):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def head_rms_gate(x, weight, gate, eps: float, dtype):
     """RMSNorm over each head's d channels, times ``weight`` [d] and the
-    head's ``gate``: x [B, T, H·d] float32, gate [B, T, H] float32 ->
-    ``dtype`` [B, T, H·d]."""
+    head's ``gate``: x [B, T, H·d] float32, gate [B, T, H] (one a head) or
+    [B, T, H·d] (one a channel) float32 -> ``dtype`` [B, T, H·d]."""
     return _head_rms_gate(x, weight, gate, eps, dtype)
 
 
@@ -667,13 +872,16 @@ def _head_rms_gate_fwd(x, weight, gate, eps, dtype):
 
 def _head_rms_gate_bwd(eps, dtype, kept, dy):
     x, weight, gate = kept
-    t, heads = x.shape[1], gate.shape[-1]
-    x, gate, dy = _rows_padded((x, gate, dy), min(_NORM_ROWS, t))
+    t = x.shape[1]
+    heads, per_channel = _gate_heads(x, weight, gate)
+    x, gate, dy = _rows_padded((x, gate, dy),
+                               _norm_rows(t, x.shape[-1], heads))
     grid, wide, narrow, step = _norm_grid(x, heads)
+    gates = wide if per_channel else narrow
     dx, dweight, dgate = pl.pallas_call(
         functools.partial(_rms_gate_grad_kernel, heads=step, eps=eps),
-        grid=grid, in_specs=[wide, _weight_spec(weight), narrow, wide],
-        out_specs=[wide, _weight_spec(weight), narrow],
+        grid=grid, in_specs=[wide, _weight_spec(weight), gates, wide],
+        out_specs=[wide, _weight_spec(weight), gates],
         out_shape=[pk._sds(x.shape, jnp.float32, x),
                    pk._sds((1,) + weight.shape, jnp.float32, x),
                    pk._sds(gate.shape, jnp.float32, x)],

@@ -167,6 +167,125 @@ def test_a_width_the_chips_kernels_do_not_take_goes_to_the_chunked_form(
     assert called and _max_rel(got.reshape(b, t, h, -1), want) <= 1e-5
 
 
+# --------------------------------------------- one decay a head (Gated DeltaNet)
+def _gdn_inputs(seed, b=2, t=150, h=2, dk=96, dv=192):
+    """q, k, v as for a channel's decay; g [B, T, H, 1] down to -20 (most
+    of a head's tokens below the per-channel form's bound of -5 and some
+    near 0), beta in (0, 2)."""
+    q, k, v, _, _ = _kda_inputs(seed, "across", b=b, t=t, h=h, dk=dk, dv=dv)
+    keys = jax.random.split(jax.random.PRNGKey(seed + 100), 2)
+    g = -20.0 * jax.random.uniform(keys[0], (b, t, h, 1)) ** 2
+    beta = 2.0 * jax.nn.sigmoid(2.0 * jax.random.normal(keys[1], (b, t, h)))
+    return q, k, v, g, beta
+
+
+@impls
+@pytest.mark.parametrize("packed", [False, True], ids=["dense", "packed"])
+@pytest.mark.parametrize("h, dk, dv", [(2, 96, 192), (6, 32, 48)],
+                         ids=["olmo_widths", "two_groups_a_slab"])
+def test_one_decay_a_head_is_the_recurrence(h, dk, dv, packed, impl):
+    """Gated DeltaNet's case: keys of 96 and values of 192 (no whole
+    128-lane slab: a block holds every head), or six heads in two groups
+    of three a block; beta up to 2 (eigenvalues down to -1) and decays
+    far below -5, where exp(G_r - G_i) is one exact [C, C] matrix a head
+    and no sub-chunk bound applies; T = 150 with a document's boundary
+    inside a chunk.  Outputs and every gradient against the recurrence,
+    which takes g [B, T, H, 1] as it is."""
+    args = _gdn_inputs(7, h=h, dk=dk, dv=dv)
+    assert float(jnp.min(args[3])) < -15 and float(jnp.max(args[4])) > 1.5
+    seg = _packed_rows(150) if packed else None
+    weight = jax.random.normal(jax.random.PRNGKey(9), (2, 150, h, dv))
+    chunked = IMPLS[impl]
+
+    def scalar(fn):
+        return lambda *a: jnp.sum(fn(*a, segment_ids=seg) * weight)
+
+    with jax.default_matmul_precision("highest"):
+        got = chunked(*args, segment_ids=seg)
+        want = kda.kda_recurrent(*args, segment_ids=seg)
+        g_got = jax.grad(scalar(chunked), argnums=range(5))(*args)
+        g_want = jax.grad(scalar(kda.kda_recurrent), argnums=range(5))(*args)
+    assert got.shape == want.shape == (2, 150, h, dv)
+    assert _max_rel(got, want) <= 1e-5
+    scale = max(float(jnp.max(jnp.abs(w))) for w in g_want)
+    for name, a, w in zip("q k v g beta".split(), g_got, g_want):
+        assert a.shape == w.shape, name
+        assert float(jnp.max(jnp.abs(a - w))) <= 1e-4 * scale, name
+        assert float(jnp.max(jnp.abs(w))) > 0, name
+
+
+def test_one_decay_a_head_by_the_kernels_is_the_chunked_form_in_bf16():
+    """bfloat16 operands at Olmo-Hybrid's widths: the pair against the
+    plain chunked form at the same types, output and every gradient (as
+    ``test_kernels_at_the_chips_block_shape_are_the_chunked_form``)."""
+    b, t, h = 1, 192, 2
+    q, k, v, g, beta = _gdn_inputs(8, b=b, t=t, h=h)
+    q, k, v = (a.astype(jnp.bfloat16) for a in (q * 96 ** -0.5, k, v))
+    seg = jnp.asarray(np.repeat([1, 2], [100, 92])[None], jnp.int32)
+    weight = jax.random.normal(jax.random.PRNGKey(9), (b, t, h, 192))
+
+    def scalar(fn):
+        return lambda *a: jnp.sum(fn(*a, segment_ids=seg) * weight)
+
+    got = _by_the_kernels(q, k, v, g, beta, seg)
+    want = kda.kda_chunked(q, k, v, g, beta, seg)
+    assert got.dtype == want.dtype == jnp.float32
+    assert _max_rel(got, want) <= 2e-3
+    g_got = jax.grad(scalar(_by_the_kernels), argnums=range(5))(
+        q, k, v, g, beta)
+    g_want = jax.grad(scalar(kda.kda_chunked), argnums=range(5))(
+        q, k, v, g, beta)
+    for name, a, w in zip("q k v g beta".split(), g_got, g_want):
+        assert a.dtype == w.dtype and a.shape == w.shape, name
+        assert _max_rel(a.astype(jnp.float32), w.astype(jnp.float32)
+                        ) <= 2e-2, name
+
+
+def test_slabs_are_lane_slabs_or_every_head():
+    """Heads of whole 128-lane slabs keep their grid of four heads a
+    step; keys of 96 and values of 192 take all 30 heads a block, in
+    groups of three; the norms' blocks shrink their rows as they widen."""
+    assert kda_kernels._slab(32, 128, 128) == 4
+    assert kda_kernels._slab(30, 96, 192) == 30
+    assert kda_kernels._heads_a_step(30) == 3
+    assert kda_kernels._slab(30, 192) == 30 and kda_kernels._slab(32, 64) == 4
+    assert kda_kernels._norm_rows(4096, 32 * 128, 32) == 256
+    assert kda_kernels._norm_rows(4096, 30 * 192, 30) == 16
+    assert kda_kernels._norm_rows(4096, 30 * 96, 30) == 32
+    assert kda_kernels._norm_rows(20, 30 * 96, 30) == 20
+
+
+@pytest.mark.parametrize("h, d", [(2, 16), (5, 96)])
+def test_a_gate_a_channel_is_the_plain_norm(h, d, monkeypatch):
+    """The output's RMSNorm a head times a gate a channel (Gated
+    DeltaNet's SiLU(x W_z)) by the kernel on [B, T, H·d], against the
+    plain formula: values and every gradient; heads of 96 in a block of
+    every head."""
+    b, t = 2, 70
+    keys = jax.random.split(jax.random.PRNGKey(6), 4)
+    x = jax.random.normal(keys[0], (b, t, h * d))
+    weight = 1.0 + 0.1 * jax.random.normal(keys[1], (d,))
+    gate = jax.nn.silu(jax.random.normal(keys[2], (b, t, h * d)))
+    cotangent = jax.random.normal(keys[3], (b, t, h * d))
+
+    def plain(x, weight, gate):
+        y = x.reshape(b, t, h, d)
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + 1e-6)
+        return (y * weight).reshape(b, t, h * d) * gate
+
+    def both(fn):
+        return (fn(x, weight, gate),) + jax.grad(
+            lambda *a: jnp.sum(fn(*a) * cotangent), argnums=range(3))(
+                x, weight, gate)
+
+    got = both(lambda *a: kda.rms_gate_heads(*a, 1e-6, jnp.float32))
+    want = both(plain)
+    for a, w in zip(got, want):
+        assert a.shape == w.shape and _max_rel(a, w) <= 1e-5
+    unit = kda.unit_heads(x, h, 0.5, jnp.float32).reshape(b, t, h, d)
+    np.testing.assert_allclose(jnp.sum(unit * unit, -1), 0.25, rtol=1e-5)
+
+
 @pytest.mark.parametrize("t", [70, 300])
 def test_head_norm_kernels_are_the_plain_norms(t, monkeypatch):
     """q's and k's L2 norm and the output's RMSNorm and gate as kernels on
@@ -730,6 +849,86 @@ def test_the_delta_rule_mixer_stays_on_the_projections_layout():
             # (the documents' marks a chunk are [B, n, 1, C] integers)
             assert (out.aval.ndim < 4 or eqn in calls
                     or out.aval.dtype == jnp.int32), eqn
+
+
+def _olmo_config(**over):
+    return _mla_config(**{
+        **dict(num_layers=2, layer_kinds=("gdn", "full"), positions="none",
+               pre_norm=False, post_norm=True, qk_norm=True, num_heads=2,
+               head_dim=24, gdn_key_dim=16, gdn_value_dim=32),
+        **over})
+
+
+def test_olmo2_blocks_and_the_gdn_mixer():
+    """OLMo 2's block (a norm on each sublayer's output, none on its
+    input), q and k normed over their whole projections, no positions, and
+    the Gated DeltaNet mixer: the parameters each makes, the gauges, and
+    the mixer's scopes with the core as two kernel calls in a gradient."""
+    from horovod_tpu import metrics
+
+    cfg = _olmo_config()
+    model = transformer.Transformer(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 70), 0, 64)
+    params = model.init(jax.random.PRNGKey(1), tokens)
+    p = params["params"]
+    assert "wpe" not in p
+    for i in range(2):
+        assert {"ln_attn_post", "ln_mlp_post"} <= set(p[f"block_{i}"])
+        assert not {"ln_attn", "ln_mlp"} & set(p[f"block_{i}"])
+    gdn = p["block_0"]["gdn"]
+    assert gdn["q"]["kernel"].shape == gdn["k"]["kernel"].shape == (48, 32)
+    assert gdn["v"]["kernel"].shape == gdn["z"]["kernel"].shape == (48, 64)
+    assert gdn["a"]["kernel"].shape == gdn["b"]["kernel"].shape == (48, 2)
+    assert gdn["A_log"].shape == gdn["dt_bias"].shape == (2,)
+    assert gdn["o_norm"]["scale"].shape == (32,)
+    assert gdn["conv_v"].shape == (4, 64)
+    attn = p["block_1"]["attn"]
+    assert attn["q_norm"]["scale"].shape == attn["k_norm"]["scale"].shape \
+        == (48,)
+    assert float(jnp.min(-jnp.exp(gdn["A_log"]))) >= -16.0
+
+    def loss(p):
+        return jnp.sum(model.apply(p, tokens)[0])
+
+    logits, _ = model.apply(params, tokens)
+    assert np.isfinite(np.asarray(logits)).all()
+    assert metrics.get_gauge("model.gdn.kernel_layers") == 1 == \
+        metrics.get_gauge("model.layer_kinds", {"kind": "gdn"})
+    eqns = [e for e in _equations(jax.make_jaxpr(jax.grad(loss))(
+        params).jaxpr) if "/gdn/" in f"/{e.source_info.name_stack}/"]
+    calls = [str(e.source_info.name_stack).split("block_0/gdn")[-1]
+             for e in eqns if e.primitive.name == "pallas_call"]
+    # the core's pair; q's and k's L2 norms and the output's norm each way
+    assert sorted(calls) == ["/conv"] * 4 + ["/core"] * 2 + ["/norm"] * 2
+    assert {"conv", "gate", "core", "norm"} <= {
+        part for e in eqns for part in str(e.source_info.name_stack).split("/")}
+    with pytest.raises(ValueError, match="pre_norm, post_norm"):
+        transformer.Transformer(dataclasses.replace(
+            cfg, post_norm=False)).init(jax.random.PRNGKey(1), tokens)
+
+
+def test_a_width_the_chip_does_not_take_falls_back_and_the_gauge_says_so(
+        monkeypatch):
+    """On the chip keys of 16 and values of 24 are no multiple of 32
+    lanes: the GDN core falls to the chunked form and its norms to XLA
+    (same output), and ``model.gdn.kernel_layers`` reads 0."""
+    from horovod_tpu import metrics
+    from horovod_tpu.ops import pallas_kernels
+
+    cfg = _olmo_config(layer_kinds=("gdn",), num_layers=1, gdn_value_dim=24)
+    model = transformer.Transformer(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (1, 40), 0, 64)
+    params = model.init(jax.random.PRNGKey(1), tokens)
+    want, _ = model.apply(params, tokens)
+    assert metrics.get_gauge("model.gdn.kernel_layers") == 1
+    called = []
+    real = kda.kda_chunk_major
+    monkeypatch.setattr(kda, "kda_chunk_major",
+                        lambda *a: called.append(1) or real(*a))
+    monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
+    got, _ = model.apply(params, tokens)
+    assert called and metrics.get_gauge("model.gdn.kernel_layers") == 0
+    assert _max_rel(got, want) <= 1e-4
 
 
 # ------------------------------------------- one group: a 256-wide router's way

@@ -252,7 +252,7 @@ def test_delta_rule_grad_compiles_for_the_chip(
     gate = jax.ShapeDtypeStruct((b, t, h * d), jnp.float32, sharding=one_chip)
     beta = jax.ShapeDtypeStruct((b, t, h), jnp.float32, sharding=one_chip)
     seg = jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip)
-    assert kda_kernels.takes(d) and not kda_kernels.takes(64)
+    assert kda_kernels.takes(d) and not kda_kernels.takes(48)
 
     def grads(q, k, v, g, beta, w, seg):
         def loss(*operands):
@@ -275,6 +275,74 @@ def test_delta_rule_grad_compiles_for_the_chip(
     kept = b * chunks * h * (d * d + 64 * 128) * 4
     assert compiled.memory_analysis().temp_size_in_bytes < \
         kept + 3 * b * padded * h * d * 4
+
+
+@pytest.mark.parametrize("packed", [False, True])
+def test_one_decay_a_head_compiles_for_the_chip(one_chip, mosaic, packed):
+    """olmo_hybrid7b.ring1x4096's Gated DeltaNet core: 30 heads of 96 keys
+    and 192 values (no whole 128-lane slab: a block holds every head, as
+    static lane slices), one decay a head [B, T, H]: a gradient is two
+    Mosaic calls and no loop, and the temporaries are what the backward
+    keeps (a state, 128 lanes wide, and an inverse a chunk and head)
+    beside o's cotangent."""
+    from horovod_tpu.ops import kda, kda_kernels
+
+    b, t, h, dk, dv = 1, 4096, 30, 96, 192
+    keys = jax.ShapeDtypeStruct((b, t, h * dk), jnp.bfloat16,
+                                sharding=one_chip)
+    values = jax.ShapeDtypeStruct((b, t, h * dv), jnp.bfloat16,
+                                  sharding=one_chip)
+    head = jax.ShapeDtypeStruct((b, t, h), jnp.float32, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((b, t, h * dv), jnp.float32, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip)
+    assert kda_kernels.takes(dk) and kda_kernels.takes(dv)
+
+    def grads(q, k, v, g, beta, w, seg):
+        def loss(*operands):
+            return jnp.sum(kda.kda(*operands, seg if packed else None) * w)
+
+        return jax.grad(loss, argnums=range(5))(q, k, v, g, beta)
+
+    compiled = jax.jit(grads).lower(
+        keys, keys, values, head, head, w, seg).compile()
+    text = compiled.as_text()
+    assert len([ln for ln in text.splitlines()
+                if "tpu_custom_call" in ln]) == 2
+    assert " while(" not in text
+    kept = b * (t // kda_kernels.CHUNK) * h * (dv * 128 + 64 * 128) * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        kept + 3 * b * t * h * dv * 4
+
+
+def test_olmo_head_norms_compile_for_the_chip(one_chip, mosaic):
+    """The norms around that core: q's L2 norm over heads of 96 and the
+    output's RMSNorm over heads of 192 times a gate a channel, each a
+    kernel forward and one backward on a block of every head."""
+    from horovod_tpu.ops import kda
+
+    b, t, h = 1, 4096, 30
+    keys = jax.ShapeDtypeStruct((b, t, h * 96), jnp.float32,
+                                sharding=one_chip)
+    values = jax.ShapeDtypeStruct((b, t, h * 192), jnp.float32,
+                                  sharding=one_chip)
+    weight = jax.ShapeDtypeStruct((192,), jnp.float32, sharding=one_chip)
+
+    def grads(x, o, weight, gate):
+        def loss(x, o, weight, gate):
+            q = kda.unit_heads(x, h, 96 ** -0.5, jnp.bfloat16)
+            y = kda.rms_gate_heads(o, weight, gate, 1e-6, jnp.bfloat16)
+            # squares: the gradient needs the forwards' outputs too
+            return (jnp.sum(jnp.square(q.astype(jnp.float32)))
+                    + jnp.sum(jnp.square(y.astype(jnp.float32))))
+
+        return jax.grad(loss, argnums=range(4))(x, o, weight, gate)
+
+    text = jax.jit(grads).lower(keys, values, weight, values).compile(
+        ).as_text()
+    assert len([ln for ln in text.splitlines()
+                if "tpu_custom_call" in ln]) == 4
+    for ln in _entry(text):  # 4096 rows are whole blocks: nothing padded
+        assert _result_and_opcode(ln)[1] not in ("pad", "copy"), ln
 
 
 @pytest.mark.parametrize("shape, dtype", [
