@@ -64,7 +64,7 @@ from ..parallel.tensor import (
 )
 from ..parallel.ulysses import ulysses_attention
 from ..ops import kda_kernels
-from ..ops.kda import kda, rms_gate_heads, unit_heads
+from ..ops.kda import conv_silu_heads, kda, rms_gate_heads
 from ..ops.pallas_kernels import (
     flash_attention,
     flash_attention_qkv,
@@ -515,16 +515,26 @@ def _short_conv(x: jax.Array, taps: jax.Array,
                 segment_ids: Optional[jax.Array]) -> jax.Array:
     """Causal depthwise convolution along T: y_t = sum_j taps[j] x_{t-j},
     [B, T, C] by [K, C], float32; a packed row's documents do not see
-    each other."""
-    b, t, _ = x.shape
+    each other (``segment_ids`` [B, T, 1]).  ``ops/kda_kernels.
+    short_conv_silu`` runs this very function on a block of rows and its
+    halo, and its VJP in the backward, so the shifts are slices and
+    concatenations, which Mosaic lowers both ways.  The benchmark's fault
+    tests patch it on this module, so the mixers hand on the name as it is
+    at call time."""
+    t = x.shape[1]
     x = x.astype(jnp.float32)
+
+    def delayed(a, j, fill):
+        j = min(j, t)
+        return jnp.concatenate([jnp.full_like(a[:, :j], fill), a[:, :t - j]],
+                               axis=1)
+
     y = x * taps[0]
     for j in range(1, taps.shape[0]):
-        shifted = jnp.pad(x, ((0, 0), (j, 0), (0, 0)))[:, :t]
+        shifted = delayed(x, j, 0.0)
         if segment_ids is not None:
-            same = segment_ids == jnp.pad(
-                segment_ids, ((0, 0), (j, 0)), constant_values=-1)[:, :t]
-            shifted = jnp.where(same[..., None], shifted, 0.0)
+            shifted = jnp.where(segment_ids == delayed(segment_ids, j, -1),
+                                shifted, 0.0)
         y = y + shifted * taps[j]
     return y
 
@@ -571,21 +581,20 @@ class KDAMixer(nn.Module):
         # SiLU, the norms and the core (ops/kda.py: its kernels read a
         # chunk of one head as a block of that array), and o comes back
         # the same way: no [B, T, H, d] and nothing chunk-major is made.
-        def convolved(name):
+        # q and k are normed in float32 and handed on as the matmuls will
+        # take them.
+        def convolved(name, scale=None):
             taps = self.param(
                 f"conv_{name}", nn.initializers.normal(0.5),
                 (cfg.kda_conv, h * d), jnp.float32)
             y = projected(name)
             with jax.named_scope("conv"):
-                return nn.silu(_short_conv(y, taps, segment_ids))
+                return conv_silu_heads(y, taps, segment_ids, _short_conv, h,
+                                       scale, cfg.dtype)
 
-        q, k, v = convolved("q"), convolved("k"), convolved("v")
+        q, k, v = (convolved("q", d ** -0.5), convolved("k", 1.0),
+                   convolved("v"))
         raw_gate = projected("f")
-        with jax.named_scope("conv"):
-            # normed in float32, handed on as the matmuls will take them
-            q = unit_heads(q, h, d ** -0.5, cfg.dtype)
-            k = unit_heads(k, h, 1.0, cfg.dtype)
-            v = v.astype(cfg.dtype)
         with jax.named_scope("gate"):
             a_log = self.param(
                 "A_log", lambda key, shape: jnp.log(jax.random.uniform(
@@ -658,19 +667,16 @@ class GDNMixer(nn.Module):
                             name=name)(x.astype(dtype))
 
         # [B, T, H·d] throughout, as the projections leave them
-        def convolved(name, width):
+        def convolved(name, width, scale=None):
             taps = self.param(f"conv_{name}", nn.initializers.normal(0.5),
                               (cfg.kda_conv, width), jnp.float32)
             y = projected(name, width)
             with jax.named_scope("conv"):
-                return nn.silu(_short_conv(y, taps, segment_ids))
+                return conv_silu_heads(y, taps, segment_ids, _short_conv, h,
+                                       scale, cfg.dtype)
 
-        q, k, v = (convolved("q", h * dk), convolved("k", h * dk),
-                   convolved("v", h * dv))
-        with jax.named_scope("conv"):
-            q = unit_heads(q, h, dk ** -0.5, cfg.dtype)
-            k = unit_heads(k, h, 1.0, cfg.dtype)
-            v = v.astype(cfg.dtype)
+        q, k, v = (convolved("q", h * dk, dk ** -0.5),
+                   convolved("k", h * dk, 1.0), convolved("v", h * dv))
         with jax.named_scope("gate"):
             a_log = self.param("A_log", _a_log_init, (h,))
             dt_bias = self.param("dt_bias", _dt_bias_init, (h,))
@@ -904,16 +910,17 @@ class Transformer(nn.Module):
                 "model.layer_kinds", sum(name in kind for kind in kinds),
                 {"kind": name})
         # the delta-rule layers whose core ran as ops/kda_kernels' pair
-        metrics.set_gauge(
-            "model.kda.kernel_layers",
-            sum("kda" in kind for kind in kinds)
-            * kda_kernels.takes(cfg.head_dim))
+        kda_layers = (sum("kda" in kind for kind in kinds)
+                      * kda_kernels.takes(cfg.head_dim))
         # and those with one decay a head (both widths taken, or neither)
-        metrics.set_gauge(
-            "model.gdn.kernel_layers",
-            sum("gdn" in kind for kind in kinds)
-            * (kda_kernels.takes(cfg.gdn_key_dim)
-               and kda_kernels.takes(cfg.gdn_value_dim)))
+        gdn_layers = (sum("gdn" in kind for kind in kinds)
+                      * (kda_kernels.takes(cfg.gdn_key_dim)
+                         and kda_kernels.takes(cfg.gdn_value_dim)))
+        metrics.set_gauge("model.kda.kernel_layers", kda_layers)
+        metrics.set_gauge("model.gdn.kernel_layers", gdn_layers)
+        # the mixers whose three short convolutions, SiLU and norms ran as
+        # ops/kda_kernels' convolution pair: it takes the same widths
+        metrics.set_gauge("model.conv.kernel_layers", kda_layers + gdn_layers)
         for i, (name, _) in enumerate(kinds):
             if name in ("full", "window") and cfg.attn_impl == "flash":
                 labels = {"kind": name}

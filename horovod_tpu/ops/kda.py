@@ -44,9 +44,11 @@ that read a chunk of a head as a block of those arrays and keep the
 chunk's triangles, the inverse, U, W and the running state in VMEM.  Of
 the forward the backward keeps the state entering each chunk and the
 chunk's inverse (``kda_kernels``' docstring has the equations); everything
-else it makes again chunk by chunk.  :func:`unit_heads` and
-:func:`rms_gate_heads` are the per-head norms around the core on the same
-layout.  No [T, T] array and no [chunk, chunk, dk] array is made anywhere.
+else it makes again chunk by chunk.  :func:`conv_silu_heads` (the short
+convolution, SiLU and q's and k's norms) and :func:`rms_gate_heads` (the
+output's norm and gate) are what comes before and after the core, on the
+same layout.  No [T, T] array and no [chunk, chunk, dk] array is made
+anywhere.
 """
 
 from __future__ import annotations
@@ -291,13 +293,30 @@ def kda(q, k, v, g, beta, segment_ids: Optional[jax.Array] = None):
     return out.reshape(b, t, -1)
 
 
+def conv_silu_heads(x, taps, segment_ids, conv, heads: int, scale, dtype):
+    """SiLU(conv(x)) and, ``scale`` given, each head's L2 norm times it
+    (q's and k's), in float32, as ``dtype``: x [B, T, H·d] as projected,
+    taps [K, H·d] (the parameters' type), ``segment_ids`` [B, T] or None,
+    ``conv(x, taps, ids)`` the causal depthwise convolution in float32
+    (ids [B, T, 1] or None).  One kernel pair on that layout where the
+    kernels take the heads' width (``kda_kernels.short_conv_silu``, which
+    runs ``conv`` on a block of rows and its halo); else XLA, the norm
+    through [B, T, H, d]."""
+    seg = (None if segment_ids is None
+           else segment_ids.astype(jnp.int32)[..., None])
+    if kda_kernels.takes(x.shape[-1] // heads):
+        return kda_kernels.short_conv_silu(x, taps, seg, conv, heads, scale,
+                                           dtype)
+    y = jax.nn.silu(conv(x, taps, seg))
+    if scale is None:
+        return y.astype(dtype)
+    return unit_heads(y, heads, scale, dtype)
+
+
 def unit_heads(x, heads: int, scale: float, dtype):
     """``x / |x|`` a head times ``scale``, in float32, as ``dtype``: x
-    [B, T, H·d] float32 (q's and k's L2 norm).  A kernel on that layout
-    where the kernels take the width; else through [B, T, H, d]."""
+    [B, T, H·d] float32, through [B, T, H, d]."""
     b, t, lanes = x.shape
-    if kda_kernels.takes(lanes // heads):
-        return kda_kernels.head_unit(x, heads, scale, dtype)
     x = x.reshape(b, t, heads, -1)
     x = x * lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
     return (x * scale).astype(dtype).reshape(b, t, lanes)

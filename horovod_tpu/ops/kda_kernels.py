@@ -670,7 +670,7 @@ def delta_rule(q, k, v, g, beta, segment_ids=None):
 
 
 # ---------------------------------------------------------------------------
-# The per-head norms around the core, on [B, T, H·d] as well: a reduction
+# The output's per-head norm and gate, on [B, T, H·d] as well: a reduction
 # over a head's d channels in XLA goes through [B, T, H, d], which under the
 # (8, 128) tiling is another array (a 67 MB copy each way, each pass).
 # ---------------------------------------------------------------------------
@@ -704,69 +704,11 @@ def _norm_grid(x, heads: int):
     return (b, t // rows, heads // step), wide, narrow, step
 
 
-def _unit_kernel(x_ref, y_ref, *, heads: int, scale: float):
-    width = x_ref.shape[-1] // heads
-    for j in range(heads):
-        lanes = slice(j * width, (j + 1) * width)
-        x = x_ref[0, :, lanes]
-        inverse = lax.rsqrt(jnp.sum(x * x, axis=1, keepdims=True) + 1e-6)
-        y_ref[0, :, lanes] = (x * inverse * scale).astype(y_ref.dtype)
-
-
-def _unit_grad_kernel(x_ref, dy_ref, dx_ref, *, heads: int, scale: float):
-    width = x_ref.shape[-1] // heads
-    for j in range(heads):
-        lanes = slice(j * width, (j + 1) * width)
-        x, dy = x_ref[0, :, lanes], dy_ref[0, :, lanes].astype(jnp.float32)
-        inverse = lax.rsqrt(jnp.sum(x * x, axis=1, keepdims=True) + 1e-6)
-        along = jnp.sum(dy * x, axis=1, keepdims=True)
-        dx_ref[0, :, lanes] = scale * inverse * (
-            dy - x * (inverse * inverse * along))
-
-
 def _rows_padded(arrays, rows: int):
     pad = -arrays[0].shape[1] % rows
     if not pad:
         return arrays
     return tuple(jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in arrays)
-
-
-def _head_unit(x, heads: int, scale: float, dtype):
-    t = x.shape[1]
-    (x,) = _rows_padded((x,), _norm_rows(t, x.shape[-1], heads))
-    grid, wide, _, step = _norm_grid(x, heads)
-    return pl.pallas_call(
-        functools.partial(_unit_kernel, heads=step, scale=scale),
-        grid=grid, in_specs=[wide], out_specs=wide,
-        out_shape=pk._sds(x.shape, dtype, x), interpret=pk._interpret(),
-    )(x)[:, :t]
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
-def head_unit(x, heads: int, scale: float, dtype):
-    """``x / |x|`` a head times ``scale``: x [B, T, H·d] float32 ->
-    ``dtype``; |x|^2 is the sum of squares over the head's d channels plus
-    1e-6."""
-    return _head_unit(x, heads, scale, dtype)
-
-
-def _head_unit_fwd(x, heads, scale, dtype):
-    return _head_unit(x, heads, scale, dtype), x
-
-
-def _head_unit_bwd(heads, scale, dtype, x, dy):
-    t = x.shape[1]
-    x, dy = _rows_padded((x, dy), _norm_rows(t, x.shape[-1], heads))
-    grid, wide, _, step = _norm_grid(x, heads)
-    return (pl.pallas_call(
-        functools.partial(_unit_grad_kernel, heads=step, scale=scale),
-        grid=grid, in_specs=[wide, wide], out_specs=wide,
-        out_shape=pk._sds(x.shape, jnp.float32, x),
-        interpret=pk._interpret(),
-    )(x, dy)[:, :t],)
-
-
-head_unit.defvjp(_head_unit_fwd, _head_unit_bwd)
 
 
 def _gated(x_ref, weight_ref, gate_ref, heads: int, eps: float):
@@ -893,3 +835,227 @@ def _head_rms_gate_bwd(eps, dtype, kept, dy):
 
 
 head_rms_gate.defvjp(_head_rms_gate_fwd, _head_rms_gate_bwd)
+
+
+# ---------------------------------------------------------------------------
+# The short convolution, SiLU and q's and k's L2 norm a head, one pass on the
+# projection's [B, T, C] as it lies: a block of rows reads the rows before it
+# (the backward also those after it) as a halo, a second block spec on the
+# same array, and the convolution's float32 output never reaches HBM.
+# ---------------------------------------------------------------------------
+
+_CONV_ROWS = 256       # tokens of a block, at most
+_CONV_BYTES = 1 << 21  # bytes of x's block, at most
+
+_CONV_PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel",) * 3)
+
+
+def _conv_layout(x, taps, heads: int, unit: bool):
+    """(tokens of a block, tokens of a halo, lanes of a block, the lane
+    slices a kernel takes one at a time) of the convolution's kernels on x
+    [B, T, C].  A halo is one tile of x's type (8 rows of float32, 16 of
+    bfloat16) and has to reach back as far as the taps.  A block's lanes
+    are whole heads where each head is normed (``_slab``), else up to eight
+    128-lane columns that divide the width; its rows ``_CONV_ROWS``, halved
+    while x's block is more than ``_CONV_BYTES``.  A kernel takes the
+    fewest heads that fill whole 128-lane columns at a time where they are
+    normed (four of 96 lanes; a slice at a column's edge costs Mosaic no
+    turn of the lanes), else a 128-lane column."""
+    t, c = x.shape[1:]
+    width = c // heads
+    halo = 32 // x.dtype.itemsize
+    if taps.shape[0] - 1 > halo:
+        raise ValueError(
+            f"{taps.shape[0]} taps reach past a halo of {halo} rows")
+    lanes = _slab(heads, width) * width
+    if not unit:
+        lanes = next((n * pk._LANES for n in range(8, 0, -1)
+                      if c % (n * pk._LANES) == 0), lanes)
+    rows = _CONV_ROWS
+    while rows > halo and rows * lanes * x.dtype.itemsize > _CONV_BYTES:
+        rows //= 2
+    rows = min(rows, -(-t // halo) * halo)
+    if not unit:
+        step = pk._LANES if lanes % pk._LANES == 0 else width
+    else:  # the fewest heads that fill whole 128-lane columns
+        step = width * next((n for n in range(1, 9)
+                             if n * width % pk._LANES == 0), lanes // width)
+    return rows, halo, lanes, [slice(a, min(a + step, lanes))
+                               for a in range(0, lanes, step)]
+
+
+def _conv_specs(x, k: int, rows: int, halo: int, lanes: int):
+    """Block specs on a grid (row, block of tokens, block of lanes), x's T
+    whole blocks: of a [B, T, C] array ("block", and the halos "before" and
+    "after" it, their index clamped to the row where none is), of the
+    [B, T, 1] segment ids the same, and of the taps [K, C]."""
+    per, last = rows // halo, x.shape[1] // halo - 1
+    wide = {"block": pl.BlockSpec((1, rows, lanes), lambda b, r, h: (b, r, h))}
+    ids = {"block": pl.BlockSpec((1, rows, 1), lambda b, r, h: (b, r, 0))}
+    for name, at in (("before", lambda r: jnp.maximum(r * per - 1, 0)),
+                     ("after", lambda r: jnp.minimum((r + 1) * per, last))):
+        wide[name] = pl.BlockSpec(
+            (1, halo, lanes), lambda b, r, h, at=at: (b, at(r), h))
+        ids[name] = pl.BlockSpec(
+            (1, halo, 1), lambda b, r, h, at=at: (b, at(r), 0))
+    return wide, ids, pl.BlockSpec((k, lanes), lambda b, r, h: (0, h))
+
+
+def _joined(refs, lanes, dropped):
+    """The rows of ``refs`` (halo, block, halo) one after another as
+    float32 [rows, lanes]; a halo that lies past the row's start or end
+    (``dropped``) is zeros, as the convolution's padding is."""
+    parts = [ref[0, :, lanes].astype(jnp.float32) for ref in refs]
+    return jnp.concatenate([
+        part if drop is None else jnp.where(drop, 0.0, part)
+        for part, drop in zip(parts, dropped)])
+
+
+def _ids(refs):
+    """The segment ids of the halos and the block, [1, rows, 1], or None."""
+    return jnp.concatenate([ref[0] for ref in refs])[None] if refs else None
+
+
+def _activated(z, scale, width: int):
+    """SiLU, then with ``scale`` x / |x| a head of ``width`` lanes times it
+    (the heads side by side along the lanes)."""
+    s = jax.nn.silu(z)
+    if scale is None:
+        return s
+    squares = s * s
+    if s.shape[-1] == width:
+        return s * lax.rsqrt(
+            jnp.sum(squares, axis=-1, keepdims=True) + 1e-6) * scale
+    head = lax.broadcasted_iota(jnp.int32, s.shape, s.ndim - 1) // width
+    inverse = jnp.zeros_like(s)
+    for j in range(s.shape[-1] // width):
+        mine = head == j
+        inverse = jnp.where(mine, lax.rsqrt(jnp.sum(
+            jnp.where(mine, squares, 0.0), axis=-1, keepdims=True) + 1e-6),
+            inverse)
+    return s * inverse * scale
+
+
+def _conv_kernel(*refs, conv, groups, scale, width: int, halo: int):
+    """refs: x's halo before its block and the block, the taps, with
+    segment ids their halo and block, y."""
+    x_refs, taps_ref, y_ref = refs[:2], refs[2], refs[-1]
+    ids = _ids(refs[3:-1])
+    first = pl.program_id(1) == 0
+    for lanes in groups:
+        x = _joined(x_refs, lanes, (first, None))
+        taps = taps_ref[:, lanes].astype(jnp.float32)
+        z = conv(x[None], taps, ids)[0, halo:]
+        y_ref[0, :, lanes] = _activated(z, scale, width).astype(y_ref.dtype)
+
+
+def _conv_grad_kernel(*refs, conv, groups, scale, width: int,
+                      halo: int):
+    """refs: x's halo before its block, the block and the halo after; the
+    taps; dy's block and the halo after; with segment ids their three; dx
+    and the block's share of the taps' gradient.  A token's dx takes the
+    cotangents of the K - 1 tokens after it, which the halo after holds."""
+    x_refs, taps_ref, dy_refs = refs[:3], refs[3], refs[4:6]
+    dx_ref, dtaps_ref = refs[-2:]
+    ids = _ids(refs[6:-2])
+    block = pl.program_id(1)
+    first, last = block == 0, block == pl.num_programs(1) - 1
+    rows = dx_ref.shape[1]
+    for lanes in groups:
+        x = _joined(x_refs, lanes, (first, None, last))
+        dy = _joined(dy_refs, lanes, (None, last))
+        z, through_conv = jax.vjp(
+            lambda x, taps: conv(x[None], taps, ids)[0], x,
+            taps_ref[:, lanes].astype(jnp.float32))
+        _, through_activation = jax.vjp(
+            functools.partial(_activated, scale=scale, width=width), z[halo:])
+        (dz,) = through_activation(dy)  # the block's tokens and the halo's
+        edge = jnp.zeros((halo, dz.shape[1]), jnp.float32)
+        dx = through_conv(jnp.concatenate([edge, dz]))[0]
+        dx_ref[0, :, lanes] = dx[halo:halo + rows].astype(dx_ref.dtype)
+        # of this block's tokens alone: the next block counts its own
+        dtaps_ref[0, 0, :, lanes] = through_conv(
+            jnp.concatenate([edge, dz[:rows], edge]))[1]
+
+
+# Jitted: a model calls the pair three times a layer each way at a handful of
+# shapes, and tracing the kernels' bodies again at every call made the step's
+# tracing, and so every run's set-up, seconds longer.
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _conv_forward(x, taps, seg, conv, heads: int, scale, dtype):
+    t = x.shape[1]
+    rows, halo, lanes, groups = _conv_layout(x, taps, heads, scale is not None)
+    (x,) = _rows_padded((x,), rows)
+    b, padded, c = x.shape
+    wide, ids, taps_spec = _conv_specs(x, taps.shape[0], rows, halo, lanes)
+    operands, specs = [x, x, taps], [wide["before"], wide["block"], taps_spec]
+    if seg is not None:
+        (seg,) = _rows_padded((seg,), rows)
+        operands += [seg, seg]
+        specs += [ids["before"], ids["block"]]
+    return pl.pallas_call(
+        functools.partial(_conv_kernel, conv=conv, groups=groups, scale=scale,
+                          width=c // heads, halo=halo),
+        grid=(b, padded // rows, c // lanes), in_specs=specs,
+        out_specs=wide["block"], out_shape=pk._sds(x.shape, dtype, x),
+        compiler_params=_CONV_PARAMS, interpret=pk._interpret(),
+    )(*operands)[:, :t]
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+def _conv_backward(x, taps, seg, dy, conv, heads: int, scale):
+    t = x.shape[1]
+    rows, halo, lanes, groups = _conv_layout(x, taps, heads, scale is not None)
+    x, dy = _rows_padded((x, dy), rows)
+    b, padded, c = x.shape
+    n, k = padded // rows, taps.shape[0]
+    wide, ids, taps_spec = _conv_specs(x, k, rows, halo, lanes)
+    operands = [x, x, x, taps, dy, dy]
+    specs = [wide["before"], wide["block"], wide["after"], taps_spec,
+             wide["block"], wide["after"]]
+    if seg is not None:
+        (seg,) = _rows_padded((seg,), rows)
+        operands += [seg] * 3
+        specs += [ids["before"], ids["block"], ids["after"]]
+    dx, dtaps = pl.pallas_call(
+        functools.partial(_conv_grad_kernel, conv=conv, groups=groups,
+                          scale=scale, width=c // heads, halo=halo),
+        grid=(b, n, c // lanes), in_specs=specs,
+        out_specs=[wide["block"], pl.BlockSpec(
+            (1, 1, k, lanes), lambda b_, r, h: (b_, r, 0, h))],
+        out_shape=[pk._sds(x.shape, x.dtype, x),
+                   pk._sds((b, n, k, c), jnp.float32, x)],
+        compiler_params=_CONV_PARAMS, interpret=pk._interpret(),
+    )(*operands)
+    return dx[:, :t], jnp.sum(dtaps, axis=(0, 1)).astype(taps.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def short_conv_silu(x, taps, seg, conv, heads: int, scale, dtype):
+    """SiLU(conv(x)) and, ``scale`` given, each head's L2 norm times it, in
+    float32, as ``dtype``: x [B, T, C] as the projection left it, taps
+    [K, C] in the parameters' type, seg [B, T, 1] int32 or None.
+    ``conv(x, taps, seg)`` is the causal depthwise convolution the kernels
+    run on a block of rows and its halo (float32 [1, rows, c] by [K, c] and
+    the block's [1, rows, 1] ids): the model's own, so that the two cannot
+    differ, and its VJP is the backward's.  What the backward keeps is x
+    and the taps; it makes the convolution, SiLU and the norm again in
+    VMEM, and gives dx in x's type and the taps' gradient as a float32
+    share a block of rows, summed here."""
+    return _conv_forward(x, taps, seg, conv, heads, scale, dtype)
+
+
+def _short_conv_silu_fwd(x, taps, seg, conv, heads, scale, dtype):
+    return (_conv_forward(x, taps, seg, conv, heads, scale, dtype),
+            (x, taps, seg))
+
+
+def _short_conv_silu_bwd(conv, heads, scale, dtype, kept, dy):
+    x, taps, seg = kept
+    dx, dtaps = _conv_backward(x, taps, seg, dy, conv, heads, scale)
+    # integer segment ids carry a float0 (empty) cotangent
+    return dx, dtaps, (None if seg is None
+                       else np.zeros(seg.shape, jax.dtypes.float0))
+
+
+short_conv_silu.defvjp(_short_conv_silu_fwd, _short_conv_silu_bwd)

@@ -288,10 +288,10 @@ def test_a_gate_a_channel_is_the_plain_norm(h, d, monkeypatch):
 
 @pytest.mark.parametrize("t", [70, 300])
 def test_head_norm_kernels_are_the_plain_norms(t, monkeypatch):
-    """q's and k's L2 norm and the output's RMSNorm and gate as kernels on
-    [B, T, H·d] (interpreted here) against the same through [B, T, H, d],
-    which a width the chip's kernels do not take falls to: values and
-    every gradient, T under and over a block of rows."""
+    """The output's RMSNorm and gate as a kernel on [B, T, H·d]
+    (interpreted here) against the same through [B, T, H, d], which a
+    width the chip's kernels do not take falls to: values and every
+    gradient, T under and over a block of rows."""
     from horovod_tpu.ops import pallas_kernels
 
     b, h, d = 2, 4, 16
@@ -306,12 +306,11 @@ def test_head_norm_kernels_are_the_plain_norms(t, monkeypatch):
             lambda *a: jnp.sum(fn(*a) * cotangent),
             argnums=range(len(args)))(*args)
 
-    unit = lambda x: kda.unit_heads(x, h, 0.25, jnp.float32)
     normed = lambda *a: kda.rms_gate_heads(*a, 1e-6, jnp.float32)
-    got = both(unit, x) + both(normed, x, weight, gate)
+    got = both(normed, x, weight, gate)
     monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
-    want = both(unit, x) + both(normed, x, weight, gate)
-    assert len(got) == 6
+    want = both(normed, x, weight, gate)
+    assert len(got) == 4
     for a, w in zip(got, want):
         assert a.shape == w.shape and _max_rel(a, w) <= 1e-5
     assert kda.unit_heads(x, h, 1.0, jnp.bfloat16).dtype == jnp.bfloat16
@@ -321,7 +320,7 @@ def test_short_convolution_keeps_to_its_document():
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 10, 3))
     taps = jax.random.normal(jax.random.PRNGKey(1), (4, 3))
     seg = jnp.asarray([[1, 1, 1, 1, 2, 2, 2, 2, 2, 2]])
-    y = transformer._short_conv(x, taps, seg)
+    y = transformer._short_conv(x, taps, seg[..., None])
     want = np.zeros((10, 3), np.float32)
     for t in range(10):
         for j in range(4):
@@ -331,6 +330,74 @@ def test_short_convolution_keeps_to_its_document():
     # without segments the second document sees the first one's last tokens
     assert not np.allclose(transformer._short_conv(x, taps, None)[0, 4],
                            want[4])
+    # a row shorter than the taps' reach
+    short = transformer._short_conv(x[:, :2], taps, None)
+    np.testing.assert_allclose(short[0], want[:2], rtol=1e-5, atol=1e-6)
+
+
+# x's type, heads, a head's width, the norm's scale (None: none, v's), T,
+# the kernels' block of rows at most, where documents start (None: a row
+# of one document, no segment ids)
+CONV_CASES = {
+    # KDA's heads of 128: a block of rows is 16 tokens, T = 40 is two and
+    # a half, so every block but the first reads a halo before it
+    "kda_heads_normed": (jnp.float32, 2, 128, 128 ** -0.5, 40, 16, None),
+    "kda_heads_plain": (jnp.float32, 2, 128, None, 40, 16, None),
+    # GDN's keys of 96: a block as wide as the array, four heads filling
+    # three 128-lane columns and one head beside them; documents starting
+    # inside the first block (10) and on the edge of the third (32)
+    "gdn_keys_normed_packed": (jnp.float32, 5, 96, 0.25, 40, 16, (10, 32)),
+    # GDN's values of 192, no norm: taken a 128-lane column at a time
+    "gdn_values_plain_packed": (jnp.float32, 2, 192, None, 40, 16, (10, 32)),
+    # bfloat16 as projected: a halo is 16 rows, a block 32
+    "bf16_normed_packed": (jnp.bfloat16, 2, 128, 0.5, 72, 32, (48, 64)),
+    # a row shorter than a halo: one block, both halos past its ends
+    "one_short_block": (jnp.float32, 2, 8, 1.0, 5, 256, None),
+}
+
+
+@pytest.mark.parametrize("case", list(CONV_CASES))
+def test_convolution_kernel_pair_is_the_plain_path(case, monkeypatch):
+    """``kda_kernels.short_conv_silu`` (interpreted here) against the
+    model's convolution, SiLU and the norm through [B, T, H, d] in XLA:
+    the output and the gradients of x and of the taps.  With blocks of
+    rows shorter than T a halo dropped, or read from the wrong rows or
+    documents, changes both."""
+    dtype, heads, width, scale, t, rows, starts = CONV_CASES[case]
+    monkeypatch.setattr(kda_kernels, "_CONV_ROWS", rows)
+    b, c = 2, heads * width
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    x = jax.random.normal(keys[0], (b, t, c)).astype(dtype)
+    taps = 0.5 * jax.random.normal(keys[1], (4, c))
+    cotangent = jax.random.normal(keys[2], (b, t, c))
+    seg = None
+    if starts is not None:
+        seg = jnp.asarray(np.searchsorted(starts, np.arange(t), "right"),
+                          jnp.int32)[None, :, None].repeat(b, 0)
+        seg = seg.at[1].set(7)  # the second row one document
+
+    def kernels(x, taps):
+        return kda_kernels.short_conv_silu(
+            x, taps, seg, transformer._short_conv, heads, scale, jnp.float32)
+
+    def plain(x, taps):
+        y = jax.nn.silu(transformer._short_conv(x, taps, seg))
+        return y if scale is None else kda.unit_heads(y, heads, scale,
+                                                      jnp.float32)
+
+    def both(fn):
+        return (fn(x, taps),) + jax.grad(
+            lambda *a: jnp.sum(fn(*a) * cotangent), argnums=(0, 1))(x, taps)
+
+    tolerance = 1e-5 if dtype == jnp.float32 else 2.0 ** -7  # dx's rounding
+    for got, want in zip(both(kernels), both(plain)):
+        assert got.shape == want.shape and got.dtype == want.dtype
+        assert _max_rel(got.astype(jnp.float32),
+                        want.astype(jnp.float32)) <= tolerance
+    if scale is not None:
+        unit = kernels(x, taps).reshape(b, t, heads, width)
+        np.testing.assert_allclose(jnp.sum(unit * unit, -1), scale ** 2,
+                                   rtol=1e-4)
 
 
 # -------------------------------------------------------- latent attention
@@ -748,8 +815,9 @@ def test_layer_kinds_choose_mixer_and_ffn_and_the_gauges_say_so():
         transformer.layer_kind(
             dataclasses.replace(cfg, layer_kinds=("kda", "ssm", "mla")), 1)
     # heads of 16 are interpreted here, so both delta-rule layers' cores
-    # ran as the kernel pair
+    # ran as the kernel pair, and their convolutions as theirs
     assert metrics.get_gauge("model.kda.kernel_layers") == 2
+    assert metrics.get_gauge("model.conv.kernel_layers") == 2
     # the older way of asking for the capacity MoE still reads the same
     old = dataclasses.replace(cfg, layer_kinds=(), ffn_kinds=(), moe_every=2)
     assert [transformer.layer_kind(old, i) for i in range(3)] == [
@@ -798,11 +866,15 @@ def test_the_gauge_counts_the_layers_whose_product_took_the_kernels(
     assert len(calls) == (layers or 0)      # a loop's body a layer
 
 
-def _equations(jaxpr):
+def _equations(jaxpr, outer=""):
+    """(equation, its scope path from the top) of ``jaxpr`` and of the
+    jaxprs inside it: a jitted call's body names its scopes from the
+    call."""
     for eqn in jaxpr.eqns:
-        yield eqn
+        scope = f"{outer}/{eqn.source_info.name_stack}"
+        yield eqn, scope
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _equations(sub)
+            yield from _equations(sub, scope)
 
 
 def test_the_delta_rule_mixer_stays_on_the_projections_layout():
@@ -811,9 +883,10 @@ def test_the_delta_rule_mixer_stays_on_the_projections_layout():
     [B, T, H, d] and no [n, B, H, C, d] array outside a kernel); the core
     is two kernel calls a layer in a gradient (the forward that keeps the
     states, the backward), both under ``kda/core`` where the benchmark's
-    readers look, and no other kernel is there (the norms' are under
-    ``kda/conv`` and ``kda``); the gauge says that the layer's core took
-    the kernels."""
+    readers look, and no other kernel is there (the convolutions' pair,
+    one for each of q, k and v each way, under ``kda/conv``, the output
+    norm's under ``kda``); the gauges say that the layer's core and
+    convolutions took the kernels."""
     from horovod_tpu import metrics
 
     cfg = _mla_config(num_layers=1, layer_kinds=("kda",), model_dim=32,
@@ -826,22 +899,25 @@ def test_the_delta_rule_mixer_stays_on_the_projections_layout():
         return jnp.sum(model.apply(p, tokens)[0])
 
     assert metrics.get_gauge("model.kda.kernel_layers") == 1 == \
+        metrics.get_gauge("model.conv.kernel_layers") == \
         metrics.get_gauge("model.layer_kinds", {"kind": "kda"})
-    under_kda = [e for e in _equations(jax.make_jaxpr(jax.grad(loss))(
-        params).jaxpr) if "/kda/" in f"/{e.source_info.name_stack}/"]
-    calls = [e for e in under_kda if e.primitive.name == "pallas_call"]
-    where = [str(e.source_info.name_stack).split("block_0/kda")[-1]
-             for e in calls]
-    # q's and k's norms, the output's norm and gate: each once each way
-    assert sorted(where) == [""] * 2 + ["/conv"] * 4 + ["/core"] * 2
-    for call in calls:
+    under_kda = [(e, scope) for e, scope in _equations(
+        jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+        if "/kda/" in f"{scope}/"]
+    calls = [(e, scope) for e, scope in under_kda
+             if e.primitive.name == "pallas_call"]
+    where = [scope.split("block_0/kda")[-1].split("/jit(")[0].rstrip("/")
+             for _, scope in calls]
+    # q's, k's and v's convolutions, the output's norm and gate: each once
+    # each way
+    assert sorted(where) == [""] * 2 + ["/conv"] * 6 + ["/core"] * 2
+    for call, scope in calls:
         chunks = call.params["grid_mapping"].grid[1]
-        assert (chunks == 128 // kda_kernels.CHUNK) == (
-            "kda/core" in str(call.source_info.name_stack))
+        assert (chunks == 128 // kda_kernels.CHUNK) == ("kda/core" in scope)
     assert {"conv", "gate", "core"} <= {
-        part for e in under_kda
-        for part in str(e.source_info.name_stack).split("/")}
-    for eqn in under_kda:
+        part for _, scope in under_kda for part in scope.split("/")}
+    calls = [call for call, _ in calls]
+    for eqn, _ in under_kda:
         for out in eqn.outvars:
             # a matmul's gradient turns its [in, out] weight, nothing more
             assert eqn.primitive.name != "transpose" or out.aval.ndim == 2, \
@@ -893,15 +969,18 @@ def test_olmo2_blocks_and_the_gdn_mixer():
     logits, _ = model.apply(params, tokens)
     assert np.isfinite(np.asarray(logits)).all()
     assert metrics.get_gauge("model.gdn.kernel_layers") == 1 == \
+        metrics.get_gauge("model.conv.kernel_layers") == \
         metrics.get_gauge("model.layer_kinds", {"kind": "gdn"})
-    eqns = [e for e in _equations(jax.make_jaxpr(jax.grad(loss))(
-        params).jaxpr) if "/gdn/" in f"/{e.source_info.name_stack}/"]
-    calls = [str(e.source_info.name_stack).split("block_0/gdn")[-1]
-             for e in eqns if e.primitive.name == "pallas_call"]
-    # the core's pair; q's and k's L2 norms and the output's norm each way
-    assert sorted(calls) == ["/conv"] * 4 + ["/core"] * 2 + ["/norm"] * 2
+    scopes = [(e, scope) for e, scope in _equations(
+        jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+        if "/gdn/" in f"{scope}/"]
+    calls = [scope.split("block_0/gdn")[-1].split("/jit(")[0].rstrip("/")
+             for e, scope in scopes if e.primitive.name == "pallas_call"]
+    # the core's pair; q's, k's and v's convolutions and the output's norm
+    # each way
+    assert sorted(calls) == ["/conv"] * 6 + ["/core"] * 2 + ["/norm"] * 2
     assert {"conv", "gate", "core", "norm"} <= {
-        part for e in eqns for part in str(e.source_info.name_stack).split("/")}
+        part for _, scope in scopes for part in scope.split("/")}
     with pytest.raises(ValueError, match="pre_norm, post_norm"):
         transformer.Transformer(dataclasses.replace(
             cfg, post_norm=False)).init(jax.random.PRNGKey(1), tokens)
@@ -910,8 +989,9 @@ def test_olmo2_blocks_and_the_gdn_mixer():
 def test_a_width_the_chip_does_not_take_falls_back_and_the_gauge_says_so(
         monkeypatch):
     """On the chip keys of 16 and values of 24 are no multiple of 32
-    lanes: the GDN core falls to the chunked form and its norms to XLA
-    (same output), and ``model.gdn.kernel_layers`` reads 0."""
+    lanes: the GDN core falls to the chunked form and its convolutions and
+    norms to XLA (same output), and ``model.gdn.kernel_layers`` and
+    ``model.conv.kernel_layers`` read 0."""
     from horovod_tpu import metrics
     from horovod_tpu.ops import pallas_kernels
 
@@ -928,6 +1008,7 @@ def test_a_width_the_chip_does_not_take_falls_back_and_the_gauge_says_so(
     monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
     got, _ = model.apply(params, tokens)
     assert called and metrics.get_gauge("model.gdn.kernel_layers") == 0
+    assert metrics.get_gauge("model.conv.kernel_layers") == 0
     assert _max_rel(got, want) <= 1e-4
 
 

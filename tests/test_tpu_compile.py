@@ -315,32 +315,27 @@ def test_one_decay_a_head_compiles_for_the_chip(one_chip, mosaic, packed):
 
 
 def test_olmo_head_norms_compile_for_the_chip(one_chip, mosaic):
-    """The norms around that core: q's L2 norm over heads of 96 and the
-    output's RMSNorm over heads of 192 times a gate a channel, each a
-    kernel forward and one backward on a block of every head."""
+    """The norm after that core: the output's RMSNorm over heads of 192
+    times a gate a channel, a kernel forward and one backward on a block of
+    every head."""
     from horovod_tpu.ops import kda
 
     b, t, h = 1, 4096, 30
-    keys = jax.ShapeDtypeStruct((b, t, h * 96), jnp.float32,
-                                sharding=one_chip)
     values = jax.ShapeDtypeStruct((b, t, h * 192), jnp.float32,
                                   sharding=one_chip)
     weight = jax.ShapeDtypeStruct((192,), jnp.float32, sharding=one_chip)
 
-    def grads(x, o, weight, gate):
-        def loss(x, o, weight, gate):
-            q = kda.unit_heads(x, h, 96 ** -0.5, jnp.bfloat16)
+    def grads(o, weight, gate):
+        def loss(o, weight, gate):
             y = kda.rms_gate_heads(o, weight, gate, 1e-6, jnp.bfloat16)
-            # squares: the gradient needs the forwards' outputs too
-            return (jnp.sum(jnp.square(q.astype(jnp.float32)))
-                    + jnp.sum(jnp.square(y.astype(jnp.float32))))
+            # a square: the gradient needs the forward's output too
+            return jnp.sum(jnp.square(y.astype(jnp.float32)))
 
-        return jax.grad(loss, argnums=range(4))(x, o, weight, gate)
+        return jax.grad(loss, argnums=range(3))(o, weight, gate)
 
-    text = jax.jit(grads).lower(keys, values, weight, values).compile(
-        ).as_text()
+    text = jax.jit(grads).lower(values, weight, values).compile().as_text()
     assert len([ln for ln in text.splitlines()
-                if "tpu_custom_call" in ln]) == 4
+                if "tpu_custom_call" in ln]) == 2
     for ln in _entry(text):  # 4096 rows are whole blocks: nothing padded
         assert _result_and_opcode(ln)[1] not in ("pad", "copy"), ln
 
@@ -350,9 +345,9 @@ def test_olmo_head_norms_compile_for_the_chip(one_chip, mosaic):
     ((2, 300, 4, 256), jnp.float32),     # ragged T, heads of two slabs
 ])
 def test_head_norms_compile_for_the_chip(one_chip, mosaic, shape, dtype):
-    """The per-head norms around the delta rule on [B, T, H·d]: q's L2
-    norm and the output's RMSNorm and gate, each a kernel forward and one
-    backward, with no copy of a [T, H·d] array beside them."""
+    """The output's RMSNorm and gate on [B, T, H·d] after the delta rule,
+    a kernel forward and one backward, with no copy of a [T, H·d] array
+    beside them."""
     from horovod_tpu.ops import kda
 
     b, t, h, d = shape
@@ -360,21 +355,63 @@ def test_head_norms_compile_for_the_chip(one_chip, mosaic, shape, dtype):
     weight = jax.ShapeDtypeStruct((d,), jnp.float32, sharding=one_chip)
     gate = jax.ShapeDtypeStruct((b, t, h), jnp.float32, sharding=one_chip)
 
-    def grads(x, o, weight, gate):
-        def loss(x, o, weight, gate):
-            q = kda.unit_heads(x, h, d ** -0.5, dtype)
+    def grads(o, weight, gate):
+        def loss(o, weight, gate):
             y = kda.rms_gate_heads(o, weight, gate, 1e-6, dtype)
-            return jnp.sum((q * y).astype(jnp.float32))
+            return jnp.sum(jnp.square(y.astype(jnp.float32)))
 
-        return jax.grad(loss, argnums=range(4))(x, o, weight, gate)
+        return jax.grad(loss, argnums=range(3))(o, weight, gate)
 
-    text = jax.jit(grads).lower(wide, wide, weight, gate).compile().as_text()
+    text = jax.jit(grads).lower(wide, weight, gate).compile().as_text()
     assert len([ln for ln in text.splitlines()
-                if "tpu_custom_call" in ln]) == 4
+                if "tpu_custom_call" in ln]) == 2
     for ln in _entry(text):
         result, opcode = _result_and_opcode(ln)
         if opcode in ("copy", "transpose"):
             assert f"{t},{h * d}]" not in result, ln
+
+
+@pytest.mark.parametrize("heads, width, normed, packed", [
+    (32, 128, True, False),   # ling3flash.ring1x4096: q and k, 4096 lanes
+    (32, 128, False, False),  # its v
+    (30, 96, True, False),    # olmo_hybrid7b.ring1x4096: q and k, 2880
+    (30, 192, False, False),  # its v, 5760 lanes
+    (30, 96, True, True),     # packed rows
+])
+def test_convolution_pair_compiles_for_the_chip(
+        one_chip, mosaic, heads, width, normed, packed):
+    """The short convolution, SiLU and (q, k) the L2 norm a head as one
+    kernel pair at the scan cells' widths, a row of 4096 bfloat16 tokens as
+    projected: a gradient is two Mosaic calls in the VMEM a kernel gets
+    unasked, and between them XLA pads, copies or turns no [T, C] array,
+    and keeps none in float32: only x and the taps are kept, and the
+    taps' gradient is a float32 share a block of rows."""
+    from horovod_tpu.models import transformer
+    from horovod_tpu.ops import kda
+
+    b, t, c = 1, 4096, heads * width
+    x = jax.ShapeDtypeStruct((b, t, c), jnp.bfloat16, sharding=one_chip)
+    taps = jax.ShapeDtypeStruct((4, c), jnp.float32, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip)
+    scale = width ** -0.5 if normed else None
+
+    def grads(x, taps, w, seg):
+        return jax.value_and_grad(lambda x, taps: jnp.sum(
+            kda.conv_silu_heads(x, taps, seg if packed else None,
+                                transformer._short_conv, heads, scale,
+                                jnp.bfloat16).astype(jnp.float32) * w),
+            argnums=(0, 1))(x, taps)
+
+    compiled = jax.jit(grads).lower(x, taps, x, seg).compile()
+    text = compiled.as_text()
+    assert len([ln for ln in text.splitlines()
+                if "tpu_custom_call" in ln]) == 2
+    for ln in _entry(text):
+        result, opcode = _result_and_opcode(ln)
+        if f"{t},{c}]" in result:
+            assert opcode not in ("copy", "pad", "transpose"), ln
+            assert not result.startswith("f32["), ln
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * t * c
 
 
 @pytest.mark.parametrize("heads, kv_heads, window, packed", [
