@@ -20,16 +20,18 @@ on.  TPU-first choices:
   heads (``num_kv_heads``), latent attention ("mla": keys and values from a
   compressed latent, DeepSeek-V2) or the delta rule with a per-channel
   decay ("kda", ops/kda), the last two with a sigmoid gate a head, or with
-  one decay a head ("gdn", Gated DeltaNet, with a SiLU gate a channel); a
-  dense MLP, the capacity MoE, or dropless routed experts of which this
-  device holds a range ("experts").
+  one decay a head ("gdn", Gated DeltaNet, with a SiLU gate a channel and
+  key heads that value heads may share); a dense MLP, the capacity MoE, or
+  dropless routed experts of which this device holds a range ("experts").
 
 * the block is GPT-2's by default (LayerNorm, learned positions, fused
   QKV with bias, GELU MLP, tied head) and a current decoder's by
   settings of :class:`TransformerConfig`: RMSNorm, rotary positions,
   separate bias-free projections, a gated SiLU MLP, norms after each
   sublayer as well as before it or instead of it (OLMo 2), q and k normed
-  over their whole projections, no positions at all, an untied head.
+  over their whole projections or a head at a time, no positions at all,
+  an untied head, RMSNorms whose weight is zero-centred (Qwen3-Next's),
+  a sigmoid gate a channel on softmax attention's output.
 * ``ut_steps`` > 1 makes it a looped LM (Universal-Transformer loop,
   arXiv:2510.25741): the blocks are made once and the whole stack is
   applied ``ut_steps`` times with the same weights, with the final norm,
@@ -123,7 +125,9 @@ class TransformerConfig:
     # backward re-runs neither the kernel nor the q/k/v projections.
     remat_save: Tuple[str, ...] = ()
     # The block (defaults are GPT-2's):
-    norm: str = "layernorm"      # "layernorm" | "rmsnorm"
+    norm: str = "layernorm"      # "layernorm" | "rmsnorm" |
+    #                              "zero_centred_rmsnorm" (times 1 + w, w
+    #                              from zeros: Qwen3-Next's)
     norm_eps: float = 1e-6
     positions: str = "learned"   # "learned" (wpe) | "rope" | "none"
     rope_theta: float = 10000.0
@@ -135,6 +139,9 @@ class TransformerConfig:
     # softmax attention's q and k each RMSNormed over the whole projection,
     # all heads at once (OLMo 2's), before the heads are split
     qk_norm: bool = False
+    # softmax attention's q and k each normed a head, over its head_dim
+    # channels, by the model's ``norm`` kind (Qwen3's q_norm and k_norm)
+    qk_head_norm: bool = False
     tie_head: bool = True        # logits through wte's transpose
     # The loop: the stack is applied ut_steps times with the same weights;
     # with exit_gate the head and a per-token gate follow every pass and
@@ -163,6 +170,9 @@ class TransformerConfig:
     rope_rules: Tuple[Tuple[str, RopeRule], ...] = ()
     # softmax attention's output times sigmoid(x W_g), one gate a head
     attn_gate: bool = False
+    # ... or one gate a channel, W_gate as wide as the heads (Qwen3-Next's:
+    # the second half of each head of its published query projection)
+    attn_channel_gate: bool = False
     # "mla" (arXiv:2405.04434 section 2.1): k and v of every head from one
     # normed latent of ``kv_lora_rank``, and a rope key of ``rope_dim`` the
     # heads share; q and k are qk_nope_dim + rope_dim wide, v ``head_dim``.
@@ -175,17 +185,25 @@ class TransformerConfig:
     # g = kda_lower_bound * sigmoid(exp(A_log) (x W_f + dt_bias)).
     kda_conv: int = 4            # the taps of both delta-rule mixers
     kda_lower_bound: float = -5.0
-    # "gdn" (Gated DeltaNet, arXiv:2412.06464): ``num_heads`` heads of
-    # ``gdn_key_dim`` keys and ``gdn_value_dim`` values, one decay a head
-    # g = -exp(A_log) softplus(x W_a + dt_bias), beta = 2 sigmoid(x W_b)
-    # (eigenvalues down to -1, arXiv:2411.12537), the output RMSNormed a
-    # head times SiLU(x W_z).
+    # "gdn" (Gated DeltaNet, arXiv:2412.06464): ``num_heads`` value heads
+    # of ``gdn_value_dim`` over ``gdn_key_heads`` key heads (0: as many)
+    # of ``gdn_key_dim``, value head h reading key head h // (num_heads /
+    # gdn_key_heads); one decay a head g = -exp(A_log) softplus(x W_a +
+    # dt_bias), beta = 2 sigmoid(x W_b) with ``gdn_allow_neg_eigval``
+    # (eigenvalues down to -1, arXiv:2411.12537) and sigmoid(x W_b)
+    # without, the output RMSNormed a head times SiLU(x W_z).
     gdn_key_dim: int = 0
     gdn_value_dim: int = 0
+    gdn_key_heads: int = 0
+    gdn_allow_neg_eigval: bool = True
     # "experts": a router over ``num_experts`` with ``experts_per_token``
     # chosen inside the best ``topk_group`` of ``n_group`` groups; this
     # device holds ``experts_held`` = (first, past the last) of them, each
     # a SwiGLU of ``expert_ff_dim``, and one shared expert of that width.
+    # ``router_scoring``: "sigmoid" scores with a selection bias
+    # (DeepSeek-V3's) or "softmax" over all experts without one (Qwen's);
+    # ``shared_expert_gate``: the shared expert times sigmoid(x w_sg), one
+    # gate a token.
     num_experts: int = 0
     experts_held: Tuple[int, int] = (0, 0)
     expert_ff_dim: int = 0
@@ -193,6 +211,8 @@ class TransformerConfig:
     n_group: int = 1
     topk_group: int = 1
     routed_scaling: float = 1.0
+    router_scoring: str = "sigmoid"
+    shared_expert_gate: bool = False
 
 
 # The name is the benchmark's: tests/benchmark/test_benchmark_hybridmoe_
@@ -233,8 +253,38 @@ def _norm(cfg: TransformerConfig, name: str) -> nn.Module:
     if cfg.norm == "rmsnorm":
         return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
                           name=name)
+    if cfg.norm == "zero_centred_rmsnorm":
+        return ZeroCentredRMSNorm(epsilon=cfg.norm_eps, name=name)
     raise ValueError(
-        f"unknown norm {cfg.norm!r}; expected 'layernorm' or 'rmsnorm'")
+        f"unknown norm {cfg.norm!r}; expected 'layernorm', 'rmsnorm' or "
+        "'zero_centred_rmsnorm'")
+
+
+class ZeroCentredRMSNorm(nn.Module):
+    """x / rms(x) * (1 + w) in float32, w from zeros (Qwen3-Next's
+    RMSNorm): the weight decay pulls the norm's gain towards 1, not 0."""
+
+    epsilon: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        scale = self.param("scale", nn.initializers.zeros, (x.shape[-1],),
+                           jnp.float32)
+        x = x.astype(jnp.float32)
+        return x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                             + self.epsilon) * (1.0 + scale)
+
+
+def _norm_weight(cfg: TransformerConfig, features: int, name: str
+                 ) -> jax.Array:
+    """What an RMSNorm of ``cfg.norm`` over ``features`` channels
+    multiplies by, for a norm computed elsewhere, under the module's
+    names: w (from ones) or, zero-centred, 1 + w (w from zeros)."""
+    if cfg.norm == "rmsnorm":
+        return _NormScale(features, name=name)()
+    if cfg.norm == "zero_centred_rmsnorm":
+        return 1.0 + _NormScale(features, nn.initializers.zeros, name=name)()
+    raise ValueError(f"a norm a head is an RMSNorm's, not {cfg.norm!r}'s")
 
 
 def rope_tables(positions: jax.Array, head_dim: int, theta: float,
@@ -375,6 +425,13 @@ class Attention(nn.Module):
                                name=f"{name}_norm")(a).astype(cfg.dtype)
                     for name, a in (("q", q), ("k", k)))
             q, k, v = (a.reshape(b, t, -1, cfg.head_dim) for a in (q, k, v))
+        elif cfg.qk_head_norm:
+            q, k, v = (column(1, name, heads=n) for name, n in (
+                ("q", heads), ("k", kv_heads), ("v", kv_heads)))
+            with jax.named_scope("qk_norm"):
+                q, k = (self._head_norm(a, name) for name, a in (
+                    ("q", q), ("k", k)))
+            q, k, v = (a.reshape(b, t, -1, cfg.head_dim) for a in (q, k, v))
         else:
             q, k, v = (
                 column(1, name, heads=n).reshape(
@@ -432,12 +489,26 @@ class Attention(nn.Module):
             out = _gate_heads(x, out[..., :cfg.head_dim])
         elif cfg.attn_gate:
             out = _gate_heads(x, out)
+        elif cfg.attn_channel_gate:
+            out = _gate_channels(x, out.reshape(b, t, -1))
         out = out.reshape(b, t, h_local * cfg.head_dim)
         return RowParallelDense(
             cfg.model_dim, axis=cfg.tp_axis, use_bias=cfg.use_bias,
             dtype=cfg.dtype, name="proj"
         )(out)
 
+    def _head_norm(self, a: jax.Array, name: str) -> jax.Array:
+        """[B, T, n·D] as projected, RMSNormed a head over its D channels
+        by ``cfg.norm``'s weight (``q_norm`` / ``k_norm``, one D-wide
+        weight the heads share), in float32, as ``cfg.dtype``: the norms'
+        kernel on the projection's layout (``ops/kda.rms_gate_heads``, a
+        gate of ones), not a re-layout to [B, T, n, D] each way."""
+        cfg = self.cfg
+        b, t, lanes = a.shape
+        weight = _norm_weight(cfg, cfg.head_dim, f"{name}_norm")
+        ones = jnp.ones((b, t, lanes // cfg.head_dim), jnp.float32)
+        return rms_gate_heads(a.astype(jnp.float32), weight, ones,
+                              cfg.norm_eps, cfg.dtype)
 
     def _latent_qkv(self, x, column, h_local: int, rope):
         """Latent attention's q, k, v as the kernels take them, heads of
@@ -511,6 +582,16 @@ def _gate_heads(x: jax.Array, out: jax.Array) -> jax.Array:
     return (out * jax.nn.sigmoid(gate)[..., None]).astype(out.dtype)
 
 
+def _gate_channels(x: jax.Array, out: jax.Array) -> jax.Array:
+    """[B, T, H·D] times sigmoid(x W_gate), one gate a channel: softmax
+    attention's output gate with ``attn_channel_gate`` (Qwen3-Next's), on
+    the heads' own layout; W_gate and the gate float32."""
+    gate = nn.Dense(out.shape[-1], use_bias=False, dtype=jnp.float32,
+                    name="gate")(x.astype(jnp.float32))
+    with jax.named_scope("gate"):
+        return (out * jax.nn.sigmoid(gate)).astype(out.dtype)
+
+
 def _short_conv(x: jax.Array, taps: jax.Array,
                 segment_ids: Optional[jax.Array]) -> jax.Array:
     """Causal depthwise convolution along T: y_t = sum_j taps[j] x_{t-j},
@@ -541,13 +622,15 @@ def _short_conv(x: jax.Array, taps: jax.Array,
 
 class _NormScale(nn.Module):
     """The weight of an ``nn.RMSNorm`` over ``features`` channels, under
-    its names, for a norm computed elsewhere."""
+    its names, for a norm computed elsewhere (from ``init``: ones, or
+    zeros for a zero-centred one)."""
 
     features: int
+    init: Any = nn.initializers.ones
 
     @nn.compact
     def __call__(self) -> jax.Array:
-        return self.param("scale", nn.initializers.ones, (self.features,),
+        return self.param("scale", self.init, (self.features,),
                           jnp.float32)
 
 
@@ -638,12 +721,15 @@ class GDNMixer(nn.Module):
     """Gated DeltaNet (arXiv:2412.06464; fla's ``GatedDeltaNet``): the
     delta rule with one decay a head over heads of ``gdn_key_dim`` keys
     and ``gdn_value_dim`` values (``ops/kda.py``, its kernels take both
-    widths where they lie in the projections).
+    widths where they lie in the projections), ``num_heads`` value heads
+    over ``gdn_key_heads`` key heads: value head h reads key head h //
+    group (Qwen3-Next's: 32 over 16), never repeated.
 
         q = L2(SiLU(Conv(x W_q))) / sqrt(dk),  k = L2(SiLU(Conv(x W_k))),
         v = SiLU(Conv(x W_v)),  beta = 2 sigmoid(x W_b) a head (the
-        transition's eigenvalues reach -1),  g = -exp(A_log) softplus(x W_a
-        + dt_bias) a head,  out = W_o (RMSNorm_head(o) * SiLU(x W_z)) a
+        transition's eigenvalues reach -1; sigmoid(x W_b) without
+        ``gdn_allow_neg_eigval``),  g = -exp(A_log) softplus(x W_a +
+        dt_bias) a head,  out = W_o (RMSNorm_head(o) * SiLU(x W_z)) a
         channel.
 
     Scopes: ``conv`` (the convolutions, SiLU and the L2 norms), ``gate``
@@ -657,34 +743,44 @@ class GDNMixer(nn.Module):
                  segment_ids: Optional[jax.Array] = None) -> jax.Array:
         cfg = self.cfg
         h, dk, dv = cfg.num_heads, cfg.gdn_key_dim, cfg.gdn_value_dim
+        hk = cfg.gdn_key_heads or h
         if _axis_present(cfg.sp_axis) and lax.axis_size(cfg.sp_axis) > 1:
             raise ValueError(
                 "the gdn mixer's state runs along the whole row: it cannot "
                 "be sequence-sharded")
+        if h % hk:
+            raise ValueError(
+                f"{hk} key heads do not divide {h} value heads")
 
         def projected(name, width, dtype=cfg.dtype):
             return nn.Dense(width, use_bias=False, dtype=dtype,
                             name=name)(x.astype(dtype))
 
         # [B, T, H·d] throughout, as the projections leave them
-        def convolved(name, width, scale=None):
+        def convolved(name, heads, width, scale=None):
             taps = self.param(f"conv_{name}", nn.initializers.normal(0.5),
-                              (cfg.kda_conv, width), jnp.float32)
-            y = projected(name, width)
+                              (cfg.kda_conv, heads * width), jnp.float32)
+            y = projected(name, heads * width)
             with jax.named_scope("conv"):
-                return conv_silu_heads(y, taps, segment_ids, _short_conv, h,
-                                       scale, cfg.dtype)
+                return conv_silu_heads(y, taps, segment_ids, _short_conv,
+                                       heads, scale, cfg.dtype)
 
-        q, k, v = (convolved("q", h * dk, dk ** -0.5),
-                   convolved("k", h * dk, 1.0), convolved("v", h * dv))
+        q, k, v = (convolved("q", hk, dk, dk ** -0.5),
+                   convolved("k", hk, dk, 1.0), convolved("v", h, dv))
         with jax.named_scope("gate"):
             a_log = self.param("A_log", _a_log_init, (h,))
             dt_bias = self.param("dt_bias", _dt_bias_init, (h,))
             g = -jnp.exp(a_log) * jax.nn.softplus(
                 projected("a", h, jnp.float32) + dt_bias)
-            beta = 2.0 * jax.nn.sigmoid(projected("b", h, jnp.float32))
+            beta = jax.nn.sigmoid(projected("b", h, jnp.float32))
+            if cfg.gdn_allow_neg_eigval:
+                beta = 2.0 * beta
         with jax.named_scope("core"):
-            o = kda(q, k, v, g, beta, segment_ids)
+            # the group is named only where value heads share key heads:
+            # otherwise the call keeps its six operands, the form the
+            # benchmark's fault tests stand in for
+            grouped = {} if hk == h else {"group": h // hk}
+            o = kda(q, k, v, g, beta, segment_ids, **grouped)
         z = projected("z", h * dv)
         with jax.named_scope("norm"):
             o = rms_gate_heads(
@@ -745,7 +841,8 @@ class Block(nn.Module):
                 hidden=cfg.expert_ff_dim, k=cfg.experts_per_token,
                 n_group=cfg.n_group, topk_group=cfg.topk_group,
                 routed_scaling=cfg.routed_scaling, dtype=cfg.dtype,
-                name="moe",
+                scoring=cfg.router_scoring,
+                shared_gate=cfg.shared_expert_gate, name="moe",
             )(h)
         elif self.ffn == "moe":
             y, aux = MoELayer(
@@ -918,6 +1015,10 @@ class Transformer(nn.Module):
                          and kda_kernels.takes(cfg.gdn_value_dim)))
         metrics.set_gauge("model.kda.kernel_layers", kda_layers)
         metrics.set_gauge("model.gdn.kernel_layers", gdn_layers)
+        if any("gdn" in kind for kind in kinds):
+            metrics.set_gauge("model.gdn.value_heads_per_key",
+                              cfg.num_heads // (cfg.gdn_key_heads
+                                                or cfg.num_heads))
         # the mixers whose three short convolutions, SiLU and norms ran as
         # ops/kda_kernels' convolution pair: it takes the same widths
         metrics.set_gauge("model.conv.kernel_layers", kda_layers + gdn_layers)

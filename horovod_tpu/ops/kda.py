@@ -63,11 +63,22 @@ from . import kda_kernels
 from .kda_kernels import MAX_EXPONENT as _MAX_EXPONENT
 
 
+def _shared_keys(q, k, v):
+    """q and k [B, T, H_k, dk] repeated to v's H heads, [B, T, H, dk]:
+    value head h reads key head h // (H / H_k)."""
+    group = v.shape[2] // q.shape[2]
+    if group == 1:
+        return q, k
+    return tuple(jnp.repeat(a, group, axis=2) for a in (q, k))
+
+
 def kda_recurrent(q, k, v, g, beta, segment_ids=None):
     """The recurrence, token by token: q, k, g [B, T, H, dk] (g [B, T, H,
     1]: one decay a head), v [B, T, H, dv], beta [B, T, H] -> o [B, T, H,
-    dv], all float32.  The definition the chunked form is tested
-    against."""
+    dv], all float32; q and k may hold fewer heads, H_k dividing H, value
+    head h reading key head h // (H / H_k).  The definition the chunked
+    form is tested against."""
+    q, k = _shared_keys(q, k, v)
     b, t, h, dk = q.shape
     if segment_ids is None:
         fresh = jnp.zeros((b, t), bool)
@@ -255,9 +266,11 @@ def kda_chunked(q, k, v, g, beta, segment_ids: Optional[jax.Array] = None,
     """:func:`kda_recurrent` in chunks of ``chunk`` tokens, in plain
     ``jax.numpy``: q, k, g [B, T, H, dk] (g [B, T, H, 1]: one decay a
     head), v [B, T, H, dv], beta [B, T, H] -> o [B, T, H, dv] float32; q's
-    type is the matmuls' operands'.  A decay a channel must lie above ``-80
-    / sub`` (the bounded gate's lower bound is -5 for ``sub`` 16); one a
+    type is the matmuls' operands'; q and k may hold fewer heads, as in
+    :func:`kda_recurrent`.  A decay a channel must lie above ``-80 /
+    sub`` (the bounded gate's lower bound is -5 for ``sub`` 16); one a
     head has no bound."""
+    q, k = _shared_keys(q, k, v)
     b, t, h, _ = q.shape
     pad = -t % chunk
     seg = (jnp.ones((b, t), jnp.int32) if segment_ids is None
@@ -277,19 +290,27 @@ def kda_chunked(q, k, v, g, beta, segment_ids: Optional[jax.Array] = None,
         b, n * chunk, h, -1)[:, :t]
 
 
-def kda(q, k, v, g, beta, segment_ids: Optional[jax.Array] = None):
+def kda(q, k, v, g, beta, segment_ids: Optional[jax.Array] = None,
+        group: int = 1):
     """The delta rule on the projections' layout: q, k [B, T, H·dk], v
     [B, T, H·dv], g [B, T, H·dk] (a decay a channel) or [B, T, H] (one a
     head, Gated DeltaNet's), beta [B, T, H] -> o [B, T, H·dv] float32, q's
-    type the matmuls' operands'.  By the kernels (``kda_kernels``) where
-    they take the heads' widths, which re-lay nothing; else by
-    :func:`kda_chunked`, whose loop reads chunk-major copies."""
+    type the matmuls' operands'.  With ``group`` (one decay a head), q and
+    k hold a key head for every ``group`` value heads, [B, T, (H /
+    group)·dk], value head h reading key head h // group.  By the kernels
+    (``kda_kernels``) where they take the heads' widths, which re-lay
+    nothing and repeat no key head; else by :func:`kda_chunked`, whose
+    loop reads chunk-major copies."""
     b, t, _ = q.shape
     heads = beta.shape[-1]
-    if all(kda_kernels.takes(a.shape[-1] // heads) for a in (q, v)):
-        return kda_kernels.delta_rule(q, k, v, g, beta, segment_ids)
-    out = kda_chunked(*(a.reshape(b, t, heads, -1) for a in (q, k, v, g)),
-                      beta, segment_ids, kda_kernels.CHUNK, kda_kernels.SUB)
+    if all(kda_kernels.takes(a.shape[-1] // n)
+           for a, n in ((q, heads // group), (v, heads))):
+        return kda_kernels.delta_rule(q, k, v, g, beta, segment_ids, group)
+    out = kda_chunked(
+        *(a.reshape(b, t, n, -1) for a, n in (
+            (q, heads // group), (k, heads // group), (v, heads),
+            (g, heads))),
+        beta, segment_ids, kda_kernels.CHUNK, kda_kernels.SUB)
     return out.reshape(b, t, -1)
 
 

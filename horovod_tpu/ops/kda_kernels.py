@@ -51,6 +51,15 @@ reference points).  Backward: with ``P_A = dA * D``, ``P_B = dB * D``,
 colsum(E)``, ``E = (dA * K K^T + dB * Q K^T) * D``; the decays of the
 state, of K_in, Q_in and K_out are those of the per-channel case summed
 over the channels.
+
+**Value heads that share a key head** (``group`` > 1, with one decay a
+head: Qwen3-Next's Gated DeltaNet): q and k hold H / group heads,
+``[B, T, (H / group)·dk]``, and value head h reads key head ``h // group``
+where it lies, as the flash kernels read a key/value head for its query
+heads (``pallas_kernels._slab_of``): a grid step's slab holds whole groups
+and its block of q and k the slab's key heads, so no repeated copy of q or
+k is made.  A key head's dq and dk are the sums of its value heads' parts,
+taken in the grid step that owns them all.
 """
 
 from __future__ import annotations
@@ -89,23 +98,27 @@ def takes(width: int) -> bool:
     return pk._interpret() or width % 32 == 0
 
 
-def _heads_a_step(heads: int) -> int:
-    """Heads a grid step owns, four where they divide.  They are a batch
-    axis of the body, so each of its small matmuls is issued for every
-    head before the next one that depends on it: a head's chunk alone is a
-    chain of some forty dependent MXU round trips that nothing hides
-    (PERF.md, PR 33: 4.7 ms a layer forward at one head, 2.1 at four; eight
-    need more VMEM than a kernel gets unasked)."""
-    return next(n for n in (4, 3, 2, 1) if heads % n == 0)
+def _heads_a_step(heads: int, group: int = 1) -> int:
+    """Heads a grid step owns, four where they divide, whole groups of
+    ``group`` value heads that share a key head.  They are a batch axis of
+    the body, so each of its small matmuls is issued for every head before
+    the next one that depends on it: a head's chunk alone is a chain of
+    some forty dependent MXU round trips that nothing hides (PERF.md, PR
+    33: 4.7 ms a layer forward at one head, 2.1 at four; eight need more
+    VMEM than a kernel gets unasked)."""
+    return next(n for n in (4, 3, 2, 1, group)
+                if heads % n == 0 and n % group == 0)
 
 
-def _slab(heads: int, *widths: int) -> int:
+def _slab(heads: int, *widths: int, group: int = 1) -> int:
     """Heads whose lanes a grid step's block holds: ``_heads_a_step``
-    where that many heads of each width are whole 128-lane slabs, else all
-    of them (a block as wide as the array is the one Mosaic takes for any
+    where that many heads of each width are whole 128-lane slabs (the
+    first width is a key's: ``group`` value heads share one), else all of
+    them (a block as wide as the array is the one Mosaic takes for any
     width)."""
-    n = _heads_a_step(heads)
-    if all(n * w % pk._LANES == 0 for w in widths):
+    n = _heads_a_step(heads, group)
+    if all(n // (group if i == 0 else 1) * w % pk._LANES == 0
+           for i, w in enumerate(widths)):
         return n
     return heads
 
@@ -288,15 +301,22 @@ def _column(block, lane, head):
     return jnp.sum(jnp.where(lane == head, block, 0.0), axis=1, keepdims=True)
 
 
-def _load(ref, width: int, heads: int, offset: int = 0):
+def _load(ref, width: int, heads: int, offset: int = 0, group: int = 1):
     """[h, C, width] of heads ``offset`` to ``offset + h`` of a
-    [1, C, slab·width] block."""
-    return jnp.stack([ref[0, :, j * width:(j + 1) * width]
+    [1, C, slab·width] block; with ``group``, head j's is head ``j //
+    group`` of the block (a key head shared by value heads)."""
+    return jnp.stack([ref[0, :, j // group * width:(j // group + 1) * width]
                       for j in range(offset, offset + heads)])
 
 
-def _store(ref, value, offset: int = 0):
-    """The reverse of :func:`_load`, in the block's type."""
+def _store(ref, value, offset: int = 0, group: int = 1):
+    """The reverse of :func:`_load`, in the block's type: with ``group``
+    the sum of each ``group`` consecutive heads' parts goes to their key
+    head."""
+    if group > 1:
+        value = jnp.stack([sum(value[j + m] for m in range(group))
+                           for j in range(0, value.shape[0], group)])
+        offset //= group
     width = value.shape[-1]
     for j in range(value.shape[0]):
         at = (offset + j) * width
@@ -320,11 +340,12 @@ def _take(ref, heads: int, offset: int):
 
 def _step(q_ref, k_ref, v_ref, g_ref, beta_ref, seg_col_ref, seg_row_ref,
           sees_ref, last_ref, heads: int, dk: int, dv: int, slab: int,
-          offset: int, per_head: bool):
+          offset: int, per_head: bool, group: int):
     """(the first head of the group of ``heads`` that starts ``offset``
     heads into this grid step's slab, its lane mask over a [C, H] block,
     the heads' betas [h, C, 1], the documents' masks, the chunk's parts);
-    ``per_head``: g is a [C, H] block too, one decay a head."""
+    ``per_head``: g is a [C, H] block too, one decay a head; ``group``
+    value heads read each key head of q's and k's blocks."""
     first = pl.program_id(2) * slab
     if offset:
         first = first + offset
@@ -337,8 +358,8 @@ def _step(q_ref, k_ref, v_ref, g_ref, beta_ref, seg_col_ref, seg_row_ref,
     masks = (jnp.logical_and(same, row > col),
              jnp.logical_and(same, row >= col),
              sees_ref[0] != 0, last_ref[0] != 0)
-    q, k, v = (_load(ref, width, heads, offset) for ref, width in (
-        (q_ref, dk), (k_ref, dk), (v_ref, dv)))
+    q, k, v = (_load(ref, width, heads, offset, n) for ref, width, n in (
+        (q_ref, dk, group), (k_ref, dk, group), (v_ref, dv, 1)))
     if per_head:
         gates = g_ref[0]
         g = jnp.stack([_column(gates, lane, first + j) for j in range(heads)])
@@ -350,14 +371,14 @@ def _step(q_ref, k_ref, v_ref, g_ref, beta_ref, seg_col_ref, seg_row_ref,
 
 
 def _forward_kernel(*refs, slab: int, heads: int, dk: int, dv: int,
-                    keep: bool, per_head: bool):
+                    keep: bool, per_head: bool, group: int):
     """refs: the nine operands of ``_specs``, o, with ``keep`` the states
     and the inverses, the scratch of every head's running state.  The
     slab's heads are taken ``heads`` at a time."""
     o_ref, state_ref = refs[9], refs[-1]
     for offset in range(0, slab, heads):
         first, _, beta, _, parts = _step(
-            *refs[:9], heads, dk, dv, slab, offset, per_head)
+            *refs[:9], heads, dk, dv, slab, offset, per_head, group)
         mine = pl.ds(first, heads)
 
         @pl.when(pl.program_id(1) == 0)
@@ -378,19 +399,19 @@ def _forward_kernel(*refs, slab: int, heads: int, dk: int, dv: int,
 
 
 def _backward_kernel(*refs, slab: int, heads: int, dk: int, dv: int,
-                     per_head: bool):
+                     per_head: bool, group: int):
     """refs: the nine operands of ``_specs``, the states, the inverses and
     o's cotangent, the five gradients, the scratch of every head's dS."""
     for offset in range(0, slab, heads):
-        _backward_group(refs, slab, heads, dk, dv, offset, per_head)
+        _backward_group(refs, slab, heads, dk, dv, offset, per_head, group)
 
 
 def _backward_group(refs, slab: int, heads: int, dk: int, dv: int,
-                    offset: int, per_head: bool):
+                    offset: int, per_head: bool, group: int):
     states_ref, inverse_ref, do_ref = refs[9:12]
     dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate_ref = refs[12:]
     first, lane, beta, (strict, lower, sees, last), p = _step(
-        *refs[:9], heads, dk, dv, slab, offset, per_head)
+        *refs[:9], heads, dk, dv, slab, offset, per_head, group)
     mine = pl.ds(first, heads)
     c, sub = refs[0].shape[1], SUB
     n = c // sub
@@ -448,7 +469,7 @@ def _backward_group(refs, slab: int, heads: int, dk: int, dv: int,
     if per_head:
         _per_head_grads(p, da_mat, db_mat, dq_in, dk_in, dk_out, dcarry,
                         sees, last, dq_ref, dk_ref, dg_ref, lane, first,
-                        offset)
+                        offset, group)
         return
 
     # the triangles: rows of sub-chunk a are lefts[a] k_cols[a]^T
@@ -492,10 +513,12 @@ def _backward_group(refs, slab: int, heads: int, dk: int, dv: int,
 
 
 def _per_head_grads(p, da_mat, db_mat, dq_in, dk_in, dk_out, dcarry, sees,
-                    last, dq_ref, dk_ref, dg_ref, lane, first, offset: int):
+                    last, dq_ref, dk_ref, dg_ref, lane, first, offset: int,
+                    group: int):
     """The backward's last part for one decay a head (the module's
-    docstring): q's and k's gradients through A, B and the decays, and
-    g's, [C, 1] a head, into its column of the [C, H] block."""
+    docstring): q's and k's gradients through A, B and the decays, summed
+    over the ``group`` value heads of a key head, and g's, [C, 1] a head,
+    into its column of the [C, H] block."""
     h, c, _ = da_mat.shape
     dtype = p["q"].dtype
     kf, qf = p["kf"], p["qf"]
@@ -524,10 +547,10 @@ def _per_head_grads(p, da_mat, db_mat, dq_in, dk_in, dk_out, dcarry, sees,
         dgs = jnp.where(lane == first + j, dg[j], dgs)
     dg_ref[0] = dgs
     _store(dq_ref, rows[:, c:]
-           + jnp.where(sees, dq_in * p["decay_in"], 0.0), offset)
+           + jnp.where(sees, dq_in * p["decay_in"], 0.0), offset, group)
     _store(dk_ref, rows[:, :c] + cols
            + jnp.where(sees, dk_in * p["decay_in"], 0.0)
-           + jnp.where(last, dk_out * p["decay_out"], 0.0), offset)
+           + jnp.where(last, dk_out * p["decay_out"], 0.0), offset, group)
 
 
 def _marks(seg):
@@ -546,12 +569,15 @@ def _marks(seg):
     return column(seg), chunks[:, :, None, :], column(sees), column(last)
 
 
-def _specs(heads: int, dk: int, dv: int, at, per_head: bool):
+def _specs(heads: int, dk: int, dv: int, at, per_head: bool,
+           group: int = 1):
     """(block specs of q, k, v, g, beta and the four marks; the spec of a
     [B, T, H·dv] array), for a grid (row, chunk, head slab) whose chunk
-    ``at(c)`` is; g is a [B, T, H] array where ``per_head``."""
-    n = _slab(heads, dk, dv)
-    keys = pl.BlockSpec((1, CHUNK, n * dk), lambda b, c, h: (b, at(c), h))
+    ``at(c)`` is; g is a [B, T, H] array where ``per_head``; q and k hold
+    a key head for every ``group`` value heads."""
+    n = _slab(heads, dk, dv, group=group)
+    keys = pl.BlockSpec((1, CHUNK, n // group * dk),
+                        lambda b, c, h: (b, at(c), h))
     values = pl.BlockSpec((1, CHUNK, n * dv), lambda b, c, h: (b, at(c), h))
     column = pl.BlockSpec((1, CHUNK, 1), lambda b, c, h: (b, at(c), 0))
     by_head = pl.BlockSpec((1, CHUNK, heads), lambda b, c, h: (b, at(c), 0))
@@ -562,10 +588,10 @@ def _specs(heads: int, dk: int, dv: int, at, per_head: bool):
         column, column], keys, values
 
 
-def _kept_specs(heads: int, dk: int, dv: int, at):
+def _kept_specs(heads: int, dk: int, dv: int, at, group: int = 1):
     """Block specs of what the backward keeps: a state and an inverse a
     chunk and head."""
-    n = _slab(heads, dk, dv)
+    n = _slab(heads, dk, dv, group=group)
     whole = lambda b, c, h: (b, at(c), h, 0, 0)
     return [pl.BlockSpec((1, 1, n, dv, dk), whole),
             pl.BlockSpec((1, 1, n, CHUNK, CHUNK), whole)]
@@ -580,29 +606,34 @@ _WHOLE = pltpu.CompilerParams(
     vmem_limit_bytes=96 * 2 ** 20)
 
 
-def _layout(q, v, g, heads: int):
-    """(dk, dv, the slab, the heads a group, whether g is one a head, the
-    compiler's parameters)."""
-    dk, dv = q.shape[-1] // heads, v.shape[-1] // heads
-    slab = _slab(heads, dk, dv)
-    return (dk, dv, slab, _heads_a_step(slab), g.shape[-1] != q.shape[-1],
-            _ORDER if slab == _heads_a_step(heads) else _WHOLE)
+def _layout(q, v, g, heads: int, group: int):
+    """(dk, dv, the slab, the heads taken at a time, whether g is one a
+    head, the compiler's parameters) of ``heads`` value heads, ``group``
+    of them a key head."""
+    dk, dv = q.shape[-1] // (heads // group), v.shape[-1] // heads
+    slab = _slab(heads, dk, dv, group=group)
+    per_head = g.shape[-1] == heads
+    if group > 1 and not per_head:
+        raise ValueError("value heads share a key head with one decay a "
+                         "head only")
+    return (dk, dv, slab, _heads_a_step(slab, group), per_head,
+            _ORDER if slab == _heads_a_step(heads, group) else _WHOLE)
 
 
-def _forward(q, k, v, g, beta, seg, heads: int, keep: bool):
+def _forward(q, k, v, g, beta, seg, heads: int, keep: bool, group: int):
     b, t, _ = q.shape
-    dk, dv, slab, group, per_head, params = _layout(q, v, g, heads)
+    dk, dv, slab, taken, per_head, params = _layout(q, v, g, heads, group)
     n = t // CHUNK
-    specs, _, values = _specs(heads, dk, dv, lambda c: c, per_head)
+    specs, _, values = _specs(heads, dk, dv, lambda c: c, per_head, group)
     out_shape = [pk._sds((b, t, heads * dv), jnp.float32, q)]
     out_specs = [values]
     if keep:
         out_shape += [pk._sds((b, n, heads, dv, dk), jnp.float32, q),
                       pk._sds((b, n, heads, CHUNK, CHUNK), jnp.float32, q)]
-        out_specs += _kept_specs(heads, dk, dv, lambda c: c)
+        out_specs += _kept_specs(heads, dk, dv, lambda c: c, group)
     return pl.pallas_call(
-        functools.partial(_forward_kernel, slab=slab, heads=group, dk=dk,
-                          dv=dv, keep=keep, per_head=per_head),
+        functools.partial(_forward_kernel, slab=slab, heads=taken, dk=dk,
+                          dv=dv, keep=keep, per_head=per_head, group=group),
         grid=(b, n, heads // slab),
         in_specs=specs, out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((heads, dv, dk), jnp.float32)],
@@ -610,17 +641,18 @@ def _forward(q, k, v, g, beta, seg, heads: int, keep: bool):
     )(q, k, v, g, beta, *_marks(seg))
 
 
-def _backward(q, k, v, g, beta, seg, states, inverses, do, heads: int):
+def _backward(q, k, v, g, beta, seg, states, inverses, do, heads: int,
+              group: int):
     b, t, _ = q.shape
-    dk, dv, slab, group, per_head, params = _layout(q, v, g, heads)
+    dk, dv, slab, taken, per_head, params = _layout(q, v, g, heads, group)
     n = t // CHUNK
     at = lambda c: n - 1 - c
-    specs, keys, values = _specs(heads, dk, dv, at, per_head)
+    specs, keys, values = _specs(heads, dk, dv, at, per_head, group)
     return pl.pallas_call(
-        functools.partial(_backward_kernel, slab=slab, heads=group, dk=dk,
-                          dv=dv, per_head=per_head),
+        functools.partial(_backward_kernel, slab=slab, heads=taken, dk=dk,
+                          dv=dv, per_head=per_head, group=group),
         grid=(b, n, heads // slab),
-        in_specs=specs + _kept_specs(heads, dk, dv, at) + [values],
+        in_specs=specs + _kept_specs(heads, dk, dv, at, group) + [values],
         out_specs=[keys, keys, values, specs[3], specs[4]],
         out_shape=[pk._sds(q.shape, q.dtype, q), pk._sds(k.shape, k.dtype, q),
                    pk._sds(v.shape, v.dtype, q),
@@ -631,18 +663,19 @@ def _backward(q, k, v, g, beta, seg, states, inverses, do, heads: int):
     )(q, k, v, g, beta, *_marks(seg), states, inverses, do)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _delta_rule(q, k, v, g, beta, seg, heads: int):
-    return _forward(q, k, v, g, beta, seg, heads, keep=False)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _delta_rule(q, k, v, g, beta, seg, heads: int, group: int):
+    return _forward(q, k, v, g, beta, seg, heads, False, group)[0]
 
 
-def _delta_rule_fwd(q, k, v, g, beta, seg, heads: int):
-    out, states, inverses = _forward(q, k, v, g, beta, seg, heads, keep=True)
+def _delta_rule_fwd(q, k, v, g, beta, seg, heads: int, group: int):
+    out, states, inverses = _forward(q, k, v, g, beta, seg, heads, True,
+                                     group)
     return out, (q, k, v, g, beta, seg, states, inverses)
 
 
-def _delta_rule_bwd(heads: int, kept, do):
-    grads = _backward(*kept, do.astype(jnp.float32), heads)
+def _delta_rule_bwd(heads: int, group: int, kept, do):
+    grads = _backward(*kept, do.astype(jnp.float32), heads, group)
     # integer segment ids carry a float0 (empty) cotangent
     return tuple(grads) + (np.zeros(kept[5].shape, jax.dtypes.float0),)
 
@@ -650,12 +683,15 @@ def _delta_rule_bwd(heads: int, kept, do):
 _delta_rule.defvjp(_delta_rule_fwd, _delta_rule_bwd)
 
 
-def delta_rule(q, k, v, g, beta, segment_ids=None):
+def delta_rule(q, k, v, g, beta, segment_ids=None, group: int = 1):
     """The chunked delta rule by the kernels: q, k [B, T, H·dk] and v
     [B, T, H·dv] of one type (the matmuls' operands'), g as q (a decay a
     channel) or as beta (one a head) and beta [B, T, H] float32,
-    ``segment_ids`` [B, T] or None -> o [B, T, H·dv] float32.  T is padded
-    to whole chunks with tokens that write nothing and decay nothing."""
+    ``segment_ids`` [B, T] or None -> o [B, T, H·dv] float32; with
+    ``group`` (one decay a head), q and k hold a key head for every
+    ``group`` value heads, [B, T, (H / group)·dk], value head h reading key
+    head h // group.  T is padded to whole chunks with tokens that write
+    nothing and decay nothing."""
     b, t, _ = q.shape
     pad = -t % CHUNK
     seg = (jnp.ones((b, t), jnp.int32) if segment_ids is None
@@ -665,7 +701,7 @@ def delta_rule(q, k, v, g, beta, segment_ids=None):
                             for a in (q, k, v, g, beta))
         seg = jnp.pad(seg, ((0, 0), (0, pad)), mode="edge")
     out = _delta_rule(q, k, v, g.astype(jnp.float32),
-                      beta.astype(jnp.float32), seg, beta.shape[-1])
+                      beta.astype(jnp.float32), seg, beta.shape[-1], group)
     return out[:, :t]
 
 

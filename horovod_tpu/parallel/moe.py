@@ -220,12 +220,15 @@ _TILE = 256
 def tile_rows(expected_pairs: float) -> int:
     """Rows of a tile where a held expert expects ``expected_pairs`` pairs
     of a batch: the fewest multiples of ``_TILE`` (at most four) that hold
-    one and a half times that.  An expert whose expected load fills a tile
-    would take one tile or two by the routers' draw, and the step's time
-    would follow the seed (0.5% between seeds at 256 pairs an expert and
-    tiles of 256; PERF.md, PR 34); with room for the draw it is one tile
-    an expert, whatever the seed."""
-    return _TILE * min(4, max(1, -(-int(1.5 * expected_pairs) // _TILE)))
+    twice that.  An expert whose expected load fills a tile would take one
+    tile or two by the routers' draw, and the step's time would follow the
+    seed (0.5% between seeds at 256 pairs an expert and tiles of 256);
+    with room for the draw it is one tile an expert, whatever the seed.
+    Twice and not one and a half: a softmax router from random weights
+    gives its busiest experts 3 to 3.7 times the mean where a biased
+    sigmoid router gives 1.9, and at 160 pairs an expert tiles of 256
+    made the step follow the seed by 0.47% (PERF.md, section 6)."""
+    return _TILE * min(4, max(1, -(-int(2 * expected_pairs) // _TILE)))
 
 
 def _plan(local: jax.Array, n_held: int, tile: int):
@@ -375,6 +378,12 @@ class ExpertFFN(nn.Module):
         s = sigmoid(x W_r);  ids, w = route_group_limited(s, b, ...)
         y = Shared(x) + sum over chosen i held here of w_i E_i(x)
 
+    With ``scoring`` "softmax" (Qwen's) s = softmax(x W_r) over all the
+    experts and there is no selection bias b (route_group_limited's
+    renormalisation of the chosen s is then ``norm_topk_prob``); with
+    ``shared_gate`` the shared expert is times sigmoid(x w_sg), one gate a
+    token.
+
     With every expert held this is the whole layer; with a range it is
     this device's part, which the other devices' parts complete by a sum
     (the shared expert counted once).  No token is dropped.  Takes the
@@ -390,6 +399,9 @@ class ExpertFFN(nn.Module):
     topk_group: int = 1
     routed_scaling: float = 1.0
     dtype: Optional[jnp.dtype] = None
+    scoring: str = "sigmoid"     # "sigmoid" (with a selection bias) |
+    #                              "softmax" (without)
+    shared_gate: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -400,16 +412,26 @@ class ExpertFFN(nn.Module):
             raise ValueError(
                 f"experts_held {self.experts_held} is no range of the "
                 f"{self.num_experts} experts")
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"unknown router scoring {self.scoring!r}; "
+                             "expected 'sigmoid' or 'softmax'")
         dtype = self.dtype or x.dtype
         xf = x.reshape(b * t, d)
         with jax.named_scope("router"):
             w_r = self.param("router", nn.initializers.lecun_normal(),
                              (d, self.num_experts), jnp.float32)
-            bias = self.param("router_bias", nn.initializers.normal(0.01),
-                              (self.num_experts,), jnp.float32)
-            scores = jax.nn.sigmoid(jnp.dot(
-                xf.astype(jnp.float32), w_r,
-                precision=lax.Precision.HIGHEST))
+            if self.scoring == "softmax":
+                bias = jnp.zeros((self.num_experts,), jnp.float32)
+                scores = jax.nn.softmax(jnp.dot(
+                    xf.astype(jnp.float32), w_r,
+                    precision=lax.Precision.HIGHEST), axis=-1)
+            else:
+                bias = self.param("router_bias",
+                                  nn.initializers.normal(0.01),
+                                  (self.num_experts,), jnp.float32)
+                scores = jax.nn.sigmoid(jnp.dot(
+                    xf.astype(jnp.float32), w_r,
+                    precision=lax.Precision.HIGHEST))
             ids, weights = route_group_limited(
                 scores, bias, self.k, self.n_group, self.topk_group,
                 self.routed_scaling)
@@ -434,5 +456,11 @@ class ExpertFFN(nn.Module):
             shared = TensorParallelMLP(
                 hidden=self.hidden, features=d, dtype=dtype, act=nn.silu,
                 gated=True, use_bias=False, name="shared")(xc)
+            if self.shared_gate:
+                w_sg = self.param("shared_gate",
+                                  nn.initializers.lecun_normal(), (d, 1),
+                                  jnp.float32)
+                shared = (shared * jax.nn.sigmoid(
+                    xf.astype(jnp.float32) @ w_sg)).astype(dtype)
         y = routed.astype(dtype) + shared
         return y.reshape(b, t, d), load.astype(jnp.float32)

@@ -1090,11 +1090,12 @@ def test_the_shares_of_a_one_group_layer_add_up():
 
 
 @pytest.mark.parametrize("expected, rows", [
-    (64, 256), (170, 256), (256, 512), (512, 768), (2048, 1024)])
+    (64, 256), (120, 256), (160, 512), (170, 512), (256, 512), (384, 768),
+    (512, 1024), (2048, 1024)])
 def test_a_tile_leaves_room_for_the_routers_draw(expected, rows):
-    """One and a half times an expert's expected pairs, in multiples of
-    256 up to four: 64 pairs an expert keep the tile of 256, 256 pairs an
-    expert take 512 (one tile an expert whatever the seed)."""
+    """Twice an expert's expected pairs, in multiples of 256 up to four:
+    64 pairs an expert keep the tile of 256, 160 and 256 pairs an expert
+    take 512 (one tile an expert whatever the seed)."""
     assert moe.tile_rows(expected) == rows
 
 

@@ -343,6 +343,9 @@ def test_olmo_head_norms_compile_for_the_chip(one_chip, mosaic):
 @pytest.mark.parametrize("shape, dtype", [
     ((1, 4096, 32, 128), jnp.bfloat16),  # ling3flash.ring1x4096
     ((2, 300, 4, 256), jnp.float32),     # ragged T, heads of two slabs
+    # qwen3next.ring1x8192: q's and k's norms a head, 16 and 2 heads of 256
+    ((1, 8192, 16, 256), jnp.bfloat16),
+    ((1, 8192, 2, 256), jnp.bfloat16),
 ])
 def test_head_norms_compile_for_the_chip(one_chip, mosaic, shape, dtype):
     """The output's RMSNorm and gate on [B, T, H·d] after the delta rule,
@@ -377,6 +380,8 @@ def test_head_norms_compile_for_the_chip(one_chip, mosaic, shape, dtype):
     (30, 96, True, False),    # olmo_hybrid7b.ring1x4096: q and k, 2880
     (30, 192, False, False),  # its v, 5760 lanes
     (30, 96, True, True),     # packed rows
+    (16, 128, True, False),   # qwen3next.ring1x8192: q and k at its 16 key
+    #                           heads (its v is ling3flash's 32 of 128)
 ])
 def test_convolution_pair_compiles_for_the_chip(
         one_chip, mosaic, heads, width, normed, packed):
@@ -459,6 +464,85 @@ def test_window_and_grouped_heads_compile_for_the_chip(
     assert compiled.memory_analysis().temp_size_in_bytes < scores / 16
 
 
+@pytest.mark.parametrize("packed", [False, True])
+def test_gated_attention_heads_of_256_compile_for_the_chip(
+        one_chip, mosaic, packed):
+    """qwen3next.ring1x8192's full layer: 16 query heads of 256 over 2
+    K/V heads, groups of 8, one row of 8192 tokens: both kernels, k and v
+    entering at their own 2 heads, a grid step owning one query head (two
+    128-lane columns)."""
+    b, t, heads, kv_heads, d = 1, 8192, 16, 2, 256
+    q = jax.ShapeDtypeStruct((b, t, heads, d), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, t, kv_heads, d), jnp.bfloat16,
+                              sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip)
+    assert pallas_kernels._slab_heads(heads, d) == 1
+
+    def grads(q, k, v, w, seg):
+        def loss(q, k, v):
+            out = pallas_kernels.flash_attention(
+                q, k, v, True, segment_ids=seg if packed else None)
+            return jnp.sum(out.astype(jnp.float32) * w)
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(grads).lower(q, kv, kv, q, seg).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 2
+    assert sum("flash_bwd_dq_dkv/pallas_call" in c for c in calls) == 1
+    for call in calls:
+        constraints = call.split("operand_layout_constraints=")[1]
+        assert constraints.count(f"bf16[{b},{t},{kv_heads * d}]") >= 2
+    assert " while(" not in text
+    # no score matrix: a query head's dk and dv, float32, before XLA sums
+    # them over a group, are the largest temporaries
+    wide = b * t * heads * d * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * wide
+
+
+@pytest.mark.parametrize("packed", [False, True])
+def test_shared_key_heads_compile_for_the_chip(one_chip, mosaic, packed):
+    """qwen3next.ring1x8192's Gated DeltaNet core: 32 value heads of 128
+    over 16 key heads of 128, one decay a value head, a row of 8192: a
+    gradient is two Mosaic calls and no loop; q and k enter at their 16
+    heads (no array of them at the value heads' width is made), and the
+    temporaries are what the backward keeps beside o's cotangent."""
+    from horovod_tpu.ops import kda, kda_kernels
+
+    b, t, hk, h, d = 1, 8192, 16, 32, 128
+    keys = jax.ShapeDtypeStruct((b, t, hk * d), jnp.bfloat16,
+                                sharding=one_chip)
+    values = jax.ShapeDtypeStruct((b, t, h * d), jnp.bfloat16,
+                                  sharding=one_chip)
+    head = jax.ShapeDtypeStruct((b, t, h), jnp.float32, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((b, t, h * d), jnp.float32, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip)
+    assert kda_kernels._slab(h, d, d, group=2) == 4
+
+    def grads(q, k, v, g, beta, w, seg):
+        def loss(*operands):
+            return jnp.sum(kda.kda(*operands, seg if packed else None,
+                                   group=2) * w)
+
+        return jax.grad(loss, argnums=range(5))(q, k, v, g, beta)
+
+    compiled = jax.jit(grads).lower(
+        keys, keys, values, head, head, w, seg).compile()
+    text = compiled.as_text()
+    assert len([ln for ln in text.splitlines()
+                if "tpu_custom_call" in ln]) == 2
+    assert " while(" not in text
+    for ln in _entry(text):
+        result, opcode = _result_and_opcode(ln)
+        if opcode in ("copy", "transpose", "broadcast", "concatenate"):
+            assert f"{t},{h * d}]" not in result, ln
+    kept = b * (t // kda_kernels.CHUNK) * h * (d * d + 64 * 128) * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        kept + 3 * b * t * h * d * 4
+
+
 @pytest.mark.parametrize("heads, turning", [(48, 64), (64, 128), (8, 64)])
 def test_rope_of_a_wide_array_fits_the_kernels_memory(
         one_chip, mosaic, heads, turning):
@@ -490,6 +574,10 @@ def test_rope_of_a_wide_array_fits_the_kernels_memory(
     (8192, 256, 32, 2048, 512),
     # ling3flash.ring1x4096: 8 of 512, tiles of 256 rows
     (4096, 512, 8, 2560, 768),
+    # qwen3next.ring1x8192's widths and router, 32 of 512 held: tiles of
+    # 256 rows at this test's 8 a token (the cell's 10 a token, 160 pairs
+    # expected, take tiles of 512 rows: the first case at these widths)
+    (8192, 512, 32, 2048, 512),
 ])
 def test_routed_experts_tile_compiles_for_the_chip(
         one_chip, mosaic, tokens, router, held, d, f):
