@@ -852,6 +852,11 @@ class ShardedZeroState:
         self._cfg = cfg
         self._snap: Optional[Dict[str, Any]] = None
 
+    @property
+    def owns(self) -> str:
+        """The state's attribute this adapter carries across a remesh."""
+        return self._states_attr
+
     # -- helpers ------------------------------------------------------
     def _config(self):
         if self._cfg is not None:

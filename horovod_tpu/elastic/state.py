@@ -252,23 +252,49 @@ class ArrayState(ObjectState):
             else:
                 setattr(self, k, copy.deepcopy(v))
 
+    def _replicated(self) -> Dict[str, Any]:
+        """The saved attributes that rank 0 broadcasts and persists.  In a
+        job of several processes one that a registered sharded adapter
+        carries spans the other processes' devices, which rank 0 cannot
+        read: each process keeps its own shards, and the adapter moves
+        them at a remesh."""
+        if jax.process_count() == 1:
+            return dict(self._saved_state)
+        owned = {getattr(spec, "owns", None)
+                 for spec in self._sharded.values()}
+        return {k: v for k, v in self._saved_state.items() if k not in owned}
+
     def sync(self) -> None:
         if self._saved_state:
             self._load_persisted()
             self.save()
-            synced = functions.broadcast_object(self._saved_state, root_rank=0)
+            synced = functions.broadcast_object(
+                self._replicated(), root_rank=0)
             for k, v in synced.items():
                 self._saved_state[k] = v
                 setattr(
                     self, k, jax.device_put(v) if k in self._array_attrs else v
                 )
 
+    def _serialize(self):
+        import pickle
+
+        return pickle.dumps(self._replicated())
+
     def _deserialize(self, blob) -> bool:
-        if not super()._deserialize(blob):
+        import pickle
+
+        try:
+            saved = pickle.loads(blob)
+        except Exception:
             return False
+        if set(saved) != set(self._replicated()):
+            return False
+        self._saved_state.update(saved)
         # re-device the array attributes (the blob holds host arrays)
-        for k in self._array_attrs:
-            setattr(self, k, jax.device_put(self._saved_state[k]))
+        for k, v in saved.items():
+            setattr(self, k,
+                    jax.device_put(v) if k in self._array_attrs else v)
         return True
 
 

@@ -1015,6 +1015,18 @@ class Transformer(nn.Module):
                          and kda_kernels.takes(cfg.gdn_value_dim)))
         metrics.set_gauge("model.kda.kernel_layers", kda_layers)
         metrics.set_gauge("model.gdn.kernel_layers", gdn_layers)
+        # the chunks a grid step of those layers' kernel calls owns
+        itemsize = jnp.dtype(cfg.dtype).itemsize
+        plans = [kda_kernels.step_plan(t, cfg.num_heads, cfg.head_dim,
+                                       cfg.head_dim, itemsize=itemsize)
+                 ] if kda_layers else []
+        if gdn_layers:
+            plans.append(kda_kernels.step_plan(
+                t, cfg.num_heads, cfg.gdn_key_dim, cfg.gdn_value_dim,
+                cfg.num_heads // (cfg.gdn_key_heads or cfg.num_heads), True,
+                itemsize))
+        metrics.set_gauge("model.delta_rule.chunks_per_step",
+                          max((chunks for chunks, _ in plans), default=0))
         if any("gdn" in kind for kind in kinds):
             metrics.set_gauge("model.gdn.value_heads_per_key",
                               cfg.num_heads // (cfg.gdn_key_heads

@@ -3,16 +3,23 @@ forward and a hand-written backward under one ``jax.custom_vjp``, on the
 projections' own layout: q, k, g ``[B, T, H·dk]``, v and o ``[B, T, H·dv]``,
 beta ``[B, T, H]``.
 
-A grid step owns one chunk of ``CHUNK`` tokens by one slab of heads'
-lanes (``_slab``: ``_heads_a_step`` heads where their keys and values are
-whole 128-lane slabs, else every head, the block as wide as the array, so
-that heads of 96 or 192 lanes are read where the projection left them);
-inside a slab the heads are taken ``_heads_a_step`` at a time, each
-group's lanes a static slice of the block.  The chunks are walked in order
-(in reverse by the backward) with every head's state, ``[dv, dk]``
-float32, in a VMEM scratch.  A chunk's triangles, the inverse, U, W and what the tokens wrote
-never leave VMEM.  The state is kept transposed so that its decay, one
-factor a key channel, runs along the lanes.
+The grid is (row, step of c chunks, slab of heads): a grid step owns c
+consecutive chunks of ``CHUNK`` tokens, a block of c·CHUNK rows, by one
+slab of heads' lanes (``_slab``: ``_heads_a_step`` heads where their keys
+and values are whole 128-lane slabs, else every head, the block as wide as
+the array, so that heads of 96 or 192 lanes are read where the projection
+left them).  Inside a slab the heads are taken some at a time, each
+group's lanes a static slice of the block, and c and the heads taken are
+``step_plan``'s, from the shapes alone.  Of a group, the c chunks by its
+heads are one batch axis of every array: all that does not touch the
+state (the decays, the triangles, the inverse, U and W; in the backward
+everything but dS's walk) is issued for the whole batch at once, and only
+the state's short recurrence walks the step's chunks in order, two
+matmuls a chunk (in reverse by the backward), with every head's state,
+``[dv, dk]`` float32, in a VMEM scratch from step to step.  A chunk's
+triangles, the inverse, U, W and what the tokens wrote never leave VMEM.
+The state is kept transposed so that its decay, one factor a key channel,
+runs along the lanes.
 
 The mathematics and the precision are ``kda.kda_chunk_major``'s: matmul
 operands are rounded to q's type where it rounds them, the decays'
@@ -27,8 +34,9 @@ along the contraction of one matmul: ``_dot``).
 
 What the backward keeps from the forward: the state entering each chunk
 (``[B, n, H, dv, dk]`` float32) and the chunk's inverse (``[B, n, H, C, C]``
-float32, ten full-precision matmuls to make again); the triangles, U, W and
-what was written are made again from q, k, v, g, beta, one chunk at a time.
+float32, ten full-precision matmuls to make again), written a block of c
+chunks at a time; the triangles, U, W and what was written are made again
+from q, k, v, g, beta.
 With ``wrote = U - W S0``, ``out = Q_in S0 + B wrote``, ``S1 = Diag(decay)
 S0 + K_out^T wrote`` and ``[U | W] = X Diag(beta) [V | K_in]``,
 ``X = (I + Diag(beta) A)^-1``:
@@ -105,7 +113,8 @@ def _heads_a_step(heads: int, group: int = 1) -> int:
     the next one that depends on it: a head's chunk alone is a chain of
     some forty dependent MXU round trips that nothing hides (PERF.md, PR
     33: 4.7 ms a layer forward at one head, 2.1 at four; eight need more
-    VMEM than a kernel gets unasked)."""
+    VMEM than a kernel gets unasked).  ``step_plan`` widens the batch with
+    the step's chunks."""
     return next(n for n in (4, 3, 2, 1, group)
                 if heads % n == 0 and n % group == 0)
 
@@ -121,6 +130,72 @@ def _slab(heads: int, *widths: int, group: int = 1) -> int:
            for i, w in enumerate(widths)):
         return n
     return heads
+
+
+# A grid step's batch: chunk-heads whose chains are issued together.  Past
+# two chunks a step a chain's own work bounds the kernel, not its latency
+# (PERF.md, PR 42: a layer's forward 1.63 -> 1.39 ms at two chunks of four
+# heads, 1.39 at four, 1.42 at eight); Mosaic unrolls the batch, so the
+# kernel's compile time grows with a step's chunk-heads (the pair at a
+# block of 30 heads: 31 s at one chunk, 82 at two, 163 at four, compiled
+# several at a time on the chip's host).
+_CHAINS = 16      # chunk-heads a group of the body takes at once, at most
+_STEP_CHAINS = 64  # chunk-heads of a grid step, at most
+_VMEM_MOST = 96 * 2 ** 20
+
+
+def _vmem_bytes(chunks: int, taken: int, slab: int, heads: int, dk: int,
+                dv: int, group: int, per_head: bool, itemsize: int) -> int:
+    """What the backward, the larger of the pair, holds in VMEM, from above
+    (compiled for a v5e: 28 MiB needed at four chunks of four 128-lane
+    heads, 20 with one decay a head, 37 at two chunks of three of Olmo's
+    30 heads): its blocks twice over (the pipeline's two buffers), the
+    running dS of every head, and 36 arrays of a chunk by a head's widest
+    lanes for each of the ``chunks`` x ``taken`` chains of a group."""
+    lanes = lambda n: -(-n // pk._LANES) * pk._LANES
+    rows = chunks * CHUNK
+    keys = rows * lanes(slab // group * dk) * itemsize
+    values = rows * lanes(slab * dv)
+    gates = rows * (lanes(heads) if per_head else lanes(slab * dk)) * 4
+    blocks = (4 * keys + values * (2 * itemsize + 4) + 2 * gates
+              + rows * (2 * lanes(heads) + 3 * pk._LANES) * 4
+              + chunks * slab * (dv * lanes(dk) + CHUNK * pk._LANES) * 4)
+    chains = chunks * taken * 36 * CHUNK * lanes(max(dk, dv)) * 4
+    return 2 * blocks + heads * dv * lanes(dk) * 4 + chains
+
+
+def _vmem_limit(*plan) -> int:
+    """The VMEM a call of the pair asks: half as much again as
+    ``_vmem_bytes`` gives, at least what a kernel gets unasked."""
+    return min(_VMEM_MOST, max(16 * 2 ** 20, 3 * _vmem_bytes(*plan) // 2))
+
+
+def step_plan(t: int, heads: int, dk: int, dv: int, group: int = 1,
+              per_head: bool = False, itemsize: int = 2):
+    """(chunks a grid step owns, heads its body takes at a time) of the
+    pair on T = ``t`` tokens of ``heads`` value heads, keys ``dk`` and
+    values ``dv`` wide, ``group`` value heads a key head, g one a head
+    where ``per_head``, q ``itemsize`` bytes an element.  The chunks
+    double while a group's batch stays within ``_CHAINS``, the step's
+    within ``_STEP_CHAINS`` and its VMEM within ``_VMEM_MOST``; then a
+    block of every head takes as many heads at a time as fit the same
+    bounds.  A row's T is padded to whole steps, and the chunks are as
+    few as give the same number of steps: no padding where whole steps
+    fit."""
+    slab = _slab(heads, dk, dv, group=group)
+    taken = _heads_a_step(slab, group)
+    fits = lambda c, h: c * h <= _CHAINS and c * slab <= _STEP_CHAINS and \
+        _vmem_bytes(c, h, slab, heads, dk, dv, group, per_head,
+                    itemsize) <= _VMEM_MOST
+    chunks = 1
+    while fits(2 * chunks, taken):
+        chunks *= 2
+    taken = max(h for h in range(taken, slab + 1)
+                if slab % h == 0 and h % group == 0
+                and (h == taken or fits(chunks, h)))
+    n = -(-t // CHUNK)
+    steps = -(-n // chunks)
+    return -(-n // steps), taken
 
 
 def _parts(x):
@@ -237,7 +312,8 @@ def _chunk(q, k, v, g, beta, strict, lower, sees, last, sub: int):
         b_mat=b_mat, decay_in=decay_in, decay_out=decay_out,
         q_in=jnp.where(sees, qf * decay_in, 0.0).astype(dtype),
         k_out=jnp.where(last, kf * decay_out, 0.0).astype(dtype),
-        carry=jnp.where(sees[c - 1:], decay_in[:, c - 1:], 0.0),  # [h, 1, dk]
+        # [h, 1, dk]
+        carry=jnp.where(sees[:, c - 1:], decay_in[:, c - 1:], 0.0),
         rhs_plain=rhs_plain, rhs=(beta * rhs_plain).astype(dtype))
 
 
@@ -278,21 +354,22 @@ def _chunk_per_head(q, k, v, g, beta, strict, lower, sees, last):
         decay_in=decay_in, decay_out=decay_out,
         q_in=jnp.where(sees, qf * decay_in, 0.0).astype(dtype),
         k_out=jnp.where(last, kf * decay_out, 0.0).astype(dtype),
-        carry=jnp.where(sees[c - 1:], decay_in[:, c - 1:], 0.0),  # [h, 1, dk]
+        # [h, 1, dk]
+        carry=jnp.where(sees[:, c - 1:], decay_in[:, c - 1:], 0.0),
         rhs_plain=rhs_plain, rhs=(beta * rhs_plain).astype(dtype))
 
 
-def _wrote(parts, inverse, state, dv: int):
-    """(what the chunk's tokens write [h, C, dv] float32, W [h, C, dk], the
-    state as the matmuls take it, Q_in S0 [h, C, dv]) from the chunk's
-    parts, its inverse and the state entering it, [h, dv, dk] float32."""
-    dtype = parts["rhs"].dtype
-    c = inverse.shape[-1]
-    solved = _dot(inverse.astype(dtype), parts["rhs"])
-    w = solved[..., dv:].astype(dtype)
-    state = state.astype(dtype)
-    read = _dot(jnp.concatenate([w, parts["q_in"]], axis=1), state, _NT)
-    return solved[..., :dv] - read[:, :c], w, state, read[:, c:]
+def _blocks_chunks(x, chunks: int):
+    """The ``chunks`` chunks of a [c·C, ...] block's rows, each [C, ...]:
+    slices at whole tiles of sublanes, so nothing is laid out again."""
+    return [x[a * CHUNK:(a + 1) * CHUNK] for a in range(chunks)]
+
+
+def _chunk_major(parts, heads: int):
+    """A batch of a grid step's chunks by its heads, chunk-major, of one
+    array a chunk that every head shares: chunk a's heads are the rows
+    a·h to (a + 1)·h of the batch."""
+    return jnp.stack([part for part in parts for _ in range(heads)])
 
 
 def _column(block, lane, head):
@@ -301,15 +378,39 @@ def _column(block, lane, head):
     return jnp.sum(jnp.where(lane == head, block, 0.0), axis=1, keepdims=True)
 
 
-def _load(ref, width: int, heads: int, offset: int = 0, group: int = 1):
-    """[h, C, width] of heads ``offset`` to ``offset + h`` of a
-    [1, C, slab·width] block; with ``group``, head j's is head ``j //
-    group`` of the block (a key head shared by value heads)."""
-    return jnp.stack([ref[0, :, j // group * width:(j // group + 1) * width]
-                      for j in range(offset, offset + heads)])
+def _columns(block, lane, first, heads: int, chunks: int):
+    """Columns ``first`` to ``first + heads`` of a [c·C, H] block as the
+    chunk-major batch [c·h, C, 1]."""
+    cols = [_blocks_chunks(_column(block, lane, first + j), chunks)
+            for j in range(heads)]
+    return jnp.stack([cols[j][a] for a in range(chunks)
+                      for j in range(heads)])
 
 
-def _store(ref, value, offset: int = 0, group: int = 1):
+def _put_columns(ref, value, lane, first, chunks: int):
+    """The reverse of :func:`_columns`, into a [1, c·C, H] block whose
+    other columns are kept."""
+    heads = value.shape[0] // chunks
+    block = ref[0]
+    for j in range(heads):
+        column = jnp.concatenate([value[a * heads + j] for a in range(chunks)])
+        block = jnp.where(lane == first + j, column, block)
+    ref[0] = block
+
+
+def _load(ref, width: int, heads: int, chunks: int, offset: int = 0,
+          group: int = 1):
+    """The chunk-major batch [c·h, C, width] of heads ``offset`` to
+    ``offset + h`` of the ``chunks`` chunks of a [1, c·C, slab·width]
+    block; with ``group``, head j's is head ``j // group`` of the block (a
+    key head shared by value heads)."""
+    return jnp.stack([
+        ref[0, a * CHUNK:(a + 1) * CHUNK,
+            j // group * width:(j // group + 1) * width]
+        for a in range(chunks) for j in range(offset, offset + heads)])
+
+
+def _store(ref, value, chunks: int, offset: int = 0, group: int = 1):
     """The reverse of :func:`_load`, in the block's type: with ``group``
     the sum of each ``group`` consecutive heads' parts goes to their key
     head."""
@@ -317,68 +418,79 @@ def _store(ref, value, offset: int = 0, group: int = 1):
         value = jnp.stack([sum(value[j + m] for m in range(group))
                            for j in range(0, value.shape[0], group)])
         offset //= group
-    width = value.shape[-1]
-    for j in range(value.shape[0]):
-        at = (offset + j) * width
-        ref[0, :, at:at + width] = value[j].astype(ref.dtype)
+    heads, width = value.shape[0] // chunks, value.shape[-1]
+    for a in range(chunks):
+        for j in range(heads):
+            at = (offset + j) * width
+            ref[0, a * CHUNK:(a + 1) * CHUNK, at:at + width] = value[
+                a * heads + j].astype(ref.dtype)
 
 
-def _put(ref, value, offset: int):
-    """A group's [h, ...] into a [1, 1, slab, ...] block."""
+def _put(ref, value, offset: int, chunk: int):
+    """A group's [h, ...] of the step's chunk ``chunk`` into a [1, c,
+    slab, ...] block."""
     if ref.shape[2] == value.shape[0]:
-        ref[0, 0] = value
+        ref[0, chunk] = value
     else:
-        ref[0, 0, offset:offset + value.shape[0]] = value
+        ref[0, chunk, offset:offset + value.shape[0]] = value
 
 
 def _take(ref, heads: int, offset: int):
-    """The reverse of :func:`_put`."""
-    if ref.shape[2] == heads:
-        return ref[0, 0]
-    return ref[0, 0, offset:offset + heads]
+    """The chunk-major batch [c·h, ...] of a group's heads of a [1, c,
+    slab, ...] block."""
+    whole = ref.shape[2] == heads
+    return jnp.concatenate([
+        ref[0, a] if whole else ref[0, a, offset:offset + heads]
+        for a in range(ref.shape[1])])
 
 
 def _step(q_ref, k_ref, v_ref, g_ref, beta_ref, seg_col_ref, seg_row_ref,
-          sees_ref, last_ref, heads: int, dk: int, dv: int, slab: int,
-          offset: int, per_head: bool, group: int):
+          sees_ref, last_ref, heads: int, chunks: int, dk: int, dv: int,
+          slab: int, offset: int, per_head: bool, group: int):
     """(the first head of the group of ``heads`` that starts ``offset``
-    heads into this grid step's slab, its lane mask over a [C, H] block,
-    the heads' betas [h, C, 1], the documents' masks, the chunk's parts);
-    ``per_head``: g is a [C, H] block too, one decay a head; ``group``
-    value heads read each key head of q's and k's blocks."""
+    heads into this grid step's slab, the lane index of a [c·C, H] block,
+    the heads' betas, the documents' masks and the chunks' parts, each a
+    chunk-major batch of the step's ``chunks`` chunks by the group's
+    heads); ``per_head``: g is a [c·C, H] block too, one decay a head;
+    ``group`` value heads read each key head of q's and k's blocks."""
     first = pl.program_id(2) * slab
     if offset:
         first = first + offset
-    c = q_ref.shape[1]
-    row, col = _iotas(c)
-    same = seg_col_ref[0] == seg_row_ref[0, 0]
+    row, col = _iotas(CHUNK)
+    same = (_chunk_major(_blocks_chunks(seg_col_ref[0], chunks), heads)
+            == _chunk_major([seg_row_ref[0, a] for a in range(chunks)],
+                            heads))
     betas = beta_ref[0]
     lane = lax.broadcasted_iota(jnp.int32, betas.shape, 1)
-    beta = jnp.stack([_column(betas, lane, first + j) for j in range(heads)])
+    beta = _columns(betas, lane, first, heads, chunks)
     masks = (jnp.logical_and(same, row > col),
              jnp.logical_and(same, row >= col),
-             sees_ref[0] != 0, last_ref[0] != 0)
-    q, k, v = (_load(ref, width, heads, offset, n) for ref, width, n in (
-        (q_ref, dk, group), (k_ref, dk, group), (v_ref, dv, 1)))
+             _chunk_major(_blocks_chunks(sees_ref[0], chunks), heads) != 0,
+             _chunk_major(_blocks_chunks(last_ref[0], chunks), heads) != 0)
+    q, k, v = (_load(ref, width, heads, chunks, offset, n)
+               for ref, width, n in ((q_ref, dk, group), (k_ref, dk, group),
+                                     (v_ref, dv, 1)))
     if per_head:
-        gates = g_ref[0]
-        g = jnp.stack([_column(gates, lane, first + j) for j in range(heads)])
+        g = _columns(g_ref[0], lane, first, heads, chunks)
         parts = _chunk_per_head(q, k, v, g, beta, *masks)
     else:
-        parts = _chunk(q, k, v, _load(g_ref, dk, heads, offset), beta,
-                       *masks, SUB)
+        parts = _chunk(q, k, v, _load(g_ref, dk, heads, chunks, offset),
+                       beta, *masks, SUB)
     return first, lane, beta, masks, parts
 
 
-def _forward_kernel(*refs, slab: int, heads: int, dk: int, dv: int,
-                    keep: bool, per_head: bool, group: int):
+def _forward_kernel(*refs, slab: int, heads: int, chunks: int, dk: int,
+                    dv: int, keep: bool, per_head: bool, group: int):
     """refs: the nine operands of ``_specs``, o, with ``keep`` the states
     and the inverses, the scratch of every head's running state.  The
-    slab's heads are taken ``heads`` at a time."""
+    slab's heads are taken ``heads`` at a time; of a group, all that does
+    not touch the state (triangles, inverse, U, W, B) is one batch of the
+    step's chunks by its heads, and only the state's recurrence walks the
+    chunks in order: a read of the state and a write to it a chunk."""
     o_ref, state_ref = refs[9], refs[-1]
     for offset in range(0, slab, heads):
         first, _, beta, _, parts = _step(
-            *refs[:9], heads, dk, dv, slab, offset, per_head, group)
+            *refs[:9], heads, chunks, dk, dv, slab, offset, per_head, group)
         mine = pl.ds(first, heads)
 
         @pl.when(pl.program_id(1) == 0)
@@ -387,33 +499,48 @@ def _forward_kernel(*refs, slab: int, heads: int, dk: int, dv: int,
 
         dtype = parts["rhs"].dtype
         inverse = _inverse_unit_lower(beta * parts["a_mat"], SUB)
-        state = state_ref[mine]
-        wrote, _, _, read = _wrote(parts, inverse, state, dv)
-        wrote = wrote.astype(dtype)
-        _store(o_ref, read + _dot(parts["b_mat"], wrote), offset)
-        state_ref[mine] = state * parts["carry"] + _dot(
-            wrote, parts["k_out"], _TN)
+        solved = _dot(inverse.astype(dtype), parts["rhs"])      # [U | W]
+        w = solved[..., dv:].astype(dtype)
+        state, states, wrote = state_ref[mine], [], []
+        for a in range(chunks):
+            at = slice(a * heads, (a + 1) * heads)
+            states.append(state)
+            wrote.append((solved[at, :, :dv] - _dot(
+                w[at], state.astype(dtype), _NT)).astype(dtype))
+            state = state * parts["carry"][at] + _dot(
+                wrote[-1], parts["k_out"][at], _TN)
+        state_ref[mine] = state
+        entering = jnp.concatenate(states)                      # S0 a chunk
+        _store(o_ref, _dot(parts["q_in"], entering.astype(dtype), _NT)
+               + _dot(parts["b_mat"], jnp.concatenate(wrote)), chunks,
+               offset)
         if keep:
-            _put(refs[10], state, offset)
-            _put(refs[11], inverse, offset)
+            for a in range(chunks):
+                at = slice(a * heads, (a + 1) * heads)
+                _put(refs[10], states[a], offset, a)
+                _put(refs[11], inverse[at], offset, a)
 
 
-def _backward_kernel(*refs, slab: int, heads: int, dk: int, dv: int,
-                     per_head: bool, group: int):
+def _backward_kernel(*refs, slab: int, heads: int, chunks: int, dk: int,
+                     dv: int, per_head: bool, group: int):
     """refs: the nine operands of ``_specs``, the states, the inverses and
     o's cotangent, the five gradients, the scratch of every head's dS."""
     for offset in range(0, slab, heads):
-        _backward_group(refs, slab, heads, dk, dv, offset, per_head, group)
+        _backward_group(refs, slab, heads, chunks, dk, dv, offset, per_head,
+                        group)
 
 
-def _backward_group(refs, slab: int, heads: int, dk: int, dv: int,
-                    offset: int, per_head: bool, group: int):
+def _backward_group(refs, slab: int, heads: int, chunks: int, dk: int,
+                    dv: int, offset: int, per_head: bool, group: int):
+    """A group's backward over the step's chunks: dS walks them in
+    reverse, ``dwrote = B^T do + K_out dS1`` and ``dS0`` a chunk; every
+    other part is one batch of the chunks by the heads."""
     states_ref, inverse_ref, do_ref = refs[9:12]
     dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate_ref = refs[12:]
     first, lane, beta, (strict, lower, sees, last), p = _step(
-        *refs[:9], heads, dk, dv, slab, offset, per_head, group)
+        *refs[:9], heads, chunks, dk, dv, slab, offset, per_head, group)
     mine = pl.ds(first, heads)
-    c, sub = refs[0].shape[1], SUB
+    c, sub = CHUNK, SUB
     n = c // sub
 
     @pl.when(pl.program_id(1) == 0)
@@ -432,22 +559,32 @@ def _backward_group(refs, slab: int, heads: int, dk: int, dv: int,
     kf, qf = p["kf"], p["qf"]
     inverse = _take(inverse_ref, heads, offset)
     state_in = _take(states_ref, heads, offset)
-    wrote, w, state, _ = _wrote(p, inverse, state_in, dv)
-    wrote = wrote.astype(dtype)
-    do = _load(do_ref, dv, heads, offset).astype(dtype)
-    dstate = dstate_ref[mine]                               # d S1, [h, dv, dk]
+    solved = _dot(inverse.astype(dtype), p["rhs"])          # [U | W]
+    w = solved[..., dv:].astype(dtype)
+    state = state_in.astype(dtype)
+    wrote = (solved[..., :dv] - _dot(w, state, _NT)).astype(dtype)
+    do = _load(do_ref, dv, heads, chunks, offset).astype(dtype)
+    through_b = _dot(p["b_mat"], do, _TN)
+    reads = jnp.concatenate([p["q_in"], w], axis=1)
+    dstate, dstates, dwrotes = dstate_ref[mine], [None] * chunks, [
+        None] * chunks                                      # d S1, [h, dv, dk]
+    for a in reversed(range(chunks)):
+        at = slice(a * heads, (a + 1) * heads)
+        dstates[a] = dstate
+        dwrotes[a] = through_b[at] + _dot(
+            p["k_out"][at], dstate.astype(dtype), _NT)      # [h, C, dv]
+        dstate = dstate * p["carry"][at] + _dot(jnp.concatenate(
+            [do[at], -dwrotes[a].astype(dtype)], axis=1), reads[at], _TN)
+    dstate_ref[mine] = dstate
+    dstate, dwrote = jnp.concatenate(dstates), jnp.concatenate(dwrotes)
     dstate_op = dstate.astype(dtype)
 
-    dwrote = (_dot(p["b_mat"], do, _TN)
-              + _dot(p["k_out"], dstate_op, _NT))             # [h, C, dv]
     db_mat = jnp.where(lower, _dot(do, wrote, _NT), 0.0)
     stacked = jnp.concatenate([do, -dwrote.astype(dtype)], axis=1)
     through_state = _dot(stacked, state)                    # [h, 2C, dk]
     dq_in, dw = through_state[:, :c], through_state[:, c:]
     dk_out = _dot(wrote, dstate_op)                         # [h, C, dk]
     dcarry = jnp.sum(dstate * state_in, axis=1, keepdims=True)
-    dstate_ref[mine] = dstate * p["carry"] + _dot(
-        stacked, jnp.concatenate([p["q_in"], w], axis=1), _TN)
 
     # [U | W] = X rhs,  X = (I + Diag(beta) A)^-1
     dsolved = jnp.concatenate([dwrote, dw], axis=2).astype(dtype)
@@ -459,22 +596,19 @@ def _backward_group(refs, slab: int, heads: int, dk: int, dv: int,
     da_mat = beta * dstrict
     dbeta = (jnp.sum(dstrict * p["a_mat"], axis=2, keepdims=True)
              + jnp.sum(drhs * p["rhs_plain"], axis=2, keepdims=True))
-    dbetas = dbeta_ref[0]
-    for j in range(heads):
-        dbetas = jnp.where(lane == first + j, dbeta[j], dbetas)
-    dbeta_ref[0] = dbetas
+    _put_columns(dbeta_ref, dbeta, lane, first, chunks)
     drhs = beta * drhs
-    _store(dv_ref, drhs[..., :dv], offset)
+    _store(dv_ref, drhs[..., :dv], chunks, offset)
     dk_in = drhs[..., dv:]
     if per_head:
         _per_head_grads(p, da_mat, db_mat, dq_in, dk_in, dk_out, dcarry,
                         sees, last, dq_ref, dk_ref, dg_ref, lane, first,
-                        offset, group)
+                        chunks, offset, group)
         return
 
     # the triangles: rows of sub-chunk a are lefts[a] k_cols[a]^T
     dlefts, dstarts = [], []
-    dg_cum, dk_total = jnp.zeros((heads, c, dk), jnp.float32), 0.0
+    dg_cum, dk_total = jnp.zeros(kf.shape, jnp.float32), 0.0
     for a in range(n):
         rows = slice(a * sub, (a + 1) * sub)
         both = jnp.concatenate([da_mat[:, rows], db_mat[:, rows]], axis=1
@@ -504,21 +638,21 @@ def _backward_group(refs, slab: int, heads: int, dk: int, dv: int,
         token == c - 1,
         jnp.sum(out_exponent, axis=1, keepdims=True) + dcarry * p["carry"],
         0.0)
-    _store(dg_ref, _running_sum(dg_cum, reverse=True), offset)
+    _store(dg_ref, _running_sum(dg_cum, reverse=True), chunks, offset)
     _store(dq_ref, dq_rows * p["row_decay"]
-           + jnp.where(sees, dq_in * p["decay_in"], 0.0), offset)
+           + jnp.where(sees, dq_in * p["decay_in"], 0.0), chunks, offset)
     _store(dk_ref, dk_rows * p["row_decay"] + dk_total
            + jnp.where(sees, dk_in * p["decay_in"], 0.0)
-           + jnp.where(last, dk_out * p["decay_out"], 0.0), offset)
+           + jnp.where(last, dk_out * p["decay_out"], 0.0), chunks, offset)
 
 
 def _per_head_grads(p, da_mat, db_mat, dq_in, dk_in, dk_out, dcarry, sees,
-                    last, dq_ref, dk_ref, dg_ref, lane, first, offset: int,
-                    group: int):
+                    last, dq_ref, dk_ref, dg_ref, lane, first, chunks: int,
+                    offset: int, group: int):
     """The backward's last part for one decay a head (the module's
     docstring): q's and k's gradients through A, B and the decays, summed
-    over the ``group`` value heads of a key head, and g's, [C, 1] a head,
-    into its column of the [C, H] block."""
+    over the ``group`` value heads of a key head, and g's, [C, 1] a head
+    and chunk, into its column of the [c·C, H] block."""
     h, c, _ = da_mat.shape
     dtype = p["q"].dtype
     kf, qf = p["kf"], p["qf"]
@@ -541,16 +675,15 @@ def _per_head_grads(p, da_mat, db_mat, dq_in, dk_in, dk_out, dcarry, sees,
         jnp.sum(jnp.sum(out_exponent, axis=2, keepdims=True), axis=1,
                 keepdims=True)
         + jnp.sum(dcarry * p["carry"], axis=2, keepdims=True), 0.0)
-    dg = _running_sum(dg_cum, reverse=True)
-    dgs = dg_ref[0]
-    for j in range(h):
-        dgs = jnp.where(lane == first + j, dg[j], dgs)
-    dg_ref[0] = dgs
+    _put_columns(dg_ref, _running_sum(dg_cum, reverse=True), lane, first,
+                 chunks)
     _store(dq_ref, rows[:, c:]
-           + jnp.where(sees, dq_in * p["decay_in"], 0.0), offset, group)
+           + jnp.where(sees, dq_in * p["decay_in"], 0.0), chunks, offset,
+           group)
     _store(dk_ref, rows[:, :c] + cols
            + jnp.where(sees, dk_in * p["decay_in"], 0.0)
-           + jnp.where(last, dk_out * p["decay_out"], 0.0), offset, group)
+           + jnp.where(last, dk_out * p["decay_out"], 0.0), chunks, offset,
+           group)
 
 
 def _marks(seg):
@@ -569,113 +702,147 @@ def _marks(seg):
     return column(seg), chunks[:, :, None, :], column(sees), column(last)
 
 
-def _specs(heads: int, dk: int, dv: int, at, per_head: bool,
+def _specs(heads: int, dk: int, dv: int, chunks: int, at, per_head: bool,
            group: int = 1):
     """(block specs of q, k, v, g, beta and the four marks; the spec of a
-    [B, T, H·dv] array), for a grid (row, chunk, head slab) whose chunk
-    ``at(c)`` is; g is a [B, T, H] array where ``per_head``; q and k hold
-    a key head for every ``group`` value heads."""
-    n = _slab(heads, dk, dv, group=group)
-    keys = pl.BlockSpec((1, CHUNK, n // group * dk),
-                        lambda b, c, h: (b, at(c), h))
-    values = pl.BlockSpec((1, CHUNK, n * dv), lambda b, c, h: (b, at(c), h))
-    column = pl.BlockSpec((1, CHUNK, 1), lambda b, c, h: (b, at(c), 0))
-    by_head = pl.BlockSpec((1, CHUNK, heads), lambda b, c, h: (b, at(c), 0))
+    [B, T, H·dv] array), for a grid (row, step of ``chunks`` chunks, head
+    slab) whose step ``at(s)`` is; g is a [B, T, H] array where
+    ``per_head``; q and k hold a key head for every ``group`` value
+    heads."""
+    n, rows = _slab(heads, dk, dv, group=group), chunks * CHUNK
+    keys = pl.BlockSpec((1, rows, n // group * dk),
+                        lambda b, s, h: (b, at(s), h))
+    values = pl.BlockSpec((1, rows, n * dv), lambda b, s, h: (b, at(s), h))
+    column = pl.BlockSpec((1, rows, 1), lambda b, s, h: (b, at(s), 0))
+    by_head = pl.BlockSpec((1, rows, heads), lambda b, s, h: (b, at(s), 0))
     return [
         keys, keys, values, by_head if per_head else keys, by_head,
         column,
-        pl.BlockSpec((1, 1, 1, CHUNK), lambda b, c, h: (b, at(c), 0, 0)),
+        pl.BlockSpec((1, chunks, 1, CHUNK), lambda b, s, h: (b, at(s), 0, 0)),
         column, column], keys, values
 
 
-def _kept_specs(heads: int, dk: int, dv: int, at, group: int = 1):
+def _kept_specs(heads: int, dk: int, dv: int, chunks: int, at,
+                group: int = 1):
     """Block specs of what the backward keeps: a state and an inverse a
     chunk and head."""
     n = _slab(heads, dk, dv, group=group)
-    whole = lambda b, c, h: (b, at(c), h, 0, 0)
-    return [pl.BlockSpec((1, 1, n, dv, dk), whole),
-            pl.BlockSpec((1, 1, n, CHUNK, CHUNK), whole)]
+    whole = lambda b, s, h: (b, at(s), h, 0, 0)
+    return [pl.BlockSpec((1, chunks, n, dv, dk), whole),
+            pl.BlockSpec((1, chunks, n, CHUNK, CHUNK), whole)]
 
 
-_ORDER = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "arbitrary", "arbitrary"))
-# A block of every head (``_slab``) holds ten times a slab's operands and
-# each head's state twice, in and kept: more than a kernel gets unasked.
-_WHOLE = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-    vmem_limit_bytes=96 * 2 ** 20)
+def _params(vmem: int):
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        vmem_limit_bytes=vmem)
 
 
 def _layout(q, v, g, heads: int, group: int):
-    """(dk, dv, the slab, the heads taken at a time, whether g is one a
-    head, the compiler's parameters) of ``heads`` value heads, ``group``
-    of them a key head."""
+    """(dk, dv, the slab, whether g is one a head) of ``heads`` value
+    heads, ``group`` of them a key head."""
     dk, dv = q.shape[-1] // (heads // group), v.shape[-1] // heads
     slab = _slab(heads, dk, dv, group=group)
     per_head = g.shape[-1] == heads
     if group > 1 and not per_head:
         raise ValueError("value heads share a key head with one decay a "
                          "head only")
-    return (dk, dv, slab, _heads_a_step(slab, group), per_head,
-            _ORDER if slab == _heads_a_step(heads, group) else _WHOLE)
+    return dk, dv, slab, per_head
 
 
-def _forward(q, k, v, g, beta, seg, heads: int, keep: bool, group: int):
+@functools.lru_cache(maxsize=8)
+def _jitted(body, avals, interpret: bool, static: tuple):
+    """``body`` jitted with the arguments named ``static`` static, one a
+    shape and mode (``avals`` and ``interpret`` are keys only).  A step's
+    layers of one shape then trace and lower a kernel's body once, not
+    once a layer (six layers' gradients at ``ling3flash``'s shape, traced
+    and lowered for the chip on a CPU: 0.8 s, 3.9 without, 2.3 with one
+    chunk a grid step); a process that calls many shapes keeps the
+    executables of eight at most."""
+    del avals, interpret
+    return jax.jit(body, static_argnames=static)
+
+
+def _call(body, *arrays, **static):
+    """``body(*arrays, **static)`` as one jitted call (``_jitted``)."""
+    avals = tuple(jax.typeof(a) for a in arrays)
+    return _jitted(body, avals, pk._interpret(), tuple(sorted(static)))(
+        *arrays, **static)
+
+
+def _forward(q, k, v, g, beta, seg, heads: int, keep: bool, group: int,
+             chunks: int, taken: int):
     b, t, _ = q.shape
-    dk, dv, slab, taken, per_head, params = _layout(q, v, g, heads, group)
+    dk, dv, slab, per_head = _layout(q, v, g, heads, group)
     n = t // CHUNK
-    specs, _, values = _specs(heads, dk, dv, lambda c: c, per_head, group)
+    specs, _, values = _specs(heads, dk, dv, chunks, lambda s: s, per_head,
+                              group)
     out_shape = [pk._sds((b, t, heads * dv), jnp.float32, q)]
     out_specs = [values]
     if keep:
         out_shape += [pk._sds((b, n, heads, dv, dk), jnp.float32, q),
                       pk._sds((b, n, heads, CHUNK, CHUNK), jnp.float32, q)]
-        out_specs += _kept_specs(heads, dk, dv, lambda c: c, group)
+        out_specs += _kept_specs(heads, dk, dv, chunks, lambda s: s, group)
     return pl.pallas_call(
-        functools.partial(_forward_kernel, slab=slab, heads=taken, dk=dk,
-                          dv=dv, keep=keep, per_head=per_head, group=group),
-        grid=(b, n, heads // slab),
+        functools.partial(_forward_kernel, slab=slab, heads=taken,
+                          chunks=chunks, dk=dk, dv=dv, keep=keep,
+                          per_head=per_head, group=group),
+        grid=(b, n // chunks, heads // slab),
         in_specs=specs, out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((heads, dv, dk), jnp.float32)],
-        compiler_params=params, interpret=pk._interpret(),
+        compiler_params=_params(_vmem_limit(
+            chunks, taken, slab, heads, dk, dv, group, per_head,
+            q.dtype.itemsize)),
+        interpret=pk._interpret(),
     )(q, k, v, g, beta, *_marks(seg))
 
 
 def _backward(q, k, v, g, beta, seg, states, inverses, do, heads: int,
-              group: int):
+              group: int, chunks: int, taken: int):
     b, t, _ = q.shape
-    dk, dv, slab, taken, per_head, params = _layout(q, v, g, heads, group)
-    n = t // CHUNK
-    at = lambda c: n - 1 - c
-    specs, keys, values = _specs(heads, dk, dv, at, per_head, group)
+    dk, dv, slab, per_head = _layout(q, v, g, heads, group)
+    steps = t // CHUNK // chunks
+    at = lambda s: steps - 1 - s
+    specs, keys, values = _specs(heads, dk, dv, chunks, at, per_head, group)
     return pl.pallas_call(
-        functools.partial(_backward_kernel, slab=slab, heads=taken, dk=dk,
-                          dv=dv, per_head=per_head, group=group),
-        grid=(b, n, heads // slab),
-        in_specs=specs + _kept_specs(heads, dk, dv, at, group) + [values],
+        functools.partial(_backward_kernel, slab=slab, heads=taken,
+                          chunks=chunks, dk=dk, dv=dv, per_head=per_head,
+                          group=group),
+        grid=(b, steps, heads // slab),
+        in_specs=specs + _kept_specs(heads, dk, dv, chunks, at, group)
+        + [values],
         out_specs=[keys, keys, values, specs[3], specs[4]],
         out_shape=[pk._sds(q.shape, q.dtype, q), pk._sds(k.shape, k.dtype, q),
                    pk._sds(v.shape, v.dtype, q),
                    pk._sds(g.shape, jnp.float32, q),
                    pk._sds(beta.shape, jnp.float32, q)],
         scratch_shapes=[pltpu.VMEM((heads, dv, dk), jnp.float32)],
-        compiler_params=params, interpret=pk._interpret(),
+        compiler_params=_params(_vmem_limit(
+            chunks, taken, slab, heads, dk, dv, group, per_head,
+            q.dtype.itemsize)),
+        interpret=pk._interpret(),
     )(q, k, v, g, beta, *_marks(seg), states, inverses, do)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _delta_rule(q, k, v, g, beta, seg, heads: int, group: int):
-    return _forward(q, k, v, g, beta, seg, heads, False, group)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def _delta_rule(q, k, v, g, beta, seg, heads: int, group: int, chunks: int,
+                taken: int):
+    return _call(_forward, q, k, v, g, beta, seg, heads=heads, keep=False,
+                 group=group, chunks=chunks, taken=taken)[0]
 
 
-def _delta_rule_fwd(q, k, v, g, beta, seg, heads: int, group: int):
-    out, states, inverses = _forward(q, k, v, g, beta, seg, heads, True,
-                                     group)
+def _delta_rule_fwd(q, k, v, g, beta, seg, heads: int, group: int,
+                    chunks: int, taken: int):
+    out, states, inverses = _call(_forward, q, k, v, g, beta, seg,
+                                  heads=heads, keep=True, group=group,
+                                  chunks=chunks, taken=taken)
     return out, (q, k, v, g, beta, seg, states, inverses)
 
 
-def _delta_rule_bwd(heads: int, group: int, kept, do):
-    grads = _backward(*kept, do.astype(jnp.float32), heads, group)
+def _delta_rule_bwd(heads: int, group: int, chunks: int, taken: int, kept,
+                    do):
+    grads = _call(_backward, *kept, do.astype(jnp.float32), heads=heads,
+                  group=group, chunks=chunks, taken=taken)
     # integer segment ids carry a float0 (empty) cotangent
     return tuple(grads) + (np.zeros(kept[5].shape, jax.dtypes.float0),)
 
@@ -690,10 +857,14 @@ def delta_rule(q, k, v, g, beta, segment_ids=None, group: int = 1):
     ``segment_ids`` [B, T] or None -> o [B, T, H·dv] float32; with
     ``group`` (one decay a head), q and k hold a key head for every
     ``group`` value heads, [B, T, (H / group)·dk], value head h reading key
-    head h // group.  T is padded to whole chunks with tokens that write
-    nothing and decay nothing."""
+    head h // group.  T is padded to whole grid steps (``chunks_a_step``
+    chunks each) with tokens that write nothing and decay nothing."""
     b, t, _ = q.shape
-    pad = -t % CHUNK
+    heads = beta.shape[-1]
+    chunks, taken = step_plan(t, heads, q.shape[-1] // (heads // group),
+                              v.shape[-1] // heads, group,
+                              g.shape[-1] == heads, q.dtype.itemsize)
+    pad = -t % (chunks * CHUNK)
     seg = (jnp.ones((b, t), jnp.int32) if segment_ids is None
            else segment_ids.astype(jnp.int32))
     if pad:
@@ -701,7 +872,8 @@ def delta_rule(q, k, v, g, beta, segment_ids=None, group: int = 1):
                             for a in (q, k, v, g, beta))
         seg = jnp.pad(seg, ((0, 0), (0, pad)), mode="edge")
     out = _delta_rule(q, k, v, g.astype(jnp.float32),
-                      beta.astype(jnp.float32), seg, beta.shape[-1], group)
+                      beta.astype(jnp.float32), seg, heads, group, chunks,
+                      taken)
     return out[:, :t]
 
 

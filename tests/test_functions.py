@@ -118,6 +118,31 @@ def test_array_state_save_restore(hvd_module):
     assert state.epoch == 1
 
 
+def test_array_state_leaves_a_sharded_attribute_to_each_process(
+        monkeypatch):
+    """In a job of several processes the attribute a sharded adapter
+    carries is neither broadcast from rank 0 nor persisted by it (its
+    shards live on the other processes' devices, and pickling it fails);
+    in one process it is persisted like every other."""
+    import pickle
+
+    from horovod_tpu.elastic.remesh import ShardedZeroState
+
+    state = ArrayState(params={"w": jnp.ones((2, 2))}, opt_state=None,
+                       epoch=1)
+    state.register_sharded("zero", ShardedZeroState(state))
+    state.opt_state = (jnp.zeros((4,)),)
+    state.save()
+    assert set(pickle.loads(state._serialize())) == {
+        "params", "opt_state", "epoch"}
+    monkeypatch.setattr(jax, "process_count", lambda: 2)
+    blob = state._serialize()
+    assert set(pickle.loads(blob)) == {"params", "epoch"}
+    state.epoch, live = 7, state.opt_state
+    assert state._deserialize(blob)
+    assert state.epoch == 1 and state.opt_state is live
+
+
 def test_elastic_run_retry_loop(hvd_module):
     """HorovodInternalError restores committed state and retries
     (reference elastic.py:151 run_fn)."""

@@ -5,6 +5,7 @@ against materialised scores, the group-limited router against ``top_k`` on
 hand-made cases, the dropless grouped product against a dense loop, and the
 shares of an expert layer against the whole layer."""
 
+import contextlib
 import dataclasses
 
 import jax
@@ -48,43 +49,96 @@ def _packed_rows(t):
     return jnp.asarray(np.stack([first, second]), jnp.int32)
 
 
-def _by_the_kernels(q, k, v, g, beta, segment_ids=None):
+@contextlib.contextmanager
+def chunks_a_step(chunks):
+    """The pair's grid steps own ``chunks`` chunks, in place of what
+    ``kda_kernels.step_plan`` chooses (None: its choice)."""
+    chosen = kda_kernels.step_plan
+    if chunks is not None:
+        kda_kernels.step_plan = lambda *a: (chunks, chosen(*a)[1])
+    try:
+        yield
+    finally:
+        kda_kernels.step_plan = chosen
+
+
+def _by_the_kernels(q, k, v, g, beta, segment_ids=None, chunks=None):
     """The kernel pair (interpreted here) on operands laid out as the
-    recurrence takes them: [B, T, H, d] is [B, T, H·d] for nothing."""
+    recurrence takes them: [B, T, H, d] is [B, T, H·d] for nothing; with
+    ``chunks``, that many chunks a grid step."""
     b, t, h, _ = q.shape
     assert kda_kernels.takes(q.shape[-1]) and kda_kernels.takes(v.shape[-1])
-    out = kda.kda(*(a.reshape(b, t, -1) for a in (q, k, v, g)), beta,
-                  segment_ids)
+    with chunks_a_step(chunks):
+        out = kda.kda(*(a.reshape(b, t, -1) for a in (q, k, v, g)), beta,
+                      segment_ids)
     return out.reshape(b, t, h, -1)
 
 
 IMPLS = {"chunked": kda.kda_chunked, "kernel": _by_the_kernels}
 impls = pytest.mark.parametrize("impl", sorted(IMPLS))
+# a row of T = 150 is three chunks: dense rows take the rule's grid step,
+# all three chunks (one step), packed rows two chunks a step, which three
+# do not fill (padded to four; boundaries inside a chunk and on the edge
+# of the step's two chunks, the state carried to the second step)
+packings = pytest.mark.parametrize("packed, chunks", [
+    pytest.param(False, None, id="dense"), pytest.param(True, 2, id="packed")])
+
+
+def _impl(impl, chunks):
+    if impl == "kernel":
+        return lambda *a, **kw: _by_the_kernels(*a, **kw, chunks=chunks)
+    return IMPLS[impl]
+
+
+def _compiled(fn, **kw):
+    """``fn`` with ``kw`` as one compiled program: op by op, the plain
+    forms and their gradients took most of these tests' time."""
+    return jax.jit(lambda *a: fn(*a, **kw))
+
+
+_RECURRENCE = {}
+
+
+def _recurrence(key, args, seg, weight):
+    """The recurrence's output and the gradients of its sum weighted by
+    ``weight``, made once for every implementation compared with it on
+    the inputs that ``key`` names."""
+    if key not in _RECURRENCE:
+        def scalar(*a):
+            return jnp.sum(kda.kda_recurrent(*a, segment_ids=seg) * weight)
+
+        with jax.default_matmul_precision("highest"):
+            _RECURRENCE[key] = (
+                _compiled(kda.kda_recurrent, segment_ids=seg)(*args),
+                _compiled(jax.grad(scalar, argnums=range(5)))(*args))
+    return _RECURRENCE[key]
 
 
 @impls
-@pytest.mark.parametrize("packed", [False, True], ids=["dense", "packed"])
+@packings
 @pytest.mark.parametrize(
     "decay", ["across", "strong_end", "weak_end", "both_ends"])
-def test_chunked_delta_rule_is_the_recurrence(decay, packed, impl):
+def test_chunked_delta_rule_is_the_recurrence(decay, packed, chunks, impl):
     """T = 150 is two chunks of 64 and a ragged third.  Outputs and every
     gradient; a gradient is held to 1e-4 of the largest gradient of the
     five (at the strong end the decays' own gradient is 1e-3 of k's, and
     float32 cancels in the chunk's running sums).  ``kernel`` is the
-    Pallas pair with its hand-written backward, two heads a grid step."""
+    Pallas pair with its hand-written backward, two heads a grid step,
+    its chunks ``packings``'."""
     args = _kda_inputs(0, decay)
     seg = _packed_rows(150) if packed else None
     weight = jax.random.normal(jax.random.PRNGKey(9), (2, 150, 2, 8))
-    chunked = IMPLS[impl]
+    chunked = _impl(impl, chunks)
 
     def scalar(fn):
         return lambda *a: jnp.sum(fn(*a, segment_ids=seg) * weight)
 
     with jax.default_matmul_precision("highest"):
-        got = chunked(*args, segment_ids=seg)
-        want = kda.kda_recurrent(*args, segment_ids=seg)
-        g_got = jax.grad(scalar(chunked), argnums=range(5))(*args)
-        g_want = jax.grad(scalar(kda.kda_recurrent), argnums=range(5))(*args)
+        got = _compiled(chunked, segment_ids=seg)(*args)
+        g_got = _compiled(jax.grad(scalar(chunked), argnums=range(5)))(
+            *args)
+    want, g_want = _recurrence(("a channel", decay, packed), args, seg,
+                               weight)
     assert got.shape == want.shape == (2, 150, 2, 8)
     assert _max_rel(got, want) <= 1e-5
     scale = max(float(jnp.max(jnp.abs(w))) for w in g_want)
@@ -101,9 +155,9 @@ def test_a_document_starts_from_an_empty_state(impl):
     q, k, v, g, beta = _kda_inputs(1, "weak_end", b=1, t=100)
     seg = jnp.asarray(np.repeat([1, 2], [37, 63])[None], jnp.int32)
     with jax.default_matmul_precision("highest"):
-        row = chunked(q, k, v, g, beta, segment_ids=seg)
-        alone = chunked(*(a[:, 37:] for a in (q, k, v, g, beta)))
-        unpacked = chunked(q, k, v, g, beta)
+        row = _compiled(chunked, segment_ids=seg)(q, k, v, g, beta)
+        alone = _compiled(chunked)(*(a[:, 37:] for a in (q, k, v, g, beta)))
+        unpacked = _compiled(chunked)(q, k, v, g, beta)
     assert _max_rel(row[:, 37:], alone) <= 1e-5
     assert _max_rel(unpacked[:, 37:], alone) > 1e-2  # the state matters
 
@@ -112,8 +166,8 @@ def test_a_document_starts_from_an_empty_state(impl):
 def test_no_exponent_passes_the_bound_at_the_strongest_decay(impl):
     q, k, v, _, beta = _kda_inputs(2, "across", t=128)
     g = jnp.full(q.shape, -4.999)
-    out, grads = jax.value_and_grad(
-        lambda g: jnp.sum(IMPLS[impl](q, k, v, g, beta)))(g)
+    out, grads = _compiled(jax.value_and_grad(
+        lambda g: jnp.sum(IMPLS[impl](q, k, v, g, beta))))(g)
     assert np.isfinite(float(out)) and bool(jnp.all(jnp.isfinite(grads)))
     with pytest.raises(ValueError, match="sub-chunks"):
         kda.kda_chunked(q, k, v, g, beta, chunk=64, sub=24)
@@ -121,7 +175,8 @@ def test_no_exponent_passes_the_bound_at_the_strongest_decay(impl):
 
 def test_kernels_at_the_chips_block_shape_are_the_chunked_form():
     """Heads of 128 (a head is one 128-lane slab), bfloat16 operands,
-    T = 192 with a document's boundary inside the second chunk: the pair
+    T = 192 with a document's boundary inside the second chunk, two
+    chunks a grid step (three chunks: padded to two steps): the pair
     against the plain chunked form at the same types, output and every
     gradient.  The two round in the same places, so they differ by the
     order of float32 sums and by where a bfloat16 rounding falls."""
@@ -130,17 +185,18 @@ def test_kernels_at_the_chips_block_shape_are_the_chunked_form():
     q, k, v = (a.astype(jnp.bfloat16) for a in (q * d ** -0.5, k, v))
     seg = jnp.asarray(np.repeat([1, 2], [100, 92])[None], jnp.int32)
     weight = jax.random.normal(jax.random.PRNGKey(9), (b, t, h, d))
+    kernels = _impl("kernel", 2)
 
     def scalar(fn):
         return lambda *a: jnp.sum(fn(*a, segment_ids=seg) * weight)
 
-    got = _by_the_kernels(q, k, v, g, beta, seg)
-    want = kda.kda_chunked(q, k, v, g, beta, seg)
+    got = _compiled(kernels)(q, k, v, g, beta, seg)
+    want = _compiled(kda.kda_chunked)(q, k, v, g, beta, seg)
     assert got.dtype == want.dtype == jnp.float32
     assert _max_rel(got, want) <= 2e-3
-    g_got = jax.grad(scalar(_by_the_kernels), argnums=range(5))(
+    g_got = _compiled(jax.grad(scalar(kernels), argnums=range(5)))(
         q, k, v, g, beta)
-    g_want = jax.grad(scalar(kda.kda_chunked), argnums=range(5))(
+    g_want = _compiled(jax.grad(scalar(kda.kda_chunked), argnums=range(5)))(
         q, k, v, g, beta)
     for name, a, w in zip("q k v g beta".split(), g_got, g_want):
         assert a.dtype == w.dtype and a.shape == w.shape, name
@@ -180,31 +236,34 @@ def _gdn_inputs(seed, b=2, t=150, h=2, dk=96, dv=192):
 
 
 @impls
-@pytest.mark.parametrize("packed", [False, True], ids=["dense", "packed"])
+@packings
 @pytest.mark.parametrize("h, dk, dv", [(2, 96, 192), (6, 32, 48)],
                          ids=["olmo_widths", "two_groups_a_slab"])
-def test_one_decay_a_head_is_the_recurrence(h, dk, dv, packed, impl):
+def test_one_decay_a_head_is_the_recurrence(h, dk, dv, packed, chunks,
+                                            impl):
     """Gated DeltaNet's case: keys of 96 and values of 192 (no whole
     128-lane slab: a block holds every head), or six heads in two groups
     of three a block; beta up to 2 (eigenvalues down to -1) and decays
     far below -5, where exp(G_r - G_i) is one exact [C, C] matrix a head
     and no sub-chunk bound applies; T = 150 with a document's boundary
     inside a chunk.  Outputs and every gradient against the recurrence,
-    which takes g [B, T, H, 1] as it is."""
+    which takes g [B, T, H, 1] as it is; the kernels' chunks as
+    ``packings`` says."""
     args = _gdn_inputs(7, h=h, dk=dk, dv=dv)
     assert float(jnp.min(args[3])) < -15 and float(jnp.max(args[4])) > 1.5
     seg = _packed_rows(150) if packed else None
     weight = jax.random.normal(jax.random.PRNGKey(9), (2, 150, h, dv))
-    chunked = IMPLS[impl]
+    chunked = _impl(impl, chunks)
 
     def scalar(fn):
         return lambda *a: jnp.sum(fn(*a, segment_ids=seg) * weight)
 
     with jax.default_matmul_precision("highest"):
-        got = chunked(*args, segment_ids=seg)
-        want = kda.kda_recurrent(*args, segment_ids=seg)
-        g_got = jax.grad(scalar(chunked), argnums=range(5))(*args)
-        g_want = jax.grad(scalar(kda.kda_recurrent), argnums=range(5))(*args)
+        got = _compiled(chunked, segment_ids=seg)(*args)
+        g_got = _compiled(jax.grad(scalar(chunked), argnums=range(5)))(
+            *args)
+    want, g_want = _recurrence(("a head", h, dk, dv, packed), args, seg,
+                               weight)
     assert got.shape == want.shape == (2, 150, h, dv)
     assert _max_rel(got, want) <= 1e-5
     scale = max(float(jnp.max(jnp.abs(w))) for w in g_want)
@@ -215,8 +274,9 @@ def test_one_decay_a_head_is_the_recurrence(h, dk, dv, packed, impl):
 
 
 def test_one_decay_a_head_by_the_kernels_is_the_chunked_form_in_bf16():
-    """bfloat16 operands at Olmo-Hybrid's widths: the pair against the
-    plain chunked form at the same types, output and every gradient (as
+    """bfloat16 operands at Olmo-Hybrid's widths: the pair, its grid step
+    the rule's (all three chunks), against the plain chunked form at the
+    same types, output and every gradient (as
     ``test_kernels_at_the_chips_block_shape_are_the_chunked_form``)."""
     b, t, h = 1, 192, 2
     q, k, v, g, beta = _gdn_inputs(8, b=b, t=t, h=h)
@@ -227,13 +287,13 @@ def test_one_decay_a_head_by_the_kernels_is_the_chunked_form_in_bf16():
     def scalar(fn):
         return lambda *a: jnp.sum(fn(*a, segment_ids=seg) * weight)
 
-    got = _by_the_kernels(q, k, v, g, beta, seg)
-    want = kda.kda_chunked(q, k, v, g, beta, seg)
+    got = _compiled(_by_the_kernels)(q, k, v, g, beta, seg)
+    want = _compiled(kda.kda_chunked)(q, k, v, g, beta, seg)
     assert got.dtype == want.dtype == jnp.float32
     assert _max_rel(got, want) <= 2e-3
-    g_got = jax.grad(scalar(_by_the_kernels), argnums=range(5))(
+    g_got = _compiled(jax.grad(scalar(_by_the_kernels), argnums=range(5)))(
         q, k, v, g, beta)
-    g_want = jax.grad(scalar(kda.kda_chunked), argnums=range(5))(
+    g_want = _compiled(jax.grad(scalar(kda.kda_chunked), argnums=range(5)))(
         q, k, v, g, beta)
     for name, a, w in zip("q k v g beta".split(), g_got, g_want):
         assert a.dtype == w.dtype and a.shape == w.shape, name
@@ -255,6 +315,54 @@ def test_slabs_are_lane_slabs_or_every_head():
     assert kda_kernels._norm_rows(20, 30 * 96, 30) == 20
 
 
+def test_a_grid_steps_chunks_and_heads_at_the_cells_shapes():
+    """The rule that picks a grid step's chunks and the heads its body
+    takes at a time: four chunks of four 128-lane heads for Ling-3.0-flash's
+    KDA and for Qwen3-Next's GDN over 16 key heads, two chunks of six of
+    Olmo-Hybrid's 30 heads of 96 and 192 (a block of every head, whose
+    every chunk more is 30 more chains to compile); a row of fewer chunks
+    takes them all, and the chunks are as few as give the same steps."""
+    plan = kda_kernels.step_plan
+    assert plan(4096, 32, 128, 128) == (4, 4)
+    assert plan(8192, 32, 128, 128, 2, True) == (4, 4)
+    assert plan(4096, 30, 96, 192, 1, True) == (2, 6)
+    assert plan(64, 32, 128, 128) == (1, 4)
+    assert plan(150, 32, 128, 128) == (3, 4)
+    assert plan(5 * 64, 32, 128, 128) == (3, 4)      # two steps of three
+    for args in [(32, 128, 128), (32, 128, 128, 2, True),
+                 (30, 96, 192, 1, True)]:
+        chunks, taken = plan(4096, *args)
+        heads, dk, dv, group, per_head = (args + (1, False))[:5]
+        slab = kda_kernels._slab(heads, dk, dv, group=group)
+        assert chunks * taken <= kda_kernels._CHAINS
+        assert chunks * slab <= kda_kernels._STEP_CHAINS
+        asked = kda_kernels._vmem_limit(chunks, taken, slab, heads, dk, dv,
+                                        group, per_head, 2)
+        assert 16 * 2 ** 20 <= asked <= kda_kernels._VMEM_MOST
+
+
+@pytest.mark.parametrize("per_head", [False, True],
+                         ids=["a_channel", "a_head"])
+def test_chunks_a_step_change_only_the_order_of_the_work(per_head):
+    """float32, T = 150 with boundaries inside a chunk and on a chunk's
+    edge: two and three chunks a grid step give what one chunk a step
+    gives, output and every gradient, to float32 rounding."""
+    inputs = _gdn_inputs if per_head else _kda_inputs
+    args = inputs(10, b=2, t=150, h=2, dk=16, dv=8) if per_head else \
+        inputs(10, "across", b=2, t=150, h=2, dk=16, dv=8)
+    seg = _packed_rows(150)
+    weight = jax.random.normal(jax.random.PRNGKey(9), (2, 150, 2, 8))
+    def run(*a, chunks):
+        out, vjp = jax.vjp(
+            lambda *a: _by_the_kernels(*a, seg, chunks=chunks), *a)
+        return (out,) + vjp(weight)
+
+    runs = [_compiled(run, chunks=chunks)(*args) for chunks in (1, 2, 3)]
+    for run in runs[1:]:
+        for a, w in zip(run, runs[0]):
+            assert _max_rel(a, w) <= 1e-6
+
+
 @pytest.mark.parametrize("h, d", [(2, 16), (5, 96)])
 def test_a_gate_a_channel_is_the_plain_norm(h, d, monkeypatch):
     """The output's RMSNorm a head times a gate a channel (Gated
@@ -274,8 +382,8 @@ def test_a_gate_a_channel_is_the_plain_norm(h, d, monkeypatch):
         return (y * weight).reshape(b, t, h * d) * gate
 
     def both(fn):
-        return (fn(x, weight, gate),) + jax.grad(
-            lambda *a: jnp.sum(fn(*a) * cotangent), argnums=range(3))(
+        return jax.jit(lambda *a: (fn(*a),) + jax.grad(
+            lambda *a: jnp.sum(fn(*a) * cotangent), argnums=range(3))(*a))(
                 x, weight, gate)
 
     got = both(lambda *a: kda.rms_gate_heads(*a, 1e-6, jnp.float32))
@@ -302,9 +410,9 @@ def test_head_norm_kernels_are_the_plain_norms(t, monkeypatch):
     cotangent = jax.random.normal(keys[3], (b, t, h * d))
 
     def both(fn, *args):
-        return (fn(*args),) + jax.grad(
+        return jax.jit(lambda *a: (fn(*a),) + jax.grad(
             lambda *a: jnp.sum(fn(*a) * cotangent),
-            argnums=range(len(args)))(*args)
+            argnums=range(len(args)))(*a))(*args)
 
     normed = lambda *a: kda.rms_gate_heads(*a, 1e-6, jnp.float32)
     got = both(normed, x, weight, gate)
@@ -386,8 +494,9 @@ def test_convolution_kernel_pair_is_the_plain_path(case, monkeypatch):
                                                       jnp.float32)
 
     def both(fn):
-        return (fn(x, taps),) + jax.grad(
-            lambda *a: jnp.sum(fn(*a) * cotangent), argnums=(0, 1))(x, taps)
+        return jax.jit(lambda *a: (fn(*a),) + jax.grad(
+            lambda *a: jnp.sum(fn(*a) * cotangent), argnums=(0, 1))(*a))(
+                x, taps)
 
     tolerance = 1e-5 if dtype == jnp.float32 else 2.0 ** -7  # dx's rounding
     for got, want in zip(both(kernels), both(plain)):
@@ -465,7 +574,7 @@ def test_latent_attention_is_the_plain_form(impl, interleave, packed):
     pos = idx - jax.lax.cummax(jnp.where(starts, idx, 0), axis=1)
     tables = transformer.rope_tables(pos, cfg.rope_dim, cfg.rope_theta)
     attn = transformer.Attention(cfg, latent=True)
-    params = attn.init(jax.random.PRNGKey(1), x, seg, tables)
+    params = jax.jit(attn.init)(jax.random.PRNGKey(1), x, seg, tables)
     assert params["params"]["kv_up"]["Dense_0"]["kernel"].shape == (
         24, 3 * 32)
     assert params["params"]["q"]["Dense_0"]["kernel"].shape == (48, 3 * 24)
@@ -478,11 +587,11 @@ def test_latent_attention_is_the_plain_form(impl, interleave, packed):
 
     weight = jax.random.normal(jax.random.PRNGKey(2), (b, t, cfg.model_dim))
     with jax.default_matmul_precision("highest"):
-        got, want = system(params, x), plain(params, x)
-        g_got = jax.grad(lambda p, x: jnp.sum(system(p, x) * weight),
-                         argnums=(0, 1))(params, x)
-        g_want = jax.grad(lambda p, x: jnp.sum(plain(p, x) * weight),
-                          argnums=(0, 1))(params, x)
+        got, want = jax.jit(system)(params, x), jax.jit(plain)(params, x)
+        g_got = jax.jit(jax.grad(lambda p, x: jnp.sum(system(p, x) * weight),
+                                 argnums=(0, 1)))(params, x)
+        g_want = jax.jit(jax.grad(lambda p, x: jnp.sum(plain(p, x) * weight),
+                                  argnums=(0, 1)))(params, x)
     assert _max_rel(got, want) <= 2e-5
     flat = jax.tree_util.tree_flatten_with_path(g_got)[0]
     for (path, a), w in zip(flat, jax.tree.leaves(g_want)):
@@ -592,12 +701,12 @@ def test_dropless_dispatch_is_the_dense_loop(load, d, f):
     assert f // expert_kernels._f_block(f) == (3 if f == 384 else 1)
     *args, local, cot = _dispatch_case(load, d=d, f=f)
     with jax.default_matmul_precision("highest"):
-        got = moe.grouped_experts(*args, local)
-        want = _dense_loop(*args, local)
-        g_got = jax.grad(lambda *a: jnp.sum(
-            moe.grouped_experts(*a, local) * cot), argnums=range(5))(*args)
-        g_want = jax.grad(lambda *a: jnp.sum(
-            _dense_loop(*a, local) * cot), argnums=range(5))(*args)
+        got = jax.jit(lambda *a: moe.grouped_experts(*a, local))(*args)
+        want = jax.jit(lambda *a: _dense_loop(*a, local))(*args)
+        g_got = jax.jit(jax.grad(lambda *a: jnp.sum(
+            moe.grouped_experts(*a, local) * cot), argnums=range(5)))(*args)
+        g_want = jax.jit(jax.grad(lambda *a: jnp.sum(
+            _dense_loop(*a, local) * cot), argnums=range(5)))(*args)
     assert _max_rel(got, want, 1.0) <= 1e-5
     for name, a, w in zip("x weights wg wu wd".split(), g_got, g_want):
         assert a.dtype == jnp.float32, name
@@ -613,8 +722,8 @@ def test_no_bfloat16_between_the_tiles_and_a_float32_parameter():
     (the float32 sums of a tile's products, never rounded on the way), for
     every expert a pair reached, and zeros for the one none did."""
     *args, local, cot = _dispatch_case("skewed", dtype=jnp.bfloat16)
-    grads = jax.grad(lambda *a: jnp.sum(
-        moe.grouped_experts(*a, local) * cot), argnums=(2, 3, 4))(*args)
+    grads = jax.jit(jax.grad(lambda *a: jnp.sum(
+        moe.grouped_experts(*a, local) * cot), argnums=(2, 3, 4)))(*args)
     for g in grads:
         assert g.dtype == jnp.float32
         rounded = g.astype(jnp.bfloat16).astype(jnp.float32)
@@ -622,8 +731,8 @@ def test_no_bfloat16_between_the_tiles_and_a_float32_parameter():
             assert float(jnp.mean(g[e] != rounded[e])) > 0.9, e
         assert not np.any(g[2])
     # and they are the float32 program's to bfloat16's rounding of the rows
-    want = jax.grad(lambda *a: jnp.sum(
-        _dense_loop(*a, local) * cot), argnums=(2, 3, 4))(
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(
+        _dense_loop(*a, local) * cot), argnums=(2, 3, 4)))(
             args[0].astype(jnp.float32), *args[1:])
     for g, w in zip(grads, want):
         assert _max_rel(g, w, 1.0) <= 3e-2
@@ -711,10 +820,10 @@ def test_a_token_may_name_one_expert_twice():
     local = local.at[:, 1].set(local[:, 0])
     assert int(jnp.sum(local[:, 0] < 4)) > 300
     with jax.default_matmul_precision("highest"):
-        got, g_got = jax.value_and_grad(lambda *a: jnp.sum(
-            moe.grouped_experts(*a, local) * cot), argnums=range(5))(*args)
-        want, g_want = jax.value_and_grad(lambda *a: jnp.sum(
-            _dense_loop(*a, local) * cot), argnums=range(5))(*args)
+        got, g_got = jax.jit(jax.value_and_grad(lambda *a: jnp.sum(
+            moe.grouped_experts(*a, local) * cot), argnums=range(5)))(*args)
+        want, g_want = jax.jit(jax.value_and_grad(lambda *a: jnp.sum(
+            _dense_loop(*a, local) * cot), argnums=range(5)))(*args)
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     for a, w in zip(g_got, g_want):
         assert _max_rel(a, w, 1.0) <= 1e-5
@@ -745,7 +854,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
 
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 40, 16))
     whole = _expert_layer((0, 64))
-    params = whole.init(jax.random.PRNGKey(1), x)["params"]
+    params = jax.jit(whole.init)(jax.random.PRNGKey(1), x)["params"]
     params["router_bias"] = 0.05 * jax.random.normal(
         jax.random.PRNGKey(2), (64,))
     model = dict(n_group=8, topk_group=4, num_experts_per_tok=8,
@@ -796,14 +905,19 @@ def test_layer_kinds_choose_mixer_and_ffn_and_the_gauges_say_so():
         n_group=2, topk_group=1, remat=True)
     model = transformer.Transformer(cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 20), 0, 64)
-    params = model.init(jax.random.PRNGKey(1), tokens)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), tokens)["params"]
     assert set(params["block_0"]) == {"kda", "ln_attn", "ln_mlp", "mlp"}
     assert set(params["block_1"]) == {"kda", "ln_attn", "ln_mlp", "moe"}
     assert set(params["block_2"]) == {"attn", "ln_attn", "ln_mlp", "moe"}
     assert params["block_1"]["moe"]["wg"].shape == (4, 48, 12)
     assert params["block_1"]["moe"]["router"].shape == (48, 8)
-    with metrics.traced_gauges() as bag:
-        logits, _ = model.apply({"params": params}, tokens)
+
+    def apply(p, t):
+        with metrics.traced_gauges() as bag:
+            logits, _ = model.apply({"params": p}, t)
+        return logits, dict(bag)
+
+    logits, bag = jax.jit(apply)(params, tokens)
     assert logits.shape == (2, 20, 64)
     for kind, count in [("kda", 2), ("mla", 1), ("full", 0), ("dense", 1),
                         ("experts", 2), ("moe", 0)]:
@@ -818,6 +932,8 @@ def test_layer_kinds_choose_mixer_and_ffn_and_the_gauges_say_so():
     # ran as the kernel pair, and their convolutions as theirs
     assert metrics.get_gauge("model.kda.kernel_layers") == 2
     assert metrics.get_gauge("model.conv.kernel_layers") == 2
+    # a row of 20 tokens is one chunk: a grid step owns it
+    assert metrics.get_gauge("model.delta_rule.chunks_per_step") == 1
     # the older way of asking for the capacity MoE still reads the same
     old = dataclasses.replace(cfg, layer_kinds=(), ffn_kinds=(), moe_every=2)
     assert [transformer.layer_kind(old, i) for i in range(3)] == [
@@ -850,15 +966,19 @@ def test_the_gauge_counts_the_layers_whose_product_took_the_kernels(
     }[family]()
     model = transformer.Transformer(cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 20), 0, 64)
-    params = model.init(jax.random.PRNGKey(1), tokens)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), tokens)["params"]
     metrics.clear_gauge("model.moe.kernel_layers")
     calls = []
     forward = moe.expert_kernels.tile_forward
     try:
         moe.expert_kernels.tile_forward = lambda *a: (
             calls.append(1), forward(*a))[1]
-        with metrics.traced_gauges():
-            logits, _ = model.apply({"params": params}, tokens)
+
+        def apply(p, t):
+            with metrics.traced_gauges():
+                return model.apply({"params": p}, t)[0]
+
+        logits = jax.jit(apply)(params, tokens)
     finally:
         moe.expert_kernels.tile_forward = forward
     assert np.isfinite(np.asarray(logits)).all()
@@ -886,14 +1006,15 @@ def test_the_delta_rule_mixer_stays_on_the_projections_layout():
     readers look, and no other kernel is there (the convolutions' pair,
     one for each of q, k and v each way, under ``kda/conv``, the output
     norm's under ``kda``); the gauges say that the layer's core and
-    convolutions took the kernels."""
+    convolutions took the kernels, and that a grid step of the core owns
+    the row's two chunks."""
     from horovod_tpu import metrics
 
     cfg = _mla_config(num_layers=1, layer_kinds=("kda",), model_dim=32,
                       num_heads=2, head_dim=128, ff_dim=32)
     model = transformer.Transformer(cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(0), (1, 128), 0, 64)
-    params = model.init(jax.random.PRNGKey(1), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), tokens)
 
     def loss(p):
         return jnp.sum(model.apply(p, tokens)[0])
@@ -901,6 +1022,8 @@ def test_the_delta_rule_mixer_stays_on_the_projections_layout():
     assert metrics.get_gauge("model.kda.kernel_layers") == 1 == \
         metrics.get_gauge("model.conv.kernel_layers") == \
         metrics.get_gauge("model.layer_kinds", {"kind": "kda"})
+    chunks = metrics.get_gauge("model.delta_rule.chunks_per_step")
+    assert chunks == kda_kernels.step_plan(128, 2, 128, 128)[0] == 2
     under_kda = [(e, scope) for e, scope in _equations(
         jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
         if "/kda/" in f"{scope}/"]
@@ -912,11 +1035,20 @@ def test_the_delta_rule_mixer_stays_on_the_projections_layout():
     # each way
     assert sorted(where) == [""] * 2 + ["/conv"] * 6 + ["/core"] * 2
     for call, scope in calls:
-        chunks = call.params["grid_mapping"].grid[1]
-        assert (chunks == 128 // kda_kernels.CHUNK) == ("kda/core" in scope)
+        if "kda/core" in scope:   # one grid step of the row's two chunks
+            mapping = call.params["grid_mapping"]
+            assert mapping.grid[1] * chunks == 128 // kda_kernels.CHUNK
+            rows = mapping.block_mappings[0].block_shape[1]
+            assert getattr(rows, "block_size", rows) == \
+                chunks * kda_kernels.CHUNK
     assert {"conv", "gate", "core"} <= {
         part for _, scope in under_kda for part in scope.split("/")}
     calls = [call for call, _ in calls]
+    # a jitted call of the core gives what its kernel gives (the kernel
+    # and every other equation inside it are checked on their own)
+    calls += [eqn for eqn, _ in under_kda if eqn.primitive.name == "jit"
+              and any(e in calls for sub in jax.core.jaxprs_in_params(
+                  eqn.params) for e, _ in _equations(sub))]
     for eqn, _ in under_kda:
         for out in eqn.outvars:
             # a matmul's gradient turns its [in, out] weight, nothing more
@@ -945,7 +1077,7 @@ def test_olmo2_blocks_and_the_gdn_mixer():
     cfg = _olmo_config()
     model = transformer.Transformer(cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 70), 0, 64)
-    params = model.init(jax.random.PRNGKey(1), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), tokens)
     p = params["params"]
     assert "wpe" not in p
     for i in range(2):
@@ -966,7 +1098,7 @@ def test_olmo2_blocks_and_the_gdn_mixer():
     def loss(p):
         return jnp.sum(model.apply(p, tokens)[0])
 
-    logits, _ = model.apply(params, tokens)
+    logits, _ = jax.jit(model.apply)(params, tokens)
     assert np.isfinite(np.asarray(logits)).all()
     assert metrics.get_gauge("model.gdn.kernel_layers") == 1 == \
         metrics.get_gauge("model.conv.kernel_layers") == \
@@ -990,25 +1122,28 @@ def test_a_width_the_chip_does_not_take_falls_back_and_the_gauge_says_so(
         monkeypatch):
     """On the chip keys of 16 and values of 24 are no multiple of 32
     lanes: the GDN core falls to the chunked form and its convolutions and
-    norms to XLA (same output), and ``model.gdn.kernel_layers`` and
-    ``model.conv.kernel_layers`` read 0."""
+    norms to XLA (same output), and ``model.gdn.kernel_layers``,
+    ``model.conv.kernel_layers`` and ``model.delta_rule.chunks_per_step``
+    read 0."""
     from horovod_tpu import metrics
     from horovod_tpu.ops import pallas_kernels
 
     cfg = _olmo_config(layer_kinds=("gdn",), num_layers=1, gdn_value_dim=24)
     model = transformer.Transformer(cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(0), (1, 40), 0, 64)
-    params = model.init(jax.random.PRNGKey(1), tokens)
-    want, _ = model.apply(params, tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), tokens)
+    want, _ = jax.jit(lambda p, t: model.apply(p, t))(params, tokens)
     assert metrics.get_gauge("model.gdn.kernel_layers") == 1
+    assert metrics.get_gauge("model.delta_rule.chunks_per_step") == 1
     called = []
     real = kda.kda_chunk_major
     monkeypatch.setattr(kda, "kda_chunk_major",
                         lambda *a: called.append(1) or real(*a))
     monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
-    got, _ = model.apply(params, tokens)
+    got, _ = jax.jit(lambda p, t: model.apply(p, t))(params, tokens)
     assert called and metrics.get_gauge("model.gdn.kernel_layers") == 0
     assert metrics.get_gauge("model.conv.kernel_layers") == 0
+    assert metrics.get_gauge("model.delta_rule.chunks_per_step") == 0
     assert _max_rel(got, want) <= 1e-4
 
 
@@ -1022,12 +1157,12 @@ def test_expert_layer_of_one_group_is_plain_top_k():
     layer = moe.ExpertFFN(
         num_experts=32, experts_held=(0, 32), hidden=12, k=8, n_group=1,
         topk_group=1, routed_scaling=2.5, dtype=jnp.float32)
-    params = layer.init(jax.random.PRNGKey(1), x)["params"]
+    params = jax.jit(layer.init)(jax.random.PRNGKey(1), x)["params"]
     params["router_bias"] = 0.05 * jax.random.normal(
         jax.random.PRNGKey(2), (32,))
     xf = x.reshape(-1, 16)
     with jax.default_matmul_precision("highest"):
-        y, load = layer.apply({"params": params}, x)
+        y, load = jax.jit(layer.apply)({"params": params}, x)
         scores = jax.nn.sigmoid(xf @ params["router"])
         _, ids = jax.lax.top_k(scores + params["router_bias"], 8)
         chosen = jnp.take_along_axis(scores, ids, axis=-1)
@@ -1108,13 +1243,13 @@ def test_a_wider_tile_is_the_same_product():
     wg, wu = (jax.random.normal(kk, (n, d, f)) / 4 for kk in keys[2:4])
     wd = jax.random.normal(keys[4], (n, f, d)) / 5
     with jax.default_matmul_precision("highest"):
-        want, g_want = jax.value_and_grad(lambda *a: jnp.sum(
-            _dense_loop(*a, local) ** 2), argnums=range(5))(
+        want, g_want = jax.jit(jax.value_and_grad(lambda *a: jnp.sum(
+            _dense_loop(*a, local) ** 2), argnums=range(5)))(
                 x, weights, wg, wu, wd)
         for tile in (256, 512):
-            got, g_got = jax.value_and_grad(lambda *a: jnp.sum(
+            got, g_got = jax.jit(jax.value_and_grad(lambda *a: jnp.sum(
                 moe.grouped_experts(*a, local, tile) ** 2),
-                argnums=range(5))(x, weights, wg, wu, wd)
+                argnums=range(5)))(x, weights, wg, wu, wd)
             assert float(got) == pytest.approx(float(want), rel=1e-5)
             for a, w in zip(g_got, g_want):
                 assert _max_rel(a, w, 1.0) <= 1e-5, tile

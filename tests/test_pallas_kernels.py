@@ -206,7 +206,7 @@ def _grads(attn, q, k, v, **kw):
     def loss(q, k, v):
         return (attn(q, k, v, **kw).astype(jnp.float32) ** 2).sum()
 
-    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
 
 
 # (b, t, h, d), causal, segment cuts, dtype, forward blocks, tolerance.
@@ -608,13 +608,13 @@ def test_flash_attention_window_and_groups(case):
     """Both kernels against ``full_attention``: the output and dq, dk, dv
     (dk and dv the sums over a group's query heads)."""
     (q, k, v, w), flash_kw, full_kw = _window_inputs(case)
-    got = flash_attention(q, k, v, True, **flash_kw)
-    want = full_attention(q, k, v, True, **full_kw)
+    got = jax.jit(lambda *a: flash_attention(*a, True, **flash_kw))(q, k, v)
+    want = jax.jit(lambda *a: full_attention(*a, True, **full_kw))(q, k, v)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
-    g_got = jax.grad(lambda *a: jnp.sum(
-        flash_attention(*a, True, **flash_kw) * w), (0, 1, 2))(q, k, v)
-    g_want = jax.grad(lambda *a: jnp.sum(
-        full_attention(*a, True, **full_kw) * w), (0, 1, 2))(q, k, v)
+    g_got = jax.jit(jax.grad(lambda *a: jnp.sum(
+        flash_attention(*a, True, **flash_kw) * w), (0, 1, 2)))(q, k, v)
+    g_want = jax.jit(jax.grad(lambda *a: jnp.sum(
+        full_attention(*a, True, **full_kw) * w), (0, 1, 2)))(q, k, v)
     for name, a, b_ in zip("dq dk dv".split(), g_got, g_want):
         assert a.shape == b_.shape, name
         np.testing.assert_allclose(a, b_, atol=5e-5, rtol=5e-5, err_msg=name)

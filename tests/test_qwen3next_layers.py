@@ -5,6 +5,7 @@ q/k norms, partial rope and a gate a channel, the softmax router beside a
 gated shared expert, and the shares of an expert layer against the whole
 layer."""
 
+import contextlib
 import dataclasses
 
 import jax
@@ -58,12 +59,33 @@ def _head_by_head(q, k, v, g, beta, segment_ids=None):
         for j in range(v.shape[2])], axis=2)
 
 
-def _by_the_kernels(q, k, v, g, beta, segment_ids=None):
+@contextlib.contextmanager
+def chunks_a_step(chunks):
+    """The pair's grid steps own ``chunks`` chunks, in place of what
+    ``kda_kernels.step_plan`` chooses (None: its choice)."""
+    chosen = kda_kernels.step_plan
+    if chunks is not None:
+        kda_kernels.step_plan = lambda *a: (chunks, chosen(*a)[1])
+    try:
+        yield
+    finally:
+        kda_kernels.step_plan = chosen
+
+
+# dense rows: the rule's grid step (all three chunks of T = 150); packed:
+# two chunks a step, which three do not fill (padded to four)
+packings = pytest.mark.parametrize("packed, chunks", [
+    pytest.param(False, None, id="dense"), pytest.param(True, 2, id="packed")])
+
+
+def _by_the_kernels(q, k, v, g, beta, segment_ids=None, chunks=None):
     """The kernel pair (interpreted here) on the projections' layout, q
-    and k at the key heads."""
+    and k at the key heads; with ``chunks``, that many chunks a grid
+    step."""
     b, t, h, _ = v.shape
-    out = kda.kda(*(a.reshape(b, t, -1) for a in (q, k, v, g)), beta,
-                  segment_ids, group=h // q.shape[2])
+    with chunks_a_step(chunks):
+        out = kda.kda(*(a.reshape(b, t, -1) for a in (q, k, v, g)), beta,
+                      segment_ids, group=h // q.shape[2])
     return out.reshape(b, t, h, -1)
 
 
@@ -71,28 +93,55 @@ IMPLS = {"recurrence": kda.kda_recurrent, "chunked": kda.kda_chunked,
          "kernel": _by_the_kernels}
 
 
+def _compiled(fn, **kw):
+    """``fn`` with ``kw`` as one compiled program: op by op, the plain
+    forms and their gradients took most of these tests' time."""
+    return jax.jit(lambda *a: fn(*a, **kw))
+
+
+_HEAD_BY_HEAD = {}
+
+
+def _each_head_alone(key, args, seg, weight):
+    """``_head_by_head``'s output and the gradients of its sum weighted by
+    ``weight``, made once for every implementation compared with it on
+    the inputs that ``key`` names."""
+    if key not in _HEAD_BY_HEAD:
+        def scalar(*a):
+            return jnp.sum(_head_by_head(*a, segment_ids=seg) * weight)
+
+        with jax.default_matmul_precision("highest"):
+            _HEAD_BY_HEAD[key] = (
+                _compiled(_head_by_head, segment_ids=seg)(*args),
+                _compiled(jax.grad(scalar, argnums=range(5)))(*args))
+    return _HEAD_BY_HEAD[key]
+
+
 @pytest.mark.parametrize("impl", sorted(IMPLS))
-@pytest.mark.parametrize("packed", [False, True], ids=["dense", "packed"])
+@packings
 @pytest.mark.parametrize("group", [2, 1], ids=["two_a_key", "one_a_key"])
-def test_shared_key_heads_are_each_value_head_alone(group, packed, impl):
+def test_shared_key_heads_are_each_value_head_alone(group, packed, chunks,
+                                                    impl):
     """T = 150 (two chunks and a ragged third), a document starting inside
     a chunk and one on its edge, beta near 0 and near 1: outputs and every
     gradient against each value head run alone with key head h // group
-    (for q and k, the sum over the value heads that read them)."""
+    (for q and k, the sum over the value heads that read them); the
+    kernels' grid step as ``packings`` says (packed: two chunks a step)."""
     args = _shared_inputs(3, group)
     assert float(jnp.min(args[4])) < 1e-3 and float(jnp.max(args[4])) > 0.999
     seg = _packed_rows(150) if packed else None
     weight = jax.random.normal(jax.random.PRNGKey(9), (2, 150, 4, 8))
     fn = IMPLS[impl]
+    if impl == "kernel":
+        fn = lambda *a, **kw: _by_the_kernels(*a, **kw, chunks=chunks)
 
     def scalar(f):
         return lambda *a: jnp.sum(f(*a, segment_ids=seg) * weight)
 
     with jax.default_matmul_precision("highest"):
-        got = fn(*args, segment_ids=seg)
-        want = _head_by_head(*args, segment_ids=seg)
-        g_got = jax.grad(scalar(fn), argnums=range(5))(*args)
-        g_want = jax.grad(scalar(_head_by_head), argnums=range(5))(*args)
+        got = _compiled(fn, segment_ids=seg)(*args)
+        g_got = _compiled(jax.grad(scalar(fn), argnums=range(5)))(*args)
+    want, g_want = _each_head_alone((group, packed), args, seg, weight)
     assert got.shape == want.shape == (2, 150, 4, 8)
     # beta near 1 and decays to -20: the chunk's inverse rounds to 1e-5
     assert _max_rel(got, want) <= 5e-5
@@ -107,20 +156,23 @@ def test_key_head_h_over_group_is_not_h_modulo_the_key_heads():
     """Reading key head h mod H_k gives another answer: the test above
     would see it."""
     q, k, v, g, beta = _shared_inputs(4, 2, b=1, t=70)
-    right = _head_by_head(q, k, v, g, beta)
-    wrong = kda.kda_recurrent(jnp.tile(q, (1, 1, 2, 1)),
-                              jnp.tile(k, (1, 1, 2, 1)), v, g, beta)
+    right = _compiled(_head_by_head)(q, k, v, g, beta)
+    wrong = _compiled(kda.kda_recurrent)(jnp.tile(q, (1, 1, 2, 1)),
+                                         jnp.tile(k, (1, 1, 2, 1)), v, g,
+                                         beta)
     assert _max_rel(wrong, right) > 1e-2
 
 
 def test_shared_keys_at_the_chips_block_shape_are_the_chunked_form():
     """Heads of 128 (a key head one 128-lane slab), 8 value heads over 4
-    key heads: two grid steps of four value heads, each reading the two
-    key heads of its block; bfloat16 operands, T = 192 with a boundary
-    inside the second chunk.  The pair against the plain chunked form at
-    the same types, output and every gradient."""
+    key heads: two slabs of four value heads, each reading the two key
+    heads of its block, and a grid step of all three chunks; bfloat16
+    operands, T = 192 with a boundary inside the second chunk.  The pair
+    against the plain chunked form at the same types, output and every
+    gradient."""
     b, t, h, d = 1, 192, 8, 128
     assert kda_kernels._slab(h, d, d, group=2) == 4
+    assert kda_kernels.step_plan(t, h, d, d, 2, True) == (3, 4)
     q, k, v, g, beta = _shared_inputs(5, 2, b=b, t=t, h=h, dk=d, dv=d)
     q, k, v = (a.astype(jnp.bfloat16) for a in (q * d ** -0.5, k, v))
     seg = jnp.asarray(np.repeat([1, 2], [100, 92])[None], jnp.int32)
@@ -129,12 +181,12 @@ def test_shared_keys_at_the_chips_block_shape_are_the_chunked_form():
     def scalar(fn):
         return lambda *a: jnp.sum(fn(*a, segment_ids=seg) * weight)
 
-    got = _by_the_kernels(q, k, v, g, beta, seg)
-    want = kda.kda_chunked(q, k, v, g, beta, seg)
+    got = _compiled(_by_the_kernels)(q, k, v, g, beta, seg)
+    want = _compiled(kda.kda_chunked)(q, k, v, g, beta, seg)
     assert _max_rel(got, want) <= 2e-3
-    g_got = jax.grad(scalar(_by_the_kernels), argnums=range(5))(
+    g_got = _compiled(jax.grad(scalar(_by_the_kernels), argnums=range(5)))(
         q, k, v, g, beta)
-    g_want = jax.grad(scalar(kda.kda_chunked), argnums=range(5))(
+    g_want = _compiled(jax.grad(scalar(kda.kda_chunked), argnums=range(5)))(
         q, k, v, g, beta)
     for name, a, w in zip("q k v g beta".split(), g_got, g_want):
         assert a.dtype == w.dtype and a.shape == w.shape, name
@@ -218,7 +270,7 @@ def test_gated_attention_is_the_dense_float32_form(packed):
     pos = transformer._positions(cfg, 2, t, seg)
     rope = transformer._rope_by_kind(cfg, pos)["full"]
     layer = transformer.Attention(cfg)
-    params = layer.init(jax.random.PRNGKey(1), x, seg, rope)["params"]
+    params = jax.jit(layer.init)(jax.random.PRNGKey(1), x, seg, rope)["params"]
     assert params["q_norm"]["scale"].shape == (32,)
     assert params["gate"]["kernel"].shape == (48, 16 * 32)
     assert not float(jnp.max(jnp.abs(params["q_norm"]["scale"])))  # zeros
@@ -227,10 +279,10 @@ def test_gated_attention_is_the_dense_float32_form(packed):
         a + 0.1 * jax.random.normal(jax.random.PRNGKey(i), a.shape)
         for i, a in enumerate(leaves)])
     with jax.default_matmul_precision("highest"):
-        got, g_got = jax.value_and_grad(lambda p: jnp.sum(
-            layer.apply({"params": p}, x, seg, rope) ** 2))(params)
-        want, g_want = jax.value_and_grad(lambda p: jnp.sum(
-            _plain_gated_attention(p, x, cfg, pos, seg) ** 2))(params)
+        got, g_got = jax.jit(jax.value_and_grad(lambda p: jnp.sum(
+            layer.apply({"params": p}, x, seg, rope) ** 2)))(params)
+        want, g_want = jax.jit(jax.value_and_grad(lambda p: jnp.sum(
+            _plain_gated_attention(p, x, cfg, pos, seg) ** 2)))(params)
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     flat = jax.tree_util.tree_flatten_with_path(g_got)[0]
     for (path, a), w in zip(flat, jax.tree.leaves(g_want)):
@@ -247,7 +299,7 @@ def test_every_norm_of_the_model_is_zero_centred():
         gdn_key_heads=2, gdn_allow_neg_eigval=False)
     model = transformer.Transformer(cfg)
     tokens = jnp.zeros((1, 16), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), tokens)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)["params"]
     for path in (("ln_f",), ("block_0", "ln_attn"), ("block_1", "ln_mlp"),
                  ("block_1", "attn", "q_norm"), ("block_1", "attn", "k_norm")):
         node = params
@@ -288,7 +340,7 @@ def test_beta_in_zero_one_without_negative_eigenvalues(monkeypatch):
     for allow in (False, True):
         model = transformer.Transformer(
             dataclasses.replace(cfg, gdn_allow_neg_eigval=allow))
-        params = model.init(jax.random.PRNGKey(1), tokens)
+        params = jax.jit(model.init)(jax.random.PRNGKey(1), tokens)
         seen.clear()
         model.apply(params, tokens)
         handed[allow] = seen["beta"][0]
@@ -309,13 +361,13 @@ def test_softmax_router_and_gated_shared_expert_are_a_dense_loop():
     selection bias is made."""
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 50, 16))
     layer = _qwen_expert_layer((0, 64), total=64)
-    params = layer.init(jax.random.PRNGKey(1), x)["params"]
+    params = jax.jit(layer.init)(jax.random.PRNGKey(1), x)["params"]
     assert "router_bias" not in params
     assert params["shared_gate"].shape == (16, 1)
     xf = x.reshape(-1, 16)
     dense = lambda p: p["Dense_0"]["kernel"]
     with jax.default_matmul_precision("highest"):
-        y, load = layer.apply({"params": params}, x)
+        y, load = jax.jit(layer.apply)({"params": params}, x)
         p = jax.nn.softmax(xf @ params["router"], -1)
         chosen, ids = jax.lax.top_k(p, 10)
         weights = chosen / chosen.sum(-1, keepdims=True)
@@ -347,7 +399,7 @@ def test_sixteen_shares_add_up_to_the_uncut_512_expert_layer():
 
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 40, 16))
     whole = _qwen_expert_layer((0, 512))
-    params = whole.init(jax.random.PRNGKey(1), x)["params"]
+    params = jax.jit(whole.init)(jax.random.PRNGKey(1), x)["params"]
     model = dict(num_experts_per_tok=10, experts_held=[0, 512])
     with jax.default_matmul_precision("highest"):
         uncut = reference._experts(params, x, model)

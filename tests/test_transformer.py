@@ -244,7 +244,8 @@ class TestSequencePacking:
     @pytest.mark.parametrize("impl", ["full", "flash"])
     def test_packed_logits_match_unpacked_per_document(self, impl):
         docs, toks, segs, model, params = self._docs_and_packed(impl=impl)
-        packed_logits, _ = model.apply(
+        apply = jax.jit(model.apply)
+        packed_logits, _ = apply(
             params, jnp.asarray(toks), jnp.asarray(segs)
         )
         packed_logits = np.asarray(packed_logits)
@@ -255,7 +256,7 @@ class TestSequencePacking:
                 for s in range(1, segs[r].max() + 1):
                     idx = np.where(segs[r] == s)[0]
                     if len(idx) == len(d) and (toks[r, idx] == d).all():
-                        solo, _ = model.apply(params, jnp.asarray(d)[None])
+                        solo, _ = apply(params, jnp.asarray(d)[None])
                         np.testing.assert_allclose(
                             packed_logits[r, idx], np.asarray(solo)[0],
                             rtol=2e-4, atol=2e-4,
@@ -299,7 +300,7 @@ class TestSequencePacking:
                 logits, jnp.asarray(toks), jnp.asarray(segs)
             )
 
-        grads = jax.grad(loss_fn)(params)
+        grads = jax.jit(jax.grad(loss_fn))(params)
         total = sum(
             float(jnp.abs(g).sum()) for g in jax.tree.leaves(grads)
         )
@@ -442,7 +443,7 @@ def test_looped_model_shares_one_set_of_weights_over_its_passes():
 
     toks = _tokens(t=16, vocab=96)
     model = _looped()
-    params = model.init(jax.random.PRNGKey(1), toks)
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), toks)
     names = set(params["params"])
     assert names == {"block_0", "block_1", "ln_f", "wte", "head",
                      "exit_gate"}  # no wpe, and no block per pass
@@ -453,19 +454,19 @@ def test_looped_model_shares_one_set_of_weights_over_its_passes():
     assert set(params["params"]["block_0"]["mlp"]) == {"wg", "wi", "wo"}
     assert all("bias" not in k for k in _tree_shapes(params)
                if "exit_gate" not in k)
-    logits, exits, aux = model.apply(params, toks)
+    logits, exits, aux = jax.jit(model.apply)(params, toks)
     assert logits.shape == (3, 2, 16, 96) and exits.shape == (3, 2, 16)
     assert metrics.get_gauge("model.layer_applications") == 6
     assert metrics.get_gauge("model.ut_steps") == 3
     # the first pass of the loop is the one-pass model with these weights
-    once, once_exits, _ = _looped(ut_steps=1).apply(params, toks)
+    once, once_exits, _ = jax.jit(_looped(ut_steps=1).apply)(params, toks)
     np.testing.assert_allclose(once[0], logits[0], atol=1e-6)
     np.testing.assert_allclose(once_exits[0], exits[0], atol=1e-6)
     assert metrics.get_gauge("model.layer_applications") == 2
     # without a gate only the last pass has a head
     gateless = {"params": {k: v for k, v in params["params"].items()
                            if k != "exit_gate"}}
-    last, _ = _looped(exit_gate=False).apply(gateless, toks)
+    last, _ = jax.jit(_looped(exit_gate=False).apply)(gateless, toks)
     np.testing.assert_allclose(last, logits[-1], atol=1e-6)
 
 
@@ -475,7 +476,7 @@ def test_what_a_rematerialised_block_keeps_changes_no_value(save):
     toks = _tokens(t=16, vocab=96)
     plain = _looped()
     remat = _looped(remat=True, remat_save=save)
-    params = plain.init(jax.random.PRNGKey(0), toks)
+    params = jax.jit(plain.init)(jax.random.PRNGKey(0), toks)
 
     def loss(model, p):
         logits, exits, _ = model.apply(p, toks)
@@ -524,11 +525,12 @@ def test_packed_rope_positions_restart_at_each_document():
     model = _looped(attn_impl="full")
     doc = _tokens(b=1, t=10, vocab=96, seed=4)
     other = _tokens(b=1, t=6, vocab=96, seed=5)
-    params = model.init(jax.random.PRNGKey(1), doc)
-    alone, _, _ = model.apply(params, doc, jnp.ones((1, 10), jnp.int32))
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), doc)
+    alone, _, _ = jax.jit(model.apply)(
+        params, doc, jnp.ones((1, 10), jnp.int32))
     row = jnp.concatenate([other, doc], axis=1)
     seg = jnp.asarray([[1] * 6 + [2] * 10])
-    packed, _, _ = model.apply(params, row, seg)
+    packed, _, _ = jax.jit(model.apply)(params, row, seg)
     np.testing.assert_allclose(packed[:, :, 6:], alone, atol=2e-5)
 
 
@@ -662,7 +664,7 @@ def test_a_head_count_and_a_rotary_rule_per_layer():
 
     model = Transformer(_mixed_config())
     toks = _tokens(t=32, vocab=64)
-    params = model.init(jax.random.PRNGKey(1), toks)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), toks)["params"]
     shapes = jax.tree.map(lambda a: a.shape, params)
     for i, heads in enumerate((4, 6, 6)):
         attn = shapes[f"block_{i}"]["attn"]
@@ -671,7 +673,7 @@ def test_a_head_count_and_a_rotary_rule_per_layer():
         assert attn["v"]["Dense_0"]["kernel"] == (32, 2 * 16)
         assert attn["proj"]["Dense_0"]["kernel"] == (heads * 16, 32)
         assert attn["gate"]["kernel"] == (32, heads)
-    logits, _ = model.apply({"params": params}, toks)
+    logits, _ = jax.jit(model.apply)({"params": params}, toks)
     assert np.isfinite(np.asarray(logits)).all()
     for kind, (count, groups, tiles) in {
             "full": (1, 2, 1), "window": (2, 3, 1)}.items():
@@ -687,8 +689,8 @@ def test_a_head_count_and_a_rotary_rule_per_layer():
         return lambda p: jnp.sum(
             net.apply({"params": p}, toks)[0] ** 2) / toks.size
 
-    got, g_got = jax.value_and_grad(loss(model))(params)
-    want, g_want = jax.value_and_grad(loss(plain))(params)
+    got, g_got = jax.jit(jax.value_and_grad(loss(model)))(params)
+    want, g_want = jax.jit(jax.value_and_grad(loss(plain)))(params)
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
         np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-3)
@@ -699,7 +701,7 @@ def test_a_head_count_and_a_rotary_rule_per_layer():
         for name, block in params.items()}
     for change, p in ((dict(window=32), params), (dict(rope_rules=()), params),
                       (dict(attn_gate=False), ungated)):
-        moved = Transformer(_mixed_config(**change)).apply(
+        moved = jax.jit(Transformer(_mixed_config(**change)).apply)(
             {"params": p}, toks)[0]
         assert float(jnp.max(jnp.abs(moved - logits))) > 1e-3, change
 
